@@ -1,7 +1,7 @@
 """ContainerProxy: per-container lifecycle state machine.
 
 Behavioral rebuild of core/invoker/.../containerpool/ContainerProxy.scala
-(:64-204 state/data taxonomy, :242-559 transitions, :675-837 run pipeline,
+(:64-204 state/data classes, :242-559 transitions, :675-837 run pipeline,
 :903-950 activation construction). The reference is an Akka FSM
 (Uninitialized -> Starting -> Running -> Ready -> Pausing -> Paused ->
 Removing); here the event loop serializes transitions so the proxy is a

@@ -12,18 +12,19 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import json
+import sys
 
 from ..core.entity import ControllerInstanceId, ExecManifest, WhiskAuthRecord
 from ..database import open_store
 from ..messaging import provider_for_bus
-from ..utils.config import config_from_env, honor_jax_platforms_env
+from ..utils.config import DeviceError, boot_jax, config_from_env
 from ..utils.logging import Logging
 from .core import Controller
 from ..utils.tasks import wait_for_shutdown
 
 
 def main() -> None:
-    honor_jax_platforms_env()
     parser = argparse.ArgumentParser(description="OpenWhisk-TPU controller")
     parser.add_argument("--bus", default="127.0.0.1:4222")
     parser.add_argument("--db", required=True)
@@ -79,6 +80,10 @@ def main() -> None:
                              "the front door answers 429 (default "
                              "CONFIG_whisk_funnel_depth or 2048)")
     args = parser.parse_args()
+    # only a device-owning process sets JAX up: a front end never touches
+    # the chip (it belongs to one process at a time)
+    if args.role != "frontend" and args.balancer == "tpu":
+        boot_jax()
 
     async def run():
         logger = Logging(level="info")
@@ -305,12 +310,15 @@ def main() -> None:
                 lb.spillover_sink = SpilloverSender(
                     provider, controller.membership,
                     metrics=logger.metrics, logger=logger)
+            device = getattr(lb, "device", None)
             print(f"controller{args.instance} up on :{args.port} "
                   f"(balancer={args.balancer}, bus={args.bus}"
                   + (f", partitions={aa_ring.n_partitions}"
                      if aa_ring is not None else "")
                   + (", role=balancer" if args.role == "balancer"
-                     else "") + ")", flush=True)
+                     else "")
+                  + (f", device={json.dumps(device)}"
+                     if device is not None else "") + ")", flush=True)
             await wait_for_shutdown()
         finally:
             if snapshotter is not None:
@@ -324,7 +332,11 @@ def main() -> None:
             if zipkin is not None:
                 await zipkin.close()
 
-    asyncio.run(run())
+    try:
+        asyncio.run(run())
+    except DeviceError as e:
+        print(f"error: {e}", file=sys.stderr)
+        raise SystemExit(1)
 
 
 if __name__ == "__main__":

@@ -1,6 +1,6 @@
 """Executable kinds of an action.
 
-Ref: common/scala/.../core/entity/Exec.scala:49-231 — the kind taxonomy:
+Ref: common/scala/.../core/entity/Exec.scala:49-231 — the family of kinds:
   CodeExec      — managed-runtime code ("python:3", "nodejs:14", ...),
                   inline string or attachment, optional `main`, binary flag
   BlackBoxExec  — arbitrary docker image (+ optional code injected at /init)

@@ -50,13 +50,14 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.placement import (PlacementState, RequestBatch, _mulmod,
                              flat_prims, release_batch_vector,
                              repair_commit_masks)
 from .sharded_state import (make_mesh, make_sharded_release,
-                            make_sharded_schedule, shard_map, shard_state)
+                            make_sharded_schedule, shard_state)
 
 #: the production mesh axis name (sharded_state's prototype used "inv")
 FLEET_AXIS = "fleet"
@@ -75,8 +76,8 @@ def make_fleet_mesh(n_shards: Optional[int] = None,
     visible device, rounded DOWN to a power of two: the balancer pads the
     invoker axis to powers of two, and `shard_state` needs the pad to
     divide evenly over the shards — a 6-device mesh would make every pow2
-    pad indivisible. Falls back to the virtual CPU devices
-    (--xla_force_host_platform_device_count) exactly like `make_mesh`."""
+    pad indivisible. More shards than the default backend has devices
+    raises (`make_mesh`)."""
     avail = len(jax.devices())
     if not n_shards:  # None OR 0 both mean "all devices, pow2-floored"
         want = _pow2_floor(max(1, avail))

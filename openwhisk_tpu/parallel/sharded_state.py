@@ -18,45 +18,23 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # newer jax exports shard_map at the top level (check_vma keyword)
-    from jax import shard_map as _shard_map_impl
-except ImportError:  # jax 0.4.x: the experimental module (check_rep keyword)
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
 
 from ..ops.placement import PlacementState, RequestBatch, _mulmod
 
 
-def shard_map(f, mesh, in_specs, out_specs, **kwargs):
-    """Compat shim over the two shard_map generations: forward the
-    skip-replication-check flag under whichever keyword this jax spells it
-    (`check_vma` at the top level, `check_rep` in the experimental module)
-    and drop it entirely if neither is understood."""
-    import inspect
-
-    params = inspect.signature(_shard_map_impl).parameters
-    check = kwargs.pop("check_vma", kwargs.pop("check_rep", None))
-    if check is not None:
-        if "check_vma" in params:
-            kwargs["check_vma"] = check
-        elif "check_rep" in params:
-            kwargs["check_rep"] = check
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kwargs)
-
-
 def make_mesh(n_devices: Optional[int] = None, axis: str = "inv") -> Mesh:
-    """Mesh over the default backend; when it has too few devices (e.g. one
-    real TPU chip) fall back to the virtual CPU devices created by
-    --xla_force_host_platform_device_count."""
-    want = n_devices or len(jax.devices())
+    """Mesh over the default backend's devices. Asking for more devices
+    than it has raises: a mesh quietly built from another backend's
+    devices (the virtual CPU ones) would serve from the host while
+    reporting a device mesh."""
     devices = jax.devices()
+    want = n_devices or len(devices)
     if len(devices) < want:
-        devices = jax.devices("cpu")
-    if len(devices) < want:
-        raise ValueError(f"need {want} devices, have {len(jax.devices())} "
-                         f"default + {len(devices)} cpu")
+        raise ValueError(
+            f"mesh needs {want} devices, the {jax.default_backend()} "
+            f"backend has {len(devices)}")
     return Mesh(devices[:want], (axis,))
 
 

@@ -7,7 +7,7 @@ import json
 import sys
 
 from . import GUEST_KEY, GUEST_UUID, make_standalone
-from ..utils.config import honor_jax_platforms_env
+from ..utils.config import DeviceError, boot_jax
 from ..utils.tasks import wait_for_shutdown
 
 
@@ -63,7 +63,6 @@ def preflight(port: int, manifest: dict = None,
 
 
 def main() -> None:
-    honor_jax_platforms_env()
     parser = argparse.ArgumentParser(description="Standalone OpenWhisk-TPU server")
     parser.add_argument("--port", type=int, default=3233)
     parser.add_argument("--db", type=str, default=None,
@@ -90,6 +89,8 @@ def main() -> None:
                         help="(tpu balancer) write-ahead placement journal "
                              "directory (snapshot + tail replay at boot)")
     args = parser.parse_args()
+    if args.balancer == "tpu":
+        boot_jax()
 
     # parse the manifest file exactly once; preflight and the server get
     # the same dict (no validate/run TOCTOU window)
@@ -128,6 +129,9 @@ def main() -> None:
             print(f"OpenWhisk-TPU standalone listening on :{args.port} "
                   f"(balancer={args.balancer})")
             print(f"  AUTH     {GUEST_UUID}:{GUEST_KEY}")
+            device = getattr(controller.load_balancer, "device", None)
+            if device is not None:
+                print(f"  DEVICE   {json.dumps(device)}")
             print(f"  API      http://127.0.0.1:{args.port}/api/v1")
             if not args.no_ui:
                 print(f"  UI       http://127.0.0.1:{args.port}/playground")
@@ -138,7 +142,11 @@ def main() -> None:
             if zipkin is not None:
                 await zipkin.close()
 
-    asyncio.run(run())
+    try:
+        asyncio.run(run())
+    except DeviceError as e:
+        print(f"error: {e}", file=sys.stderr)
+        raise SystemExit(1)
 
 
 if __name__ == "__main__":

@@ -189,15 +189,69 @@ def services(inv: dict, python: str = sys.executable,
 
 
 # ------------------------------------------------------------------ local up
+def device_owners(inv: dict) -> List[str]:
+    """The services that build a device balancer at boot (TpuBalancer
+    initializes its device state in its constructor): every controller of
+    a `balancer: tpu` topology."""
+    ctrl = inv["controllers"]
+    if ctrl.get("balancer", "tpu") != "tpu":
+        return []
+    return [f"controller{i}" for i in range(ctrl["count"])]
+
+
+def _count_chips(env: Dict[str, str]) -> int:
+    """TPU chips on this host as JAX reports them, counted by a child that
+    exits before any service starts — the launcher itself never
+    initializes JAX, or it would hold the chips its services need."""
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax; d = jax.devices(); "
+         "print(len(d) if d[0].platform == 'tpu' else 0)"],
+        capture_output=True, text=True, env=env, check=True)
+    return int(out.stdout.strip().splitlines()[-1])
+
+
+def chip_env(owners: List[str], env: Dict[str, str],
+             count_chips=_count_chips) -> Dict[str, Dict[str, str]]:
+    """Per-service environment that gives each device-owning service its
+    own chip — a chip belongs to one process at a time. More owners than
+    chips is refused here, up front, instead of surfacing as a boot error
+    in some controller's log. One owner keeps the default view of every
+    chip (the fleet mesh spans them). With JAX_PLATFORMS naming cpu the
+    topology is the CPU twin and there are no chips to hand out."""
+    from ..utils.config import cpu_requested
+    if not owners or cpu_requested(env.get("JAX_PLATFORMS")):
+        return {}
+    chips = count_chips(env)
+    if len(owners) > chips:
+        raise SystemExit(
+            f"error: {len(owners)} device-owning services "
+            f"({', '.join(owners)}) but {chips} TPU chip(s) on this host; a "
+            f"chip belongs to one process at a time. Lower "
+            f"controllers.count, use `balancer: sharding`, or export "
+            f"JAX_PLATFORMS=cpu to run the CPU twin on purpose.")
+    if len(owners) == 1:
+        return {}
+    return {name: {"TPU_VISIBLE_CHIPS": str(i),
+                   "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+                   "TPU_PROCESS_BOUNDS": "1,1,1",
+                   "TPU_MESH_CONTROLLER_ADDRESS": f"localhost:{8476 + i}",
+                   "TPU_MESH_CONTROLLER_PORT": str(8476 + i)}
+            for i, name in enumerate(owners)}
+
+
 def up(inv: dict) -> None:
     rundir = inv["rundir"]
-    os.makedirs(rundir, exist_ok=True)
     env = _env(inv)
     env.setdefault("PYTHONPATH", os.getcwd())
+    per_service = chip_env(device_owners(inv), env)
+    os.makedirs(rundir, exist_ok=True)
     started = []
     for svc in services(inv):
         log = open(os.path.join(rundir, f"{svc['name']}.log"), "ab")
-        proc = subprocess.Popen(svc["argv"], stdout=log, stderr=log, env=env,
+        proc = subprocess.Popen(svc["argv"], stdout=log, stderr=log,
+                                env={**env,
+                                     **per_service.get(svc["name"], {})},
                                 start_new_session=True)
         with open(os.path.join(rundir, f"{svc['name']}.pid"), "w") as f:
             f.write(str(proc.pid))

@@ -485,7 +485,7 @@ class TestFunnelSharedDeployment:
             import loadgen
             out = loadgen.multiproc_fixed_rate(
                 rate=48, procs=2, duration=1.0, n_invokers=2,
-                shared=True, p99_bound_ms=60000.0)
+                p99_bound_ms=60000.0)
         finally:
             sys.path.remove("tools")
         assert out["topology"] == "shared"

@@ -574,7 +574,7 @@ class TestBenchCompare:
         import tools.bench_compare as bc
         old = {"value": 100.0, "balancer": {"backend": "tpu"}}
         new = {"value": 10.0, "balancer": {"backend": "cpu"},
-               "backend": "cpu_fallback"}
+               "backend": "cpu"}
         a, b = self._rounds(tmp_path, old, new)
         sys.argv = ["bench_compare", a, b]
         assert bc.main() == 0

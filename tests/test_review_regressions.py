@@ -387,7 +387,7 @@ def test_readback_failure_reverses_device_placements():
         conc0 = np.asarray(bal.state.conc_free).copy()
 
         def poisoned(out):
-            raise RuntimeError("tunnel died mid-readback")
+            raise RuntimeError("device died mid-readback")
 
         bal._read_back = poisoned
         ident = Identity.generate("guest")

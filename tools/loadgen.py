@@ -317,6 +317,7 @@ class _BalancerTarget:
             HEALTHY, maybe_batch_publish)
         from openwhisk_tpu.core.entity import ControllerInstanceId, Identity
         from openwhisk_tpu.messaging import MemoryMessagingProvider
+        from openwhisk_tpu.utils.logging import Logging
         from openwhisk_tpu.utils.waterfall import GLOBAL_WATERFALL
 
         GLOBAL_WATERFALL.enabled = self.waterfall
@@ -325,6 +326,7 @@ class _BalancerTarget:
         # prewarm off by default: background XLA compiles are pure GIL
         # contention inside a latency-measurement window (the PR-5 lesson)
         self.bal = TpuBalancer(provider, ControllerInstanceId("0"),
+                               logger=Logging(level="warn"),
                                managed_fraction=1.0, blackbox_fraction=0.0,
                                kernel=self.kernel, prewarm=self.prewarm,
                                fleet_mesh=self.fleet_mesh)
@@ -477,9 +479,9 @@ def serve_funnel(n_invokers: int = 16, kernel: str = "auto",
     """The balancer-role process of the shared deployment: boots the TCP
     bus broker on a free port, the ONE TpuBalancer owning the (simulated)
     device fleet, the echo-invoker fleet, and a `FunnelReceiver` draining
-    `ctrlfunnel0`. Prints `FUNNELREADY:{"port": P}` once the fleet is
-    healthy, then serves until stdin closes (the parent's shutdown
-    signal) or SIGTERM."""
+    `ctrlfunnel0`. Prints `FUNNELREADY:{"port": P, "device": {...}}` once
+    the fleet is healthy, then serves until stdin closes (the parent's
+    shutdown signal) or SIGTERM."""
 
     async def go() -> None:
         import bench
@@ -492,12 +494,14 @@ def serve_funnel(n_invokers: int = 16, kernel: str = "auto",
         from openwhisk_tpu.core.entity import ControllerInstanceId
         from openwhisk_tpu.messaging.tcp import (TcpBusServer,
                                                  TcpMessagingProvider)
+        from openwhisk_tpu.utils.logging import Logging
 
         p = port or _free_port()
         server = TcpBusServer("127.0.0.1", p)
         await server.start()
         provider = TcpMessagingProvider("127.0.0.1", p)
         bal = TpuBalancer(provider, ControllerInstanceId("0"),
+                          logger=Logging(level="warn"),
                           managed_fraction=1.0, blackbox_fraction=0.0,
                           kernel=kernel, prewarm=False)
         await bal.start()
@@ -522,7 +526,8 @@ def serve_funnel(n_invokers: int = 16, kernel: str = "auto",
         recv = FunnelReceiver(provider, ControllerInstanceId("0"), bal,
                               resolver=resolver)
         recv.start()
-        print(FUNNEL_READY_PREFIX + json.dumps({"port": p}), flush=True)
+        print(FUNNEL_READY_PREFIX + json.dumps(
+            {"port": p, "device": bal.device}), flush=True)
 
         stop_ev = asyncio.Event()
         loop = asyncio.get_event_loop()
@@ -823,6 +828,9 @@ def sweep_balancer(rate0: float = 32.0, duration: float = 2.5,
                 "gc_tuned": gc_tuned,
                 "stragglers": {str(k): v for k, v
                                in target.stragglers_applied.items()},
+                # what the balancer in THIS process ran on (None in a
+                # funnel worker: the balancer process owns the device)
+                "device": getattr(target.bal, "device", None),
                 "fleet_mesh": bool(fleet_mesh),
                 "fleet_shards": getattr(target.bal, "n_shards", 1),
                 "sustained": bool(head["sustainable"]
@@ -857,79 +865,62 @@ def multiproc_fixed_rate(rate: float, procs: int, duration: float = 2.5,
                          p99_bound_ms: float = DEFAULT_P99_BOUND_MS,
                          dist: str = "poisson", n_invokers: int = 16,
                          kernel: str = "auto", seed: int = 1,
-                         fleet_mesh: bool = False, gc_tune: bool = True,
-                         waterfall: bool = True,
+                         gc_tune: bool = True,
                          host_observatory: bool = False,
-                         timeout_s: float = 600.0,
-                         shared: bool = False) -> dict:
-    """`--procs N`: the multi-process generator (ROADMAP item 1's "keep
-    the verdict honest" note). At 4k+ offered/s ONE Python generator loop
-    is itself a measurable fraction of the box: its task churn and GC
-    share the core with the system under test, and fire-lag verdicts
-    start blaming the harness. This mode forks N worker generators, each
-    firing an INDEPENDENT Poisson schedule at rate/N (independent Poisson
-    processes superpose to a Poisson process at the full rate, so the
-    offered process is exactly the single-generator one), and merges the
-    per-worker SAMPLES into the headline percentiles — merged from the
-    union, because quantiles do not compose across workers. Each worker
-    keeps its own open_loop self-check, so a failed verdict still blames
-    the specific worker (gc_pause vs event_loop_stall) instead of the
-    fleet.
-
-    Honesty note, by design (`topology: "twins"`): each worker drives
-    its OWN balancer + echo fleet twin (the in-process publish entry
-    point cannot be shared across processes). The merged number is
-    therefore N generator-honest twins at rate/N each, the right verdict
-    when the question is "is the GENERATOR the bottleneck", and says so
-    in `targets`.
-
-    `shared=True` (`topology: "shared"`, ISSUE 20) removes that caveat:
-    ONE `--serve-funnel` balancer process owns the device fleet, and the
-    N workers are front-end processes forwarding their admission waves
-    over the TCP bus funnel. The merged-schedule sustained rate is then
-    the SYSTEM-under-test headline — one shared balancer really placed
-    every row — which is exactly the number the twins mode must not
-    claim."""
+                         timeout_s: float = 600.0) -> dict:
+    """`--procs N` / `--shared`: the multi-process SHARED deployment
+    (ISSUE 20). ONE `--serve-funnel` balancer process owns the device and
+    the echo fleet; N front-end worker processes each fire an INDEPENDENT
+    Poisson schedule at rate/N (independent Poisson processes superpose to
+    a Poisson process at the full rate) and forward their admission waves
+    over the TCP bus funnel. This parent and the workers never initialize
+    JAX — a chip belongs to one process at a time, which is why there is
+    no mode that builds a balancer per worker. The per-worker SAMPLES
+    merge into the headline percentiles — from the union, because
+    quantiles do not compose across workers — and each worker keeps its
+    own open_loop self-check, so a failed verdict blames the specific
+    worker (gc_pause vs event_loop_stall) instead of the fleet. One shared
+    balancer really placed every row, so the merged-schedule sustained
+    rate IS the system-under-test number."""
     import subprocess
+    import tempfile
 
     procs = max(1, int(procs))
     share = rate / procs
-    serve = None
     funnel_endpoint = None
+    device = None
     balancer_note = None
-    serve_err = None
-    if shared:
-        import tempfile
-        serve_cmd = [sys.executable, os.path.abspath(__file__),
-                     "--serve-funnel", "--invokers", str(n_invokers),
-                     "--kernel", kernel]
-        # stderr to a spool file: the balancer process outlives the
-        # workers and logs freely — a PIPE would fill and wedge it
-        serve_err = tempfile.TemporaryFile(mode="w+")
-        serve = subprocess.Popen(serve_cmd, stdin=subprocess.PIPE,
-                                 stdout=subprocess.PIPE,
-                                 stderr=serve_err, text=True)
-        ready_by = time.monotonic() + 120.0
-        while time.monotonic() < ready_by:
-            line = serve.stdout.readline()
-            if not line:
-                break  # balancer process died before becoming ready
-            if line.startswith(FUNNEL_READY_PREFIX):
-                p = json.loads(line[len(FUNNEL_READY_PREFIX):])["port"]
-                funnel_endpoint = f"127.0.0.1:{p}"
-                break
-        if funnel_endpoint is None:
-            serve.kill()
-            try:
-                serve.wait(timeout=10.0)
-            except Exception:  # noqa: BLE001 — diagnostics only
-                pass
-            serve_err.seek(0)
-            err = serve_err.read()
-            serve_err.close()
-            raise RuntimeError(
-                "shared deployment: balancer process never became ready"
-                + (f"; stderr tail: {err[-400:]}" if err else ""))
+    serve_cmd = [sys.executable, os.path.abspath(__file__),
+                 "--serve-funnel", "--invokers", str(n_invokers),
+                 "--kernel", kernel]
+    # stderr to a spool file: the balancer process outlives the
+    # workers and logs freely — a PIPE would fill and wedge it
+    serve_err = tempfile.TemporaryFile(mode="w+")
+    serve = subprocess.Popen(serve_cmd, stdin=subprocess.PIPE,
+                             stdout=subprocess.PIPE,
+                             stderr=serve_err, text=True)
+    ready_by = time.monotonic() + 120.0
+    while time.monotonic() < ready_by:
+        line = serve.stdout.readline()
+        if not line:
+            break  # balancer process died before becoming ready
+        if line.startswith(FUNNEL_READY_PREFIX):
+            ready = json.loads(line[len(FUNNEL_READY_PREFIX):])
+            funnel_endpoint = f"127.0.0.1:{ready['port']}"
+            device = ready.get("device")
+            break
+    if funnel_endpoint is None:
+        serve.kill()
+        try:
+            serve.wait(timeout=10.0)
+        except Exception:  # noqa: BLE001 — diagnostics only
+            pass
+        serve_err.seek(0)
+        err = serve_err.read()
+        serve_err.close()
+        raise RuntimeError(
+            "shared deployment: balancer process never became ready"
+            + (f"; stderr tail: {err[-400:]}" if err else ""))
     try:
         workers = []
         for i in range(procs):
@@ -938,26 +929,19 @@ def multiproc_fixed_rate(rate: float, procs: int, duration: float = 2.5,
                    "--dist", dist, "--invokers", str(n_invokers),
                    "--kernel", kernel, "--seed",
                    str(seed + 1009 * (i + 1)),
-                   "--p99-bound-ms", str(p99_bound_ms), "--emit-samples"]
-            if shared:
-                # funnel worker: front end only — the waterfall stages
-                # live in the balancer process, and the worker ident
-                # keys its funnel origin instance
-                cmd += ["--funnel", funnel_endpoint, "--no-waterfall",
-                        "--worker-ident", str(i)]
-            if fleet_mesh:
-                cmd.append("--fleet-mesh")
+                   "--p99-bound-ms", str(p99_bound_ms), "--emit-samples",
+                   # funnel worker: front end only — the waterfall stages
+                   # live in the balancer process, and the worker ident
+                   # keys its funnel origin instance
+                   "--funnel", funnel_endpoint, "--no-waterfall",
+                   "--worker-ident", str(i)]
             if not gc_tune:
                 cmd.append("--no-gc-tune")
-            if not waterfall and not shared:
-                cmd.append("--no-waterfall")
             if host_observatory:
                 # each worker stamps its fleet identity and emits raw
                 # integer bucket counts; the parent merges them into ONE
                 # fleet snapshot (ISSUE 16) instead of N per-worker blobs
                 cmd.append("--host-observatory")
-                if not shared:
-                    cmd += ["--worker-ident", str(i)]
             workers.append(subprocess.Popen(cmd, stdout=subprocess.PIPE,
                                             stderr=subprocess.PIPE,
                                             text=True))
@@ -1002,28 +986,27 @@ def multiproc_fixed_rate(rate: float, procs: int, duration: float = 2.5,
                     tail += f"; stderr tail: {err[-400:]}"
                 stderr_tails.append(tail)
     finally:
-        if serve is not None:
-            # shutdown signal is stdin EOF; fall back to kill on a wedge
+        # shutdown signal is stdin EOF; fall back to kill on a wedge
+        try:
+            serve.stdin.close()
+        except Exception:  # noqa: BLE001
+            pass
+        try:
+            serve.wait(timeout=30.0)
+        except Exception:  # noqa: BLE001 — includes TimeoutExpired
+            serve.kill()
             try:
-                serve.stdin.close()
+                serve.wait(timeout=10.0)
             except Exception:  # noqa: BLE001
                 pass
-            try:
-                serve.wait(timeout=30.0)
-            except Exception:  # noqa: BLE001 — includes TimeoutExpired
-                serve.kill()
-                try:
-                    serve.wait(timeout=10.0)
-                except Exception:  # noqa: BLE001
-                    pass
-            err = ""
-            try:
-                serve_err.seek(0)
-                err = serve_err.read()
-                serve_err.close()
-            except Exception:  # noqa: BLE001 — diagnostics only
-                pass
-            balancer_note = err[-500:] if err else None
+        err = ""
+        try:
+            serve_err.seek(0)
+            err = serve_err.read()
+            serve_err.close()
+        except Exception:  # noqa: BLE001 — diagnostics only
+            pass
+        balancer_note = err[-500:] if err else None
     ok_rows = [r for r in rows if r and (r.get("headline") or {})]
     samples = sorted(s for r in ok_rows
                      for s in (r.get("headline") or {}).get("samples_ms")
@@ -1077,23 +1060,18 @@ def multiproc_fixed_rate(rate: float, procs: int, duration: float = 2.5,
     fleet_sustained_per_sec = round(
         sum(w.get("throughput_per_sec") or 0.0
             for w in per_worker if "error" not in w), 1)
-    if shared:
-        targets = ("one shared balancer+fleet process behind the " +
-                   str(procs) + "-worker admission funnel; the merged-"
-                   "schedule sustained rate IS the system-under-test "
-                   "headline")
-    else:
-        targets = ("one balancer+fleet twin per worker (generator-"
-                   "honesty mode; the single-process headline remains "
-                   "the system-under-test number)")
     return {
         "mode": "open_loop_multiproc",
-        "topology": "shared" if shared else "twins",
+        "topology": "shared",
         "procs": procs,
+        "device": device,
         "dist": dist,
         "offered_rate": rate,
         "per_worker_rate": share,
-        "targets": targets,
+        "targets": ("one shared balancer+fleet process behind the "
+                    f"{procs}-worker admission funnel; the merged-"
+                    "schedule sustained rate IS the system-under-test "
+                    "headline"),
         "funnel_endpoint": funnel_endpoint,
         "balancer_stderr_tail": balancer_note,
         "sustained": bool(all_sustained
@@ -1139,8 +1117,9 @@ def main() -> None:
                     help="run the SHARED deployment's balancer-role "
                          "process: TCP bus broker + the one device-"
                          "owning balancer + echo fleet + FunnelReceiver; "
-                         "prints FUNNELREADY:{\"port\": P} when healthy "
-                         "and serves until stdin closes")
+                         "prints FUNNELREADY:{\"port\": P, \"device\": "
+                         "{...}} when healthy and serves until stdin "
+                         "closes")
     ap.add_argument("--serve-port", type=int, default=None,
                     help="fixed port for --serve-funnel (default: pick "
                          "a free one)")
@@ -1148,17 +1127,18 @@ def main() -> None:
                     help="worker mode for the shared deployment: drive a "
                          "FunnelBalancer front end against the "
                          "--serve-funnel process at HOST:PORT instead of "
-                         "an in-process balancer twin")
+                         "an in-process balancer")
     ap.add_argument("--shared", action="store_true",
-                    help="with --procs N: ONE shared balancer process "
-                         "(auto-spawned --serve-funnel) fed by N funnel "
-                         "front-end workers — topology 'shared' — "
-                         "instead of N independent balancer twins")
+                    help="run the shared deployment: ONE balancer process "
+                         "(auto-spawned --serve-funnel, the only device "
+                         "owner) fed by --procs funnel front-end workers; "
+                         "implied by --procs N > 1")
     ap.add_argument("--procs", type=int, default=1,
-                    help="fork N worker generators with partitioned "
-                         "Poisson schedules at rate/N each and merge the "
-                         "per-worker sample sets (requires --rate; keeps "
-                         "generator churn off the verdict at 4k+/s)")
+                    help="fork N front-end worker generators with "
+                         "partitioned Poisson schedules at rate/N each "
+                         "against one shared balancer process and merge "
+                         "the per-worker sample sets (requires --rate; "
+                         "keeps generator churn off the verdict at 4k+/s)")
     ap.add_argument("--seed", type=int, default=1,
                     help="schedule seed (workers get derived seeds)")
     ap.add_argument("--emit-samples", action="store_true",
@@ -1187,6 +1167,12 @@ def main() -> None:
                          "(CONFIG_whisk_loadBalancer_fleetMesh semantics; "
                          "shard count = visible devices pow2-floored)")
     args = ap.parse_args()
+    multiproc = args.procs > 1 or args.shared
+    if not (multiproc or args.funnel):
+        # this process builds the balancer and owns the device; the
+        # --procs parent and the --funnel workers never initialize JAX
+        from openwhisk_tpu.utils.config import boot_jax
+        boot_jax()
     if args.serve_funnel:
         # the balancer-role process never prints a JSON verdict line —
         # its contract is the FUNNELREADY line + serving until EOF
@@ -1194,14 +1180,13 @@ def main() -> None:
                      port=args.serve_port)
         return
     try:
-        if args.procs > 1 or args.shared:
+        if multiproc:
             if args.rate is None:
                 ap.error("--procs/--shared requires --rate (fixed-rate "
                          "measurement; sweeps stay single-process)")
-            if args.stragglers:
-                ap.error("--stragglers is single-process only (each "
-                         "--procs worker drives its own fleet twin, so "
-                         "a shared straggler index is meaningless)")
+            if args.stragglers or args.fleet_mesh:
+                ap.error("--stragglers/--fleet-mesh are single-process "
+                         "only (the shared balancer process takes neither)")
             if args.trace_keep_all or args.trace_export:
                 ap.error("--trace-keep-all/--trace-export are "
                          "single-process only (each worker's store is "
@@ -1210,11 +1195,8 @@ def main() -> None:
                 rate=args.rate, procs=args.procs, duration=args.duration,
                 p99_bound_ms=args.p99_bound_ms, dist=args.dist,
                 n_invokers=args.invokers, kernel=args.kernel,
-                seed=args.seed, fleet_mesh=args.fleet_mesh,
-                gc_tune=not args.no_gc_tune,
-                waterfall=not args.no_waterfall,
-                host_observatory=args.host_observatory,
-                shared=args.shared)
+                seed=args.seed, gc_tune=not args.no_gc_tune,
+                host_observatory=args.host_observatory)
         else:
             out = sweep_balancer(rate0=args.rate0, duration=args.duration,
                                  p99_bound_ms=args.p99_bound_ms,
@@ -1234,12 +1216,13 @@ def main() -> None:
                                  trace_keep_all=args.trace_keep_all,
                                  trace_export=args.trace_export,
                                  funnel=args.funnel)
-    except Exception as e:  # noqa: BLE001 — one parseable line, always
+    except Exception as e:  # noqa: BLE001 — one parseable line, always;
+        # but a failed run is a failure: exit non-zero
         import traceback
         traceback.print_exc(file=sys.stderr)
         print(json.dumps({"mode": "open_loop", "error": f"{type(e).__name__}: {e}",
                           "sustained_activations_per_sec": None}))
-        return
+        raise SystemExit(1)
     print(json.dumps(out))
 
 
