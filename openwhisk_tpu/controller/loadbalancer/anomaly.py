@@ -59,6 +59,7 @@ from ...ops.anomaly import (S_ANOMALY_FLAG, S_ERR_SPIKE, S_EWMA_MS,
                             make_anomaly_step)
 from ...utils.config import load_config
 from ...utils.ring_buffer import SeqRingBuffer
+from ...utils.waterfall import span
 from .telemetry import FAST_WINDOW_S, SLOW_WINDOW_S
 
 #: alert FSM states (`resolved`/`cancelled` appear only as transition
@@ -433,6 +434,10 @@ class AnomalyPlane:
         tick (TPU/sharding) or the completion stream (lean, maybe_tick)."""
         if not self.enabled:
             return {}
+        with span("ow_anomaly_tick"):
+            return self._tick(metrics, now)
+
+    def _tick(self, metrics, now: Optional[float]) -> dict:
         now = time.monotonic() if now is None else now
         self._last_tick = now
         tp = self._telemetry

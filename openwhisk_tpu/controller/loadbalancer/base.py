@@ -36,7 +36,7 @@ from ...utils.tracestore import GLOBAL_TRACE_STORE
 from ...utils.tracing import trace_id_of
 from ...utils.transaction import TransactionId
 from ...utils.waterfall import (GLOBAL_WATERFALL, STAGE_COMPLETION_ACK,
-                                ActivationWaterfall)
+                                ActivationWaterfall, span)
 from ...ops.profiler import KernelProfiler
 from ...ops.telemetry import (OUTCOME_ERROR, OUTCOME_SUCCESS, OUTCOME_TIMEOUT)
 from .anomaly import AnomalyPlane
@@ -438,8 +438,10 @@ class CommonLoadBalancer(LoadBalancer):
         return promise
 
     def _timeout_fire(self, entry: ActivationEntry) -> None:
-        self.process_completion(entry.id, forced=True, is_system_error=False,
-                                invoker=entry.invoker)
+        with span("ow_timeout_fire"):
+            self.process_completion(entry.id, forced=True,
+                                    is_system_error=False,
+                                    invoker=entry.invoker)
 
     # -- HA leadership (membership.py fires this on claim/demote) ----------
     def set_leadership(self, epoch: int, active: bool) -> None:
@@ -577,7 +579,9 @@ class CommonLoadBalancer(LoadBalancer):
 
     async def send_activation_to_invoker(self, msg: ActivationMessage,
                                          invoker: InvokerInstanceId) -> None:
-        await self.producer.send(self.prepare_dispatch(msg, invoker), msg)
+        with span("ow_produce", n=1):
+            topic = self.prepare_dispatch(msg, invoker)
+        await self.producer.send(topic, msg)
 
     # -- completion-ack feed (ref :205-346) --------------------------------
     def start_ack_feed(self) -> None:
@@ -601,13 +605,20 @@ class CommonLoadBalancer(LoadBalancer):
         feed_box["feed"] = self._ack_feed
         self._ack_feed.start()
 
+    def _ack_decode_span(self, raw: bytes, **counts):
+        feed = self._ack_feed
+        return span("ow_ack_decode", bytes=len(raw),
+                    free=feed.free_capacity if feed is not None else 0,
+                    **counts)
+
     def process_acknowledgement(self, raw: bytes) -> None:
         try:
             # decode_message: the ack parse is the completion fan-in's
             # per-activation JSON cost — the host observatory counts its
             # bytes + wall time under {hop="completion_ack",deserialize}
-            ack: AcknowledgementMessage = decode_message(
-                parse_ack, raw, "completion_ack")
+            with self._ack_decode_span(raw, acks=1):
+                ack: AcknowledgementMessage = decode_message(
+                    parse_ack, raw, "completion_ack")
         except (ValueError, KeyError) as e:
             if self.logger:
                 self.logger.error(TransactionId.LOADBALANCER,
@@ -617,13 +628,20 @@ class CommonLoadBalancer(LoadBalancer):
 
     def _process_ack(self, ack: AcknowledgementMessage) -> None:
         """One decoded ack through the serial completion path."""
-        if ack.activation is not None:
-            self.process_result(ack.activation_id, ack.activation)
-        if ack.is_slot_free:
-            self.process_completion(ack.activation_id,
-                                    forced=False,
-                                    is_system_error=ack.is_system_error,
-                                    invoker=ack.invoker)
+        with span("ow_ack_process", acks=1) as sp:
+            if ack.activation is not None:
+                self.process_result(ack.activation_id, ack.activation)
+            if ack.is_slot_free:
+                self.process_completion(ack.activation_id,
+                                        forced=False,
+                                        is_system_error=ack.is_system_error,
+                                        invoker=ack.invoker)
+            sp.set_metadata(releases=self._releases_queued())
+
+    def _releases_queued(self) -> int:
+        """Slot releases waiting for a device step (the device balancer's
+        queue; the CPU balancers release in place)."""
+        return 0
 
     def process_acknowledgement_frame(self, raw: bytes) -> None:
         """A columnar ack batch frame off the completion feed: ONE decode
@@ -631,7 +649,9 @@ class CommonLoadBalancer(LoadBalancer):
         (or, with `batched_ack` off, a serial replay of each ack —
         bit-exact with N independent frames)."""
         try:
-            _kind, acks = decode_batch(raw)
+            with self._ack_decode_span(raw) as sp:
+                _kind, acks = decode_batch(raw)
+                sp.set_metadata(acks=len(acks))
         except (ValueError, KeyError, IndexError, TypeError,
                 AssertionError) as e:
             if self.logger:
@@ -661,6 +681,11 @@ class CommonLoadBalancer(LoadBalancer):
         anomaly burn-gauge tick runs once per batch instead of per ack.
         Decision-for-decision identical to process_completion; acks off
         the wire are never `forced` (only the timeout timer forces)."""
+        with span("ow_ack_process", acks=len(acks)) as sp:
+            self._process_acks(acks)
+            sp.set_metadata(releases=self._releases_queued())
+
+    def _process_acks(self, acks: List[AcknowledgementMessage]) -> None:
         wf = self.waterfall
         now_ns = time.monotonic_ns() if wf.enabled else 0
         now_mono = time.monotonic()

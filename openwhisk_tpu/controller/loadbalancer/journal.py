@@ -156,12 +156,12 @@ class PlacementJournal:
         self._flush_waiters = 0
 
     # -- write side --------------------------------------------------------
-    def append(self, rec: dict) -> None:
+    def append(self, rec: dict) -> int:
         """Buffer one record (must carry a monotonic `seq`). Cheap on the
         caller's thread: serialize + enqueue; durability happens on the
-        writer thread in fsync batches."""
+        writer thread in fsync batches. Returns the frame's bytes."""
         if self._broken:
-            return
+            return 0
         frame = _frame(json.dumps(rec, separators=(",", ":")).encode())
         with self._lock:
             self._pending.append((int(rec["seq"]), frame))
@@ -172,6 +172,7 @@ class PlacementJournal:
                     daemon=True)
                 self._writer.start()
             self._lock.notify_all()
+        return len(frame)
 
     def flush(self, timeout: float = 10.0) -> bool:
         """Block until everything appended so far is durable (shutdown,

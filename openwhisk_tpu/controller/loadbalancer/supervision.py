@@ -28,6 +28,7 @@ from ...messaging.message import PingMessage
 from ...utils.ring_buffer import RingBuffer
 from ...utils.scheduler import Scheduler
 from ...utils.transaction import TransactionId
+from ...utils.waterfall import span
 from .base import HEALTHY, OFFLINE, UNHEALTHY, UNRESPONSIVE, InvokerHealth
 
 SUCCESS = "success"
@@ -102,10 +103,12 @@ class InvokerPool:
 
         async def handle(payload: bytes):
             try:
-                ping = PingMessage.parse(payload)
-                if ping.admin:
-                    self.invoker_admin[ping.instance.instance] = ping.admin
-                self.on_ping(ping.instance)
+                with span("ow_ping"):
+                    ping = PingMessage.parse(payload)
+                    if ping.admin:
+                        self.invoker_admin[ping.instance.instance] = \
+                            ping.admin
+                    self.on_ping(ping.instance)
             except (ValueError, KeyError):
                 pass
             box["feed"].processed()
@@ -151,15 +154,17 @@ class InvokerPool:
             self._transition(st, st.classify())
 
     async def _check_offline(self) -> None:
-        now = time.monotonic()
-        for st in self.invokers.values():
-            if st.status != OFFLINE and now - st.last_ping > self.ping_timeout:
-                self._transition(st, OFFLINE)
-        if self.on_tick is not None:
-            try:
-                self.on_tick()
-            except Exception:  # noqa: BLE001 — a gauge refresh must never
-                pass           # kill the health watchdog
+        with span("ow_supervision_tick", n=len(self.invokers)):
+            now = time.monotonic()
+            for st in self.invokers.values():
+                if st.status != OFFLINE \
+                        and now - st.last_ping > self.ping_timeout:
+                    self._transition(st, OFFLINE)
+            if self.on_tick is not None:
+                try:
+                    self.on_tick()
+                except Exception:  # noqa: BLE001 — a gauge refresh must
+                    pass           # never kill the health watchdog
 
     def _maybe_recover(self, st: InvokerActorState) -> None:
         now = time.monotonic()

@@ -41,6 +41,7 @@ from ...ops.telemetry import (DEFAULT_BUCKETS, N_OUTCOMES, OUTCOME_ERROR,
                               NumpyLatencyAccumulator, bucket_bounds_ms)
 from ...utils.config import load_config
 from ...utils.eventlog import identity
+from ...utils.waterfall import span
 
 #: burn-rate windows (seconds): the classic fast/slow alerting pair
 FAST_WINDOW_S = 60.0
@@ -349,6 +350,10 @@ class TelemetryPlane:
         sharding balancers) and the completion path (maybe_tick)."""
         if not self.enabled:
             return {}
+        with span("ow_telemetry_tick"):
+            return self._tick(metrics, now)
+
+    def _tick(self, metrics, now: Optional[float]) -> dict:
         now = time.monotonic() if now is None else now
         self._last_tick = now
         if not self._snapshots or now - self._snapshots[-1][0] >= 1.0:

@@ -68,12 +68,19 @@ from ...utils.hostprof import GLOBAL_HOST_OBSERVATORY
 from ...utils.tracing import export_tracing_gauges, trace_id_of
 from ...utils.waterfall import (STAGE_BATCH_ASSEMBLE, STAGE_DEVICE_DISPATCH,
                                 STAGE_DEVICE_READBACK, STAGE_PUBLISH_ENQUEUE,
-                                STAGE_SPILL_FORWARD)
+                                STAGE_SPILL_FORWARD, span)
 from .base import (HEALTHY, CommonLoadBalancer, InvokerHealth,
                    LoadBalancerException, LoadBalancerThrottleException)
 from .flight_recorder import (BatchRecord, free_slot_histogram,
                               occupancy_json)
 from .supervision import InvokerPool
+
+
+@jax.jit
+def books_ref_copy(free_mb):
+    """`_books_ref`'s device-side copy, jitted under its own name so a
+    trace's module line reads `jit_books_ref_copy`, not `jit_copy`."""
+    return jnp.copy(free_mb)
 
 
 @dataclass(frozen=True)
@@ -716,6 +723,7 @@ class TpuBalancer(CommonLoadBalancer):
         self._rel_ring = ColumnRing(4, max_batch * 4)
         self._health_updates: Dict[int, bool] = {}
         self._flush_task: Optional[asyncio.Task] = None
+        self._gc_watched = False
         self._step_lock = asyncio.Lock()
         # device-step pipelining: dispatch is async (JAX returns future
         # arrays immediately), so batch N+1 can be dispatched while batch
@@ -754,7 +762,7 @@ class TpuBalancer(CommonLoadBalancer):
         # the supervision watchdog also drains completion events that
         # arrived while no placement traffic was flowing (idle fleets must
         # still converge their device counts)
-        self.telemetry.device_fold()
+        self._telemetry_fold()
         self.telemetry.tick(self.metrics)
         # anomaly detection rides the same tick: the device program
         # dispatches now and its scores harvest NEXT tick (no device sync
@@ -1429,8 +1437,11 @@ class TpuBalancer(CommonLoadBalancer):
         buffers: under donation the next dispatched step invalidates
         self.state, so holders crossing an await/thread boundary get their
         own device-side copy (n_pad int32s — never the [N, A] matrix)."""
-        return (jnp.copy(self.state.free_mb) if self._donate
+        return (books_ref_copy(self.state.free_mb) if self._donate
                 else self.state.free_mb)
+
+    def _releases_queued(self) -> int:
+        return len(self._releases)
 
     def _set_inflight(self, delta: int) -> None:
         """Single writer for the in-flight step counter and its gauge —
@@ -1580,6 +1591,12 @@ class TpuBalancer(CommonLoadBalancer):
 
     # -- lifecycle ---------------------------------------------------------
     async def start(self) -> None:
+        # a collection stops the loop like any other code: the `ow_gc`
+        # span (and the pause accounting) must not wait for
+        # Controller.start's install()
+        if not self._gc_watched:
+            self._gc_watched = True
+            GLOBAL_HOST_OBSERVATORY.watch_gc()
         self.start_ack_feed()
         self.supervision.start()
         # warm the first-traffic bucket signature while the fleet is still
@@ -1620,6 +1637,9 @@ class TpuBalancer(CommonLoadBalancer):
         self._releases.clear()
         self._rel_ring.clear()
         await super().close()
+        if self._gc_watched:
+            self._gc_watched = False
+            GLOBAL_HOST_OBSERVATORY.unwatch_gc()
 
     # -- publish -----------------------------------------------------------
     def _standby_error(self) -> Optional[LoadBalancerException]:
@@ -1690,51 +1710,53 @@ class TpuBalancer(CommonLoadBalancer):
             err = self._partition_refusal(msg, pid)
             if err is not None:
                 raise err
-        req, slot_key, fqn_str = self._build_row(action, msg)
-        fut: asyncio.Future = asyncio.get_event_loop().create_future()
-        # trailing fields feed the flight recorder: enqueue time (queue-age
-        # digest), the activation/action ids for the decision row, and the
-        # trace id (exemplar plumbing on OpenMetrics scrapes)
-        aid_str = msg.activation_id.asString
-        t_now = time.monotonic()
-        self._note_arrival(t_now)
-        entry = (req, fut, slot_key, t_now,
-                 aid_str, fqn_str,
-                 trace_id_of(msg.trace_context))
-        if pid is not None:
-            # active/active: the row's (partition, epoch) rides the entry
-            # so the dispatch-time journal record carries per-partition
-            # ids + the epoch each row was admitted under (a spilled row
-            # keeps its origin's stamp when that is ahead of ours)
-            entry = entry + ((pid, self._row_epoch(msg, pid)),)
-        # waterfall: the activation is now IN the balancer's queue — the
-        # delta from here to batch_assemble is pure queueing/window wait
-        self.waterfall.stamp(aid_str, STAGE_PUBLISH_ENQUEUE)
-        if self.ring_assembly:
-            # the packed-matrix column lands in the preallocated ring NOW
-            # (one C-speed write) — flush-time assembly is two slice
-            # copies. The entry is built FIRST: an exception between a
-            # ring push and its queue append would desync the two FIFOs
-            # and shift every later request's geometry.
-            self._req_ring.push(req)
-        self._pending.append(entry)
-        # inline fast path: with free pipeline capacity, dispatch NOW
-        # (synchronously — the assembly+enqueue body has no awaits) when the
-        # batch is full, or on an idle FAST device (sub-window round trips:
-        # overlap is real, so eager dispatch just cuts latency). On a
-        # device with a slow round trip they serialize, so splitting an
-        # arrival wave into eager sub-batches multiplies wire time —
-        # measured RTT (EWMA of the readback histogram) picks the policy.
-        # Under arrival PRESSURE (_coalesce_window_s > 0) eager dispatch is
-        # the tax, not the cure: per-arrival steps ship batches of 1-3 and
-        # the fixed dispatch cost dominates the loop — hold the window and
-        # let the batch fill instead.
-        if not ((len(self._pending) >= self.max_batch
-                 or (self._inflight_steps == 0
-                     and self._rtt_ewma_ms < self.RTT_FAST_MS
-                     and self._coalesce_window_s() == 0.0))
-                and self._try_flush_now()):
-            self._arm_flush(urgent=len(self._pending) >= self.max_batch)
+        # the span ends before the await: it covers this thread's time
+        with span("ow_admit", n=1):
+            req, slot_key, fqn_str = self._build_row(action, msg)
+            fut: asyncio.Future = asyncio.get_event_loop().create_future()
+            # trailing fields feed the flight recorder: enqueue time
+            # (queue-age digest), the activation/action ids for the decision
+            # row, and the trace id (exemplar plumbing on OpenMetrics scrapes)
+            aid_str = msg.activation_id.asString
+            t_now = time.monotonic()
+            self._note_arrival(t_now)
+            entry = (req, fut, slot_key, t_now,
+                     aid_str, fqn_str,
+                     trace_id_of(msg.trace_context))
+            if pid is not None:
+                # active/active: the row's (partition, epoch) rides the entry
+                # so the dispatch-time journal record carries per-partition
+                # ids + the epoch each row was admitted under (a spilled row
+                # keeps its origin's stamp when that is ahead of ours)
+                entry = entry + ((pid, self._row_epoch(msg, pid)),)
+            # waterfall: the activation is now IN the balancer's queue — the
+            # delta from here to batch_assemble is pure queueing/window wait
+            self.waterfall.stamp(aid_str, STAGE_PUBLISH_ENQUEUE)
+            if self.ring_assembly:
+                # the packed-matrix column lands in the preallocated ring NOW
+                # (one C-speed write) — flush-time assembly is two slice
+                # copies. The entry is built FIRST: an exception between a
+                # ring push and its queue append would desync the two FIFOs
+                # and shift every later request's geometry.
+                self._req_ring.push(req)
+            self._pending.append(entry)
+            # inline fast path: with free pipeline capacity, dispatch NOW
+            # (synchronously — the assembly+enqueue body has no awaits) when
+            # the batch is full, or on an idle FAST device (sub-window round
+            # trips: overlap is real, so eager dispatch just cuts latency).
+            # On a device with a slow round trip they serialize, so splitting
+            # an arrival wave into eager sub-batches multiplies wire time —
+            # measured RTT (EWMA of the readback histogram) picks the policy.
+            # Under arrival PRESSURE (_coalesce_window_s > 0) eager dispatch
+            # is the tax, not the cure: per-arrival steps ship batches of 1-3
+            # and the fixed dispatch cost dominates the loop — hold the
+            # window and let the batch fill instead.
+            if not ((len(self._pending) >= self.max_batch
+                     or (self._inflight_steps == 0
+                         and self._rtt_ewma_ms < self.RTT_FAST_MS
+                         and self._coalesce_window_s() == 0.0))
+                    and self._try_flush_now()):
+                self._arm_flush(urgent=len(self._pending) >= self.max_batch)
         try:
             inv_idx, forced = await fut
         except asyncio.CancelledError:
@@ -1802,6 +1824,12 @@ class TpuBalancer(CommonLoadBalancer):
         per-pair default."""
         if not self.batch_publish:
             return super().publish_many(pairs)
+        with span("ow_admit", n=len(pairs)):
+            return self._admit_many(pairs)
+
+    def _admit_many(self, pairs) -> List[asyncio.Future]:
+        """`publish_many`'s synchronous body: row build, slot allocation,
+        ring push, the shared flush decision."""
         loop = asyncio.get_event_loop()
         outs: List[asyncio.Future] = [loop.create_future() for _ in pairs]
         err = self._standby_error()
@@ -1922,6 +1950,11 @@ class TpuBalancer(CommonLoadBalancer):
         serial path's raised send errors). All rows of a readback wave run
         their callbacks in one sweep, so their sends coalesce into the
         same bus frames the serial path's fan-out produced."""
+        with span("ow_placed"):
+            self._place_row(fut, req, slot_key, aid, msg, action, out)
+
+    def _place_row(self, fut: asyncio.Future, req: tuple, slot_key: str,
+                   aid: str, msg, action, out: asyncio.Future) -> None:
         wf = self.waterfall
         try:
             if fut.cancelled():
@@ -2241,6 +2274,23 @@ class TpuBalancer(CommonLoadBalancer):
         return (self.journal is not None and not self._journal_mute
                 and not self.ha_standby)
 
+    def _journal_mesh_header(self) -> None:
+        """Topology header: ONE `mesh` record ahead of this writer's
+        first append (rides alongside `reg`/`cluster`), so replay can
+        refuse a different device count with a logged reason."""
+        if self.mesh is not None and not self._journal_mesh_stamped:
+            self._journal_mesh_stamped = True
+            from ...parallel.fleet_mesh import mesh_topology
+            self._journal_append({"t": "mesh", **mesh_topology(self.mesh)})
+
+    def _journal_next_seq(self) -> int:
+        """The seq the next `_journal_append` will stamp (0: journal off),
+        for spans that open before their record is written."""
+        if not self._journal_live():
+            return 0
+        self._journal_mesh_header()
+        return self._journal_seq + 1
+
     def _journal_append(self, rec: dict) -> int:
         """Stamp the next seq (and fencing epoch) onto `rec` and append.
         Returns the seq (0 when journaling is off). Called on the event
@@ -2249,20 +2299,15 @@ class TpuBalancer(CommonLoadBalancer):
         snapshot's `journal_seq` is exactly consistent with its books."""
         if not self._journal_live():
             return 0
-        if (self.mesh is not None and not self._journal_mesh_stamped
-                and rec.get("t") != "mesh"):
-            # topology header: ONE `mesh` record ahead of this writer's
-            # first append (rides alongside `reg`/`cluster`), so replay
-            # can refuse a different device count with a logged reason
-            self._journal_mesh_stamped = True
-            from ...parallel.fleet_mesh import mesh_topology
-            self._journal_append({"t": "mesh", **mesh_topology(self.mesh)})
+        if rec.get("t") != "mesh":
+            self._journal_mesh_header()
         self._journal_seq += 1
         rec["seq"] = self._journal_seq
         if self.fence_epoch is not None:
             rec["epoch"] = self.fence_epoch
         try:
-            self.journal.append(rec)
+            with span("ow_journal") as sp:
+                sp.set_metadata(bytes=self.journal.append(rec) or 0)
         except Exception as e:  # noqa: BLE001 — journaling degrades, the
             # placement path never dies for the flight data recorder
             if self.logger:
@@ -2904,22 +2949,10 @@ class TpuBalancer(CommonLoadBalancer):
             # fused path) and health (exact-size; dict keys are unique)
             folded = bool(self._releases)
             try:
-                rel_np = ups = None
-                if self._releases:
-                    rel_np = self._release_packed()
-                    self.state = self._release_packed_fn(self.state, rel_np)
-                if self._health_updates:
-                    ups, self._health_updates = self._health_updates, {}
-                    self.state = set_health(self.state, list(ups.keys()),
-                                            list(ups.values()))
-                if (rel_np is not None or ups) and self._journal_live():
-                    fold = {"t": "fold"}
-                    if rel_np is not None:
-                        fold["rel"] = encode_array(rel_np)
-                    if ups:
-                        fold["health"] = [[int(k), bool(v)]
-                                          for k, v in ups.items()]
-                    self._journal_append(fold)
+                if folded or self._health_updates:
+                    with span("ow_fold", rows=min(len(self._releases),
+                                                  self.max_batch)):
+                        self._fold_now()
             except Exception as e:  # noqa: BLE001 — a failed donated fold
                 # may have CONSUMED self.state: without a rebuild every
                 # later idle fold dies on the deleted buffer and a
@@ -2936,7 +2969,7 @@ class TpuBalancer(CommonLoadBalancer):
                 # cache on — refresh it off-loop so idle fleets converge
                 self._refresh_books_async()
             try:
-                self.telemetry.device_fold()
+                self._telemetry_fold()
             except Exception as e:  # noqa: BLE001 — a telemetry failure
                 # must not kill the flush task (stranding queued releases)
                 if self.logger:
@@ -2954,24 +2987,34 @@ class TpuBalancer(CommonLoadBalancer):
         self._set_inflight(1)
         self._dispatch_batch()
 
-    def _dispatch_batch(self) -> None:
-        batch, self._pending = self._pending[: self.max_batch], \
-            self._pending[self.max_batch:]
-        t0 = time.monotonic()
-        b = len(batch)
-        # ONE shared power-of-two bucket for the release AND request axes:
-        # R and B are independent static dims of the fused program, so
-        # their cross product is the jit cache-key space — log2 x log2
-        # combos, most compiled mid-run the first time an arrival pattern
-        # surfaces them (the batch-shaped ack path made this chronic:
-        # measured as repeated ~400 ms first-sight compile stalls).
-        # Padding both axes to max(R_bucket, B_bucket) collapses the key
-        # space to log2(max_batch) shapes, which one warmup pass covers;
-        # the cost is a few masked zero rows in a kernel that is already
-        # shape-padded.
-        n_rel = min(len(self._releases), self.max_batch)
-        bp = max(self._bucket(b, self.max_batch),
-                 self._bucket(n_rel, self.max_batch) if n_rel else 8)
+    def _fold_now(self) -> None:
+        """The release-only / health fold and its journal record (one
+        `ow_fold` span to one `fold` record)."""
+        rel_np = ups = None
+        if self._releases:
+            rel_np = self._release_packed()
+            self.state = self._release_packed_fn(self.state, rel_np)
+        if self._health_updates:
+            ups, self._health_updates = self._health_updates, {}
+            self.state = set_health(self.state, list(ups.keys()),
+                                    list(ups.values()))
+        if self._journal_live():
+            fold = {"t": "fold"}
+            if rel_np is not None:
+                fold["rel"] = encode_array(rel_np)
+            if ups:
+                fold["health"] = [[int(k), bool(v)]
+                                  for k, v in ups.items()]
+            self._journal_append(fold)
+
+    def _telemetry_fold(self, seq: int = 0) -> None:
+        with span("ow_telemetry_fold", seq=seq):
+            self.telemetry.device_fold()
+
+    def _assemble_batch(self, batch, b: int, bp: int, t0: float):
+        """Host packing of one micro-batch (the `ow_assemble` span): the
+        request matrix, the flight-recorder digest, the drained releases
+        and health flips, and the ONE buffer the step takes."""
         # ONE packed request matrix: row layout must match
         # make_fused_step_packed (offset..rand, valid); request tuples are
         # already in row order, so one C-speed np.array call fills it.
@@ -3010,8 +3053,7 @@ class TpuBalancer(CommonLoadBalancer):
         # waterfall: assemble/dispatch/readback are BATCH events — one
         # shared timestamp per edge for every activation in the batch (the
         # aid list is built once, only when the plane is live)
-        wf = self.waterfall
-        wf_aids = [e[4] for e in batch] if wf.enabled else None
+        wf_aids = [e[4] for e in batch] if self.waterfall.enabled else None
         rel_np = self._release_packed(pad_to=bp)
         health_np = self._health_packed()
         # releases + health flips + schedule: ONE device program over ONE
@@ -3022,7 +3064,37 @@ class TpuBalancer(CommonLoadBalancer):
         # cancellation window can orphan the popped batch.
         buf = np.concatenate([rel_np.ravel(), health_np.ravel(),
                               req_np.ravel()])
-        t_assembled = time.monotonic()
+        return req_np, rec, wf_aids, rel_np, health_np, buf
+
+    def _dispatch_batch(self) -> None:
+        batch, self._pending = self._pending[: self.max_batch], \
+            self._pending[self.max_batch:]
+        t0 = time.monotonic()
+        b = len(batch)
+        # ONE shared power-of-two bucket for the release AND request axes:
+        # R and B are independent static dims of the fused program, so
+        # their cross product is the jit cache-key space — log2 x log2
+        # combos, most compiled mid-run the first time an arrival pattern
+        # surfaces them (the batch-shaped ack path made this chronic:
+        # measured as repeated ~400 ms first-sight compile stalls).
+        # Padding both axes to max(R_bucket, B_bucket) collapses the key
+        # space to log2(max_batch) shapes, which one warmup pass covers;
+        # the cost is a few masked zero rows in a kernel that is already
+        # shape-padded.
+        n_rel = min(len(self._releases), self.max_batch)
+        bp = max(self._bucket(b, self.max_batch),
+                 self._bucket(n_rel, self.max_batch) if n_rel else 8)
+        # the id the spans of this micro-batch share: its batch record's
+        # journal seq or, journal off, its books seq
+        books_seq = self._next_books_seq()
+        seq = self._journal_next_seq() or books_seq
+        with span("ow_assemble", seq=seq, b=b, bp=bp, n_rel=n_rel,
+                  pending=len(self._pending), inflight=self._inflight_steps):
+            req_np, rec, wf_aids, rel_np, health_np, buf = \
+                self._assemble_batch(batch, b, bp, t0)
+            t_assembled = time.monotonic()
+        rate_on = self.rate_limit_per_minute is not None
+        wf = self.waterfall
         # shadow counterfactual (quality plane, every K batches): a
         # decision-only pass over the SAME packed buffer, enqueued BEFORE
         # the (possibly donating) production step so it reads the
@@ -3038,15 +3110,16 @@ class TpuBalancer(CommonLoadBalancer):
             k = self.quality.shadow_every_n
             if k > 0 and self._quality_batches % k == 0:
                 try:
-                    if rate_on:
-                        shadow_out = self._shadow_fn(
-                            (self.state, self._bucket_state), buf,
-                            self._shadow_penalty, now32,
-                            rel_np.shape[1], health_np.shape[1], bp)
-                    else:
-                        shadow_out = self._shadow_fn(
-                            self.state, buf, self._shadow_penalty,
-                            rel_np.shape[1], health_np.shape[1], bp)
+                    with span("ow_shadow", seq=seq):
+                        if rate_on:
+                            shadow_out = self._shadow_fn(
+                                (self.state, self._bucket_state), buf,
+                                self._shadow_penalty, now32,
+                                rel_np.shape[1], health_np.shape[1], bp)
+                        else:
+                            shadow_out = self._shadow_fn(
+                                self.state, buf, self._shadow_penalty,
+                                rel_np.shape[1], health_np.shape[1], bp)
                 except Exception as e:  # noqa: BLE001 — the shadow is
                     # observability: it must never take placement down
                     shadow_out = None
@@ -3058,13 +3131,16 @@ class TpuBalancer(CommonLoadBalancer):
         # dispatch-stage outlier in the waterfall into an attributed cause
         GLOBAL_HOST_OBSERVATORY.begin_dispatch()
         try:
-            if rate_on:
-                (self.state, self._bucket_state), out = self._packed_fn(
-                    (self.state, self._bucket_state), buf, now32,
-                    rel_np.shape[1], health_np.shape[1], bp)
-            else:
-                self.state, out = self._packed_fn(
-                    self.state, buf, rel_np.shape[1], health_np.shape[1], bp)
+            # JAX's own `PjitFunction(packed)` span nests in this one
+            with span("ow_step", seq=seq):
+                if rate_on:
+                    (self.state, self._bucket_state), out = self._packed_fn(
+                        (self.state, self._bucket_state), buf, now32,
+                        rel_np.shape[1], health_np.shape[1], bp)
+                else:
+                    self.state, out = self._packed_fn(
+                        self.state, buf, rel_np.shape[1],
+                        health_np.shape[1], bp)
         except Exception as e:  # noqa: BLE001 — a failed dispatch must not
             # leak the permit, the host-side conc slots, or strand the
             # publishers (device capacity from the drained releases is
@@ -3092,10 +3168,11 @@ class TpuBalancer(CommonLoadBalancer):
         q_summary = None
         if self.quality.enabled:
             try:
-                q_summary = self.quality.device_step(
-                    self.state.free_mb, self.state.conc_free,
-                    self.state.health, self._quality_ewma,
-                    self._quality_caps, req_np[:9], out, shadow_out)
+                with span("ow_quality", seq=seq):
+                    q_summary = self.quality.device_step(
+                        self.state.free_mb, self.state.conc_free,
+                        self.state.health, self._quality_ewma,
+                        self._quality_caps, req_np[:9], out, shadow_out)
             except Exception as e:  # noqa: BLE001 — scoring must never
                 # take the placement path down with it
                 if self.logger:
@@ -3110,7 +3187,8 @@ class TpuBalancer(CommonLoadBalancer):
             jrec = {
                 "t": "batch", "R": int(rel_np.shape[1]),
                 "H": int(health_np.shape[1]), "B": bp,
-                "rows": rows, "b": b, "buf": encode_array(buf),
+                "rows": int(req_np.shape[0]), "b": b,
+                "buf": encode_array(buf),
                 "aids": [e[4] for e in batch]}
             if self.partition_ring is not None:
                 # active/active: the record carries its rows' ring
@@ -3143,7 +3221,7 @@ class TpuBalancer(CommonLoadBalancer):
         # for a near-empty fold on every micro-batch.
         try:
             if self.telemetry.pending >= self.TELEMETRY_FOLD_MIN:
-                self.telemetry.device_fold()
+                self._telemetry_fold(seq)
         except Exception as e:  # noqa: BLE001 — telemetry must never take
             # the placement path down with it
             if self.logger:
@@ -3157,10 +3235,6 @@ class TpuBalancer(CommonLoadBalancer):
                           int(t_assembled * 1e9))
             wf.stamp_many(wf_aids, STAGE_DEVICE_DISPATCH,
                           int(t_dispatched * 1e9))
-        self.metrics.histogram("loadbalancer_tpu_assembly_ms",
-                               (t_assembled - t0) * 1e3)
-        self.metrics.histogram("loadbalancer_tpu_dispatch_ms",
-                               (t_dispatched - t_assembled) * 1e3)
         self.metrics.histogram("loadbalancer_tpu_batch_size", b)
         self.profiler.observe_phase("assembly", (t_assembled - t0) * 1e3)
         self.profiler.observe_phase("dispatch",
@@ -3178,10 +3252,11 @@ class TpuBalancer(CommonLoadBalancer):
         # under donation the NEXT dispatched step consumes self.state's
         # buffers while this step's readback is still crossing the wire —
         # _books_ref hands the worker thread its own device-side copy
-        books = self._books_ref()
+        with span("ow_books_ref", seq=seq):
+            books = self._books_ref()
         task = asyncio.get_event_loop().create_task(
             self._readback_step(batch, b, out, t0, req_np, rec, books,
-                                self._next_books_seq(), jseq, q_summary))
+                                books_seq, jseq, q_summary, seq))
         self._readbacks.add(task)
         task.add_done_callback(self._readbacks.discard)
 
@@ -3210,10 +3285,15 @@ class TpuBalancer(CommonLoadBalancer):
 
     async def _readback_step(self, batch, b, out, t0, req_np, rec=None,
                              books_free=None, books_seq=0,
-                             journal_seq=0, q_summary=None) -> None:
+                             journal_seq=0, q_summary=None,
+                             seq=0) -> None:
         # the step-duration stamp is taken ON the worker thread so the
         # metric measures device step + readback, not loop re-scheduling
         def _read():
+            with span("ow_readback_wait", seq=seq):
+                return _read_spanned()
+
+        def _read_spanned():
             t_r0 = time.monotonic()
             arrs = self._read_back(out)
             t_r1 = time.monotonic()
@@ -3269,17 +3349,19 @@ class TpuBalancer(CommonLoadBalancer):
         try:
             (chosen_np, forced_np, throttled_np, rounds), t_done, books_np = \
                 await asyncio.to_thread(_read)
-            self._install_books(books_np, books_seq)
-            if journal_seq and self._journal_live():
-                # the committed decision vector, keyed to the dispatch-time
-                # batch record: replay asserts parity against it, and the
-                # throttled bits tell replay which requests the device rate
-                # admission rejected (they consumed no capacity)
-                enc = (((chosen_np[:b].astype(np.int64) + 1) << 2)
-                       | (throttled_np[:b].astype(np.int64) << 1)
-                       | forced_np[:b].astype(np.int64))
-                self._journal_append({"t": "ack", "for": journal_seq,
-                                      "out": [int(v) for v in enc]})
+            with span("ow_readback_resume", seq=seq):
+                self._install_books(books_np, books_seq)
+                if journal_seq and self._journal_live():
+                    # the committed decision vector, keyed to the
+                    # dispatch-time batch record: replay asserts parity
+                    # against it, and the throttled bits tell replay which
+                    # requests the device rate admission rejected (they
+                    # consumed no capacity)
+                    enc = (((chosen_np[:b].astype(np.int64) + 1) << 2)
+                           | (throttled_np[:b].astype(np.int64) << 1)
+                           | forced_np[:b].astype(np.int64))
+                    self._journal_append({"t": "ack", "for": journal_seq,
+                                          "out": [int(v) for v in enc]})
         except Exception as e:  # noqa: BLE001 — publishers must not hang,
             # and their host-side conc slots must not leak. The DISPATCH
             # succeeded (only the host conversion failed), so the device
@@ -3295,14 +3377,16 @@ class TpuBalancer(CommonLoadBalancer):
                     jnp.asarray(req_np[5]), jnp.asarray(req_np[4]),
                     jnp.asarray(req_np[6]),
                     jnp.asarray(req_np[8]) * (chosen >= 0).astype(jnp.int32)])
-                self.state = self._release_packed_fn(self.state, rel)
-                if journal_seq and self._journal_live():
-                    # the dispatch-time batch record stands; journal its
-                    # on-device reversal so replay undoes it identically
-                    # (np.asarray syncs, but this is already an error path)
-                    self._journal_append({"t": "fold",
-                                          "rel": encode_array(
-                                              np.asarray(rel))})
+                with span("ow_fold", rows=b):
+                    self.state = self._release_packed_fn(self.state, rel)
+                    if journal_seq and self._journal_live():
+                        # the dispatch-time batch record stands; journal
+                        # its on-device reversal so replay undoes it
+                        # identically (np.asarray syncs, but this is
+                        # already an error path)
+                        self._journal_append({"t": "fold",
+                                              "rel": encode_array(
+                                                  np.asarray(rel))})
             except Exception:  # noqa: BLE001 — device genuinely dead: keep
                 # the host refcounts PINNED so the slot indices cannot be
                 # reassigned to a different action and inherit the phantom
@@ -3327,41 +3411,50 @@ class TpuBalancer(CommonLoadBalancer):
                                   f"(compensated={compensated})",
                                   "TpuBalancer")
             return
-        self._set_inflight(-1)
-        self._capacity_free.set()
-        wf = self.waterfall
-        if wf.enabled:
-            wf.stamp_many([e[4] for e in batch], STAGE_DEVICE_READBACK,
-                          int(t_done * 1e9))
-        dt_ms = (t_done - t0) * 1e3
-        self.metrics.histogram("loadbalancer_tpu_schedule_batch_ms", dt_ms)
-        self.metrics.counter("loadbalancer_tpu_scheduled", b)
-        if self.placement_kernel_resolved == "repair" and rounds > 0:
-            # how many speculate-commit rounds the batch actually cost —
-            # the knob's health signal (repair pays off iff this stays near
-            # 1; a fleet-sized spike means pathological intra-batch
-            # contention and the scan kernel would serve better). Batches
-            # the "auto" hybrid routed to the scan program report 0 and
-            # stay out of the histogram.
-            self.metrics.histogram("loadbalancer_repair_rounds", rounds)
-            if rec is not None:
-                rec.digest["repair_rounds"] = rounds
-        t_f0 = time.monotonic()
-        for (req, fut, slot_key, _t, aid, *_), inv_idx, f, thr in zip(
-                batch, chosen_np, forced_np, throttled_np):
-            if fut.cancelled():
-                # abandoned publisher (client disconnected while awaiting
-                # placement): nobody will ever ack this activation, so give
-                # back what the schedule fold reserved for it (throttled
-                # requests carry chosen == -1: nothing was reserved) —
-                # and drop its waterfall vector, which will never finish
-                self._abandon_placement(int(inv_idx), req, slot_key)
-                wf.discard(aid)
-            elif not fut.done():
-                fut.set_result((-2 if thr else int(inv_idx), bool(f)))
-        t_f1 = time.monotonic()
-        fanout_ms = (t_f1 - t_f0) * 1e3
-        self.metrics.histogram("loadbalancer_tpu_fanout_ms", fanout_ms)
+        with span("ow_fanout", seq=seq, b=b):
+            self._set_inflight(-1)
+            self._capacity_free.set()
+            wf = self.waterfall
+            if wf.enabled:
+                wf.stamp_many([e[4] for e in batch], STAGE_DEVICE_READBACK,
+                              int(t_done * 1e9))
+            dt_ms = (t_done - t0) * 1e3
+            self.metrics.histogram("loadbalancer_tpu_schedule_batch_ms",
+                                   dt_ms)
+            self.metrics.counter("loadbalancer_tpu_scheduled", b)
+            if self.placement_kernel_resolved == "repair" and rounds > 0:
+                # how many speculate-commit rounds the batch actually cost
+                # — the knob's health signal (repair pays off iff this
+                # stays near 1; a fleet-sized spike means pathological
+                # intra-batch contention and the scan kernel would serve
+                # better). Batches the "auto" hybrid routed to the scan
+                # program report 0 and stay out of the histogram.
+                self.metrics.histogram("loadbalancer_repair_rounds", rounds)
+                if rec is not None:
+                    rec.digest["repair_rounds"] = rounds
+            t_f0 = time.monotonic()
+            for (req, fut, slot_key, _t, aid, *_), inv_idx, f, thr in zip(
+                    batch, chosen_np, forced_np, throttled_np):
+                if fut.cancelled():
+                    # abandoned publisher (client disconnected while
+                    # awaiting placement): nobody will ever ack this
+                    # activation, so give back what the schedule fold
+                    # reserved for it (throttled requests carry chosen ==
+                    # -1: nothing was reserved) — and drop its waterfall
+                    # vector, which will never finish
+                    self._abandon_placement(int(inv_idx), req, slot_key)
+                    wf.discard(aid)
+                elif not fut.done():
+                    fut.set_result((-2 if thr else int(inv_idx), bool(f)))
+            fanout_ms = (time.monotonic() - t_f0) * 1e3
+        with span("ow_record", seq=seq):
+            self._record_step(rec, batch, chosen_np, forced_np,
+                              throttled_np, fanout_ms, dt_ms, b)
+
+    def _record_step(self, rec, batch, chosen_np, forced_np, throttled_np,
+                     fanout_ms: float, dt_ms: float, b: int) -> None:
+        """What the profiler, the flight recorder and the trace store take
+        from one read-back micro-batch (the `ow_record` span)."""
         prof = self.profiler
         prof.observe_phase("fanout", fanout_ms)
         prof.observe_phase("total", dt_ms,

@@ -35,6 +35,7 @@ from typing import Optional
 
 from ..utils.config import load_config
 from ..utils.microbatch import MicroCoalescer
+from ..utils.waterfall import span
 from .connector import MessageProducer, encode_message
 
 #: process-wide coalescing health counters, exported as gauges by the
@@ -163,6 +164,15 @@ class CoalescingProducer(MessageProducer):
         if not self.batch_wire:
             await self.inner.send_many([item for (item, _fut) in batch])
             return
+        # the span covers the encode, not the awaited send
+        with span("ow_produce", n=len(batch)) as sp:
+            out = self._encode_flush(batch)
+            sp.set_metadata(bytes=sum(len(p) for _t, p, _m in out))
+        await self.inner.send_many(out)
+
+    def _encode_flush(self, batch) -> list:
+        """The synchronous half of `_ship` with the batch wire on: one
+        `(topic, payload, msg)` per (topic, family) group or lone item."""
         from .connector import encode_batch
         # group deferred-encode messages per (topic, family), preserving
         # per-topic arrival order WITHIN a family (the serial ordering
@@ -226,7 +236,7 @@ class CoalescingProducer(MessageProducer):
                 out.append((topic, payload, batch_msg))
             else:
                 out.append(it)
-        await self.inner.send_many(out)
+        return out
 
     @staticmethod
     def _fail_group(group, exc) -> None:

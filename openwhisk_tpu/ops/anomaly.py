@@ -121,8 +121,8 @@ def make_anomaly_step(alpha: float, z_threshold: float,
         return jnp.where(n > 0, 0.5 * (lo + hi), 0.0)
 
     @jax.jit
-    def step(state: AnomalyState, inv_buckets, inv_lat_ms, inv_outcomes
-             ) -> Tuple[AnomalyState, object]:
+    def anomaly_step(state: AnomalyState, inv_buckets, inv_lat_ms,
+                     inv_outcomes) -> Tuple[AnomalyState, object]:
         f32 = jnp.float32
         count = jnp.sum(inv_buckets, axis=1).astype(f32)
         prev_count = jnp.sum(state.prev_buckets, axis=1).astype(f32)
@@ -194,7 +194,7 @@ def make_anomaly_step(alpha: float, z_threshold: float,
                                  ewma_err, ewma_tm, ticks)
         return new_state, scores
 
-    return step
+    return anomaly_step
 
 
 def _masked_median_np(x: np.ndarray, mask: np.ndarray) -> float:

@@ -102,7 +102,7 @@ def make_record_packed():
     import jax.numpy as jnp
 
     @jax.jit
-    def record_packed(state: TelemetryState, ev) -> TelemetryState:
+    def telemetry_fold(state: TelemetryState, ev) -> TelemetryState:
         inv, ns, lat_us, outcome, valid = ev
         n_buckets = state.inv_buckets.shape[1]
         # integer-exact log2 bucket: count the bounds each sample exceeds
@@ -125,7 +125,7 @@ def make_record_packed():
             state.ns_outcomes.at[ns, k].add(v),
         )
 
-    return record_packed
+    return telemetry_fold
 
 
 class DeviceLatencyAccumulator:

@@ -68,7 +68,7 @@ from typing import Dict, List, Optional, Tuple
 from .config import load_config
 from .eventlog import identity
 from .ring_buffer import SeqRingBuffer
-from .waterfall import bucket_bounds_ms, bucket_of_us
+from .waterfall import bucket_bounds_ms, bucket_of_us, span
 
 #: full-rate sampling during an armed capture window (the always-on rate
 #: is `sampleHz`); bounded by captureLimitS so a capture can never become
@@ -231,6 +231,8 @@ class HostObservatory:
         self._sampler_stop: Optional[threading.Event] = None
         self._capture: Optional[dict] = None
         self._gc_t0_ns = 0
+        self._gc_span = None
+        self._gc_watchers = 0
         self._dispatch_depth = 0
         self._reset_aggregates()
 
@@ -307,7 +309,7 @@ class HostObservatory:
         interval = max(1.0, float(self.config.lag_probe_ms)) / 1e3
         self._probe_next = loop.time() + interval
         self._probe_handle = loop.call_at(self._probe_next, self._probe_tick)
-        gc.callbacks.append(self._gc_cb)
+        self.watch_gc()
         if self.config.sample_hz > 0 and hasattr(sys, "_current_frames"):
             self._sampler_stop = threading.Event()
             self._sampler = threading.Thread(
@@ -335,10 +337,7 @@ class HostObservatory:
             loop.set_task_factory(self._prev_factory)
         self._prev_factory = None
         self._factory_ref = None
-        try:
-            gc.callbacks.remove(self._gc_cb)
-        except ValueError:
-            pass
+        self.unwatch_gc()
         if self._sampler_stop is not None:
             self._sampler_stop.set()
         if self._sampler is not None:
@@ -412,10 +411,37 @@ class HostObservatory:
             })
 
     # -- plane 2: gc pauses ------------------------------------------------
+    def watch_gc(self) -> None:
+        """Register the gc callback alone (pause accounting and the
+        `ow_gc` span), for an owner that runs without `install()`: the
+        balancer's start(). Counted, so each owner pairs it with one
+        `unwatch_gc()` and the last one out removes the callback."""
+        self._gc_watchers += 1
+        if self._gc_watchers == 1:
+            gc.callbacks.append(self._gc_cb)
+
+    def unwatch_gc(self) -> None:
+        if self._gc_watchers == 0:
+            return
+        self._gc_watchers -= 1
+        if self._gc_watchers == 0:
+            try:
+                gc.callbacks.remove(self._gc_cb)
+            except ValueError:
+                pass
+
     def _gc_cb(self, phase: str, info: dict) -> None:
         if phase == "start":
+            # a collection stops every thread: the span, entered and
+            # exited by hand on the thread that triggered it, is what a
+            # profiler session shows of it
+            self._gc_span = span("ow_gc", gen=info.get("generation", 2))
+            self._gc_span.__enter__()
             self._gc_t0_ns = time.perf_counter_ns()
             return
+        sp, self._gc_span = self._gc_span, None
+        if sp is not None:
+            sp.__exit__(None, None, None)
         t0 = self._gc_t0_ns
         if t0 == 0:
             return

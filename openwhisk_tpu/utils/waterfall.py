@@ -46,6 +46,7 @@ from __future__ import annotations
 
 import functools
 import math
+import sys
 import threading
 import time
 from dataclasses import dataclass
@@ -121,6 +122,40 @@ def bucket_bounds_ms(n_buckets: int) -> List[float]:
     Cached: tail_threshold_ms() reads the bounds once per completion
     verdict (ISSUE 18) — callers must not mutate the returned list."""
     return [(2 ** i) / 1000.0 for i in range(max(1, n_buckets - 1))]
+
+
+class _NoSpan:
+    """What `span` hands a process that never imported JAX (an invoker,
+    the bus broker): no profiler session can run there."""
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **counts) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+def span(name: str, **counts):
+    """A host span on the profiler's clock: `jax.profiler.TraceAnnotation`,
+    so the program's spans and the device's ops land in one `.xplane.pb`.
+    Tracing is on exactly while a profiler session runs (`benchmark/run.py
+    --trace 1`, an operator's capture with `trace_dir`); otherwise a span
+    is one object construction and an inactive TraceMe. `counts` (integers
+    already at hand) become the event's stats; one known only at the end
+    goes through `set_metadata` on the entered span. Per thread, so never
+    around an `await`: a suspended coroutine would cover other tasks'
+    time. Names start `ow_`."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return _NO_SPAN
+    return jax.profiler.TraceAnnotation(name, **counts)
 
 
 class ActivationWaterfall:

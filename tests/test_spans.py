@@ -1,0 +1,313 @@
+"""ISSUE 25: the program's host spans on the profiler's clock.
+
+`utils/waterfall.span` is `jax.profiler.TraceAnnotation`: off (no profiler
+session) it keeps nothing and changes no decision; on, one toy run on the
+CPU twin leaves every `ow_*` span of the table in the trace, one `ow_step`
+per journaled batch record, one `ow_fold` per fold record, every
+activation counted once in an `ow_assemble`'s `b`, and no two spans of the
+event loop's thread overlapping except by nesting (the no-`await` rule).
+No assertion here is on a time.
+"""
+from __future__ import annotations
+
+import asyncio
+import gc
+import glob
+import os
+import sys
+import tracemalloc
+
+import pytest
+
+from openwhisk_tpu.controller.loadbalancer import TpuBalancer
+from openwhisk_tpu.controller.loadbalancer.base import maybe_batch_publish
+from openwhisk_tpu.controller.loadbalancer.journal import PlacementJournal
+from openwhisk_tpu.controller.loadbalancer.quality import (QualityConfig,
+                                                           QualityPlane)
+from openwhisk_tpu.core.entity import (ActionLimits, ActivationId,
+                                       ActivationResponse, CodeExec,
+                                       ControllerInstanceId, EntityName,
+                                       EntityPath, ExecutableWhiskAction,
+                                       Identity, InvokerInstanceId, MB,
+                                       MemoryLimit, TimeLimit,
+                                       WhiskActivation)
+from openwhisk_tpu.core.entity.ids import DocRevision
+from openwhisk_tpu.messaging import (ActivationMessage,
+                                     CombinedCompletionAndResultMessage,
+                                     MemoryMessagingProvider, MessageFeed,
+                                     PingMessage, maybe_coalesce)
+from openwhisk_tpu.messaging.columnar import is_batch_payload
+from openwhisk_tpu.messaging.connector import decode_batch, decode_message
+from openwhisk_tpu.utils import waterfall
+from openwhisk_tpu.utils.hostprof import GLOBAL_HOST_OBSERVATORY
+from openwhisk_tpu.utils.transaction import TransactionId
+
+#: every span name of ISSUE 25's table, plus the row continuation
+SPANS = {
+    "ow_admit", "ow_assemble", "ow_step", "ow_fold", "ow_shadow",
+    "ow_quality", "ow_telemetry_fold", "ow_books_ref", "ow_record",
+    "ow_journal", "ow_readback_wait", "ow_readback_resume", "ow_fanout",
+    "ow_placed", "ow_produce", "ow_ack_decode", "ow_ack_process", "ow_ping",
+    "ow_supervision_tick", "ow_telemetry_tick", "ow_anomaly_tick",
+    "ow_timeout_fire", "ow_gc"}
+N_INVOKERS = 4
+BATCHES = (5, 9, 3)
+
+
+def _action(name: str) -> ExecutableWhiskAction:
+    a = ExecutableWhiskAction(EntityPath("guest"), EntityName(name),
+                              CodeExec(kind="python:3", code="x"),
+                              limits=ActionLimits(TimeLimit(5000),
+                                                  MemoryLimit(MB(256))))
+    a.rev = DocRevision("1-b")
+    return a
+
+
+def _msg(action, ident) -> ActivationMessage:
+    return ActivationMessage(
+        TransactionId(), action.fully_qualified_name, action.rev.rev, ident,
+        ActivationId.generate(), ControllerInstanceId("0"), True, {})
+
+
+def _echo_invoker(provider, instance) -> MessageFeed:
+    """Acks every activation at once, except the action named `lost`."""
+    topic = instance.as_string
+    provider.ensure_topic(topic)
+    producer = maybe_coalesce(provider.get_producer())
+    box = {}
+
+    async def handle(payload: bytes) -> None:
+        if is_batch_payload(payload):
+            _kind, msgs = decode_batch(payload)
+        else:
+            msgs = [decode_message(ActivationMessage.parse, payload,
+                                   "activation")]
+        for msg in msgs:
+            if str(msg.action.name) == "lost":
+                continue
+            act = WhiskActivation(
+                EntityPath(str(msg.user.namespace.name)), msg.action.name,
+                msg.user.subject, msg.activation_id, 0, 0,
+                ActivationResponse.success({"ok": True}), duration=1)
+            producer.send_nowait(
+                f"completed{msg.root_controller_index.as_string}",
+                CombinedCompletionAndResultMessage(msg.transid, act,
+                                                   instance))
+        box["feed"].processed()
+
+    box["feed"] = MessageFeed(topic, provider.get_consumer(topic, topic),
+                              64, handle)
+    return box["feed"].start()
+
+
+async def _idle(bal) -> None:
+    for _ in range(400):
+        if not (bal._inflight_steps or bal._pending or bal._releases
+                or bal._health_updates or bal._readbacks):
+            break
+        await asyncio.sleep(0.01)
+    await asyncio.sleep(0.05)
+
+
+async def _toy_run(journal_dir: str, trace_dir=None) -> dict:
+    """One fixed toy run; traced from the first publish to the last fold
+    when `trace_dir` is given. Returns the journal's records of that
+    stretch and the number of activations published in it."""
+    import jax
+
+    provider = MemoryMessagingProvider()
+    bal = TpuBalancer(
+        provider, ControllerInstanceId("0"), managed_fraction=1.0,
+        blackbox_fraction=0.0, prewarm=False,
+        quality=QualityPlane(QualityConfig(enabled=True, shadow_every_n=1)))
+    bal.TIMEOUT_FACTOR, bal.STD_TIMEOUT, bal.TIMEOUT_ADDON = 0, 0.0, 0.4
+    journal = PlacementJournal(journal_dir)
+    bal.attach_journal(journal)
+    await bal.start()
+    instances = [InvokerInstanceId(i, user_memory=MB(2048))
+                 for i in range(N_INVOKERS)]
+    feeds = [_echo_invoker(provider, inst) for inst in instances]
+    pinger = provider.get_producer()
+    provider.ensure_topic("health")
+
+    async def ping() -> None:
+        for inst in instances:
+            await pinger.send("health", PingMessage(inst))
+
+    for _ in range(200):
+        await ping()
+        await asyncio.sleep(0.05)
+        if sum(h.status == "up" for h in await bal.invoker_health()) \
+                >= N_INVOKERS:
+            break
+    else:
+        raise RuntimeError("fleet never became healthy")
+    await _idle(bal)
+
+    if trace_dir is not None:
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    try:
+        seq0 = bal._journal_seq
+        ident = Identity.generate("guest")
+        publisher = maybe_batch_publish(bal)
+        published = 0
+        for k, n in enumerate(BATCHES):
+            msgs = [(a, _msg(a, ident)) for a in
+                    (_action(f"act{(k + i) % 4}") for i in range(n))]
+            promises = await asyncio.gather(
+                *[publisher.publish(a, m) for a, m in msgs])
+            await asyncio.gather(*promises)
+            published += n
+            await _idle(bal)
+        # the serial SPI, and an activation nobody acks
+        one = _action("act1")
+        await (await bal.publish(one, _msg(one, ident)))
+        lost = _action("lost")
+        with pytest.raises(Exception):
+            await (await bal.publish(lost, _msg(lost, ident)))
+        published += 2
+        gc.collect()
+        await ping()
+        await asyncio.sleep(1.3)   # one 1 Hz supervision tick at least
+        await _idle(bal)
+        seq1 = bal._journal_seq
+    finally:
+        if trace_dir is not None:
+            jax.profiler.stop_trace()
+    for f in feeds:
+        await f.stop()
+    await bal.close()
+    journal.close()
+    records = [r for r in PlacementJournal(journal_dir).records()
+               if seq0 < r["seq"] <= seq1]
+    return {"records": records, "published": published}
+
+
+def _decisions(records: list) -> list:
+    batches = {r["seq"]: r["b"] for r in records if r["t"] == "batch"}
+    return [(batches[r["for"]], r["out"]) for r in records
+            if r["t"] == "ack"]
+
+
+def _host_lines(trace_dir: str) -> list:
+    """[(events of one thread)] with events as (name, start, end, stats)."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    lines = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:CPU"):
+            continue
+        for line in plane.lines:
+            evs = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                    dict(ev.stats)) for ev in line.events
+                   if ev.name.startswith("ow_")]
+            if evs:
+                lines.append(evs)
+    return lines
+
+
+@pytest.fixture(scope="module")
+def runs(tmp_path_factory):
+    tmp = tmp_path_factory.mktemp("spans")
+    plain = asyncio.run(_toy_run(str(tmp / "journal-plain")))
+    traced = asyncio.run(_toy_run(str(tmp / "journal-traced"),
+                                  str(tmp / "trace")))
+    traced["lines"] = _host_lines(str(tmp / "trace"))
+    return plain, traced
+
+
+def test_tracing_changes_no_decision(runs):
+    plain, traced = runs
+    assert _decisions(plain["records"]) == _decisions(traced["records"])
+    assert len(_decisions(plain["records"])) == len(BATCHES) + 2
+
+
+def test_the_trace_holds_every_span_of_the_table(runs):
+    _plain, traced = runs
+    seen = {name for line in traced["lines"] for name, *_ in line}
+    assert seen >= SPANS, SPANS - seen
+
+
+def test_steps_and_folds_are_the_journal_s_records(runs):
+    _plain, traced = runs
+    recs = traced["records"]
+    names = [name for line in traced["lines"] for name, *_ in line]
+    assert names.count("ow_step") \
+        == sum(r["t"] == "batch" for r in recs) == len(BATCHES) + 2
+    assert names.count("ow_fold") == sum(r["t"] == "fold" for r in recs) > 0
+    assert names.count("ow_journal") == len(recs)
+    assemble = [st for line in traced["lines"] for name, _s, _e, st in line
+                if name == "ow_assemble"]
+    assert sum(st["b"] for st in assemble) == traced["published"]
+    # the spans of one micro-batch share its batch record's seq
+    assert sorted(st["seq"] for st in assemble) \
+        == sorted(r["seq"] for r in recs if r["t"] == "batch")
+    steps = [st["seq"] for line in traced["lines"] for name, _s, _e, st
+             in line if name == "ow_step"]
+    assert sorted(steps) == sorted(st["seq"] for st in assemble)
+    frames = [st for line in traced["lines"] for name, _s, _e, st in line
+              if name == "ow_ack_decode"]
+    assert sum(st["acks"] for st in frames) == traced["published"] - 1
+    assert all(st["bytes"] > 0 for st in frames)
+
+
+def test_loop_spans_overlap_only_by_nesting(runs):
+    _plain, traced = runs
+    (loop,) = [line for line in traced["lines"]
+               if any(name == "ow_assemble" for name, *_ in line)]
+    # the readback waits on a worker thread, never on the loop's
+    assert not any(name == "ow_readback_wait" for name, *_ in loop)
+    assert any(name == "ow_readback_wait" for line in traced["lines"]
+               if line is not loop for name, *_ in line)
+    stack = []
+    for name, start, end, _st in sorted(loop, key=lambda e: (e[1], -e[2])):
+        while stack and stack[-1][1] <= start:
+            stack.pop()
+        if stack:
+            assert end <= stack[-1][1], (name, stack[-1][0])
+        stack.append((name, end))
+
+
+def test_a_span_off_keeps_nothing(monkeypatch):
+    gc.collect()
+    tracemalloc.start()
+    try:
+        before = tracemalloc.take_snapshot()
+        for i in range(20000):
+            with waterfall.span("ow_x", seq=i, b=7) as sp:
+                sp.set_metadata(acks=3)
+        gc.collect()
+        after = tracemalloc.take_snapshot()
+    finally:
+        tracemalloc.stop()
+    here = [d for d in after.compare_to(before, "filename")
+            if d.traceback[0].filename in (__file__, waterfall.__file__)]
+    assert sum(d.size_diff for d in here) < 4096
+    # a process that never imported JAX (an invoker) gets the null span
+    monkeypatch.setitem(sys.modules, "jax", None)
+    sp = waterfall.span("ow_x", seq=1)
+    assert sp is waterfall._NO_SPAN
+    with sp as entered:
+        entered.set_metadata(n=1)
+
+
+def test_the_gc_span_needs_no_install_and_is_counted():
+    obs = GLOBAL_HOST_OBSERVATORY
+    assert not obs.installed
+    watchers = obs._gc_watchers
+    obs.watch_gc()
+    obs.watch_gc()
+    assert gc.callbacks.count(obs._gc_cb) == 1
+    n = sum(obs._gc_count)
+    gc.collect()
+    assert sum(obs._gc_count) == n + 1 and obs._gc_span is None
+    obs.unwatch_gc()
+    assert gc.callbacks.count(obs._gc_cb) == 1
+    obs.unwatch_gc()
+    assert obs._gc_watchers == watchers
+    assert gc.callbacks.count(obs._gc_cb) == (1 if watchers else 0)
