@@ -177,22 +177,24 @@ class Controller:
         # controller's /metrics page. install() is a refused no-op when
         # CONFIG_whisk_hostProfiling_enabled=false or another controller
         # in this process already owns the observatory.
-        from ..utils.hostprof import GLOBAL_HOST_OBSERVATORY, tune_gc
+        from ..utils.hostprof import GLOBAL_HOST_OBSERVATORY
         self._host_observatory_owner = GLOBAL_HOST_OBSERVATORY.install(
             metrics=self.metrics)
-        # opt-in GC tuning (CONFIG_whisk_host_gc_enabled): freeze the
-        # boot-time permanent heap out of the collector and raise the
-        # thresholds — full gen-2 scans were measured at 100-250 ms event
-        # loop stalls under load (utils/hostprof.py GcTuningConfig)
-        tuned = tune_gc()
-        if tuned is not None:
+        self.cache_invalidation.start()
+        if hasattr(self.load_balancer, "start"):
+            await self.load_balancer.start()
+        # the served path owns the collector while it serves
+        # (utils/hostprof.py tune_gc: the boot heap frozen, the generations
+        # sized for serving). A TpuBalancer's start() has taken its share
+        # and logged it; beside any other balancer the controller takes
+        # it here, and stop() hands it back.
+        if getattr(self.load_balancer, "gc_tuned", None) is None:
+            tuned = GLOBAL_HOST_OBSERVATORY.tune_gc()
+            self._gc_tuner = True
             self.logger.info("controller",
                              f"gc tuned: froze {tuned['frozen']} objects, "
                              f"thresholds {tuned['thresholds']}",
                              "Controller")
-        self.cache_invalidation.start()
-        if hasattr(self.load_balancer, "start"):
-            await self.load_balancer.start()
         if hasattr(self.load_balancer, "prepare_health_test_action"):
             # system test action for probing unhealthy invokers
             # (ref InvokerPool.prepare, InvokerSupervision.scala:239-252)
@@ -246,10 +248,13 @@ class Controller:
                          "Controller")
 
     async def stop(self) -> None:
+        from ..utils.hostprof import GLOBAL_HOST_OBSERVATORY
         if getattr(self, "_host_observatory_owner", False):
-            from ..utils.hostprof import GLOBAL_HOST_OBSERVATORY
             GLOBAL_HOST_OBSERVATORY.uninstall()
             self._host_observatory_owner = False
+        if getattr(self, "_gc_tuner", False):
+            GLOBAL_HOST_OBSERVATORY.untune_gc()
+            self._gc_tuner = False
         if self._runner:
             await self._runner.cleanup()
         if self.membership is not None:

@@ -723,7 +723,9 @@ class TpuBalancer(CommonLoadBalancer):
         self._rel_ring = ColumnRing(4, max_batch * 4)
         self._health_updates: Dict[int, bool] = {}
         self._flush_task: Optional[asyncio.Task] = None
-        self._gc_watched = False
+        #: what start() froze and set ({frozen, thresholds}); None while
+        #: this balancer holds no share of the collector's policy
+        self.gc_tuned: Optional[dict] = None
         self._step_lock = asyncio.Lock()
         # device-step pipelining: dispatch is async (JAX returns future
         # arrays immediately), so batch N+1 can be dispatched while batch
@@ -1591,12 +1593,6 @@ class TpuBalancer(CommonLoadBalancer):
 
     # -- lifecycle ---------------------------------------------------------
     async def start(self) -> None:
-        # a collection stops the loop like any other code: the `ow_gc`
-        # span (and the pause accounting) must not wait for
-        # Controller.start's install()
-        if not self._gc_watched:
-            self._gc_watched = True
-            GLOBAL_HOST_OBSERVATORY.watch_gc()
         self.start_ack_feed()
         self.supervision.start()
         # warm the first-traffic bucket signature while the fleet is still
@@ -1604,6 +1600,19 @@ class TpuBalancer(CommonLoadBalancer):
         if self.prewarm and \
                 (8, self.HEALTH_BATCH, 8) not in self._warm_sigs:
             self._spawn_warm([(8, self.HEALTH_BATCH, 8)])
+        # a collection stops the loop like any other code, so the served
+        # path owns the collector while it serves: the `ow_gc` span with
+        # the pause accounting, and the policy (utils/hostprof.py tune_gc:
+        # the boot heap frozen, the generations sized for serving). Both
+        # are counted, and close() hands both back.
+        if self.gc_tuned is None:
+            GLOBAL_HOST_OBSERVATORY.watch_gc()
+            self.gc_tuned = GLOBAL_HOST_OBSERVATORY.tune_gc()
+            if self.logger:
+                self.logger.info(
+                    None,
+                    f"gc tuned: froze {self.gc_tuned['frozen']} objects, "
+                    f"thresholds {self.gc_tuned['thresholds']}")
 
     async def close(self) -> None:
         self._closing = True  # no new flush tasks from here on
@@ -1637,8 +1646,9 @@ class TpuBalancer(CommonLoadBalancer):
         self._releases.clear()
         self._rel_ring.clear()
         await super().close()
-        if self._gc_watched:
-            self._gc_watched = False
+        if self.gc_tuned is not None:
+            self.gc_tuned = None
+            GLOBAL_HOST_OBSERVATORY.untune_gc()
             GLOBAL_HOST_OBSERVATORY.unwatch_gc()
 
     # -- publish -----------------------------------------------------------

@@ -36,7 +36,8 @@ a bounded capture plane, all within a <5% overhead budget (the
 
 Exposition (register_renderer on the installing process's MetricEmitter):
 `openwhisk_host_event_loop_lag_seconds`,
-`openwhisk_host_gc_pause_seconds{generation}`, `openwhisk_host_tasks_*`,
+`openwhisk_host_gc_pause_seconds{generation}`,
+`openwhisk_host_gc_frozen_objects`, `openwhisk_host_tasks_*`,
 `openwhisk_host_serde_{seconds,bytes}_total{hop,direction}`. Read side:
 auth-gated `GET /admin/profile/host` (snapshot) and
 `POST /admin/profile/host/capture` (bounded capture window), following the
@@ -102,52 +103,24 @@ class HostProfilingConfig:
     buckets: int = 30
 
 
-@dataclass(frozen=True)
-class GcTuningConfig:
-    """`CONFIG_whisk_host_gc_*` env overrides for `tune_gc()`.
-
-    Rationale (measured by this module's GC plane, ISSUE 12): CPython's
-    default thresholds (700, 10, 10) run a FULL-heap gen-2 collection
-    every ~70k surviving allocations. A loaded controller allocates
-    hundreds of objects per activation over a permanent heap of ~1M
-    objects (jax's module graph alone), so gen-2 fires mid-burst and
-    stalls the event loop for 100-250 ms — the observatory measured GC at
-    ~12% of wall with 262 ms p99 gen-2 pauses at 2k activations/s.
-    `tune_gc()` freezes the post-boot permanent heap out of the collector
-    (gc.freeze) and raises the thresholds so cycles still collect but
-    full scans amortize over far more allocations. Default OFF for the
-    product (`enabled=false`): operators opt in per deployment; the
-    open-loop harness (tools/loadgen.py) opts in for its own process and
-    says so in the generator block."""
-    enabled: bool = False
-    gen0: int = 50000
-    gen1: int = 50
-    gen2: int = 100
-    freeze: bool = True
-
-    @classmethod
-    def from_env(cls) -> "GcTuningConfig":
-        return load_config(cls, env_path="host.gc")
-
-
-def tune_gc(config: Optional[GcTuningConfig] = None,
-            force: bool = False) -> Optional[dict]:
-    """Apply the GC tuning above (see GcTuningConfig). Returns what was
-    done ({frozen, thresholds}) or None when disabled. `force=True`
-    applies regardless of the enabled flag (the harness's explicit
-    opt-in). One full collection runs first so freeze() pins a clean
-    heap."""
-    cfg = config if config is not None else GcTuningConfig.from_env()
-    if not (cfg.enabled or force):
-        return None
-    gc.collect()
-    frozen = 0
-    if cfg.freeze:
-        gc.freeze()
-        frozen = gc.get_freeze_count()
-    gc.set_threshold(int(cfg.gen0), int(cfg.gen1), int(cfg.gen2))
-    return {"frozen": frozen,
-            "thresholds": [int(cfg.gen0), int(cfg.gen1), int(cfg.gen2)]}
+#: CPython's generation thresholds while a balancer serves (its defaults
+#: are 700, 10, 10), set by `HostObservatory.tune_gc()`. Only the young one
+#: is raised. Its count is NET of deallocations and steady traffic's net is
+#: zero, so it only has to clear the largest burst steady traffic makes, a
+#: pipeline's worth of full batches (4 x 256 rows x ~60 tracked objects);
+#: above that only true garbage climbs to it, and collections come in
+#: proportion to garbage made, whatever the fleet or the rate. The served
+#: path makes no reference cycles (tests/test_spans.py holds that); the
+#: older generations keep CPython's ratios for whatever else in the process
+#: does: garbage whose objects outlived a young collection while still in
+#: use waits in an older generation, and with those thresholds raised too
+#: it piled up there for one collection several times longer than any the
+#: defaults made. The defaults cost fleet1k 209.6 us of the event loop per
+#: activation and a 262.8 ms full collection every second
+#: (PERF_LEDGER.jsonl, PR 25, `host_gc_us.closed`,
+#: `loop_block_max_ms.closed`); PERF.md section 6 (PR 26) has what these
+#: read on the chip and what lost.
+GC_SERVING_THRESHOLDS = (50_000, 10, 10)
 
 
 class _TimedCoro:
@@ -233,6 +206,8 @@ class HostObservatory:
         self._gc_t0_ns = 0
         self._gc_span = None
         self._gc_watchers = 0
+        self._gc_tuners = 0
+        self._gc_found = gc.get_threshold()
         self._dispatch_depth = 0
         self._reset_aggregates()
 
@@ -429,6 +404,31 @@ class HostObservatory:
                 gc.callbacks.remove(self._gc_cb)
             except ValueError:
                 pass
+
+    def tune_gc(self) -> dict:
+        """The served path's collector policy, owned by the balancer's
+        start() / close(): one full collection, `gc.freeze()` so that no
+        later collection traverses the boot heap (jax's module graph),
+        and GC_SERVING_THRESHOLDS. Counted like `watch_gc()`: the first
+        owner in applies it, each pairs it with one `untune_gc()`, and
+        the last one out puts back what the first found. Returns what
+        holds now, for the owner's start-up log line."""
+        self._gc_tuners += 1
+        if self._gc_tuners == 1:
+            self._gc_found = gc.get_threshold()
+            gc.collect()
+            gc.freeze()
+            gc.set_threshold(*GC_SERVING_THRESHOLDS)
+        return {"frozen": gc.get_freeze_count(),
+                "thresholds": list(gc.get_threshold())}
+
+    def untune_gc(self) -> None:
+        if self._gc_tuners == 0:
+            return
+        self._gc_tuners -= 1
+        if self._gc_tuners == 0:
+            gc.unfreeze()
+            gc.set_threshold(*self._gc_found)
 
     def _gc_cb(self, phase: str, info: dict) -> None:
         if phase == "start":
@@ -794,6 +794,8 @@ class HostObservatory:
                                    gc_collected, openmetrics)
         out += self._plain_counter("openwhisk_host_gc_uncollectable_total",
                                    gc_uncollectable, openmetrics)
+        out += ["# TYPE openwhisk_host_gc_frozen_objects gauge",
+                f"openwhisk_host_gc_frozen_objects {gc.get_freeze_count()}"]
         serde_rows = sorted(serde.items())
         out += counter_family_text(
             "openwhisk_host_serde_seconds_total",

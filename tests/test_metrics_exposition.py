@@ -360,6 +360,13 @@ class TestExpositionFormat:
         assert types["openwhisk_host_loop_stalls_total"] == "counter"
         assert types[
             "openwhisk_host_gc_pauses_in_dispatch_total"] == "counter"
+        # the boot heap the started balancer froze out of the collector
+        # (ISSUE 26): scraped while it serves, so far more than the few
+        # hundred objects CPython itself keeps there
+        assert types["openwhisk_host_gc_frozen_objects"] == "gauge"
+        (frozen,) = [ln for ln in text.splitlines() if ln.startswith(
+            "openwhisk_host_gc_frozen_objects ")]
+        assert int(frozen.split()[1]) > 10_000
         assert types["openwhisk_host_serde_seconds_total"] == "counter"
         assert types["openwhisk_host_serde_bytes_total"] == "counter"
         serde_lines = [ln for ln in text.splitlines() if ln.startswith(

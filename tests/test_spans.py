@@ -39,7 +39,8 @@ from openwhisk_tpu.messaging import (ActivationMessage,
 from openwhisk_tpu.messaging.columnar import is_batch_payload
 from openwhisk_tpu.messaging.connector import decode_batch, decode_message
 from openwhisk_tpu.utils import waterfall
-from openwhisk_tpu.utils.hostprof import GLOBAL_HOST_OBSERVATORY
+from openwhisk_tpu.utils.hostprof import (GC_SERVING_THRESHOLDS,
+                                          GLOBAL_HOST_OBSERVATORY)
 from openwhisk_tpu.utils.transaction import TransactionId
 
 #: every span name of ISSUE 25's table, plus the row continuation
@@ -69,12 +70,22 @@ def _msg(action, ident) -> ActivationMessage:
         ActivationId.generate(), ControllerInstanceId("0"), True, {})
 
 
-def _echo_invoker(provider, instance) -> MessageFeed:
-    """Acks every activation at once, except the action named `lost`."""
+def _echo_invoker(provider, instance, service_s: float = 0.0) -> MessageFeed:
+    """Acks every activation, at once or `service_s` later, except the
+    action named `lost`."""
     topic = instance.as_string
     provider.ensure_topic(topic)
     producer = maybe_coalesce(provider.get_producer())
     box = {}
+
+    def ack(msg) -> None:
+        act = WhiskActivation(
+            EntityPath(str(msg.user.namespace.name)), msg.action.name,
+            msg.user.subject, msg.activation_id, 0, 0,
+            ActivationResponse.success({"ok": True}), duration=1)
+        producer.send_nowait(
+            f"completed{msg.root_controller_index.as_string}",
+            CombinedCompletionAndResultMessage(msg.transid, act, instance))
 
     async def handle(payload: bytes) -> None:
         if is_batch_payload(payload):
@@ -85,14 +96,10 @@ def _echo_invoker(provider, instance) -> MessageFeed:
         for msg in msgs:
             if str(msg.action.name) == "lost":
                 continue
-            act = WhiskActivation(
-                EntityPath(str(msg.user.namespace.name)), msg.action.name,
-                msg.user.subject, msg.activation_id, 0, 0,
-                ActivationResponse.success({"ok": True}), duration=1)
-            producer.send_nowait(
-                f"completed{msg.root_controller_index.as_string}",
-                CombinedCompletionAndResultMessage(msg.transid, act,
-                                                   instance))
+            if service_s > 0:
+                asyncio.get_running_loop().call_later(service_s, ack, msg)
+            else:
+                ack(msg)
         box["feed"].processed()
 
     box["feed"] = MessageFeed(topic, provider.get_consumer(topic, topic),
@@ -107,6 +114,34 @@ async def _idle(bal) -> None:
             break
         await asyncio.sleep(0.01)
     await asyncio.sleep(0.05)
+
+
+async def _healthy_fleet(provider, bal, service_s: float = 0.0) -> tuple:
+    """N_INVOKERS echo invokers, pinged until the balancer holds them all
+    up. Returns their feeds and the coroutine function that pings them."""
+    # the first batches compile on the event loop; on a loaded box that
+    # can outlast the 10 s ping timeout, and the fleet then reads offline
+    bal.supervision.ping_timeout = 600.0
+    instances = [InvokerInstanceId(i, user_memory=MB(2048))
+                 for i in range(N_INVOKERS)]
+    feeds = [_echo_invoker(provider, inst, service_s) for inst in instances]
+    pinger = provider.get_producer()
+    provider.ensure_topic("health")
+
+    async def ping() -> None:
+        for inst in instances:
+            await pinger.send("health", PingMessage(inst))
+
+    for _ in range(200):
+        await ping()
+        await asyncio.sleep(0.05)
+        if sum(h.status == "up" for h in await bal.invoker_health()) \
+                >= N_INVOKERS:
+            break
+    else:
+        raise RuntimeError("fleet never became healthy")
+    await _idle(bal)
+    return feeds, ping
 
 
 async def _toy_run(journal_dir: str, trace_dir=None) -> dict:
@@ -124,25 +159,7 @@ async def _toy_run(journal_dir: str, trace_dir=None) -> dict:
     journal = PlacementJournal(journal_dir)
     bal.attach_journal(journal)
     await bal.start()
-    instances = [InvokerInstanceId(i, user_memory=MB(2048))
-                 for i in range(N_INVOKERS)]
-    feeds = [_echo_invoker(provider, inst) for inst in instances]
-    pinger = provider.get_producer()
-    provider.ensure_topic("health")
-
-    async def ping() -> None:
-        for inst in instances:
-            await pinger.send("health", PingMessage(inst))
-
-    for _ in range(200):
-        await ping()
-        await asyncio.sleep(0.05)
-        if sum(h.status == "up" for h in await bal.invoker_health()) \
-                >= N_INVOKERS:
-            break
-    else:
-        raise RuntimeError("fleet never became healthy")
-    await _idle(bal)
+    feeds, ping = await _healthy_fleet(provider, bal)
 
     if trace_dir is not None:
         opts = jax.profiler.ProfileOptions()
@@ -311,3 +328,150 @@ def test_the_gc_span_needs_no_install_and_is_counted():
     obs.unwatch_gc()
     assert obs._gc_watchers == watchers
     assert gc.callbacks.count(obs._gc_cb) == (1 if watchers else 0)
+
+
+# -- ISSUE 26: the served path owns the collector --------------------------
+
+CTL_PORT = 13481
+
+
+def _plain_balancer(provider) -> TpuBalancer:
+    return TpuBalancer(provider, ControllerInstanceId("0"),
+                       managed_fraction=1.0, blackbox_fraction=0.0,
+                       prewarm=False)
+
+
+def _collector() -> tuple:
+    return gc.get_threshold(), gc.get_freeze_count()
+
+
+def _serving(found: tuple) -> bool:
+    """Is the collector under the serving policy, or as `found`?"""
+    thresholds, frozen = _collector()
+    if thresholds == GC_SERVING_THRESHOLDS:
+        assert frozen > found[1]
+        return True
+    # all of it unfrozen: a fresh CPython 3.12 holds a few hundred objects
+    # of its own in the permanent generation, until the first unfreeze()
+    assert thresholds == found[0] and frozen in (0, found[1])
+    return False
+
+
+async def _two_balancers(close_order) -> list:
+    a, b = (_plain_balancer(MemoryMessagingProvider()) for _ in range(2))
+    found = _collector()
+    seen = []
+    await a.start()
+    await a.start()   # a second start() takes no second share
+    seen.append(_serving(found))
+    await b.start()
+    assert a.gc_tuned["thresholds"] == list(GC_SERVING_THRESHOLDS)
+    assert 0 < b.gc_tuned["frozen"] <= a.gc_tuned["frozen"]
+    for bal in (a, b)[::close_order]:
+        seen.append(_serving(found))
+        await bal.close()
+        assert bal.gc_tuned is None
+    seen.append(_serving(found))
+    return seen
+
+
+async def _never_started() -> list:
+    found = _collector()
+    await _plain_balancer(MemoryMessagingProvider()).close()
+    return [_serving(found)]
+
+
+async def _controller(tpu: bool) -> list:
+    from openwhisk_tpu.controller.core import Controller
+    from openwhisk_tpu.controller.loadbalancer import LeanBalancer
+
+    class StubInvoker:
+        async def stop(self) -> None:
+            pass
+
+    async def no_invoker(invoker_id, provider):
+        return StubInvoker()
+
+    provider = MemoryMessagingProvider()
+    lb = _plain_balancer(provider) if tpu else LeanBalancer(
+        provider, ControllerInstanceId("0"), no_invoker,
+        user_memory=MB(512))
+    controller = Controller(ControllerInstanceId("0"), provider,
+                            load_balancer=lb)
+    found = _collector()
+    tuners = GLOBAL_HOST_OBSERVATORY._gc_tuners
+    await controller.start(port=CTL_PORT)
+    try:
+        # one share, whoever took it: the balancer, or the controller
+        # beside a balancer that takes none
+        assert GLOBAL_HOST_OBSERVATORY._gc_tuners == tuners + 1
+        assert (getattr(lb, "gc_tuned", None) is not None) == tpu
+        seen = [_serving(found)]
+    finally:
+        await controller.stop()
+    return seen + [_serving(found)]
+
+
+@pytest.mark.parametrize("scenario,seen", [
+    (lambda: _two_balancers(1), [True, True, True, False]),
+    (lambda: _two_balancers(-1), [True, True, True, False]),
+    (_never_started, [False]),
+    (lambda: _controller(tpu=True), [True, False]),
+    (lambda: _controller(tpu=False), [True, False]),
+], ids=["closed_first_in_first_out", "closed_last_in_first_out",
+        "close_without_start", "controller_with_a_tpu_balancer",
+        "controller_with_another_balancer"])
+def test_the_balancer_lifecycle_owns_the_collector(scenario, seen):
+    """start() freezes the boot heap and sets GC_SERVING_THRESHOLDS, once
+    however many balancers and controllers the process starts; the last
+    close() restores thresholds and freeze count exactly."""
+    assert GLOBAL_HOST_OBSERVATORY._gc_tuners == 0, \
+        "an earlier test left a balancer (and the collector's policy) open"
+    assert asyncio.run(scenario()) == seen
+    assert GLOBAL_HOST_OBSERVATORY._gc_tuners == 0
+
+
+ACTIVATIONS, CALLERS = 2048, 32
+
+
+def test_the_served_path_makes_no_reference_cycles():
+    """The finding GC_SERVING_THRESHOLDS rests on: with a service time over
+    0 (so `setup_activation`'s entry <-> TimerHandle cycle exists until
+    `timeout_task.cancel()` breaks it) a few thousand activations through
+    publish -> ack -> promise leave next to nothing for the collector:
+    reference counts free the rest. A cycle per row would read >= 1."""
+    async def go() -> int:
+        provider = MemoryMessagingProvider()
+        bal = _plain_balancer(provider)
+        await bal.start()
+        feeds, _ping = await _healthy_fleet(provider, bal, service_s=0.002)
+        ident = Identity.generate("guest")
+        actions = [_action(f"act{i}") for i in range(4)]
+        publisher = maybe_batch_publish(bal)
+
+        async def caller(k: int, n: int) -> None:
+            for i in range(n):
+                a = actions[(k + i) % 4]
+                await (await publisher.publish(a, _msg(a, ident)))
+
+        async def drive(total: int) -> None:
+            await asyncio.gather(*[caller(k, total // CALLERS)
+                                   for k in range(CALLERS)])
+            await _idle(bal)
+
+        try:
+            await drive(ACTIVATIONS // 8)   # compiles happen here
+            gc.collect()
+            gc.disable()
+            try:
+                await drive(ACTIVATIONS)
+                return gc.collect()
+            finally:
+                gc.enable()
+        finally:
+            for f in feeds:
+                await f.stop()
+            await bal.close()
+
+    unreachable = asyncio.run(go())
+    assert unreachable < 0.1 * ACTIVATIONS, unreachable

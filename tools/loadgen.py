@@ -580,7 +580,7 @@ def sweep_balancer(rate0: float = 32.0, duration: float = 2.5,
                    kernel: str = "auto", waterfall: bool = True,
                    fixed_rate: Optional[float] = None, seed: int = 1,
                    host_observatory: Optional[bool] = None,
-                   gc_tune: bool = True, fleet_mesh: bool = False,
+                   fleet_mesh: bool = False,
                    keep_samples: bool = False,
                    worker_ident: Optional[int] = None,
                    stragglers=None, trace_keep_all: bool = False,
@@ -598,14 +598,10 @@ def sweep_balancer(rate0: float = 32.0, duration: float = 2.5,
     overhead rider's OFF half; None (default) leaves the process-global
     state alone.
 
-    `gc_tune` (default True, reported as `gc_tuned` in the block): after
-    the target boots, freeze the permanent heap out of the collector and
-    raise the GC thresholds (utils/hostprof.py tune_gc) — the same knob a
-    production controller gets via CONFIG_whisk_host_gc_enabled. Without
-    it, CPython's default full-heap gen-2 collections stall the loop
-    100-250 ms mid-window and the fire-lag verdict blames the generator;
-    the open_loop GC self-check still measures and reports whatever
-    pauses remain, so the tuning is a measured choice, not a blind one."""
+    `gc_tuned` in the block is what the balancer's start() froze and set
+    (utils/hostprof.py tune_gc: the served path owns the collector), None
+    in a funnel worker, which holds no balancer; the open_loop GC
+    self-check measures and reports whatever pauses remain."""
 
     async def go() -> dict:
         from openwhisk_tpu.utils.hostprof import GLOBAL_HOST_OBSERVATORY
@@ -648,10 +644,6 @@ def sweep_balancer(rate0: float = 32.0, duration: float = 2.5,
                                      fleet_mesh=fleet_mesh,
                                      stragglers=stragglers)
         await target.start()
-        gc_tuned = None
-        if gc_tune:
-            from openwhisk_tpu.utils.hostprof import tune_gc
-            gc_tuned = tune_gc(force=True)
         try:
             # warm long enough to actually FINISH the first-sight compiles
             # a rate's batch/release buckets trigger (ISSUE 8's coalescing
@@ -825,7 +817,7 @@ def sweep_balancer(rate0: float = 32.0, duration: float = 2.5,
                 "mode": "open_loop",
                 "funnel_endpoint": funnel,
                 "dist": dist,
-                "gc_tuned": gc_tuned,
+                "gc_tuned": getattr(target.bal, "gc_tuned", None),
                 "stragglers": {str(k): v for k, v
                                in target.stragglers_applied.items()},
                 # what the balancer in THIS process ran on (None in a
@@ -865,7 +857,6 @@ def multiproc_fixed_rate(rate: float, procs: int, duration: float = 2.5,
                          p99_bound_ms: float = DEFAULT_P99_BOUND_MS,
                          dist: str = "poisson", n_invokers: int = 16,
                          kernel: str = "auto", seed: int = 1,
-                         gc_tune: bool = True,
                          host_observatory: bool = False,
                          timeout_s: float = 600.0) -> dict:
     """`--procs N` / `--shared`: the multi-process SHARED deployment
@@ -935,8 +926,6 @@ def multiproc_fixed_rate(rate: float, procs: int, duration: float = 2.5,
                    # keys its funnel origin instance
                    "--funnel", funnel_endpoint, "--no-waterfall",
                    "--worker-ident", str(i)]
-            if not gc_tune:
-                cmd.append("--no-gc-tune")
             if host_observatory:
                 # each worker stamps its fleet identity and emits raw
                 # integer bucket counts; the parent merges them into ONE
@@ -1109,10 +1098,6 @@ def main() -> None:
                     help="arm the host hot-loop observatory "
                          "(utils/hostprof.py) for the run and attach its "
                          "snapshot as `host` in the JSON line")
-    ap.add_argument("--no-gc-tune", action="store_true",
-                    help="skip the harness GC tuning (freeze + raised "
-                         "thresholds); default is tuned, reported in "
-                         "`gc_tuned`")
     ap.add_argument("--serve-funnel", action="store_true",
                     help="run the SHARED deployment's balancer-role "
                          "process: TCP bus broker + the one device-"
@@ -1195,7 +1180,7 @@ def main() -> None:
                 rate=args.rate, procs=args.procs, duration=args.duration,
                 p99_bound_ms=args.p99_bound_ms, dist=args.dist,
                 n_invokers=args.invokers, kernel=args.kernel,
-                seed=args.seed, gc_tune=not args.no_gc_tune,
+                seed=args.seed,
                 host_observatory=args.host_observatory)
         else:
             out = sweep_balancer(rate0=args.rate0, duration=args.duration,
@@ -1208,7 +1193,6 @@ def main() -> None:
                                  host_observatory=(True
                                                    if args.host_observatory
                                                    else None),
-                                 gc_tune=not args.no_gc_tune,
                                  fleet_mesh=args.fleet_mesh,
                                  keep_samples=args.emit_samples,
                                  worker_ident=args.worker_ident,
