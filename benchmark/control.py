@@ -9,8 +9,9 @@ then puts the CONTROL in the program's place: the plain reference with one
 stated guarantee broken, driven over the same requests and releases in the
 same order, and prints the numbers the same comparison gives it (the upper
 reading). The control folds a step's releases after its requests instead of
-before (stale books: the later, rarer flush a faster step would be tempted
-by) and has to come out as not correct on every seed of every cell.
+before (stale books, memory and spare permits alike: the later, rarer
+flush a faster step would be tempted by) and has to come out as not correct
+on every seed of every cell.
 """
 from __future__ import annotations
 

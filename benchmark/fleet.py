@@ -3,8 +3,10 @@
 One feed per invoker on the in-memory bus, as `bench._echo_invoker` has it,
 but an activation's completion ack is sent after the ACTION's service time
 from a timer (`loop.call_later`), so a slow activation never blocks its
-invoker's feed. Every delivery and every ack is recorded for the comparison
-that decides `correct`.
+invoker's feed, however many activations one container of the action holds
+at once (an I/O-bound action, the case upstream's docs/concurrency.md is
+for): the invoker has no container model. Every delivery and every ack is
+recorded for the comparison that decides `correct`.
 """
 from __future__ import annotations
 
@@ -27,8 +29,8 @@ class SimFleet:
         self.memory_of = memory_of
         #: activation id -> invoker indices it was delivered to
         self.deliveries: Dict[str, List[int]] = {}
-        #: (invoker, memory MB) of every ack sent
-        self.completions: List[Tuple[int, int]] = []
+        #: (invoker, action's fully qualified name, memory MB) of every ack
+        self.completions: List[Tuple[int, str, int]] = []
         self.ack_errors = 0
         #: set-up's shape ladder: while `hold` is set, deliveries are parked
         #: (whatever the action's service time) and `release_held` acks them
@@ -99,7 +101,7 @@ class SimFleet:
                 EntityPath(str(msg.user.namespace.name)), msg.action.name,
                 msg.user.subject, msg.activation_id, now, now,
                 ActivationResponse.success({"ok": True}), duration=1)
-            self.completions.append((idx, mem))
+            self.completions.append((idx, str(msg.action), mem))
             producer.send_nowait(
                 f"completed{msg.root_controller_index.as_string}",
                 CombinedCompletionAndResultMessage(msg.transid, act, instance)

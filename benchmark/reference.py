@@ -7,15 +7,23 @@ copy of the semantics of `openwhisk_tpu/models/sharding_policy.py` and
 yardstick; it imports nothing of the program): home invoker = hash %
 fleet, probe in a step coprime to the fleet size, first invoker with free
 memory wins, total overload forces a rotation-picked usable invoker and
-over-commits it. Per-action concurrency is 1 in every cell, so an
-invoker's books are its free memory alone; a cell that brings concurrent
-containers brings their books here too.
+over-commits it. An invoker's books have upstream's two levels
+(NestedSemaphore): its free memory, and per ACTION ("<fqn>:<MB>", never the
+program's slot) the spare permits of the containers it holds of an action
+whose concurrency C is over 1. Such an action fits where it has a spare
+permit or the memory for a new container; a new container takes the memory
+and mints C - 1 spares; a release returns one permit, and when C are spare
+again one container's memory goes back. C = 1 is the memory alone.
 
-From the program's run the comparison takes only ORDER and RANDOMNESS: the
-order of step inputs (which activation ids were scheduled in which step,
-which release rows and health flips were folded before it) and the random
-number the program drew for a forced placement. Names and memory of each
-activation come from the benchmark's own catalogue, and the journaled
+From the program's run the comparison takes only ORDER, RANDOMNESS and
+SLOTS: the order of step inputs (which activation ids were scheduled in
+which step, which release rows and health flips were folded before it), the
+random number the program drew for a forced placement, and the slot (the
+column of its dense permit table) each scheduled activation carries. A
+release row names (invoker, slot, MB, maxc); the reference keeps its own
+record of which action holds which slot while activations of it are in
+flight and translates the row through it. Names, memory and concurrency of
+each activation come from the benchmark's own catalogue, and the journaled
 health flips are held to the fleet's own truth: the benchmark's invokers
 are all up and pinging, so `unusable` counts any of them the books hold
 unusable at a step of the window.
@@ -44,12 +52,47 @@ def pairwise_coprimes(x: int) -> List[int]:
     return out or [1]
 
 
+def action_key(fqn: str, mem: int) -> str:
+    """What a pool of concurrent containers is keyed by."""
+    return f"{fqn}:{mem}"
+
+
 class _Invoker:
-    __slots__ = ("free_mb", "usable")
+    """NestedSemaphore's two levels of books for one invoker."""
+    __slots__ = ("free_mb", "usable", "spare")
 
     def __init__(self, free_mb: int, usable: bool):
         self.free_mb = free_mb
         self.usable = usable
+        #: action key -> spare permits of the containers held of it
+        self.spare: Dict[str, int] = {}
+
+    def acquire(self, key: str, mem: int, maxc: int) -> bool:
+        """Take a spare permit (and say so), or else the memory (forced or
+        not: the caller has decided) and, for C > 1, mint C - 1 spares."""
+        if maxc > 1:
+            have = self.spare.get(key, 0)
+            if have > 0:
+                self.spare[key] = have - 1
+                return True
+            self.spare[key] = maxc - 1
+        self.free_mb -= mem
+        return False
+
+    def release(self, key: str, mem: int, maxc: int) -> None:
+        if maxc > 1:
+            have = self.spare.get(key, 0) + 1
+            if have < maxc:
+                self.spare[key] = have
+                return
+            # a whole container is idle again (ResizableSemaphore's
+            # reduction): its permits go, its memory comes back
+            have -= maxc
+            if have:
+                self.spare[key] = have
+            else:
+                self.spare.pop(key, None)
+        self.free_mb += mem
 
 
 class ReferenceFleet:
@@ -62,6 +105,8 @@ class ReferenceFleet:
         #: the CONTROL's broken guarantee: fold a step's releases after
         #: its requests instead of before (stale books)
         self.late_release = late_release
+        #: placements that took a spare permit of a container already held
+        self.shared = 0
         self._steps: List[int] = [1]
 
     def register(self, idx: int, user_memory_mb: int, usable: bool) -> None:
@@ -79,18 +124,23 @@ class ReferenceFleet:
         if 0 <= idx < len(self.invokers):
             self.invokers[idx].usable = usable
 
-    def schedule(self, namespace: str, fqn: str, mem: int,
-                 rand: int) -> Tuple[Optional[int], bool]:
+    def schedule(self, namespace: str, fqn: str, mem: int, rand: int,
+                 maxc: int = 1) -> Tuple[Optional[int], bool]:
         size = self.managed_count
         if size == 0:
             return None, False
+        key = action_key(fqn, mem)
+        pooled = maxc > 1
         h = generate_hash(namespace, fqn)
         step = self._steps[h % len(self._steps)]
         idx = h % size
         for _ in range(size):
             inv = self.invokers[idx]
-            if inv.usable and inv.free_mb >= mem:
-                inv.free_mb -= mem
+            # it fits where there is the memory for a container or, of a
+            # container already held, a spare permit
+            if inv.usable and (inv.free_mb >= mem or (
+                    pooled and inv.spare.get(key, 0) > 0)):
+                self.shared += inv.acquire(key, mem, maxc)
                 return idx, False
             idx = (idx + step) % size
         best = None
@@ -101,15 +151,20 @@ class ReferenceFleet:
                     best = (r, i)
         if best is None:
             return None, False
-        self.invokers[best[1]].free_mb -= mem
+        self.invokers[best[1]].acquire(key, mem, maxc)
         return best[1], True
 
-    def release(self, idx: int, mem: int) -> None:
+    def release(self, idx: int, mem: int, fqn: str = "",
+                maxc: int = 1) -> None:
         if 0 <= idx < len(self.invokers):
-            self.invokers[idx].free_mb += mem
+            self.invokers[idx].release(action_key(fqn, mem), mem, maxc)
 
     def free_mb(self) -> List[int]:
         return [inv.free_mb for inv in self.invokers]
+
+    def spare(self) -> List[Dict[str, int]]:
+        """Per invoker, the spare permits it holds by action key."""
+        return [dict(inv.spare) for inv in self.invokers]
 
     def unusable(self, fleet_size: int) -> int:
         """How many of the `fleet_size` invokers the deployment runs are
@@ -130,30 +185,91 @@ def _parse_mb(size_json) -> int:
     return int(num) * scale // (1 << 20)
 
 
+class _Slots:
+    """The reference's own record of which action holds which of the
+    program's slots while activations of it are in flight."""
+
+    def __init__(self):
+        #: slot -> {(fqn, MB): [concurrency, activations in flight]}
+        self.holding: Dict[int, Dict[Tuple[str, int], list]] = {}
+        #: action key -> the slot it held last; slot -> its last action key
+        self.slot_of: Dict[str, int] = {}
+        self.key_of: Dict[int, str] = {}
+        self.conflicts = 0
+
+    def take(self, slot: int, fqn: str, mem: int, maxc: int) -> None:
+        held = self.holding.setdefault(slot, {})
+        if (fqn, mem) in held:
+            held[fqn, mem][1] += 1
+        else:
+            # two actions in one slot at once: their pools are conflated
+            self.conflicts += bool(held)
+            held[fqn, mem] = [maxc, 1]
+        key = action_key(fqn, mem)
+        self.slot_of[key] = slot
+        self.key_of[slot] = key
+
+    def give(self, slot: int, mem: int) -> Optional[Tuple[str, int]]:
+        """The (fqn, concurrency) of the action a release row of `mem` MB
+        in `slot` belongs to; None where nothing of that size holds it."""
+        held = self.holding.get(slot) or {}
+        for (fqn, mb), entry in held.items():
+            if mb == mem:
+                entry[1] -= 1
+                if entry[1] <= 0:
+                    del held[fqn, mb]
+                return fqn, entry[0]
+        return None
+
+    def permit_table(self, spare: List[Dict[str, int]]) -> Tuple[dict, int]:
+        """The program's dense permit table as these books would fill it:
+        {(invoker, slot): spare permits} of the action that last held each
+        slot, and how many spare pools have no cell to stand in (their
+        action's last slot has gone to another since)."""
+        cells, homeless = {}, 0
+        for i, pools in enumerate(spare):
+            for key, n in pools.items():
+                slot = self.slot_of.get(key)
+                if not n:
+                    continue
+                if slot is not None and self.key_of[slot] == key:
+                    cells[i, slot] = n
+                else:
+                    homeless += 1
+        return cells, homeless
+
+
 def replay(records: Iterable[dict], sent: Dict[str, tuple],
            fleet: ReferenceFleet, fleet_size: int) -> dict:
     """Drive `fleet` through the program's journal in mutation order.
 
-    `sent[aid] = (namespace, fqn, memory_mb)` is the benchmark's own record
-    of what it published, `fleet_size` the invokers its fleet runs.
-    Returns per activation the program's decision and the reference's,
-    every release row, and one entry per dispatch of the placement program
-    (`fused`: a step that scheduled requests; else a release-only fold)."""
+    `sent[aid] = (namespace, fqn, memory_mb, concurrency)` is the
+    benchmark's own record of what it published, `fleet_size` the invokers
+    its fleet runs. Returns per activation the program's decision and the
+    reference's, every release row as (invoker, fqn, MB), and one entry per
+    dispatch of the placement program (`fused`: a step that scheduled
+    requests; else a release-only fold)."""
     records = list(records)
     acks = {int(r["for"]): r["out"] for r in records if r.get("t") == "ack"}
     program: Dict[str, Tuple[int, bool]] = {}
     reference: Dict[str, Tuple[Optional[int], bool]] = {}
     unknown_aids = unacked = input_mismatch = 0
-    releases: List[Tuple[int, int]] = []
+    releases: List[Tuple[int, str, int]] = []
     steps: List[dict] = []
+    slots = _Slots()
 
     def fold_releases(rel: np.ndarray) -> int:
-        inv, _slot, mem, maxc, valid = rel
-        rows = np.flatnonzero(valid)
-        for j in rows:
-            fleet.release(int(inv[j]), int(mem[j]))
-            releases.append((int(inv[j]), int(mem[j])))
-        return int(np.count_nonzero(maxc[rows] != 1))
+        inv, slot, mem, maxc, valid = rel
+        wrong = 0
+        for j in np.flatnonzero(valid):
+            i, mb = int(inv[j]), int(mem[j])
+            fqn, conc = slots.give(int(slot[j]), mb) or ("", 1)
+            # a row no action in flight accounts for gives its memory back
+            # as the program says, and is counted
+            wrong += (not fqn) or int(maxc[j]) != conc
+            fleet.release(i, mb, fqn, conc)
+            releases.append((i, fqn, mb))
+        return wrong
 
     for rec in records:
         t = rec.get("t")
@@ -191,12 +307,14 @@ def replay(records: Iterable[dict], sent: Dict[str, tuple],
                 if meta is None:
                     unknown_aids += 1
                     continue
-                ns, fqn, mem = meta
+                ns, fqn, mem, conc = meta
                 distinct.add(fqn)
-                if int(req[4, col]) != mem or int(req[6, col]) != 1:
+                if int(req[4, col]) != mem or int(req[6, col]) != conc:
                     input_mismatch += 1
-                reference[aid] = fleet.schedule(ns, fqn, mem,
-                                                int(req[7, col]))
+                placed = fleet.schedule(ns, fqn, mem, int(req[7, col]), conc)
+                reference[aid] = placed
+                if placed[0] is not None:
+                    slots.take(int(req[5, col]), fqn, mem, conc)
                 if out is not None:
                     v = int(out[col])
                     program[aid] = ((v >> 2) - 1, bool(v & 1))
@@ -206,10 +324,13 @@ def replay(records: Iterable[dict], sent: Dict[str, tuple],
                           "B": B, "R": R, "distinct": len(distinct),
                           "aids": rec["aids"][:b],
                           "unusable": fleet.unusable(fleet_size)})
+    permits, homeless = slots.permit_table(fleet.spare())
     return {"program": program, "reference": reference, "steps": steps,
             "releases": releases, "unknown_aids": unknown_aids,
             "unacked": unacked, "input_mismatch": input_mismatch,
-            "free_mb": fleet.free_mb()}
+            "slot_conflict": slots.conflicts, "shared": fleet.shared,
+            "free_mb": fleet.free_mb(), "permits": permits,
+            "permits_homeless": homeless}
 
 
 #: every number compared, with its limit; all are exact comparisons
@@ -217,22 +338,42 @@ LIMITS = {
     "lost": 0,             # published, promise never resolved (drain + 60 s)
     "not_once": 0,         # not delivered to exactly one invoker exactly once
     "misdelivered": 0,     # delivered to another invoker than the one chosen
-    "unjournaled": 0,      # published but in no journaled step (or unacked)
+    "unjournaled": 0,      # published but in no journaled step (or unacked),
+                           # or a journaled input differs from the catalogue
     "unusable": 0,         # most invokers held unusable at a window's step
     "decision_mismatch": 0,  # (invoker, forced) differs from the reference
-    "release_mismatch": 0,   # release rows != completions, per invoker and MB
-    "books_mismatch": 0,     # invokers whose final free MB differ
+    "release_mismatch": 0,   # release rows != completions, per invoker,
+                             # action and MB
+    "books_mismatch": 0,     # invokers whose final free MB differ, and cells
+                             # of the permit table that differ
+    "slot_conflict": 0,      # two actions in flight in one slot at once
 }
+
+
+def permit_cells_differing(program_conc_free, replayed: dict) -> int:
+    """Cells of the program's permit table int32[N, A] that differ from the
+    reference's spare permits of the action that last held that slot."""
+    table = np.asarray(program_conc_free)
+    want = np.zeros_like(table)
+    outside = replayed["permits_homeless"]
+    for (i, slot), n in replayed["permits"].items():
+        if i < want.shape[0] and slot < want.shape[1]:
+            want[i, slot] = n
+        else:
+            outside += 1
+    return int(np.count_nonzero(table != want)) + outside
 
 
 def compare(replayed: dict, *, sent: Dict[str, tuple],
             resolved: Dict[str, bool], deliveries: Dict[str, List[int]],
-            completions: List[Tuple[int, int]],
-            program_free_mb: List[int], window_aids: set,
+            completions: List[Tuple[int, str, int]],
+            program_free_mb: List[int], program_conc_free,
+            window_aids: set,
             decisions: Optional[Dict[str, Tuple[int, bool]]] = None) -> dict:
     """The numbers that decide `correct`. `window_aids` are the
-    activations of the timed window; `decisions` replaces the program's
-    journaled decisions (the control hands in its own)."""
+    activations of the timed window; `completions` the fleet's acks as
+    (invoker, fqn, MB); `decisions` replaces the program's journaled
+    decisions (the control hands in its own)."""
     program = decisions if decisions is not None else replayed["program"]
     reference = replayed["reference"]
     lost = sum(1 for aid in sent if not resolved.get(aid))
@@ -259,10 +400,12 @@ def compare(replayed: dict, *, sent: Dict[str, tuple],
     books = sum(1 for i in range(n)
                 if i >= len(program_free_mb)
                 or int(program_free_mb[i]) != ref_free[i])
+    books += permit_cells_differing(program_conc_free, replayed)
     numbers = {"lost": lost, "not_once": not_once,
                "misdelivered": misdelivered, "unjournaled": unjournaled,
                "unusable": unusable, "decision_mismatch": mismatch,
-               "release_mismatch": release_mismatch, "books_mismatch": books}
+               "release_mismatch": release_mismatch, "books_mismatch": books,
+               "slot_conflict": replayed["slot_conflict"]}
     return {"numbers": numbers,
             "correct": all(numbers[k] <= LIMITS[k] for k in LIMITS),
             "compared": len(program)}

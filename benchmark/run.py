@@ -150,9 +150,9 @@ class Sut:
         from openwhisk_tpu.controller.loadbalancer.journal import \
             journal_from_config
         from openwhisk_tpu.core.entity import (
-            ActionLimits, ActivationId, CodeExec, ControllerInstanceId,
-            EntityName, EntityPath, ExecutableWhiskAction, Identity, MB,
-            MemoryLimit, TimeLimit)
+            ActionLimits, ActivationId, CodeExec, ConcurrencyLimit,
+            ControllerInstanceId, EntityName, EntityPath,
+            ExecutableWhiskAction, Identity, MB, MemoryLimit, TimeLimit)
         from openwhisk_tpu.core.entity.ids import DocRevision
         from openwhisk_tpu.messaging import (ActivationMessage,
                                              MemoryMessagingProvider)
@@ -168,6 +168,10 @@ class Sut:
         top = int(cfg["action_memory_max_mb"])
         if MB(top) > MemoryLimit.MAX:
             MemoryLimit.MAX = MB(top)
+        # and its per-action concurrency ceiling (upstream
+        # CONFIG_whisk_concurrencyLimit_max; absent = 1, the feature off)
+        ConcurrencyLimit.MAX = max(
+            ConcurrencyLimit.MAX, int(cfg.get("action_concurrency_max", 1)))
         GLOBAL_WATERFALL.reset()
         provider = MemoryMessagingProvider()
         self.bal = TpuBalancer(
@@ -218,11 +222,12 @@ class Sut:
                 ev[E_INV] = n - 1
                 tel.accumulator.fold(ev)
                 b *= 2
-        for name, mem in zip(cat.names, cat.memory_mb):
+        for name, mem, conc in zip(cat.names, cat.memory_mb, cat.concurrency):
             a = ExecutableWhiskAction(
                 EntityPath(cat.namespace), EntityName(name),
                 CodeExec(kind="python:3", code="x"),
-                limits=ActionLimits(TimeLimit(60_000), MemoryLimit(MB(mem))))
+                limits=ActionLimits(TimeLimit(60_000), MemoryLimit(MB(mem)),
+                                    concurrency=ConcurrencyLimit(conc)))
             a.rev = DocRevision("1-b")
             self._actions.append(a)
         self._ident = Identity.generate(cat.namespace)
@@ -267,7 +272,7 @@ class Sut:
         aid = msg.activation_id.asString
         self.aid[i] = aid
         self.sent[aid] = (cat.namespace, str(action.fully_qualified_name),
-                          cat.memory_mb[rank])
+                          cat.memory_mb[rank], cat.concurrency[rank])
         # as the API handler does: the waterfall starts at the (scheduled)
         # arrival, so its stage deltas telescope to the client's latency
         self._waterfall.begin(aid, t0_ns=self.sched_ns[i])
@@ -464,6 +469,7 @@ async def run_cell(res: dict, seed: int, seconds: float, trace: bool,
             break
     await asyncio.sleep(0.5)
     program_free = [int(v) for v in np.asarray(sut.bal.state.free_mb)]
+    program_conc = np.asarray(sut.bal.state.conc_free)
     peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
                for d in jax.devices()[:res["cell"]["chips"]])
     geometry = {"N": int(sut.bal._n_pad), "A": int(sut.bal.action_slots),
@@ -485,7 +491,8 @@ async def run_cell(res: dict, seed: int, seconds: float, trace: bool,
     observed = {"sent": sut.sent, "resolved": resolved,
                 "deliveries": sut.fleet.deliveries,
                 "completions": sut.fleet.completions,
-                "program_free_mb": program_free, "window_aids": win_aids}
+                "program_free_mb": program_free,
+                "program_conc_free": program_conc, "window_aids": win_aids}
     verdict = reference.compare(replayed, **observed)
     ref_s = time.monotonic() - t_ref0
 
@@ -545,7 +552,9 @@ async def run_cell(res: dict, seed: int, seconds: float, trace: bool,
         "seed": seed, "workload": res["cell"]["name"], "geometry": geometry,
         "window_s": window_s, "drained_s": round(drained_s, 3),
         "never_answered": len(pending), "reference_s": round(ref_s, 3),
-        "compared": verdict["compared"], "journal_records": len(records),
+        "compared": verdict["compared"],
+        "placed_on_a_spare_permit": replayed["shared"],
+        "journal_records": len(records),
         "compiles_total": compiles["n"],
         "compiles_in_window": compiles["in_window"],
         "lowerings_in_window": compiles["lowered_in_window"],

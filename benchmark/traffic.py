@@ -3,9 +3,12 @@
 Every seed gets the SAME multiset of work in another order, so that runs
 with different seeds measure the same load:
 
-* the action catalogue (rank -> memory, service time) is a fixed quantile
-  set of the config's distributions, dealt to ranks by the config's own
-  `catalog_seed`, never by `--seed`;
+* the action catalogue (rank -> memory, service time, per-action
+  concurrency) is a fixed quantile set of the config's distributions, dealt
+  to ranks by the config's own `catalog_seed`, never by `--seed`.
+  `actions.concurrency` is an int (every action) or `{"values": [...],
+  "weights": [...]}`, dealt from a random stream of its own, so a
+  configuration that states one concurrency keeps its catalogue bit for bit;
 * `--seed` picks the action names (so their home invokers and probe
   steps), the order of the request sequence and the order of the arrival
   gaps;
@@ -33,6 +36,7 @@ class Catalog:
     names: List[str]          # by popularity rank, hottest first
     memory_mb: List[int]
     service_s: List[float]
+    concurrency: List[int]    # activations one container of the action holds
 
 
 def _rng(seed: int, stream: int) -> np.random.Generator:
@@ -52,11 +56,6 @@ def _exact_counts(weights, n: int) -> np.ndarray:
 
 def make_catalog(config: dict, seed: int) -> Catalog:
     spec = config["actions"]
-    if int(spec["concurrency"]) != 1:
-        # concurrent containers need a ConcurrencyLimit on the action and
-        # their books in the plain reference: the cell that needs them
-        # brings both
-        raise ValueError("per-action concurrency other than 1")
     n = int(spec["count"])
     deal = _rng(int(config["catalog_seed"]), 1)
     mem = np.repeat(np.asarray(spec["memory_mb"], np.int64),
@@ -73,12 +72,24 @@ def make_catalog(config: dict, seed: int) -> Catalog:
         service = np.clip(float(svc["median"]) * np.exp(float(svc["sigma"]) * z),
                           float(svc["min"]), float(svc["max"])) / 1e3
         deal.shuffle(service)
+    conc = spec["concurrency"]
+    if isinstance(conc, dict):
+        conc = np.repeat(np.asarray(conc["values"], np.int64),
+                         _exact_counts(conc["weights"], n))
+        _rng(int(config["catalog_seed"]), 5).shuffle(conc)
+    else:
+        conc = np.full(n, int(conc), np.int64)
+    top = int(config.get("action_concurrency_max", 1))
+    if conc.min() < 1 or conc.max() > top:
+        raise ValueError(f"actions.concurrency outside [1, {top}], the "
+                         "deployment's action_concurrency_max")
     tag = f"{int(seed):x}"
     return Catalog(
         namespace=str(config["namespace"]),
         names=[f"a{tag}x{k}" for k in range(n)],
         memory_mb=[int(m) for m in mem],
-        service_s=[float(s) for s in service])
+        service_s=[float(s) for s in service],
+        concurrency=[int(c) for c in conc])
 
 
 def popularity(mix: dict, n_actions: int) -> np.ndarray:

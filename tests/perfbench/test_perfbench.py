@@ -4,6 +4,7 @@ the comparison that decides `correct` with its control and its planted
 faults, and one toy run end to end on the CPU twin. No assertion here is on
 a time or a rate."""
 import asyncio
+import copy
 import json
 import os
 import re
@@ -19,9 +20,10 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 
 
 def _toy_root(tmp_path) -> str:
-    """A temp copy of the benchmark's data files plus a toy configuration,
-    two toy cells and a new metric with a reader of its own: files added,
-    none edited."""
+    """A temp copy of the benchmark's data files plus two toy
+    configurations (`toyc`: the same fleet with concurrent containers),
+    their toy cells and a new metric with a reader of its own: files
+    added, none edited."""
     root = str(tmp_path)
     for sub in ("configs", "traffic", "metrics", "readers"):
         shutil.copytree(os.path.join(BENCH, sub),
@@ -34,6 +36,11 @@ def _toy_root(tmp_path) -> str:
     cfg["actions"]["count"] = 48
     cfg["actions"]["service_ms"].update(median=20, min=5, max=120)
     _dump(root, "benchmark/configs/toy.json", cfg)
+    cfg = copy.deepcopy(cfg)
+    cfg.update(name="toyc", action_concurrency_max=8)
+    cfg["actions"]["concurrency"] = {"values": [1, 2, 5],
+                                     "weights": [1, 1, 1]}
+    _dump(root, "benchmark/configs/toyc.json", cfg)
     base = {"popularity": {"dist": "zipf", "exponent": 1.0},
             "warm_seconds": 0.5, "drain_seconds": 10}
     _dump(root, "benchmark/traffic/toy-closed.json",
@@ -44,17 +51,19 @@ def _toy_root(tmp_path) -> str:
           {"reader": "steps_seen", "args": {}})
     with open(os.path.join(root, "benchmark/readers/steps_seen.py"), "w") as f:
         f.write("def read(art):\n    return float(len(art['steps'])) or None\n")
-    manifest["configs"].append({"name": "toy", "source": "test", "why": "t",
-                                "file": "benchmark/configs/toy.json",
-                                "reduced": []})
-    for loop, e2e in (("closed", "completed_per_s"),
-                      ("open", "overhead_p50_ms")):
+    for name in ("toy", "toyc"):
+        manifest["configs"].append({"name": name, "source": "test",
+                                    "why": "t", "reduced": [],
+                                    "file": f"benchmark/configs/{name}.json"})
+    for name, loop, e2e in (("toy", "closed", "completed_per_s"),
+                            ("toy", "open", "overhead_p50_ms"),
+                            ("toyc", "closed", "completed_per_s")):
         manifest["workloads"].append(
-            {"name": f"toy-{loop}", "config": "toy", "traffic": f"toy-{loop}",
-             "chips": 1, "why": "t"})
+            {"name": f"{name}-{loop}", "config": name,
+             "traffic": f"toy-{loop}", "chips": 1, "why": "t"})
         for m in manifest["end_to_end"]:
             if m["name"] == e2e:
-                m["workloads"].append(f"toy-{loop}")
+                m["workloads"].append(f"{name}-{loop}")
     manifest["per_layer"].append(
         {"name": "steps_seen.closed", "unit": "steps", "better": "higher",
          "source": "program_counter", "layer": "admission and batch assembly",
@@ -85,10 +94,26 @@ def short_shape_ladder():
     patch.undo()
 
 
+@pytest.fixture(scope="module", autouse=True)
+def limits_as_they_were():
+    """`Sut.start` raises the program's class-constant ceilings to the
+    deployment's; hand them back to the tests of other files."""
+    from openwhisk_tpu.core.entity import ConcurrencyLimit, MemoryLimit
+    was = MemoryLimit.MAX, ConcurrencyLimit.MAX
+    yield
+    MemoryLimit.MAX, ConcurrencyLimit.MAX = was
+
+
 @pytest.fixture(scope="module")
 def toy(tmp_path_factory):
     root = _toy_root(tmp_path_factory.mktemp("toy"))
     return (root,) + _run(root, "toy-closed")
+
+
+@pytest.fixture(scope="module")
+def toyc(tmp_path_factory):
+    root = _toy_root(tmp_path_factory.mktemp("toyc"))
+    return (root,) + _run(root, "toyc-closed")
 
 
 # -- the data files and the lookup ------------------------------------------
@@ -285,6 +310,26 @@ def test_the_control_comes_out_as_not_correct(toy):
     assert out["verdict"]["numbers"]["decision_mismatch"] == 0
 
 
+def test_mixed_concurrency_toy_run_and_its_control(toyc):
+    _root, res, device, out = toyc
+    assert sorted(set(out["observed"]["sent"][a][3]
+                      for a in out["observed"]["sent"])) == [1, 2, 5]
+    line = run.build_result(res, out, False, device)
+    assert line["correct"] is True and line["failed"] == 0
+    assert list(line["checked"]) == list(reference.LIMITS)
+    assert [v["value"] for v in line["checked"].values()] == [0] * 9
+    # the second level of books did decide: containers were shared
+    reqs = sum(s["b"] for s in out["replayed"]["steps"])
+    assert reqs == out["verdict"]["compared"] > line["attempted"] > 0
+    assert 0 < out["replayed"]["shared"] < reqs
+    # after the drain no container is held: both tables are noughts
+    assert not np.count_nonzero(out["observed"]["program_conc_free"])
+    assert not out["replayed"]["permits"]
+    ctl = control.control_verdict(out, res["config"])
+    assert ctl["correct"] is False
+    assert ctl["numbers"]["decision_mismatch"] > 0
+
+
 def _state_unchanged(sut):
     real = sut.bal._packed_fn
 
@@ -321,15 +366,61 @@ def _an_invoker_held_unusable(sut):
     sut.bal._health_updates[3] = False
 
 
-@pytest.mark.parametrize("fault,number", [
-    (_state_unchanged, "decision_mismatch"),
-    (_answer_altered, "decision_mismatch"),
-    (_half_the_releases_left_out, "release_mismatch"),
-    (_an_invoker_held_unusable, "unusable"),
+def _spare_permits_ignored(sut):
+    # every step starts from a permit table of noughts: a container never
+    # takes a second activation once its step is over
+    real = sut.bal._packed_fn
+
+    def blind(state, *args):
+        return real(state._replace(conc_free=state.conc_free * 0), *args)
+    sut.bal._packed_fn = blind
+
+
+def _release_rows_say_concurrency_one(sut):
+    real = sut.bal._queue_release
+
+    def flat(inv, slot, mem, _maxc, key):
+        real(inv, slot, mem, 1, key)
+    sut.bal._queue_release = flat
+
+
+def _two_keys_handed_one_slot(sut):
+    # the allocator keeps its own count; every key is told slot 7
+    real = sut.bal._slots.acquire
+
+    def same(key):
+        real(key)
+        return 7
+    sut.bal._slots.acquire = same
+
+
+def _one_permit_never_returned(sut):
+    real, done = sut.bal._packed_fn, []
+
+    def leaky(state, *args):
+        new, out = real(state, *args)
+        if not done:
+            done.append(1)
+            new = new._replace(conc_free=new.conc_free.at[2, 9].add(-1))
+        return new, out
+    sut.bal._packed_fn = leaky
+
+
+@pytest.mark.parametrize("fault,number,cell", [
+    (_state_unchanged, "decision_mismatch", "toy-closed"),
+    (_answer_altered, "decision_mismatch", "toy-closed"),
+    (_half_the_releases_left_out, "release_mismatch", "toy-closed"),
+    (_an_invoker_held_unusable, "unusable", "toy-closed"),
+    (_state_unchanged, "decision_mismatch", "toyc-closed"),
+    (_spare_permits_ignored, "decision_mismatch", "toyc-closed"),
+    (_release_rows_say_concurrency_one, "unjournaled", "toyc-closed"),
+    (_two_keys_handed_one_slot, "slot_conflict", "toyc-closed"),
+    (_one_permit_never_returned, "books_mismatch", "toyc-closed"),
 ])
-def test_a_broken_timed_path_comes_out_as_not_correct(tmp_path, fault, number):
+def test_a_broken_timed_path_comes_out_as_not_correct(tmp_path, fault, number,
+                                                      cell):
     root = _toy_root(tmp_path)
-    res, device, out = _run(root, "toy-closed", faults=fault)
+    res, device, out = _run(root, cell, faults=fault)
     line = run.build_result(res, out, False, device)
     assert line["correct"] is False
     assert line["checked"][number]["value"] > 0
