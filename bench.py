@@ -78,10 +78,10 @@ def _build_fused(kernel: str):
         interpret = jax.default_backend() == "cpu"
 
         def sched(st, b):
-            ts, chosen, forced = schedule_batch_pallas(
+            ts, *out = schedule_batch_pallas(
                 to_transposed(st), b, interpret=interpret)
             return (PlacementState(ts.free_mb, ts.conc_free.T, ts.health),
-                    chosen, forced)
+                    *out)
 
         return make_fused_step(None, sched)
     if kernel == "pallas_repair":
@@ -91,10 +91,10 @@ def _build_fused(kernel: str):
         interpret = jax.default_backend() == "cpu"
 
         def sched(st, b):
-            ts, chosen, forced, rounds = schedule_batch_repair_pallas(
+            ts, *out = schedule_batch_repair_pallas(
                 to_transposed(st), b, interpret=interpret)
             return (PlacementState(ts.free_mb, ts.conc_free.T, ts.health),
-                    chosen, forced, rounds)
+                    *out)
 
         return make_fused_step(release_batch_vector, sched)
     if kernel == "repair":
@@ -128,7 +128,7 @@ def _bench_kernel(kernel: str, n_invokers: int = N_INVOKERS,
 
     def step(carry):
         state, rel_inv, rel_ok = carry
-        state, chosen, forced, _rounds = fused(
+        state, chosen, forced, _warm, _rounds = fused(
             state, rel_inv, batch.conc_slot, batch.need_mb, batch.max_conc,
             rel_ok, hidx, hval, hmask, batch)
         return (state, jnp.clip(chosen, 0), chosen >= 0), chosen
@@ -186,10 +186,10 @@ def _parity_check(n_invokers: int = 512, action_slots: int = 128) -> bool:
         fused = _build_fused(kernel)
         # two steps: the second exercises release-fold + scheduling on
         # non-trivial books
-        state, chosen1, forced1, _ = fused(
+        state, chosen1, forced1, _, _ = fused(
             state, rel_inv, batch.conc_slot, batch.need_mb, batch.max_conc,
             no_rel, hidx, hval, hmask, batch)
-        state, chosen2, forced2, _ = fused(
+        state, chosen2, forced2, _, _ = fused(
             state, jnp.clip(chosen1, 0), batch.conc_slot, batch.need_mb,
             batch.max_conc, chosen1 >= 0, hidx, hval, hmask, batch)
         outs[kernel] = tuple(np.asarray(x) for x in
@@ -2599,7 +2599,7 @@ def _repair_parity_rounds(batch_size: int, n_invokers: int = 1024,
         rel_ok = jnp.zeros((batch_size,), bool)
         acc = []
         for _ in range(steps):
-            state, chosen, forced, r = fused(
+            state, chosen, forced, _warm, r = fused(
                 state, rel_inv, batch.conc_slot, batch.need_mb,
                 batch.max_conc, rel_ok, hidx, hval, hmask, batch)
             acc.append((np.asarray(chosen), np.asarray(forced)))
@@ -2879,7 +2879,7 @@ def _fleet_sweep_row(mesh, fleet: int, batch_size: int, iters: int,
         rel_ok = jnp.zeros((batch_size,), bool)
         acc = []
         for _ in range(2):
-            st, chosen, forced, r = fused(
+            st, chosen, forced, _warm, r = fused(
                 st, rel_inv, batch.conc_slot, batch.need_mb,
                 batch.max_conc, rel_ok, hidx, hval, hmask, batch)
             acc.append((np.asarray(chosen), np.asarray(forced), int(r)))
@@ -2900,7 +2900,7 @@ def _fleet_sweep_row(mesh, fleet: int, batch_size: int, iters: int,
 
     def step(carry):
         st, rel_inv, rel_ok = carry
-        st, chosen, forced, _r = fused_sh(
+        st, chosen, forced, _warm, _r = fused_sh(
             st, rel_inv, batch.conc_slot, batch.need_mb, batch.max_conc,
             rel_ok, hidx, hval, hmask, batch)
         return (st, jnp.clip(chosen, 0), chosen >= 0), chosen

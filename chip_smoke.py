@@ -334,10 +334,11 @@ def leg_c_child() -> None:
                 p = pallas_fn(to_transposed(state), batch, penalty=pen)
                 same = (np.array_equal(x[1], p[1])
                         and np.array_equal(x[2], p[2])
+                        and np.array_equal(x[3], p[3])
                         and np.array_equal(x[0].free_mb, p[0].free_mb)
                         and np.array_equal(x[0].conc_free,
                                            np.asarray(p[0].conc_free).T)
-                        and (len(x) < 4 or int(x[3]) == int(p[3])))
+                        and (len(x) < 5 or int(x[4]) == int(p[4])))
                 check(same, f"{pallas_fn.__name__} != {xla_fn.__name__} at "
                       f"{n}x{a} b={b} penalized={pen is not None}")
                 cases += 1

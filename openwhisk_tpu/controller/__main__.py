@@ -15,7 +15,8 @@ import asyncio
 import json
 import sys
 
-from ..core.entity import ControllerInstanceId, ExecManifest, WhiskAuthRecord
+from ..core.entity import (ControllerInstanceId, ExecManifest,
+                           WhiskAuthRecord, limits_from_config)
 from ..database import open_store
 from ..messaging import provider_for_bus
 from ..utils.config import DeviceError, boot_jax, config_from_env
@@ -92,6 +93,7 @@ def main() -> None:
         controller = snapshotter = journal = None
         try:
             ExecManifest.initialize()
+            limits_from_config()
             provider = provider_for_bus(args.bus)
             store = open_store(args.db)
             instance = ControllerInstanceId(args.instance)

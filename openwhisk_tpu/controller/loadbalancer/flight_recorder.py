@@ -32,11 +32,14 @@ from typing import Dict, List, Optional, Sequence, Tuple
 from ...utils.config import load_config
 from ...utils.ring_buffer import SeqRingBuffer
 
-#: decision-row tuple layout (kept a tuple, not a dict, on the hot path)
-D_AID, D_ACTION, D_CHOSEN, D_INVOKER, D_FORCED, D_THROTTLED, D_SLOT_MB = \
-    range(7)
+#: decision-row tuple layout (kept a tuple, not a dict, on the hot path);
+#: the TPU balancer's rows carry an eighth field, the kernels' warm bit
+#: (placed on a spare permit of a container the invoker already holds) —
+#: the CPU balancers' rows have seven
+D_AID, D_ACTION, D_CHOSEN, D_INVOKER, D_FORCED, D_THROTTLED, D_SLOT_MB, \
+    D_WARM = range(8)
 
-DecisionRow = Tuple[str, str, int, Optional[str], bool, bool, int]
+DecisionRow = Tuple  # 7 fields, or 8 with D_WARM
 
 
 @dataclass(frozen=True)
@@ -65,7 +68,7 @@ class BatchRecord:
 
     @staticmethod
     def decision_json(row: DecisionRow) -> dict:
-        return {
+        out = {
             "activation_id": row[D_AID],
             "action": row[D_ACTION],
             "invoker_index": row[D_CHOSEN],
@@ -74,6 +77,9 @@ class BatchRecord:
             "throttled": row[D_THROTTLED],
             "slot_mb": row[D_SLOT_MB],
         }
+        if len(row) > D_WARM:
+            out["warm"] = row[D_WARM]
+        return out
 
     def to_json(self, with_decisions: bool = True) -> dict:
         out = {

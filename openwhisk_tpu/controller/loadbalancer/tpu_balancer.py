@@ -56,7 +56,8 @@ from ...ops.placement import (PlacementState, RequestBatch, init_state,
                               make_shadow_step_packed,
                               release_batch, release_batch_vector,
                               schedule_batch, schedule_batch_repair,
-                              set_health, unpack_chosen, unpack_step_output)
+                              journal_words, set_health, unpack_chosen,
+                              unpack_step_output, unpack_warm)
 from .journal import decode_array, encode_array
 from ...ops.throttle import init_buckets
 from ...utils.config import device_info, load_config
@@ -267,17 +268,17 @@ def _pallas_pair(placement_kernel: str):
 
     @jax.jit
     def sched_scan(st, batch):
-        ts, chosen, forced = schedule_batch_pallas(
+        ts, *out = schedule_batch_pallas(
             to_transposed(st), batch, interpret=interpret)
         return (PlacementState(ts.free_mb, ts.conc_free.T, ts.health),
-                chosen, forced)
+                *out)
 
     @jax.jit
     def sched_repair(st, batch):
-        ts, chosen, forced, rounds = schedule_batch_repair_pallas(
+        ts, *out = schedule_batch_repair_pallas(
             to_transposed(st), batch, interpret=interpret)
         return (PlacementState(ts.free_mb, ts.conc_free.T, ts.health),
-                chosen, forced, rounds)
+                *out)
 
     sched_scan._pallas_kind = "scan"
     sched_repair._pallas_kind = "repair"
@@ -2645,7 +2646,7 @@ class TpuBalancer(CommonLoadBalancer):
         detail: dict = {"b": b, "aids": rec.get("aids") or [],
                         "acked": ack is not None, "mismatches": 0}
         if ack is not None:
-            derived = np.asarray(out)[:b].astype(np.int64)
+            derived = journal_words(np.asarray(out)[:b].astype(np.int64))
             recorded = np.asarray(ack["out"], np.int64)[:b]
             thr = ((recorded >> 1) & 1).astype(bool)
             mism = int(np.count_nonzero(derived[~thr] != recorded[~thr]))
@@ -3289,8 +3290,8 @@ class TpuBalancer(CommonLoadBalancer):
     def _read_back(self, out):
         """Device->host conversion seam (runs on the worker thread);
         a separate method so tests can inject readback failures. The packed
-        step returns B+1 elements: B decisions + the trailing repair-round
-        count (0 for scan/pallas/sharded kernels)."""
+        step returns B+1 elements: B decision words + the trailing
+        repair-round count (0 for scan/pallas/sharded kernels)."""
         return unpack_step_output(np.asarray(out))
 
     async def _readback_step(self, batch, b, out, t0, req_np, rec=None,
@@ -3306,6 +3307,15 @@ class TpuBalancer(CommonLoadBalancer):
         def _read_spanned():
             t_r0 = time.monotonic()
             arrs = self._read_back(out)
+            # the kernels' exact warm bit, off the host copy `_read_back`
+            # just made (the seam's 4-tuple stays what tests inject), and
+            # the step's counts of rows placed on a spare permit of a
+            # container already there and of rows forced: counted here, off
+            # the loop; both count every journaled step's rows, abandoned
+            # ones included
+            warm = unpack_warm(np.asarray(out)[:-1])
+            counts = (int(np.count_nonzero(warm[:b])),
+                      int(np.count_nonzero(arrs[1][:b])))
             t_r1 = time.monotonic()
             rb_ms = (t_r1 - t_r0) * 1e3
             self.metrics.histogram("loadbalancer_tpu_readback_ms", rb_ms)
@@ -3354,10 +3364,11 @@ class TpuBalancer(CommonLoadBalancer):
                         self.logger.warn(
                             None, f"quality summary failed: {e!r}",
                             "TpuBalancer")
-            return arrs, t_r1, free_np
+            return arrs, warm, counts, t_r1, free_np
 
         try:
-            (chosen_np, forced_np, throttled_np, rounds), t_done, books_np = \
+            (chosen_np, forced_np, throttled_np, rounds), warm_np, \
+                (n_warm, n_forced), t_done, books_np = \
                 await asyncio.to_thread(_read)
             with span("ow_readback_resume", seq=seq):
                 self._install_books(books_np, books_seq)
@@ -3366,7 +3377,8 @@ class TpuBalancer(CommonLoadBalancer):
                     # dispatch-time batch record: replay asserts parity
                     # against it, and the throttled bits tell replay which
                     # requests the device rate admission rejected (they
-                    # consumed no capacity)
+                    # consumed no capacity); the layout is the journal's
+                    # own (placement.journal_words: no warm bit)
                     enc = (((chosen_np[:b].astype(np.int64) + 1) << 2)
                            | (throttled_np[:b].astype(np.int64) << 1)
                            | forced_np[:b].astype(np.int64))
@@ -3421,7 +3433,7 @@ class TpuBalancer(CommonLoadBalancer):
                                   f"(compensated={compensated})",
                                   "TpuBalancer")
             return
-        with span("ow_fanout", seq=seq, b=b):
+        with span("ow_fanout", seq=seq, b=b, warm=n_warm, forced=n_forced):
             self._set_inflight(-1)
             self._capacity_free.set()
             wf = self.waterfall
@@ -3432,6 +3444,7 @@ class TpuBalancer(CommonLoadBalancer):
             self.metrics.histogram("loadbalancer_tpu_schedule_batch_ms",
                                    dt_ms)
             self.metrics.counter("loadbalancer_tpu_scheduled", b)
+            self.metrics.counter("loadbalancer_tpu_warm_placements", n_warm)
             if self.placement_kernel_resolved == "repair" and rounds > 0:
                 # how many speculate-commit rounds the batch actually cost
                 # — the knob's health signal (repair pays off iff this
@@ -3459,10 +3472,10 @@ class TpuBalancer(CommonLoadBalancer):
             fanout_ms = (time.monotonic() - t_f0) * 1e3
         with span("ow_record", seq=seq):
             self._record_step(rec, batch, chosen_np, forced_np,
-                              throttled_np, fanout_ms, dt_ms, b)
+                              throttled_np, warm_np, fanout_ms, dt_ms, b)
 
     def _record_step(self, rec, batch, chosen_np, forced_np, throttled_np,
-                     fanout_ms: float, dt_ms: float, b: int) -> None:
+                     warm_np, fanout_ms: float, dt_ms: float, b: int) -> None:
         """What the profiler, the flight recorder and the trace store take
         from one read-back micro-batch (the `ow_record` span)."""
         prof = self.profiler
@@ -3475,7 +3488,8 @@ class TpuBalancer(CommonLoadBalancer):
             # are filed only for slow batches (a live capture window takes
             # everything); skipped batches still refresh the gauges
             self._record_batch(rec, batch, chosen_np, forced_np, throttled_np,
-                               fanout_ms, file=prof.admit_batch(dt_ms))
+                               warm_np, fanout_ms,
+                               file=prof.admit_batch(dt_ms))
             # after the record files: the device span's batch_seq tag is
             # the assigned ring seq (the join key /admin/trace ships)
             self._trace_batch_hooks(rec, batch, forced_np, dt_ms, b)
@@ -3497,7 +3511,9 @@ class TpuBalancer(CommonLoadBalancer):
         the `divergent` mark when the shadow counterfactual disagreed,
         the `exemplar` force-keep (the phase histogram just pinned this
         trace id onto a bucket line — every rendered exemplar must
-        resolve), and the `forced` mark per force-placed row."""
+        resolve), and the `forced` mark per force-placed row. A mark is
+        a reason to KEEP a trace, so a warm placement gets none: a kept
+        trace reads `warm` off its flight-recorder row (`placement`)."""
         from ...utils.tracestore import GLOBAL_TRACE_STORE, synthetic_span
         store = GLOBAL_TRACE_STORE
         if not store.active:
@@ -3520,7 +3536,7 @@ class TpuBalancer(CommonLoadBalancer):
                 store.mark(e[6], "forced")
 
     def _record_batch(self, rec, batch, chosen_np, forced_np, throttled_np,
-                      fanout_ms: float, file: bool = True) -> None:
+                      warm_np, fanout_ms: float, file: bool = True) -> None:
         """Finish and file the flight-recorder record for one micro-batch,
         and refresh the introspection gauges. `file=False` (tail-sampled
         fast batch) refreshes the gauges without ringing the record."""
@@ -3529,13 +3545,14 @@ class TpuBalancer(CommonLoadBalancer):
         if file:
             n_reg = len(self._registry)
             decisions = rec.decisions
-            for (req, fut, slot_key, t_enq, aid, act, _tid, *_), ci, f, thr \
-                    in zip(batch, chosen_np, forced_np, throttled_np):
+            for (req, fut, slot_key, t_enq, aid, act, _tid, *_), ci, f, thr, \
+                    w in zip(batch, chosen_np, forced_np, throttled_np,
+                             warm_np):
                 ci = int(ci)
                 name = (self._registry[ci].as_string
                         if 0 <= ci < n_reg else None)
                 decisions.append((aid, act, ci, name, bool(f), bool(thr),
-                                  req[self.R_NEED_MB]))
+                                  req[self.R_NEED_MB], bool(w)))
             fr.record(rec)
         m = self.metrics
         d = rec.digest

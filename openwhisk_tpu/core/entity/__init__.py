@@ -8,7 +8,7 @@ from .names import (DEFAULT_NAMESPACE, EntityName, EntityPath,
                     FullyQualifiedEntityName)
 from .parameters import MalformedEntity, Parameters, ParameterValue
 from .limits import (ActionLimits, ConcurrencyLimit, LimitViolation, LogLimit,
-                     MemoryLimit, TimeLimit)
+                     MemoryLimit, TimeLimit, limits_from_config)
 from .exec import (BLACKBOX_KIND, SEQUENCE_KIND, BlackBoxExec, CodeExec, Exec,
                    ExecMetaData, SequenceExec)
 from .manifest import (DEFAULT_MANIFEST_JSON, ExecManifest, ImageName,

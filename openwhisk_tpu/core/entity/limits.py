@@ -4,14 +4,18 @@ Refs: MemoryLimit.scala:49-51, TimeLimit.scala:54-56, LogLimit.scala,
 ConcurrencyLimit.scala:51-53, ActionLimits.scala. Defaults mirror the
 reference's application.conf:368-394 (memory 128-512 MB std 256; time
 100 ms - 5 min std 1 min; logs 0-10 MB std 10 MB; concurrency 1-1 std 1 —
-intra-container concurrency is opt-in by raising `ConcurrencyLimit.MAX`).
-All are class-configurable the way the reference reads them from config.
+intra-container concurrency is opt-in: a deployment sets
+`CONFIG_whisk_concurrencyLimit_max`). Memory and concurrency bounds are
+read from the reference's configuration names at boot
+(`limits_from_config`, called by the controller, invoker and standalone
+entry points); the class constants below are their defaults.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
 from typing import Optional
 
+from ...utils.config import load_config
 from .parameters import MalformedEntity
 from .size import MB, ByteSize
 
@@ -139,16 +143,18 @@ class ConcurrencyLimit:
     process at once. Disabled (max=1) by default, exactly as the reference."""
     MIN = 1
     STD = 1
-    MAX = 1  # deployments raise this to opt in (e.g. 500)
+    #: a deployment opts in at boot with CONFIG_whisk_concurrencyLimit_max
+    #: (and _min / _std), read by `limits_from_config`
+    MAX = 1
 
     __slots__ = ("max_concurrent",)
 
     def __init__(self, concurrency: Optional[int] = None):
         c = concurrency if concurrency is not None else self.STD
         if c < self.MIN:
-            raise LimitViolation(f"concurrency {c} below allowed threshold {self.MIN}")
+            raise LimitViolation(f"concurrency {c} below allowed threshold of {self.MIN}")
         if c > self.MAX:
-            raise LimitViolation(f"concurrency {c} exceeds allowed threshold {self.MAX}")
+            raise LimitViolation(f"concurrency {c} exceeds allowed threshold of {self.MAX}")
         self.max_concurrent = c
 
     def to_json(self):
@@ -163,6 +169,43 @@ class ConcurrencyLimit:
 
     def __repr__(self):
         return str(self.max_concurrent)
+
+
+@dataclass(frozen=True)
+class ConcurrencyLimitConfig:
+    """`whisk.concurrency-limit` (ref application.conf:390-394):
+    CONFIG_whisk_concurrencyLimit_{min,std,max}."""
+    min: int = 1
+    std: int = 1
+    max: int = 1
+
+
+@dataclass(frozen=True)
+class MemoryLimitConfig:
+    """`whisk.memory` (ref application.conf:376-380), sizes as the
+    reference writes them ("512 m") or as this repo does ("512 MB"):
+    CONFIG_whisk_memory_{min,std,max}."""
+    min: str = "128 m"
+    std: str = "256 m"
+    max: str = "512 m"
+
+
+def limits_from_config() -> None:
+    """Set `ConcurrencyLimit` and `MemoryLimit`'s bounds from the
+    deployment's configuration; without it they are the reference's
+    defaults. Called once at boot, before any action is read or written."""
+    c = load_config(ConcurrencyLimitConfig, env_path="concurrency_limit")
+    if not 1 <= c.min <= c.std <= c.max:
+        raise ValueError(f"whisk.concurrency-limit wants 1 <= min <= std "
+                         f"<= max, got {c.min}/{c.std}/{c.max}")
+    ConcurrencyLimit.MIN, ConcurrencyLimit.STD, ConcurrencyLimit.MAX = \
+        c.min, c.std, c.max
+    m = load_config(MemoryLimitConfig, env_path="memory")
+    lo, std, hi = (ByteSize.from_string(v) for v in (m.min, m.std, m.max))
+    if not MB(1) <= lo <= std <= hi:
+        raise ValueError(f"whisk.memory wants 1 MB <= min <= std <= max, "
+                         f"got {lo}/{std}/{hi}")
+    MemoryLimit.MIN, MemoryLimit.STD, MemoryLimit.MAX = lo, std, hi
 
 
 @dataclass

@@ -9,7 +9,8 @@ import re
 from functools import total_ordering
 
 _UNITS = {"B": 1, "KB": 1024, "MB": 1024**2, "GB": 1024**3, "TB": 1024**4}
-_RX = re.compile(r"^\s*(\d+(?:\.\d+)?)\s*([KMGT]?B)?\s*$", re.IGNORECASE)
+#: the unit's B is optional: the reference's application.conf writes "512 m"
+_RX = re.compile(r"^\s*(\d+(?:\.\d+)?)\s*([KMGT]?)B?\s*$", re.IGNORECASE)
 
 
 @total_ordering
@@ -27,7 +28,7 @@ class ByteSize:
         m = _RX.match(s)
         if not m:
             raise ValueError(f"invalid size string {s!r} (want e.g. '256 MB')")
-        return cls(float(m.group(1)), (m.group(2) or "B"))
+        return cls(float(m.group(1)), m.group(2) + "B")
 
     @property
     def to_kb(self) -> int:

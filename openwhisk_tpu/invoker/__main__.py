@@ -14,7 +14,8 @@ import asyncio
 
 from ..containerpool import ContainerPoolConfig
 from ..containerpool.factory import FACTORY_PROVIDERS
-from ..core.entity import ExecManifest, InvokerInstanceId, MB
+from ..core.entity import (ExecManifest, InvokerInstanceId, MB,
+                           limits_from_config)
 from ..database import ArtifactActivationStore, EntityStore, open_store
 from ..messaging import provider_for_bus
 from ..utils.logging import Logging
@@ -50,6 +51,7 @@ def main() -> None:
         invoker = server = None
         try:
             ExecManifest.initialize()
+            limits_from_config()
             provider = provider_for_bus(args.bus)
             store = open_store(args.db)
             instance_id = await InstanceIdAssigner(store).assign(

@@ -23,10 +23,9 @@ EWMAs and the post-commit capacity books), emitting three quantities:
                `1 - free/cap` over healthy, non-padding invokers): 0 is a
                perfectly level fleet, >1 means placement is piling load.
   attribution  forced / overflow (placed off the home invoker) / throttled
-               / unplaced counts, plus a cold-start APPROXIMATION: placed
-               rows whose action slot shows no spare warm permit at the
-               chosen invoker post-commit (the exact per-row use_conc bit
-               is not recoverable from the packed decision vector).
+               / unplaced counts, and cold starts: placed rows that took
+               memory for a new container, i.e. placed and not `warm` (the
+               kernels' per-row use_conc bit in the decision word), exact.
 
 A shadow decision vector (the counterfactual kernel's output for the same
 batch) folds in the same program: divergent-row counts, the predicted-cost
@@ -50,6 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .placement import unpack_chosen, unpack_warm
 from .telemetry import DEFAULT_BUCKETS, _bounds_us
 
 #: counter-vector layout (int32[N_COUNTERS]); the plane exposes these by
@@ -86,13 +86,6 @@ def init_quality_state(n_pad: int, n_buckets: int = DEFAULT_BUCKETS,
                         xp.zeros((n_pad,), xp.int32))
 
 
-def _decode(out_vec, xp):
-    chosen = (out_vec >> 2) - 1
-    forced = (out_vec & 1) > 0
-    throttled = ((out_vec >> 1) & 1) > 0
-    return chosen, forced, throttled
-
-
 def _score_math(xp, free_post, conc_bn, health, ewma_ms, cap_mb,
                 req, out_vec, shadow_vec, bounds_us):
     """The one copy of the scoring arithmetic, written against the numpy/
@@ -105,7 +98,7 @@ def _score_math(xp, free_post, conc_bn, health, ewma_ms, cap_mb,
     offset, size, home = req[0], req[1], req[2]
     need, slot = req[4], req[5]
     valid = req[8] > 0
-    chosen, forced, throttled = _decode(out_vec[:b], xp)
+    chosen, forced, throttled = unpack_chosen(out_vec[:b])
     placed = valid & (chosen >= 0)
     chosen_c = xp.clip(chosen, 0, n - 1)
 
@@ -131,9 +124,7 @@ def _score_math(xp, free_post, conc_bn, health, ewma_ms, cap_mb,
     home_g = offset + home
     overflow = placed & ~forced & (chosen != home_g)
     unplaced = valid & ~placed & ~throttled
-    conc_at = xp.sum(xp.where(idx[None, :] == chosen_c[:, None], conc_bn, 0),
-                     axis=1)
-    cold = placed & (conc_at <= 0)
+    cold = placed & ~unpack_warm(out_vec[:b])
 
     m = health & (cap_mb > 0)
     k = xp.maximum(xp.sum(m.astype(xp.int32)), 1).astype(xp.float32)
@@ -154,7 +145,7 @@ def _score_math(xp, free_post, conc_bn, health, ewma_ms, cap_mb,
         xp.sum(unplaced.astype(xp.int32)), xp.sum(cold.astype(xp.int32))]
 
     if shadow_vec is not None:
-        s_chosen, _, _ = _decode(shadow_vec[:b], xp)
+        s_chosen, _, _ = unpack_chosen(shadow_vec[:b])
         divergent = valid & (s_chosen != chosen)
         both = divergent & placed & (s_chosen >= 0)
         s_c = xp.clip(s_chosen, 0, n - 1)

@@ -46,8 +46,9 @@ fleet slice), home, step_inv (modular inverse of the coprime step), need_mb,
 conc_slot, max_conc, rand (forced-placement choice), valid.
 
 Returns (new_state, chosen int32[B] — global invoker index or -1, forced
-bool[B]). Overload forces a random usable invoker (over-commit); no usable
-invokers -> -1.
+bool[B], warm bool[B] — placed on a spare permit of a container the invoker
+already holds, the capacity update's own `use_conc`). Overload forces a
+random usable invoker (over-commit); no usable invokers -> -1.
 """
 from __future__ import annotations
 
@@ -167,21 +168,22 @@ def _schedule_one(state: PlacementState, req, penalty=None
     conc_free = state.conc_free.at[sel, slot].add(conc_delta.astype(jnp.int32))
 
     out_choice = jnp.where(placed, sel, -1)
-    return PlacementState(free_mb, conc_free, state.health), (out_choice, forced)
+    return (PlacementState(free_mb, conc_free, state.health),
+            (out_choice, forced, use_conc))
 
 
 @jax.jit
 def schedule_batch(state: PlacementState, batch: RequestBatch, penalty=None
-                   ) -> Tuple[PlacementState, jax.Array, jax.Array]:
+                   ) -> Tuple[PlacementState, jax.Array, jax.Array, jax.Array]:
     """Place a micro-batch sequentially (lax.scan) with vectorized probes.
     `penalty=None` (the production default) traces identically to the
     penalty-free kernel; see `_schedule_one` for the augmented geometry."""
     reqs = (batch.offset, batch.size, batch.home, batch.step_inv,
             batch.need_mb, batch.conc_slot, batch.max_conc, batch.rand,
             batch.valid)
-    new_state, (chosen, forced) = jax.lax.scan(
+    new_state, (chosen, forced, warm) = jax.lax.scan(
         lambda s, r: _schedule_one(s, r, penalty), state, reqs)
-    return new_state, chosen, forced
+    return new_state, chosen, forced, warm
 
 
 class RepairPrims(NamedTuple):
@@ -437,7 +439,7 @@ def _probe_geometry(n: int, batch: RequestBatch, penalty=None):
 def schedule_batch_repair(state: PlacementState, batch: RequestBatch,
                           penalty=None
                           ) -> Tuple[PlacementState, jax.Array, jax.Array,
-                                     jax.Array]:
+                                     jax.Array, jax.Array]:
     """Speculate-and-repair: bit-exact `schedule_batch` semantics with the
     B-length sequential dependency chain collapsed to the conflict count.
 
@@ -492,7 +494,8 @@ def schedule_batch_repair(state: PlacementState, batch: RequestBatch,
     (rare; typically 1 + the depth of the worst per-invoker overflow
     chain).
 
-    Returns (state, chosen, forced, rounds) — `rounds` is the repair-loop
+    Returns (state, chosen, forced, warm, rounds) — `warm` is each row's
+    `use_conc` of the round that settled it; `rounds` is the repair-loop
     trip count, exported by the balancer as the loadbalancer_repair_rounds
     summary family.
     """
@@ -512,11 +515,11 @@ def schedule_batch_repair(state: PlacementState, batch: RequestBatch,
     simple = batch.max_conc <= 1
 
     def cond(carry):
-        _, pending, _, _, rounds = carry
+        _, pending, _, _, _, rounds = carry
         return jnp.any(pending) & (rounds <= b)
 
     def body(carry):
-        state, pending, chosen, forced_acc, rounds = carry
+        state, pending, chosen, forced_acc, warm_acc, rounds = carry
         # per-round speculation: only the capacity-dependent half of the
         # probe re-runs (conc column gather + memory eligibility)
         conc_bn = state.conc_free[:, batch.conc_slot].T   # [B, N]
@@ -559,14 +562,16 @@ def schedule_batch_repair(state: PlacementState, batch: RequestBatch,
         chosen = jnp.where(safe, jnp.where(placed, sel, jnp.int32(-1)),
                            chosen)
         forced_acc = forced_acc | (safe & forced)
+        warm_acc = warm_acc | (safe & use_conc)
         return (PlacementState(free_mb, conc_free, state.health),
-                pending & ~safe, chosen, forced_acc, rounds + 1)
+                pending & ~safe, chosen, forced_acc, warm_acc, rounds + 1)
 
-    state, _, chosen, forced, rounds = jax.lax.while_loop(
+    state, _, chosen, forced, warm, rounds = jax.lax.while_loop(
         cond, body, (state, batch.valid,
                      jnp.full((b,), -1, jnp.int32),
-                     jnp.zeros((b,), bool), jnp.int32(0)))
-    return state, chosen, forced, rounds
+                     jnp.zeros((b,), bool), jnp.zeros((b,), bool),
+                     jnp.int32(0)))
+    return state, chosen, forced, warm, rounds
 
 
 def _release_one(state: PlacementState, rel) -> Tuple[PlacementState, Tuple]:
@@ -699,8 +704,8 @@ def make_fused_step(release_fn=None, schedule_fn=None):
     (release_fn, schedule_fn) pair — the XLA kernels (default scan or the
     repair kernel), the shard_map'd variants, or the pallas schedule.
 
-    Returns (state, chosen, forced, rounds): schedule kernels without a
-    repair loop (scan / pallas / sharded) report rounds == 0.
+    Returns (state, chosen, forced, warm, rounds): schedule kernels
+    without a repair loop (scan / pallas / sharded) report rounds == 0.
     """
     release_fn = release_fn or release_batch
     schedule_fn = schedule_fn or schedule_batch
@@ -716,8 +721,8 @@ def make_fused_step(release_fn=None, schedule_fn=None):
         state = state._replace(health=state.health.at[health_idx].set(
             jnp.where(health_valid, health_val, cur)))
         out = schedule_fn(state, batch)
-        rounds = out[3] if len(out) > 3 else jnp.int32(0)
-        return out[0], out[1], out[2], rounds
+        rounds = out[4] if len(out) > 4 else jnp.int32(0)
+        return out[0], out[1], out[2], out[3], rounds
 
     return fused
 
@@ -745,10 +750,11 @@ def make_fused_step_packed(release_fn=None, schedule_fn=None,
     TRANSFER COUNT — not the kernel — dominates the step. Packing collapses
     the inputs to ONE flat int32 buffer (rel [5*R] ++ health [3*H] ++ req
     [9*B] here, [10*B] in the admit variant; split by static shape inside
-    the program) and the outputs to ONE int32 vector: B elements of
-    ((chosen+1)<<2) | throttled<<1 | forced (always 0 for throttled here;
-    callers decode with `unpack_chosen`) plus ONE trailing element carrying
-    the repair-round count (0 for schedule kernels without a repair loop).
+    the program) and the outputs to ONE int32 vector: B decision words
+    (`pack_decisions`: ((chosen+1)<<3) | warm<<2 | throttled<<1 | forced,
+    throttled always 0 here; callers decode with `unpack_chosen`) plus ONE
+    trailing element carrying the repair-round count (0 for schedule
+    kernels without a repair loop).
     R/H/B are static per compile; the balancer's power-of-two bucketing
     bounds the cache-key count.
 
@@ -774,10 +780,10 @@ def make_fused_step_packed(release_fn=None, schedule_fn=None,
         req = buf[5 * R + 3 * H:].reshape(9, B)
         batch = RequestBatch(req[0], req[1], req[2], req[3], req[4], req[5],
                              req[6], req[7], req[8].astype(bool))
-        state, chosen, forced, rounds = fused(
+        state, chosen, forced, warm, rounds = fused(
             state, rel[0], rel[1], rel[2], rel[3], rel[4].astype(bool),
             health[0], health[1].astype(bool), health[2].astype(bool), batch)
-        out = ((chosen + 1) << 2) | forced.astype(jnp.int32)
+        out = pack_decisions(chosen, forced, warm)
         return state, jnp.concatenate([out, rounds.reshape(1)])
 
     return packed
@@ -789,8 +795,8 @@ def make_fused_admit_step_packed(release_fn=None, schedule_fn=None,
     the fused program folds releases and health, ADMITS the batch against
     per-namespace buckets (Entitlement.scala:86-153 / RateThrottler.scala as
     a vectorized segmented count — see ops/throttle.py), then schedules only
-    the admitted requests. Over-rate requests come back flagged in bit 1 of
-    the packed output and never consume placement capacity.
+    the admitted requests. Over-rate requests come back flagged in the
+    throttled bit of the decision word and never consume placement capacity.
 
     req grows a 10th row: ns_slot (the balancer's namespace->bucket index).
     `donate=True` donates the whole (state, buckets) carry.
@@ -811,11 +817,10 @@ def make_fused_admit_step_packed(release_fn=None, schedule_fn=None,
         throttled = valid & ~admitted
         batch = RequestBatch(req[0], req[1], req[2], req[3], req[4], req[5],
                              req[6], req[7], admitted)
-        state, chosen, forced, rounds = fused(
+        state, chosen, forced, warm, rounds = fused(
             state, rel[0], rel[1], rel[2], rel[3], rel[4].astype(bool),
             health[0], health[1].astype(bool), health[2].astype(bool), batch)
-        out = (((chosen + 1) << 2) | (throttled.astype(jnp.int32) << 1)
-               | forced.astype(jnp.int32))
+        out = pack_decisions(chosen, forced, warm, throttled)
         return (state, buckets), jnp.concatenate([out, rounds.reshape(1)])
 
     return packed
@@ -826,7 +831,7 @@ def make_shadow_step_packed(release_fn=None, schedule_fn=None):
     packed buffer, same release/health folds, but the schedule runs with an
     augmented probe geometry (`penalty` int32[N]) and NOTHING it computes
     is written back — the caller keeps its live state, this program returns
-    only the packed decision vector ((chosen+1)<<2 | forced, no repair-round
+    only the packed decision vector (`pack_decisions` words, no repair-round
     tail). Never donates: the production step consumes (and may donate) the
     very same state buffers after the shadow has enqueued, so the shadow
     must leave them untouched.
@@ -851,7 +856,7 @@ def make_shadow_step_packed(release_fn=None, schedule_fn=None):
         batch = RequestBatch(req[0], req[1], req[2], req[3], req[4], req[5],
                              req[6], req[7], req[8].astype(bool))
         out = schedule_fn(state, batch, penalty)
-        return ((out[1] + 1) << 2) | out[2].astype(jnp.int32)
+        return pack_decisions(out[1], out[2], out[3])
 
     return shadow
 
@@ -861,7 +866,7 @@ def make_shadow_admit_step_packed(release_fn=None, schedule_fn=None):
     admission fold re-runs against the SAME bucket state and `now` as the
     production step — admit_batch is a pure function, so the admitted set
     is identical — but neither the buckets nor the placement state are
-    returned. Output encodes throttled in bit 1 like the production step.
+    returned. Output encodes throttled like the production step.
     """
     from .throttle import admit_batch
 
@@ -885,10 +890,20 @@ def make_shadow_admit_step_packed(release_fn=None, schedule_fn=None):
         batch = RequestBatch(req[0], req[1], req[2], req[3], req[4], req[5],
                              req[6], req[7], admitted)
         out = schedule_fn(state, batch, penalty)
-        return (((out[1] + 1) << 2) | (throttled.astype(jnp.int32) << 1)
-                | out[2].astype(jnp.int32))
+        return pack_decisions(out[1], out[2], out[3], throttled)
 
     return shadow
+
+
+def pack_decisions(chosen, forced, warm, throttled=None):
+    """The step's decision word, one int32 per request (device jnp):
+    ((chosen+1)<<3) | warm<<2 | throttled<<1 | forced. `chosen` < 2**17
+    (`_mulmod`'s bound) leaves the word far inside int32."""
+    out = (((chosen + 1) << 3) | (warm.astype(jnp.int32) << 2)
+           | forced.astype(jnp.int32))
+    if throttled is not None:
+        out = out | (throttled.astype(jnp.int32) << 1)
+    return out
 
 
 def unpack_chosen(out):
@@ -897,11 +912,28 @@ def unpack_chosen(out):
     requests carry chosen == -1 (they were never scheduled). NOTE: the
     packed step returns B+1 elements — slice off the trailing repair-round
     counter (`out[:-1]`) before decoding, or use `unpack_step_output`."""
-    return (out >> 2) - 1, (out & 1).astype(bool), ((out >> 1) & 1).astype(bool)
+    return (out >> 3) - 1, (out & 1).astype(bool), ((out >> 1) & 1).astype(bool)
+
+
+def unpack_warm(out):
+    """The decision words' warm bit (host numpy or device jnp) -> bool:
+    the request was placed on a spare permit of a container its invoker
+    already held, and took no memory."""
+    return ((out >> 2) & 1).astype(bool)
+
+
+def journal_words(out):
+    """Decision words as the journal's `ack` records persist them:
+    ((chosen+1)<<2) | throttled<<1 | forced, the layout every journal on
+    disk and every reader of one has (replay, the time-travel debugger,
+    the benchmark's reference). The warm bit is not persisted: replay
+    re-derives it with the books, through the same kernels."""
+    return ((out >> 3) << 2) | (out & 3)
 
 
 def unpack_step_output(out):
     """Decode a full packed step output vector (B+1 elements):
-    -> (chosen, forced, throttled, repair_rounds int)."""
+    -> (chosen, forced, throttled, repair_rounds int); the warm bits are
+    `unpack_warm(out[:-1])`."""
     chosen, forced, throttled = unpack_chosen(out[:-1])
     return chosen, forced, throttled, int(out[-1])

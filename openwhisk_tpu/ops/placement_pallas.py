@@ -18,7 +18,9 @@ Layout notes (TPU tiling wants the fleet on the 128-lane axis):
 Semantics are identical to ops/placement.py::schedule_batch (asserted by
 tests in interpret mode AND by chip_smoke.py's parity leg on the chip):
 same probe-rank argmin, same forced placement, same NestedSemaphore capacity
-updates, same sequential intra-batch resolution. `fits_vmem` /
+updates, same sequential intra-batch resolution. Both kernels return their
+per-row bits in ONE int32 row, `flags` = forced | warm<<1 (warm = the row's
+`use_conc`), so the exact warm bit costs no output buffer of its own. `fits_vmem` /
 `fits_vmem_repair` report whether a configuration qualifies (larger fleets
 use the XLA/sharded path).
 """
@@ -112,8 +114,13 @@ def to_transposed(state: PlacementState) -> PlacementState:
                           state.health)
 
 
+def _unpack_flags(flags):
+    """A kernel's `flags` row -> (forced bool[B], warm bool[B])."""
+    return (flags & 1) > 0, (flags & 2) > 0
+
+
 def _kernel_body(reqs_ref, health_ref, free_ref, conc_ref, chosen_ref,
-                 forced_ref, free_out, conc_out, pen_ref=None):
+                 flags_ref, free_out, conc_out, pen_ref=None):
     n = free_out.shape[1]
     b = chosen_ref.shape[1]
     # the penalized rank can exceed n + 2 (one probe-ring lap per penalty
@@ -127,7 +134,7 @@ def _kernel_body(reqs_ref, health_ref, free_ref, conc_ref, chosen_ref,
     free_out[:] = free_ref[:]
     conc_out[:] = conc_ref[:]
     chosen_ref[:] = jnp.full((1, b), -1, jnp.int32)
-    forced_ref[:] = jnp.zeros((1, b), jnp.int32)
+    flags_ref[:] = jnp.zeros((1, b), jnp.int32)
 
     def body(i, _):
         offset = reqs_ref[i, 0]
@@ -183,28 +190,30 @@ def _kernel_body(reqs_ref, health_ref, free_ref, conc_ref, chosen_ref,
 
         at_i = bidx == i
         chosen_ref[:] = jnp.where(at_i & placed, chosen, chosen_ref[:])
-        forced_ref[:] = jnp.where(at_i & forced, 1, forced_ref[:])
+        flags = jnp.where(forced, 1, 0) | jnp.where(use_conc, 2, 0)
+        flags_ref[:] = jnp.where(at_i, flags, flags_ref[:])
         return 0
 
     jax.lax.fori_loop(0, b, body, 0)
 
 
-def _kernel(reqs_ref, health_ref, free_ref, conc_ref, chosen_ref, forced_ref,
+def _kernel(reqs_ref, health_ref, free_ref, conc_ref, chosen_ref, flags_ref,
             free_out, conc_out):
     _kernel_body(reqs_ref, health_ref, free_ref, conc_ref, chosen_ref,
-                 forced_ref, free_out, conc_out)
+                 flags_ref, free_out, conc_out)
 
 
 def _kernel_penalized(reqs_ref, health_ref, free_ref, conc_ref, pen_ref,
-                      chosen_ref, forced_ref, free_out, conc_out):
+                      chosen_ref, flags_ref, free_out, conc_out):
     _kernel_body(reqs_ref, health_ref, free_ref, conc_ref, chosen_ref,
-                 forced_ref, free_out, conc_out, pen_ref=pen_ref)
+                 flags_ref, free_out, conc_out, pen_ref=pen_ref)
 
 
 @partial(jax.jit, static_argnames=("interpret",))
 def schedule_batch_pallas(state: PlacementState, batch: RequestBatch,
                           interpret: bool = False, penalty=None
-                          ) -> Tuple[PlacementState, jax.Array, jax.Array]:
+                          ) -> Tuple[PlacementState, jax.Array, jax.Array,
+                                     jax.Array]:
     """Drop-in for schedule_batch, state in transposed ([A, N]) layout.
     `penalty=None` traces the original kernel unchanged; a penalty vector
     appends one [1, N] VMEM input AFTER the aliased state buffers, so the
@@ -237,13 +246,13 @@ def schedule_batch_pallas(state: PlacementState, batch: RequestBatch,
                 pl.BlockSpec(memory_space=pltpu.VMEM),
                 pl.BlockSpec(memory_space=pltpu.VMEM)]
     if penalty is None:
-        chosen, forced, free_o, conc_o = pl.pallas_call(
+        chosen, flags, free_o, conc_o = pl.pallas_call(
             _kernel, out_shape=out_shape, in_specs=in_specs,
             out_specs=out_specs, input_output_aliases={2: 2, 3: 3},
             interpret=interpret,
         )(reqs, health2, free2, state.conc_free)
     else:
-        chosen, forced, free_o, conc_o = pl.pallas_call(
+        chosen, flags, free_o, conc_o = pl.pallas_call(
             _kernel_penalized, out_shape=out_shape,
             in_specs=in_specs + [pl.BlockSpec(memory_space=pltpu.VMEM)],
             out_specs=out_specs, input_output_aliases={2: 2, 3: 3},
@@ -252,11 +261,11 @@ def schedule_batch_pallas(state: PlacementState, batch: RequestBatch,
           penalty.astype(jnp.int32).reshape(1, n))
 
     new_state = PlacementState(free_o.reshape(n), conc_o, state.health)
-    return new_state, chosen.reshape(b), forced.reshape(b) > 0
+    return (new_state, chosen.reshape(b)) + _unpack_flags(flags.reshape(b))
 
 
 def _repair_kernel_body(reqs_ref, reqs_v_ref, health_ref, free_ref, conc_ref,
-                        chosen_ref, forced_ref, rounds_ref, free_out,
+                        chosen_ref, flags_ref, rounds_ref, free_out,
                         conc_out, conc_bn_ref, pen_ref=None):
     """Speculate-and-repair in ONE kernel: full-batch probe, the shared
     conflict rules (ops.placement.repair_commit_masks with the pairwise
@@ -317,14 +326,16 @@ def _repair_kernel_body(reqs_ref, reqs_v_ref, health_ref, free_ref, conc_ref,
     col_conc_geom = usable  # permit visibility is masked to the partition
 
     # Mosaic cannot carry i1 vectors through scf.while ("failed to
-    # legalize scf.yield", v5e / libtpu 0.0.34): the two masks ride the
-    # loop as int32 0/1 columns and are compared back to bool inside
+    # legalize scf.yield", v5e / libtpu 0.0.34): the masks ride the loop
+    # as int32 columns (pending 0/1; flags = forced | warm<<1, written
+    # once, in the round that settles the row) and are compared back to
+    # bool inside
     def cond(carry):
         pending_i, _, _, rounds = carry
         return (jnp.max(pending_i) > 0) & (rounds <= b)
 
     def body(carry):
-        pending_i, chosen, forced_i, rounds = carry
+        pending_i, chosen, flags_i, rounds = carry
         pending = pending_i > 0
         # per-round speculation: gather each request's conc column row
         # (the only dynamically-indexed read; slots pre-clamped host-side)
@@ -393,10 +404,12 @@ def _repair_kernel_body(reqs_ref, reqs_v_ref, health_ref, free_ref, conc_ref,
         jax.lax.fori_loop(0, b, put, 0)
         chosen = jnp.where(safe, jnp.where(placed, sel, jnp.int32(-1)),
                            chosen)
-        forced_i = jnp.where(safe & forced, 1, forced_i)
-        return (jnp.where(safe, 0, pending_i), chosen, forced_i, rounds + 1)
+        flags_i = jnp.where(
+            safe, jnp.where(forced, 1, 0) | jnp.where(use_conc, 2, 0),
+            flags_i)
+        return (jnp.where(safe, 0, pending_i), chosen, flags_i, rounds + 1)
 
-    _, chosen, forced_i, rounds = jax.lax.while_loop(
+    _, chosen, flags_i, rounds = jax.lax.while_loop(
         cond, body, (valid.astype(jnp.int32),
                      jnp.full((b, 1), -1, jnp.int32),
                      jnp.zeros((b, 1), jnp.int32), jnp.int32(0)))
@@ -404,24 +417,24 @@ def _repair_kernel_body(reqs_ref, reqs_v_ref, health_ref, free_ref, conc_ref,
     # [B, 1] -> [1, B] result rows via the diagonal-mask transpose
     chosen_ref[:] = jnp.sum(jnp.where(eye_bb, chosen, 0), axis=0,
                             keepdims=True)
-    forced_ref[:] = jnp.sum(jnp.where(eye_bb, forced_i, 0), axis=0,
-                            keepdims=True)
+    flags_ref[:] = jnp.sum(jnp.where(eye_bb, flags_i, 0), axis=0,
+                           keepdims=True)
     rounds_ref[0, 0] = rounds
 
 
 def _repair_kernel(reqs_ref, reqs_v_ref, health_ref, free_ref, conc_ref,
-                   chosen_ref, forced_ref, rounds_ref, free_out, conc_out,
+                   chosen_ref, flags_ref, rounds_ref, free_out, conc_out,
                    conc_bn_ref):
     _repair_kernel_body(reqs_ref, reqs_v_ref, health_ref, free_ref, conc_ref,
-                        chosen_ref, forced_ref, rounds_ref, free_out,
+                        chosen_ref, flags_ref, rounds_ref, free_out,
                         conc_out, conc_bn_ref)
 
 
 def _repair_kernel_penalized(reqs_ref, reqs_v_ref, health_ref, free_ref,
-                             conc_ref, pen_ref, chosen_ref, forced_ref,
+                             conc_ref, pen_ref, chosen_ref, flags_ref,
                              rounds_ref, free_out, conc_out, conc_bn_ref):
     _repair_kernel_body(reqs_ref, reqs_v_ref, health_ref, free_ref, conc_ref,
-                        chosen_ref, forced_ref, rounds_ref, free_out,
+                        chosen_ref, flags_ref, rounds_ref, free_out,
                         conc_out, conc_bn_ref, pen_ref=pen_ref)
 
 
@@ -429,9 +442,9 @@ def _repair_kernel_penalized(reqs_ref, reqs_v_ref, health_ref, free_ref,
 def schedule_batch_repair_pallas(state: PlacementState, batch: RequestBatch,
                                  interpret: bool = False, penalty=None
                                  ) -> Tuple[PlacementState, jax.Array,
-                                            jax.Array, jax.Array]:
+                                            jax.Array, jax.Array, jax.Array]:
     """Drop-in for ops.placement.schedule_batch_repair (state in the
-    kernel's transposed [A, N] layout): same (state, chosen, forced,
+    kernel's transposed [A, N] layout): same (state, chosen, forced, warm,
     rounds) contract, bit-exact with the XLA repair kernel — the conflict
     rules are literally the same function (`repair_commit_masks`), only
     the index primitives differ (pairwise vs scatter/sort; their
@@ -469,7 +482,7 @@ def schedule_batch_repair_pallas(state: PlacementState, batch: RequestBatch,
                  pl.BlockSpec(memory_space=pltpu.VMEM),
                  pl.BlockSpec(memory_space=pltpu.VMEM))
     if penalty is None:
-        chosen, forced, rounds, free_o, conc_o = pl.pallas_call(
+        chosen, flags, rounds, free_o, conc_o = pl.pallas_call(
             _repair_kernel, out_shape=out_shape, in_specs=in_specs,
             out_specs=out_specs,
             scratch_shapes=[pltpu.VMEM((b, n), jnp.int32)],
@@ -477,7 +490,7 @@ def schedule_batch_repair_pallas(state: PlacementState, batch: RequestBatch,
             interpret=interpret,
         )(reqs, reqs, health2, free2, state.conc_free)
     else:
-        chosen, forced, rounds, free_o, conc_o = pl.pallas_call(
+        chosen, flags, rounds, free_o, conc_o = pl.pallas_call(
             _repair_kernel_penalized, out_shape=out_shape,
             in_specs=in_specs + [pl.BlockSpec(memory_space=pltpu.VMEM)],
             out_specs=out_specs,
@@ -488,5 +501,5 @@ def schedule_batch_repair_pallas(state: PlacementState, batch: RequestBatch,
           penalty.astype(jnp.int32).reshape(1, n))
 
     new_state = PlacementState(free_o.reshape(n), conc_o, state.health)
-    return (new_state, chosen.reshape(b), forced.reshape(b) > 0,
-            rounds.reshape(()))
+    return ((new_state, chosen.reshape(b)) + _unpack_flags(flags.reshape(b))
+            + (rounds.reshape(()),))

@@ -111,7 +111,8 @@ def mesh_topology(mesh: Optional[Mesh]) -> dict:
 def make_fleet_repair_schedule(mesh: Mesh, axis: Optional[str] = None,
                                penalized: bool = False):
     """The speculate-and-repair schedule over the fleet mesh — bit-exact
-    `schedule_batch_repair` semantics (state, chosen, forced, rounds) with
+    `schedule_batch_repair` semantics (state, chosen, forced, warm, rounds;
+    `use_conc` is already replicated, it reads the psum'd permit) with
     the [B, N] probe sharded to [B, n_local] per device.
 
     Exactness argument, per round:
@@ -192,11 +193,11 @@ def make_fleet_repair_schedule(mesh: Mesh, axis: Optional[str] = None,
         simple = batch.max_conc <= 1
 
         def cond(carry):
-            _, _, pending, _, _, rounds = carry
+            _, _, pending, _, _, _, rounds = carry
             return jnp.any(pending) & (rounds <= b)
 
         def body(carry):
-            free, conc, pending, chosen, forced_acc, rounds = carry
+            free, conc, pending, chosen, forced_acc, warm_acc, rounds = carry
             conc_bn = conc[:, batch.conc_slot].T             # [B, n_local]
             has_conc = conc_bn > 0
             eligible = usable & (has_conc
@@ -243,28 +244,29 @@ def make_fleet_repair_schedule(mesh: Mesh, axis: Optional[str] = None,
             chosen = jnp.where(safe, jnp.where(placed, sel, jnp.int32(-1)),
                                chosen)
             forced_acc = forced_acc | (safe & forced)
+            warm_acc = warm_acc | (safe & use_conc)
             return (free, conc, pending & ~safe, chosen, forced_acc,
-                    rounds + 1)
+                    warm_acc, rounds + 1)
 
-        free, conc, _, chosen, forced, rounds = jax.lax.while_loop(
+        free, conc, _, chosen, forced, warm, rounds = jax.lax.while_loop(
             cond, body,
             (state.free_mb, state.conc_free, batch.valid,
              jnp.full((b,), -1, jnp.int32), jnp.zeros((b,), bool),
-             jnp.int32(0)))
+             jnp.zeros((b,), bool), jnp.int32(0)))
         return PlacementState(free, conc, state.health), chosen, forced, \
-            rounds
+            warm, rounds
 
     state_spec = PlacementState(P(axis), P(axis, None), P(axis))
     batch_spec = RequestBatch(*([P()] * 9))
     if penalized:
         fn = shard_map(_sharded, mesh=mesh,
                        in_specs=(state_spec, batch_spec, P(axis)),
-                       out_specs=(state_spec, P(), P(), P()),
+                       out_specs=(state_spec, P(), P(), P(), P()),
                        check_vma=False)
     else:
         fn = shard_map(lambda s, b: _sharded(s, b), mesh=mesh,
                        in_specs=(state_spec, batch_spec),
-                       out_specs=(state_spec, P(), P(), P()),
+                       out_specs=(state_spec, P(), P(), P(), P()),
                        check_vma=False)
     return jax.jit(fn)
 

@@ -6,7 +6,9 @@ conc_free on ("inv", None); the request batch is replicated. Each scan step:
      index) plus the forced-placement fallback candidate,
   2. one all_gather of those 4 scalars per device elects the global winner
      (the collective is tiny and rides ICI),
-  3. only the owning device applies the capacity update (masked scatter).
+  3. only the owning device applies the capacity update (masked scatter),
+     and only it knows the row's warm bit (`use_conc`); one psum over the
+     finished [B] column after the scan replicates it.
 This preserves the exact sequential semantics of the single-device kernel —
 and therefore of the reference's one-at-a-time scheduler — at any shard
 count, which the parity tests assert on an 8-way virtual mesh.
@@ -111,7 +113,7 @@ def make_sharded_schedule(mesh: Mesh, axis: str = "inv"):
                                          max_conc - 1, 0))
         conc_free = state.conc_free.at[lsel, slot].add(conc_delta.astype(jnp.int32))
         new_state = PlacementState(free_mb, conc_free, state.health)
-        return new_state, (jnp.where(placed, sel, -1), forced)
+        return new_state, (jnp.where(placed, sel, -1), forced, use_conc)
 
     def _sharded(state: PlacementState, batch: RequestBatch):
         n_local = state.free_mb.shape[0]  # inside shard_map: local shape
@@ -120,15 +122,16 @@ def make_sharded_schedule(mesh: Mesh, axis: str = "inv"):
         reqs = (batch.offset, batch.size, batch.home, batch.step_inv,
                 batch.need_mb, batch.conc_slot, batch.max_conc, batch.rand,
                 batch.valid)
-        new_state, (chosen, forced) = jax.lax.scan(
+        new_state, (chosen, forced, warm_mine) = jax.lax.scan(
             lambda s, r: _local_body(s, r, shard_offset, n_total), state, reqs)
-        return new_state, chosen, forced
+        warm = jax.lax.psum(warm_mine.astype(jnp.int32), axis) > 0
+        return new_state, chosen, forced, warm
 
     state_spec = PlacementState(P(axis), P(axis, None), P(axis))
     batch_spec = RequestBatch(*([P()] * 9))
     fn = shard_map(_sharded, mesh=mesh,
                    in_specs=(state_spec, batch_spec),
-                   out_specs=(state_spec, P(), P()),
+                   out_specs=(state_spec, P(), P(), P()),
                    check_vma=False)
     return jax.jit(fn)
 

@@ -18,7 +18,8 @@ from ..controller.core import Controller
 from ..controller.loadbalancer.lean import LeanBalancer
 from ..core.entity import (BasicAuthenticationAuthKey, ControllerInstanceId,
                            EntityName, ExecManifest, Identity, InvokerInstanceId,
-                           MB, Namespace, Secret, Subject, UUID, WhiskAuthRecord)
+                           MB, Namespace, Secret, Subject, UUID, WhiskAuthRecord,
+                           limits_from_config)
 from ..database import ArtifactActivationStore, EntityStore
 from ..invoker.reactive import InvokerReactive
 from ..messaging.memory import MemoryMessagingProvider
@@ -58,6 +59,7 @@ async def make_standalone(port: int = 3233, artifact_store=None,
     dump."""
     logger = logger or Logging(level="warn")
     ExecManifest.initialize(manifest)
+    limits_from_config()
     provider = MemoryMessagingProvider()
     instance = ControllerInstanceId("0")
 

@@ -59,7 +59,7 @@ def bench_single(n_invokers: int, batch: int, iters: int, slot_mb: int = 2048,
     req = _example_batch(n_invokers, batch, seed=seed)
 
     def step(state):
-        state, chosen, forced = schedule_batch(state, req)
+        state, chosen, forced, _warm = schedule_batch(state, req)
         ok = chosen >= 0
         return release_batch(state, jnp.clip(chosen, 0), req.conc_slot,
                              req.need_mb, req.max_conc, ok), chosen
@@ -86,7 +86,7 @@ def bench_sharded(n_invokers: int, batch: int, iters: int, n_shards: int = 8,
     release = make_sharded_release(mesh)
 
     def step(state):
-        state, chosen, forced = schedule(state, req)
+        state, chosen, forced, _warm = schedule(state, req)
         ok = chosen >= 0
         return release(state, jnp.clip(chosen, 0), req.conc_slot,
                        req.need_mb, req.max_conc, ok), chosen

@@ -83,7 +83,7 @@ def simulate(n_invokers: int, rounds: int, batch: int, n_actions: int = 32,
                             ("offset", "size", "home", "step_inv", "need_mb",
                              "conc_slot", "max_conc", "rand")),
                           valid=jnp.ones((batch,), bool))
-        kstate, chosen, _forced = schedule_batch(kstate, rb)
+        kstate, chosen, _forced, _warm = schedule_batch(kstate, rb)
         kernel_out = [int(c) for c in np.asarray(chosen)]
 
         for a, oc, kc in zip(picks, oracle_out, kernel_out):

@@ -97,6 +97,13 @@ def _packed_buf(rng, n, r, h, b, rows=9, slots=16):
     return np.concatenate([rel.ravel(), health.ravel(), req.ravel()])
 
 
+def _words(chosen, forced=0, throttled=0, warm=0):
+    """Decision words in the packed step's layout (ops.placement
+    .pack_decisions), from host arrays."""
+    return (((np.asarray(chosen, np.int32) + 1) << 3) | (warm << 2)
+            | (throttled << 1) | forced).astype(np.int32)
+
+
 def _fuzz_scorer_inputs(rng, n, b, slots=8, shadow=True):
     """Random post-commit books + a random (but well-formed) packed
     decision vector — the scorer consumes decisions, it need not have
@@ -126,8 +133,9 @@ def _fuzz_scorer_inputs(rng, n, b, slots=8, shadow=True):
         chosen = rng.randint(-1, n, b).astype(np.int32)
         throttled = ((rng.rand(b) < 0.1) & (chosen < 0)).astype(np.int32)
         forced = ((rng.rand(b) < 0.2) & (chosen >= 0)).astype(np.int32)
-        return (((chosen + 1) << 2) | (throttled << 1)
-                | forced).astype(np.int32)
+        warm = ((rng.rand(b) < 0.4) & (chosen >= 0)
+                & (forced == 0)).astype(np.int32)
+        return _words(chosen, forced, throttled, warm)
 
     return (free, conc, health, ewma, cap, req, vec(),
             vec() if shadow else None)
@@ -197,19 +205,20 @@ class TestScorerParity:
         n, nb = 4, 8
         free = np.asarray([512, 512, 512, 512], np.int32)
         conc = np.zeros((n, 2), np.int32)
-        conc[1, 0] = 1  # invoker1 slot0 has a warm permit
+        conc[1, 0] = 1  # invoker1 slot0 has a spare permit left
         health = np.asarray([True, True, True, False])
         ewma = np.asarray([100.0, 5.0, 0.0, 0.0], np.float32)
         cap = np.full(n, 1024, np.int32)
-        # rows: placed@home(0), overflow(chosen=1,home=0), throttled,
-        #       unplaced, invalid
+        # rows: placed@home(0) on new memory, overflow(chosen=1,home=0) on
+        #       a spare permit (warm), throttled, unplaced, invalid
         req = np.zeros((9, 5), np.int32)
         req[1] = n          # size: whole fleet
         req[4] = 128        # need_mb
         req[8] = [1, 1, 1, 1, 0]
         chosen = np.asarray([0, 1, -1, -1, 0], np.int32)
         throttled = np.asarray([0, 0, 1, 0, 0], np.int32)
-        out = (((chosen + 1) << 2) | (throttled << 1)).astype(np.int32)
+        warm = np.asarray([0, 1, 0, 0, 0], np.int32)
+        out = _words(chosen, throttled=throttled, warm=warm)
         qs, summary = quality_step_np(
             init_quality_state(n, nb, numpy=True), free, conc, health,
             ewma, cap, req, out)
@@ -235,8 +244,8 @@ class TestScorerParity:
         req[1] = n
         req[4] = 128
         req[8] = 1
-        out = (((np.asarray([0, 1, 2]) + 1) << 2)).astype(np.int32)
-        shadow = (((np.asarray([1, 1, 2]) + 1) << 2)).astype(np.int32)
+        out = _words([0, 1, 2])
+        shadow = _words([1, 1, 2])
         qs, summary = quality_step_np(
             init_quality_state(n, nb, numpy=True), free, conc, health,
             ewma, cap, req, out, shadow)
@@ -364,14 +373,14 @@ class TestShadowCounterfactual:
             need_mb=jnp.full((1,), 128, jnp.int32), conc_slot=z,
             max_conc=jnp.ones((1,), jnp.int32), rand=z,
             valid=jnp.ones((1,), bool))
-        _, chosen0, forced0 = schedule_batch(state, batch)
+        _, chosen0, forced0, _ = schedule_batch(state, batch)
         assert int(chosen0[0]) == 0 and not bool(forced0[0])
         pen = jnp.asarray([2, 0, 0, 0], jnp.int32)
-        _, chosen_p, forced_p = schedule_batch(state, batch, pen)
+        _, chosen_p, forced_p, _ = schedule_batch(state, batch, pen)
         assert int(chosen_p[0]) == 1  # next probe stop, not the home
         assert not bool(forced_p[0])
         # penalizing everything reorders, never unplaces: still placed
-        _, chosen_all, _ = schedule_batch(
+        _, chosen_all, _, _ = schedule_batch(
             state, batch, jnp.full((n,), 3, jnp.int32))
         assert int(chosen_all[0]) >= 0
 

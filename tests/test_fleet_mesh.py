@@ -101,10 +101,11 @@ class TestFleetKernelParity:
             for trial in range(3):
                 st = _dirty_state(rng, n)
                 batch = _rand_batch(rng, n, b)
-                s1, c1, f1, r1 = schedule_batch_repair(st, batch)
-                s2, c2, f2, r2 = sched(shard_state(st, mesh), batch)
+                s1, c1, f1, w1, r1 = schedule_batch_repair(st, batch)
+                s2, c2, f2, w2, r2 = sched(shard_state(st, mesh), batch)
                 assert _same(c1, c2), (n, b, trial)
                 assert _same(f1, f2), (n, b, trial)
+                assert _same(w1, w2), (n, b, trial)
                 assert _states_equal(s1, s2), (n, b, trial)
                 assert int(r1) == int(r2), (n, b, trial)
 
@@ -118,10 +119,10 @@ class TestFleetKernelParity:
         st = _dirty_state(rng, n)
         batch = _rand_batch(rng, n, b, need=np.full(b, 1900, np.int32),
                             maxc_pool=(1,))
-        s1, c1, f1, r1 = schedule_batch_repair(st, batch)
-        s2, c2, f2, r2 = sched(shard_state(st, mesh), batch)
+        s1, c1, f1, w1, r1 = schedule_batch_repair(st, batch)
+        s2, c2, f2, w2, r2 = sched(shard_state(st, mesh), batch)
         assert bool(np.asarray(f1).any()), "protocol must actually force"
-        assert _same(c1, c2) and _same(f1, f2)
+        assert _same(c1, c2) and _same(f1, f2) and _same(w1, w2)
         assert _states_equal(s1, s2) and int(r1) == int(r2)
 
     def test_container_open_burst_parity(self, mesh):
@@ -134,9 +135,9 @@ class TestFleetKernelParity:
         st = init_state(n, [2048] * n, n_pad=n, action_slots=16)
         batch = _rand_batch(rng, n, b, maxc_pool=(4,), slots=4,
                             invalid_frac=0.0)
-        s1, c1, f1, r1 = schedule_batch_repair(st, batch)
-        s2, c2, f2, r2 = sched(shard_state(st, mesh), batch)
-        assert _same(c1, c2) and _same(f1, f2)
+        s1, c1, f1, w1, r1 = schedule_batch_repair(st, batch)
+        s2, c2, f2, w2, r2 = sched(shard_state(st, mesh), batch)
+        assert _same(c1, c2) and _same(f1, f2) and _same(w1, w2)
         assert _states_equal(s1, s2) and int(r1) == int(r2)
 
     def test_release_vector_parity_incl_conflation(self, mesh):
@@ -168,9 +169,10 @@ class TestFleetKernelParity:
         st2 = shard_state(st1, mesh)
         for step in range(4):
             batch = _rand_batch(rng, n, b)
-            st1, c1, f1, r1 = schedule_batch_repair(st1, batch)
-            st2, c2, f2, r2 = sched(st2, batch)
-            assert _same(c1, c2) and int(r1) == int(r2), step
+            st1, c1, f1, w1, r1 = schedule_batch_repair(st1, batch)
+            st2, c2, f2, w2, r2 = sched(st2, batch)
+            assert _same(c1, c2) and _same(w1, w2), step
+            assert int(r1) == int(r2), step
             inv = jnp.asarray(np.clip(np.asarray(c1), 0, None), jnp.int32)
             ok = jnp.asarray(np.asarray(c1) >= 0)
             st1 = release_batch_vector(st1, inv, batch.conc_slot,
@@ -188,10 +190,11 @@ class TestFleetKernelParity:
         n, b = 32, 24
         st = _dirty_state(rng, n)
         batch = _rand_batch(rng, n, b)
-        s1, c1, f1 = schedule_batch(st, batch)
+        s1, c1, f1, w1 = schedule_batch(st, batch)
         out = sched(shard_state(st, mesh), batch)
-        s2, c2, f2 = out[0], out[1], out[2]
-        assert _same(c1, c2) and _same(f1, f2) and _states_equal(s1, s2)
+        s2, c2, f2, w2 = out
+        assert _same(c1, c2) and _same(f1, f2) and _same(w1, w2)
+        assert _states_equal(s1, s2)
 
     def test_auto_pair_is_per_bucket_hybrid(self, mesh):
         """fleet_pair('auto') routes by static batch width exactly like
@@ -207,12 +210,12 @@ class TestFleetKernelParity:
         small = _rand_batch(rng, n, 8)
         big = _rand_batch(rng, n, 64)
         out_small = sched(shard_state(st, mesh), small)
-        assert len(out_small) == 3  # the scan pair: no rounds element
-        s1, c1, _f1 = schedule_batch(st, small)
+        assert len(out_small) == 4  # the scan pair: no rounds element
+        s1, c1, _f1, _w1 = schedule_batch(st, small)
         assert _same(c1, out_small[1])
         out_big = sched(shard_state(st, mesh), big)
-        s2, c2, _f2, r2 = schedule_batch_repair(st, big)
-        assert _same(c2, out_big[1]) and int(out_big[3]) == int(r2)
+        s2, c2, _f2, _w2, r2 = schedule_batch_repair(st, big)
+        assert _same(c2, out_big[1]) and int(out_big[4]) == int(r2)
 
     def test_grow_reshard_continues_bit_exact(self, mesh):
         """Fleet growth = reshard: re-pad the invoker axis (holds
@@ -225,8 +228,8 @@ class TestFleetKernelParity:
         st1 = _dirty_state(rng, n1)
         st2 = shard_state(st1, mesh)
         batch = _rand_batch(rng, n1, b)
-        st1, c1, _f, _r = schedule_batch_repair(st1, batch)
-        st2, c2, _f2, _r2 = sched(st2, batch)
+        st1, c1, _f, _w, _r = schedule_batch_repair(st1, batch)
+        st2, c2, _f2, _w2, _r2 = sched(st2, batch)
         assert _same(c1, c2)
 
         def grow(st, pad):
@@ -246,8 +249,8 @@ class TestFleetKernelParity:
         st1 = grow(st1, n2)
         st2 = shard_state(grow(st2, n2), mesh)
         batch2 = _rand_batch(rng, n2, b)
-        st1, c1, _f, r1 = schedule_batch_repair(st1, batch2)
-        st2, c2, _f2, r2 = sched(st2, batch2)
+        st1, c1, _f, _w, r1 = schedule_batch_repair(st1, batch2)
+        st2, c2, _f2, _w2, r2 = sched(st2, batch2)
         assert _same(c1, c2) and int(r1) == int(r2)
         assert _states_equal(st1, st2)
 

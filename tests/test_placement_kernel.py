@@ -171,17 +171,24 @@ def _random_trace(n_actions, B, seed, conc_choices=(1,), bb_prob=0.0,
     return trace
 
 
-def _run_oracle(st, trace):
+def _run_oracle(st, trace, warm=None):
     """Run the oracle with the SAME deterministic forced-choice rotation the
-    kernel batch carries (host passes identical rand to both paths)."""
+    kernel batch carries (host passes identical rand to both paths). A list
+    passed as `warm` gets one bool per request: the oracle took a spare
+    slot of a container the invoker already held, i.e. placed the request
+    and left the chosen invoker's memory as it was."""
     out = []
     for i, (ns, act, mem, conc, bb) in enumerate(trace):
         _, size = st.partition(bb)
         h = generate_hash(ns, act)
         rand = (h ^ (i * 2654435761)) % max(size, 1)
+        before = [inv.semaphore.available_permits for inv in st.invokers]
         chosen, forced = schedule(st, ns, act, mem, conc, bb,
                                   forced_rand=rand)
         out.append((chosen if chosen is not None else -1, forced))
+        if warm is not None:
+            warm.append(chosen is not None and before[chosen]
+                        == st.invokers[chosen].semaphore.available_permits)
     return out
 
 
@@ -205,7 +212,7 @@ def test_kernel_matches_oracle_exactly(n_invokers, n_actions, conc, bb):
     batch = _batch_from_trace(st, trace, slot_of)
     kstate = init_state(n_invokers, [st.invoker_slot_mb(1024)] * n_invokers,
                         action_slots=128)
-    kstate, chosen, forced = schedule_batch(kstate, batch)
+    kstate, chosen, forced, _warm = schedule_batch(kstate, batch)
     chosen = np.asarray(chosen)
     forced = np.asarray(forced)
 
@@ -220,6 +227,107 @@ def test_kernel_matches_oracle_exactly(n_invokers, n_actions, conc, bb):
     np.testing.assert_array_equal(kernel_free, oracle_free)
 
 
+def _kernel_family(name):
+    """One placement-kernel family behind one face: (place, schedule,
+    release) over a [N, A] state, whatever layout or mesh it runs on."""
+    from openwhisk_tpu.ops.placement import (release_batch_vector,
+                                             schedule_batch_repair)
+    if name == "xla_scan":
+        return (lambda s: s), schedule_batch, release_batch
+    if name == "xla_repair":
+        return (lambda s: s), schedule_batch_repair, release_batch_vector
+    if name.startswith("pallas"):
+        from openwhisk_tpu.ops.placement_pallas import (
+            schedule_batch_pallas, schedule_batch_repair_pallas,
+            to_transposed)
+        fn = (schedule_batch_repair_pallas if name == "pallas_repair"
+              else schedule_batch_pallas)
+
+        def sched(state, batch):
+            ts, *out = fn(to_transposed(state), batch, interpret=True)
+            return (to_transposed(ts), *out)
+
+        return (lambda s: s), sched, release_batch
+    from openwhisk_tpu.parallel import (make_fleet_release_vector,
+                                        make_fleet_repair_schedule,
+                                        make_mesh, make_sharded_release,
+                                        make_sharded_schedule, shard_state)
+    mesh = make_mesh(8)
+    if name == "mesh_scan":
+        return ((lambda s: shard_state(s, mesh)),
+                make_sharded_schedule(mesh), make_sharded_release(mesh))
+    return ((lambda s: shard_state(s, mesh)),
+            make_fleet_repair_schedule(mesh, axis="inv"),
+            make_fleet_release_vector(mesh, axis="inv"))
+
+
+@pytest.mark.parametrize("maxc", [1, 2, 5, 50])
+@pytest.mark.parametrize("kernel", [
+    "xla_scan", "xla_repair",
+    pytest.param("pallas_scan", marks=pytest.mark.pallas),
+    pytest.param("pallas_repair", marks=pytest.mark.pallas),
+    pytest.param("mesh_scan", marks=pytest.mark.mesh),
+    pytest.param("mesh_repair", marks=pytest.mark.mesh)])
+def test_every_kernel_matches_oracle_on_streams(kernel, maxc):
+    """The oracle parity above, for every schedule kernel and over a
+    STREAM: four batches with random releases between them, on a fleet
+    short enough of memory to step and to force. Per row the kernel's
+    (chosen, forced) are the oracle's, its `warm` bit is the oracle's
+    "took a spare slot", and after every batch and every release fold both
+    levels of the books agree."""
+    n, n_actions, b = 8, 6, 32
+    rng = random.Random(31 * maxc + len(kernel))
+    st = ShardingPolicyState.build([256] * n)
+    slot_of = _make_slot_allocator()
+    place, sched, rel = _kernel_family(kernel)
+    kstate = place(init_state(n, [st.invoker_slot_mb(256)] * n,
+                              action_slots=8))
+    # memory and concurrency are properties of an action, for the stream
+    props = [(rng.choice((128, 256)), (maxc, 1, maxc)[a % 3])
+             for a in range(n_actions)]
+    in_flight, seen_warm, seen_forced, keys = [], 0, 0, set()
+
+    def books_agree():
+        np.testing.assert_array_equal(
+            np.asarray(kstate.free_mb),
+            [inv.semaphore.available_permits for inv in st.invokers])
+        conc = np.asarray(kstate.conc_free)
+        for i, inv in enumerate(st.invokers):
+            for key in keys:
+                assert conc[i, slot_of(key)] == \
+                    inv.semaphore.concurrent_slots_available(key)
+
+    for step in range(4):
+        trace = [(f"ns{a % 3}", f"action{a}", *props[a], False)
+                 for a in (rng.randrange(n_actions) for _ in range(b))]
+        batch = _batch_from_trace(st, trace, slot_of)
+        keys.update(f"{act}:{mem}" for _ns, act, mem, _c, _bb in trace)
+        o_warm = []
+        oracle = _run_oracle(st, trace, o_warm)
+        kstate, chosen, forced, warm = sched(kstate, batch)[:4]
+        got = list(zip(np.asarray(chosen).tolist(),
+                       np.asarray(forced).tolist()))
+        assert got == oracle, (kernel, maxc, step)
+        assert np.asarray(warm).tolist() == o_warm, (kernel, maxc, step)
+        seen_warm += sum(o_warm)
+        seen_forced += sum(f for _c, f in oracle)
+        books_agree()
+        in_flight += [(c, act, mem, conc) for (c, _f), (_ns, act, mem, conc,
+                                                       _bb)
+                      in zip(oracle, trace) if c >= 0]
+        rng.shuffle(in_flight)
+        done, in_flight = in_flight[:len(in_flight) // 2], \
+            in_flight[len(in_flight) // 2:]
+        cols = np.zeros((5, b * 4), np.int32)
+        for j, (inv, act, mem, conc) in enumerate(done):
+            release(st, inv, act, mem, conc)
+            cols[:, j] = (inv, slot_of(f"{act}:{mem}"), mem, conc, 1)
+        kstate = rel(kstate, *(jnp.asarray(c) for c in cols[:4]),
+                     jnp.asarray(cols[4].astype(bool)))
+        books_agree()
+    assert seen_forced > 0 and (seen_warm > 0) == (maxc > 1)
+
+
 def test_kernel_release_roundtrip():
     """schedule then release returns the state to its initial books."""
     st = ShardingPolicyState.build([512] * 8)
@@ -227,7 +335,7 @@ def test_kernel_release_roundtrip():
     trace = _random_trace(5, 64, seed=3, conc_choices=(1, 4), mems=(128, 256))
     batch = _batch_from_trace(st, trace, slot_of)
     kstate0 = init_state(8, [512] * 8, action_slots=64)
-    kstate, chosen, forced = schedule_batch(kstate0, batch)
+    kstate, chosen, forced, _warm = schedule_batch(kstate0, batch)
     chosen = np.asarray(chosen)
     ok = chosen >= 0
     kstate = release_batch(kstate, jnp.asarray(chosen.clip(0)),
@@ -246,7 +354,7 @@ def test_kernel_health_mask_and_no_capacity():
     st = ShardingPolicyState.build([256] * 4)
     batch = _batch_from_trace(st, [("ns", "a", 256, 1, False)],
                               _make_slot_allocator())
-    _, chosen, forced = schedule_batch(kstate, batch)
+    _, chosen, forced, _warm = schedule_batch(kstate, batch)
     assert int(chosen[0]) == -1 and not bool(forced[0])
 
 
@@ -256,7 +364,7 @@ def test_kernel_padding_rows_never_chosen():
         st, [("ns", f"a{i}", 256, 1, False) for i in range(9)],
         _make_slot_allocator())
     kstate = init_state(3, [256] * 3, n_pad=16, action_slots=8)
-    _, chosen, forced = schedule_batch(kstate, batch)
+    _, chosen, forced, _warm = schedule_batch(kstate, batch)
     assert np.asarray(chosen).max() < 3
 
 
@@ -266,7 +374,7 @@ def test_forced_overcommit_goes_negative_and_recovers():
     trace = [("ns", "a", 256, 1, False)] * 4
     batch = _batch_from_trace(st, trace, slot_of)
     kstate = init_state(2, [256] * 2, action_slots=8)
-    kstate, chosen, forced = schedule_batch(kstate, batch)
+    kstate, chosen, forced, _warm = schedule_batch(kstate, batch)
     assert np.asarray(forced)[2:].all()
     assert np.asarray(kstate.free_mb).min() < 0  # ForcibleSemaphore overcommit
     # releases heal the books
@@ -296,11 +404,12 @@ class TestShardedParity:
         batch = _batch_from_trace(st, trace, slot_of)
 
         single = init_state(64, [1024] * 64, action_slots=64)
-        s1, c1, f1 = schedule_batch(single, batch)
+        s1, c1, f1, w1 = schedule_batch(single, batch)
 
         sharded0 = shard_state(init_state(64, [1024] * 64, action_slots=64), mesh8)
         sched = make_sharded_schedule(mesh8)
-        s2, c2, f2 = sched(sharded0, batch)
+        s2, c2, f2, w2 = sched(sharded0, batch)
+        np.testing.assert_array_equal(np.asarray(w1), np.asarray(w2))
 
         np.testing.assert_array_equal(np.asarray(c1), np.asarray(c2))
         np.testing.assert_array_equal(np.asarray(f1), np.asarray(f2))
@@ -350,7 +459,7 @@ class TestNorthStarScale:
         assert int(np.asarray(batch.step_inv).max()) * (n - 1) > 2**31, \
             "trace does not exercise the overflow regime"
         kstate = init_state(n, [st.invoker_slot_mb(2048)] * n, action_slots=64)
-        kstate, chosen, forced = schedule_batch(kstate, batch)
+        kstate, chosen, forced, _warm = schedule_batch(kstate, batch)
         oracle = _run_oracle(st, trace)
         for i, ((oc, of), kc, kf) in enumerate(zip(oracle, np.asarray(chosen),
                                                    np.asarray(forced))):
@@ -375,9 +484,10 @@ class TestNorthStarScale:
         batch = _batch_from_trace(st, trace, slot_of)
 
         single = init_state(n, [2048] * n, action_slots=32)
-        s1, c1, f1 = schedule_batch(single, batch)
+        s1, c1, f1, w1 = schedule_batch(single, batch)
         sharded = shard_state(init_state(n, [2048] * n, action_slots=32), mesh)
-        s2, c2, f2 = make_sharded_schedule(mesh)(sharded, batch)
+        s2, c2, f2, w2 = make_sharded_schedule(mesh)(sharded, batch)
+        np.testing.assert_array_equal(np.asarray(w1), np.asarray(w2))
         np.testing.assert_array_equal(np.asarray(c1), np.asarray(c2))
         np.testing.assert_array_equal(np.asarray(f1), np.asarray(f2))
         np.testing.assert_array_equal(np.asarray(s1.free_mb),

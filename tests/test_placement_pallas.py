@@ -24,9 +24,10 @@ from openwhisk_tpu.ops.placement_pallas import (fits_vmem,
 def test_pallas_matches_xla(n, batch, seed):
     state = init_state(n, [1024] * n, action_slots=64)
     req = _example_batch(n, batch, seed=seed)
-    s1, c1, f1 = schedule_batch(state, req)
-    s2, c2, f2 = schedule_batch_pallas(to_transposed(state), req,
-                                       interpret=True)
+    s1, c1, f1, w1 = schedule_batch(state, req)
+    s2, c2, f2, w2 = schedule_batch_pallas(to_transposed(state), req,
+                                           interpret=True)
+    np.testing.assert_array_equal(np.asarray(w1), np.asarray(w2))
     np.testing.assert_array_equal(np.asarray(c1), np.asarray(c2))
     np.testing.assert_array_equal(np.asarray(f1), np.asarray(f2))
     np.testing.assert_array_equal(np.asarray(s1.free_mb),
@@ -40,9 +41,10 @@ def test_pallas_respects_health_mask_and_overload():
     state = init_state(n, [256] * n, action_slots=8)
     state = set_health(state, list(range(8)), [False] * 8)
     req = _example_batch(n, 48, seed=9)  # demand far exceeds capacity
-    s1, c1, f1 = schedule_batch(state, req)
-    s2, c2, f2 = schedule_batch_pallas(to_transposed(state), req,
-                                       interpret=True)
+    s1, c1, f1, w1 = schedule_batch(state, req)
+    s2, c2, f2, w2 = schedule_batch_pallas(to_transposed(state), req,
+                                           interpret=True)
+    np.testing.assert_array_equal(np.asarray(w1), np.asarray(w2))
     np.testing.assert_array_equal(np.asarray(c1), np.asarray(c2))
     np.testing.assert_array_equal(np.asarray(f1), np.asarray(f2))
     # unhealthy invokers never chosen, even forced
@@ -73,9 +75,10 @@ def test_pallas_out_of_range_slots_match_xla_scatter_semantics():
     # OOB slot 9 with max_conc=4, then a legit request on slot 3 (the
     # clamped column) with max_conc=4
     req = mk([9, 3, 3], [4, 4, 4])
-    s1, c1, f1 = schedule_batch(state, req)
-    s2, c2, f2 = schedule_batch_pallas(to_transposed(state), req,
-                                       interpret=True)
+    s1, c1, f1, w1 = schedule_batch(state, req)
+    s2, c2, f2, w2 = schedule_batch_pallas(to_transposed(state), req,
+                                           interpret=True)
+    np.testing.assert_array_equal(np.asarray(w1), np.asarray(w2))
     np.testing.assert_array_equal(np.asarray(c1), np.asarray(c2))
     np.testing.assert_array_equal(np.asarray(s1.free_mb),
                                   np.asarray(s2.free_mb))

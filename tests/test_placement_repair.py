@@ -70,10 +70,11 @@ def _random_state(n, rng, mem=1024, slots=16, unhealthy_p=0.2,
 
 
 def _assert_same_outcome(scan_out, repair_out):
-    s_state, s_chosen, s_forced = scan_out
-    r_state, r_chosen, r_forced = repair_out[:3]
+    s_state, s_chosen, s_forced, s_warm = scan_out
+    r_state, r_chosen, r_forced, r_warm = repair_out[:4]
     np.testing.assert_array_equal(np.asarray(s_chosen), np.asarray(r_chosen))
     np.testing.assert_array_equal(np.asarray(s_forced), np.asarray(r_forced))
+    np.testing.assert_array_equal(np.asarray(s_warm), np.asarray(r_warm))
     np.testing.assert_array_equal(np.asarray(s_state.free_mb),
                                   np.asarray(r_state.free_mb))
     np.testing.assert_array_equal(np.asarray(s_state.conc_free),
@@ -97,7 +98,7 @@ class TestRepairKernelParity:
             r_out = schedule_batch_repair(r_state, batch)
             _assert_same_outcome(s_out, r_out)
             s_state, r_state = s_out[0], r_out[0]
-            assert int(r_out[3]) >= 1  # at least one commit round ran
+            assert int(r_out[4]) >= 1  # at least one commit round ran
 
     def test_overload_forced_parity(self):
         """Memory pressure forces random-rotation placement (over-commit):
@@ -119,7 +120,8 @@ class TestRepairKernelParity:
         state = init_state(n, [1024] * n, action_slots=8)
         state = state._replace(health=jnp.zeros((n,), bool))
         batch = _random_batch(n, b, rng)
-        r_state, chosen, forced, rounds = schedule_batch_repair(state, batch)
+        r_state, chosen, forced, _warm, rounds = schedule_batch_repair(
+            state, batch)
         assert (np.asarray(chosen) == -1).all()
         assert not np.asarray(forced).any()
         np.testing.assert_array_equal(np.asarray(r_state.free_mb),
@@ -170,7 +172,7 @@ class TestRepairKernelParity:
         # DESIGN (they never commute with order-inverted column reads), so
         # this shape serializes partially — measured 23 rounds — and only
         # the "well below B" contract applies
-        assert int(r_out[3]) < b // 4
+        assert int(r_out[4]) < b // 4
 
         # the fleet >> batch claim proper: memory-dominant traffic (the
         # production bulk; max_conc <= 1) sees almost no conflicts
@@ -180,7 +182,7 @@ class TestRepairKernelParity:
         s2 = schedule_batch(state2, batch2)
         r2 = schedule_batch_repair(state2, batch2)
         _assert_same_outcome(s2, r2)
-        assert int(r2[3]) <= 4
+        assert int(r2[4]) <= 4
 
 
 class TestReleaseVectorParity:

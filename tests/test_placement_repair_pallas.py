@@ -93,20 +93,20 @@ class TestPrimsEquivalence:
 def _pallas_repair(state, batch):
     from openwhisk_tpu.ops.placement_pallas import (
         schedule_batch_repair_pallas, to_transposed)
-    ts, chosen, forced, rounds = schedule_batch_repair_pallas(
+    ts, *out = schedule_batch_repair_pallas(
         to_transposed(state), batch, interpret=True)
     from openwhisk_tpu.ops.placement import PlacementState
-    return (PlacementState(ts.free_mb, ts.conc_free.T, ts.health), chosen,
-            forced, rounds)
+    return (PlacementState(ts.free_mb, ts.conc_free.T, ts.health), *out)
 
 
 def _assert_repair_parity(state, batch, check_rounds=True):
-    s_state, s_chosen, s_forced = schedule_batch(state, batch)
-    x_state, x_chosen, x_forced, x_rounds = schedule_batch_repair(state,
-                                                                 batch)
-    p_state, p_chosen, p_forced, p_rounds = _pallas_repair(state, batch)
+    s_state, s_chosen, s_forced, s_warm = schedule_batch(state, batch)
+    x_rounds = schedule_batch_repair(state, batch)[4]
+    p_state, p_chosen, p_forced, p_warm, p_rounds = _pallas_repair(state,
+                                                                   batch)
     np.testing.assert_array_equal(np.asarray(s_chosen), np.asarray(p_chosen))
     np.testing.assert_array_equal(np.asarray(s_forced), np.asarray(p_forced))
+    np.testing.assert_array_equal(np.asarray(s_warm), np.asarray(p_warm))
     np.testing.assert_array_equal(np.asarray(s_state.free_mb),
                                   np.asarray(p_state.free_mb))
     np.testing.assert_array_equal(np.asarray(s_state.conc_free),
@@ -176,7 +176,8 @@ class TestPallasRepairParity:
         state = init_state(n, [1024] * n, action_slots=8)
         state = state._replace(health=jnp.zeros((n,), bool))
         batch = _random_batch(n, b, rng)
-        p_state, p_chosen, p_forced, p_rounds = _pallas_repair(state, batch)
+        p_state, p_chosen, p_forced, _warm, p_rounds = _pallas_repair(
+            state, batch)
         assert (np.asarray(p_chosen) == -1).all()
         assert not np.asarray(p_forced).any()
         assert int(p_rounds) == 1
