@@ -5,8 +5,8 @@ scheduling inner loop — the reference's per-activation CPU probe walk
 (ShardingContainerPoolBalancer.schedule) — runs as a vectorized device
 kernel over the live fleet state:
 
-  publish() ──> micro-batch buffer ──┐ (adaptive window: flush at max_batch
-                                     │  or after batch_window seconds)
+  publish() ──> micro-batch buffer ──┐ (flush at max_batch, or after the
+                                     │  dispatch hold / batch_window)
   completion acks ──> release buffer ┤
   health transitions ─> health buffer┤
                                      ▼
@@ -15,9 +15,11 @@ kernel over the live fleet state:
              assignments ──> ActivationMessage dispatch over the bus
 
 Design notes (SURVEY §7 "hard parts"):
-  - batching vs latency: requests wait at most `batch_window` (default
-    2 ms) or until `max_batch` queue; a single in-flight device step at a
-    time keeps ordering and lets the next window fill while one computes.
+  - batching vs latency: a partly filled batch is held for
+    DISPATCH_HOLD_K x the loop time a fused step is measured to cost
+    (under arrival pressure), else `batch_window` (default 2 ms), or until
+    `max_batch` queue; dispatch is loop-serialized, which keeps ordering
+    and lets the next batch fill while one computes.
   - host<->device coherence: acks and health flips never touch device state
     directly — they buffer host-side and fold in at the next step boundary
     (double-buffered deltas), so the kernel never races its own state.
@@ -35,6 +37,7 @@ from __future__ import annotations
 import asyncio
 import time
 import zlib
+from collections import deque
 from dataclasses import dataclass
 from typing import Dict, List, Optional
 
@@ -126,13 +129,13 @@ class PlacementPathConfig:
     #: has interpret mode there). "force": calibrate even on the CPU twin
     #: (tests / bench's auto_pick row). "off": static resolver only.
     calibrate_kernel: str = "auto"   # auto | force | off
-    #: adaptive_window: under arrival pressure, trade a bounded
-    #: accumulation delay (ADAPTIVE_WINDOW_MS) for bigger micro-batches
-    #: instead of eager per-arrival dispatch. An idle or slow-trickle
-    #: balancer keeps the eager fast path (zero added latency); a loaded
-    #: one stops paying one fixed-cost device dispatch per 1-3 arrivals —
-    #: the dominant per-activation tax at high open-loop rates on the CPU
-    #: twin. Off = the exact pre-coalescing eager/window policy.
+    #: adaptive_window: under arrival pressure, hold a partly filled
+    #: micro-batch open for DISPATCH_HOLD_K times the loop time one fused
+    #: step is measured to cost (TpuBalancer._note_step_cost) instead of
+    #: dispatching per arrival. An idle or slow-trickle balancer keeps the
+    #: eager fast path (zero added latency); a loaded one stops paying one
+    #: fixed-cost device dispatch per 1-3 arrivals. Off = the exact
+    #: pre-coalescing eager/window policy.
     adaptive_window: bool = True
     #: fleet_mesh: shard the invoker axis of the placement state over a
     #: ('fleet',) device mesh (parallel/fleet_mesh.py) — the horizontal-
@@ -621,6 +624,12 @@ class TpuBalancer(CommonLoadBalancer):
         self._gap_ewma_ms = 1000.0
         self._last_gap_ms = 1e9
         self._last_pub_t = time.monotonic()
+        #: what the last HOLD_SAMPLES fused steps cost the loop (s) and the
+        #: hold derived from them (`_note_step_cost`). Zeros until that
+        #: many steps have run: an unmeasured balancer holds nothing.
+        self._step_costs = deque([0.0] * self.HOLD_SAMPLES,
+                                 maxlen=self.HOLD_SAMPLES)
+        self._hold_s = 0.0
         self.managed_fraction = managed_fraction
         self.blackbox_fraction = blackbox_fraction
         self.batch_window = batch_window
@@ -2780,13 +2789,29 @@ class TpuBalancer(CommonLoadBalancer):
 
     # -- the device step ---------------------------------------------------
 
-    #: adaptive dispatch window (see PlacementPathConfig.adaptive_window):
-    #: the bounded accumulation delay a loaded balancer trades for batch
-    #: size, and the minimum batch a window must be expected to gather to
-    #: be worth holding (below that, eager dispatch wins on latency with
-    #: nothing to amortize)
-    ADAPTIVE_WINDOW_MS = 8.0
-    ADAPTIVE_MIN_BATCH = 4
+    #: the dispatch hold (see PlacementPathConfig.adaptive_window): how long
+    #: a partly filled batch is held open = DISPATCH_HOLD_K x what one fused
+    #: step costs the loop, measured (`_note_step_cost`). What a hold
+    #: amortises is that cost, so it is the only thing that sizes it: a
+    #: hold of K step costs keeps the steps' share of the loop at or under
+    #: 1 / (K + 1) whatever the fleet, the planes or the rows per step, and
+    #: a loop that makes its steps wait lengthens the hold by itself.
+    #: K settled ON THE CHIP (TPU v5e, one seed a cell, PERF.md section 6,
+    #: PR 29), at K = 1 / 2 / 3 / 4 (in brackets the 8 ms constant it
+    #: replaced, same seed):
+    #:   standalone16-noop-open   12.31 / 9.31 / 9.79 / 11.58 ms (13.72)
+    #:   fleet1k-zipf-open        23.87 / 19.19 / 17.89 / 16.57 ms (17.81)
+    #:   standalone16-noop-closed 4,351 / 4,249 / 4,051 / 3,334 act/s (3,948)
+    #: of overhead_p50_ms and completed_per_s. 1 falls under the arrival
+    #: gap at 800/s (a step per arrival: the reverted eager policy), 2
+    #: drives fleet1k's half-busy loop into its own queue, 4 holds the
+    #: convoy of 128 for 12.6 ms: 3 is the one that costs no cell anything.
+    DISPATCH_HOLD_K = 3
+    #: step costs kept; the estimate is their second smallest, so it
+    #: stands while six of eight samples are stalls (set-up's ladder
+    #: compiles six bucket shapes in a row; a collection pauses one step
+    #: for 50-300 ms) and one freak low moves nothing
+    HOLD_SAMPLES = 8
 
     def _note_arrival(self, now: float) -> None:
         """Track the publish inter-arrival EWMA — the pressure signal the
@@ -2811,17 +2836,28 @@ class TpuBalancer(CommonLoadBalancer):
             self._gap_ewma_ms *= 0.9 ** (n - 1)
             self._last_gap_ms = 0.0
 
+    def _note_step_cost(self, cost_s: float) -> None:
+        """What one fused step cost the loop, into the estimate the hold is
+        derived from: from the moment its hold ran out (the loop's queue in
+        front of the flush task, the step lock, the pipeline's room) to
+        `_dispatch_batch`'s last line; an inline step has no such moment
+        and counts `_dispatch_batch` alone. One sort of HOLD_SAMPLES floats
+        a step; nothing per activation."""
+        self._step_costs.append(cost_s)
+        self._hold_s = self.DISPATCH_HOLD_K * sorted(self._step_costs)[1]
+        self.metrics.gauge("loadbalancer_dispatch_hold_ms",
+                           self._hold_s * 1e3)
+
     def _coalesce_window_s(self) -> float:
-        """> 0 when arrival pressure says windowed batching beats eager
-        dispatch: the EWMA predicts at least ADAPTIVE_MIN_BATCH arrivals
-        inside one window, and the instantaneous gap confirms traffic is
+        """The hold (s) when arrival pressure says it will gather more
+        rows, else 0: the inter-arrival EWMA predicts another arrival
+        inside one hold, and the instantaneous gap confirms traffic is
         still flowing (a lone request after a burst must not inherit the
-        burst's window)."""
-        if (self.adaptive_window
-                and self._gap_ewma_ms * self.ADAPTIVE_MIN_BATCH
-                <= self.ADAPTIVE_WINDOW_MS
-                and self._last_gap_ms <= self.ADAPTIVE_WINDOW_MS):
-            return self.ADAPTIVE_WINDOW_MS / 1e3
+        burst's hold)."""
+        hold_ms = self._hold_s * 1e3
+        if (self.adaptive_window and self._gap_ewma_ms <= hold_ms
+                and self._last_gap_ms <= hold_ms):
+            return self._hold_s
         return 0.0
 
     def _arm_flush(self, urgent: bool = False) -> None:
@@ -2844,13 +2880,16 @@ class TpuBalancer(CommonLoadBalancer):
         # loop INSIDE the task until drained: a tail call to _arm_flush would
         # be a no-op (this task is not done() yet) and strand leftover work
         while True:
+            due = time.monotonic() + delay
             if delay:
                 await asyncio.sleep(delay)
             async with self._step_lock:
-                await self._device_step()
+                await self._device_step(delay, due)
             if not (self._pending or self._releases or self._health_updates):
                 return
-            delay = self._coalesce_window_s() or self.batch_window
+            # a batch that is full already is held for nothing
+            delay = (0.0 if len(self._pending) >= self.max_batch
+                     else self._coalesce_window_s() or self.batch_window)
 
     #: request-tuple field indices (row order of the packed matrix)
     R_NEED_MB, R_CONC_SLOT, R_MAX_CONC = 4, 5, 6
@@ -2954,7 +2993,8 @@ class TpuBalancer(CommonLoadBalancer):
             return True
         return False
 
-    async def _device_step(self) -> None:
+    async def _device_step(self, held_s: float = 0.0,
+                           due: Optional[float] = None) -> None:
         if not self._pending:
             # nothing to schedule: fold releases (padded+masked like the
             # fused path) and health (exact-size; dict keys are unique)
@@ -2996,7 +3036,7 @@ class TpuBalancer(CommonLoadBalancer):
             self._capacity_free.clear()
             await self._capacity_free.wait()
         self._set_inflight(1)
-        self._dispatch_batch()
+        self._dispatch_batch(held_s, due)
 
     def _fold_now(self) -> None:
         """The release-only / health fold and its journal record (one
@@ -3077,7 +3117,11 @@ class TpuBalancer(CommonLoadBalancer):
                               req_np.ravel()])
         return req_np, rec, wf_aids, rel_np, health_np, buf
 
-    def _dispatch_batch(self) -> None:
+    def _dispatch_batch(self, held_s: float = 0.0,
+                        due: Optional[float] = None) -> None:
+        """One fused step. `held_s`: what the flush task slept before it,
+        and `due`: when that sleep should have ended; 0 and None from the
+        inline paths (a full batch, eager on an idle pipeline)."""
         batch, self._pending = self._pending[: self.max_batch], \
             self._pending[self.max_batch:]
         t0 = time.monotonic()
@@ -3099,8 +3143,10 @@ class TpuBalancer(CommonLoadBalancer):
         # journal seq or, journal off, its books seq
         books_seq = self._next_books_seq()
         seq = self._journal_next_seq() or books_seq
+        # hold_us: the hold that closed this batch; a full one closed on size
         with span("ow_assemble", seq=seq, b=b, bp=bp, n_rel=n_rel,
-                  pending=len(self._pending), inflight=self._inflight_steps):
+                  pending=len(self._pending), inflight=self._inflight_steps,
+                  hold_us=int(held_s * 1e6) if b < self.max_batch else 0):
             req_np, rec, wf_aids, rel_np, health_np, buf = \
                 self._assemble_batch(batch, b, bp, t0)
             t_assembled = time.monotonic()
@@ -3270,6 +3316,7 @@ class TpuBalancer(CommonLoadBalancer):
                                 books_seq, jseq, q_summary, seq))
         self._readbacks.add(task)
         task.add_done_callback(self._readbacks.discard)
+        self._note_step_cost(time.monotonic() - (due or t0))
 
     def _refresh_books_async(self) -> None:
         """Refresh occupancy()'s cached books off a device step that has no
