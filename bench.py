@@ -339,14 +339,10 @@ def _balancer_bench(n_invokers: int = 16, total: int = 2000,
                     profiling: bool = True,
                     anomaly: bool = True,
                     waterfall: bool = True,
-                    fleet_observatory: bool = True,
-                    **host_path) -> dict:
+                    fleet_observatory: bool = True) -> dict:
     """TpuBalancer.publish() end-to-end on the in-memory bus with echo
     invokers: the full host path (slot alloc, micro-batch assembly, device
     step, promise fan-out, bus send) that the raw kernel number omits.
-    `host_path` forwards hot-path knobs (placement_kernel, pipeline_depth,
-    donate_state, ring_assembly) straight to the TpuBalancer constructor —
-    the pipeline_speedup rider toggles them.
 
     CLOSED-loop by construction (`concurrency` workers behind a
     semaphore): the system sets the arrival rate, so the percentiles
@@ -372,7 +368,7 @@ def _balancer_bench(n_invokers: int = 16, total: int = 2000,
         prof = KernelProfiler(ProfilingConfig(enabled=profiling))
         bal = TpuBalancer(provider, ControllerInstanceId("0"),
                           managed_fraction=1.0, blackbox_fraction=0.0,
-                          kernel=kernel, profiler=prof, **host_path)
+                          kernel=kernel, profiler=prof)
         bal.flight_recorder.enabled = flight_recorder
         bal.telemetry.enabled = telemetry
         bal.anomaly.enabled = anomaly
@@ -2496,7 +2492,7 @@ def _bus_e2e_point(knobs_on: bool, rate: float, duration: float) -> dict:
     scoreboard (run in a fresh subprocess via _cpu_subprocess_json — the
     ISSUE 8 knobs are env-driven, read at balancer/producer construction,
     so setting them here before the sweep builds its target is enough).
-    The toggles cover bus coalescing + the adaptive dispatch window ONLY:
+    The toggle covers bus coalescing ONLY:
     loadgen enters at balancer.publish, so the admission plane is not on
     this measured path (it is exercised by the HTTP burst drive in the
     verify recipe and tests/test_admission.py instead)."""
@@ -2504,10 +2500,8 @@ def _bus_e2e_point(knobs_on: bool, rate: float, duration: float) -> dict:
     # set BOTH branches explicitly: a knobs-off env inherited from the
     # operator's shell would otherwise silently turn the on-vs-off
     # scoreboard into serial-vs-serial
-    v = "true" if knobs_on else "false"
-    os.environ.update({
-        "CONFIG_whisk_bus_coalesce_enabled": v,
-        "CONFIG_whisk_loadBalancer_adaptiveWindow": v})
+    os.environ["CONFIG_whisk_bus_coalesce_enabled"] = (
+        "true" if knobs_on else "false")
     from tools.loadgen import sweep_balancer
     row = sweep_balancer(fixed_rate=rate, duration=duration)
     budget = row.get("stage_budget") or {}
@@ -2658,32 +2652,6 @@ def _repair_compile_census(batch_sizes, n_invokers: int = 256) -> dict:
             "recompiles_unexpected": prof.compiles_unexpected}
 
 
-def _auto_pick_row(n_invokers: int, b: int) -> dict:
-    """The kernel="auto" calibration, run exactly as the balancer's prewarm
-    drainer runs it (same `calibrate_backend_rates`, same cache): which
-    backend the measured rate picks at the headline geometry, plus the
-    cached per-backend numbers."""
-    import jax
-
-    from openwhisk_tpu.controller.loadbalancer.tpu_balancer import (
-        _next_pow2, calibrate_backend_rates)
-    from openwhisk_tpu.ops.placement_pallas import fits_vmem_repair
-
-    n_pad = _next_pow2(n_invokers)
-    on_cpu = jax.default_backend() == "cpu"
-    include = fits_vmem_repair(n_pad, 256, b)
-    cal = calibrate_backend_rates(
-        n_pad, 256, b, b, b, include_pallas=include,
-        iters=2 if on_cpu else 5)
-    out = dict(cal)
-    out["backend"] = jax.default_backend()
-    if on_cpu:
-        # the CPU twin can only measure interpret-mode pallas — an honest
-        # relative number for the CACHE mechanics, not a device verdict
-        out["note"] = "cpu twin: pallas rate is interpret mode"
-    return out
-
-
 def _repair_vs_scan(batch_sizes=(64, 256, 1024), n_invokers: int = 1024,
                     repeats: int = 3, iters: int = 12) -> Optional[dict]:
     """The PR-5/PR-10 tentpole rider: speculate-and-repair vs the reference
@@ -2699,8 +2667,7 @@ def _repair_vs_scan(batch_sizes=(64, 256, 1024), n_invokers: int = 1024,
     parity still asserted. A `convoy` row measures the documented worst
     case — the largest B over the headline's FIXED 64-action pool, i.e.
     deep same-action overflow chains — where the scan is expected to win.
-    An `auto_pick` row reports which backend the kernel="auto" calibration
-    chose and the cached measured rates. Acceptance: repair >= scan at
+    Acceptance: repair >= scan at
     B=64 and >= 2x at B=1024, parity true (pallas included),
     recompiles_unexpected == 0."""
     try:
@@ -2769,14 +2736,9 @@ def _repair_vs_scan(batch_sizes=(64, 256, 1024), n_invokers: int = 1024,
         n_max = max(n_invokers, 4 * b_max)
         measure("convoy", b_max, n_max,
                 _example_batch(n_max, b_max, seed=7), 1, 3)
-        try:
-            auto_pick = _auto_pick_row(n_invokers, min(256, b_max))
-        except Exception as e:  # noqa: BLE001 — the row is advisory
-            auto_pick = {"error": repr(e)}
         return {"rows": rows, "parity": parity_all,
                 "repeats": repeats,
                 "pallas_backend": "interpret" if on_cpu else "device",
-                "auto_pick": auto_pick,
                 "protocol": "per-action burst held at 4 (the headline "
                             "protocol's B=256/64-action ratio) with "
                             "fleet/batch >= 4; the convoy row is the "
@@ -2788,45 +2750,6 @@ def _repair_vs_scan(batch_sizes=(64, 256, 1024), n_invokers: int = 1024,
                 "compile_census": _repair_compile_census(batch_sizes)}
     except Exception as e:  # noqa: BLE001 — rider is auxiliary
         print(f"# repair_vs_scan failed: {e!r}", file=sys.stderr)
-        return None
-
-
-def _pipeline_speedup(repeats: int = 3, total: int = 1200,
-                      concurrency: int = 64) -> Optional[dict]:
-    """The PR-5 end-to-end rider: the full balancer path with the host-path
-    overhaul ON (auto placement kernel, pipelined dispatch, buffer
-    donation where the backend supports it, ring assembly — the defaults)
-    vs OFF (scan kernel, single in-flight step, no donation,
-    list-of-tuples assembly — the bit-exact legacy path). Prewarm is off
-    in BOTH configs: the compile-ahead ladder is a cold-start feature, and
-    in a short measured window where every bucket is already compiled its
-    background compiles are pure 2-core contention noise. Acceptance:
-    speedup >= 2x on the same box, zero unexpected recompiles either
-    way."""
-    try:
-        on_rates, off_rates, recompiles = [], [], 0
-        for _ in range(repeats):
-            on = _balancer_bench(total=total, concurrency=concurrency,
-                                 kernel="xla", prewarm=False)
-            off = _balancer_bench(total=total, concurrency=concurrency,
-                                  kernel="xla", placement_kernel="scan",
-                                  pipeline_depth=1, donate_state=False,
-                                  ring_assembly=False, prewarm=False)
-            on_rates.append(on["activations_per_sec"])
-            off_rates.append(off["activations_per_sec"])
-            recompiles += (on["recompiles_unexpected"]
-                           + off["recompiles_unexpected"])
-        on_med = statistics.median(on_rates)
-        off_med = statistics.median(off_rates)
-        return {
-            "rate_pipelined": round(on_med, 1),
-            "rate_single_inflight": round(off_med, 1),
-            "speedup": round(on_med / off_med, 2) if off_med else None,
-            "repeats": repeats,
-            "recompiles_unexpected": recompiles,
-        }
-    except Exception as e:  # noqa: BLE001 — rider is auxiliary
-        print(f"# pipeline_speedup failed: {e!r}", file=sys.stderr)
         return None
 
 
@@ -3787,7 +3710,6 @@ def _run(args) -> Optional[dict]:
     placement_quality_overhead = None
     funnel_10k = None
     repair_vs_scan = None
-    pipeline_speedup = None
     bus_coalesce_speedup = None
     failover_downtime = None
     partition_chaos = None
@@ -3844,8 +3766,6 @@ def _run(args) -> Optional[dict]:
         incident_overhead = timed_rider("_incident_overhead",
                                         _incident_overhead)
         repair_vs_scan = timed_rider("_repair_vs_scan", _repair_vs_scan)
-        pipeline_speedup = timed_rider("_pipeline_speedup",
-                                       _pipeline_speedup)
         recorder_overhead = timed_rider("_flight_recorder_overhead",
                                         _flight_recorder_overhead)
         telemetry_overhead = timed_rider("_telemetry_overhead",
@@ -3896,9 +3816,10 @@ def _run(args) -> Optional[dict]:
     # at THIS stage's geometry (fleet padded to a power of two, 256 action
     # slots) — the same resolver TpuBalancer uses, not a re-implementation;
     # both kernel rows ride along in `kernels`
-    from openwhisk_tpu.controller.loadbalancer.tpu_balancer import (
-        _next_pow2, resolve_auto_kernel)
-    default_kernel = resolve_auto_kernel(_next_pow2(args.fleet), 256)
+    from openwhisk_tpu.controller.loadbalancer.kernel_choice import choose
+    from openwhisk_tpu.controller.loadbalancer.tpu_balancer import \
+        _next_pow2
+    default_kernel = choose(_next_pow2(args.fleet), 256, 256).backend
     if default_kernel not in kernels:
         default_kernel = "xla" if "xla" in kernels else "pallas"
     headline = kernels.get(default_kernel) or next(iter(kernels.values()))
@@ -3920,7 +3841,7 @@ def _run(args) -> Optional[dict]:
         "spread_pct": headline["spread_pct"],
         "kernel_selection": {
             "default": default_kernel,
-            "policy": "kernel='auto' (TpuBalancer.resolve_auto_kernel): "
+            "policy": "kernel='auto' (loadbalancer/kernel_choice.choose): "
                       "pallas on TPU while the state fits VMEM, else xla "
                       "(large fleets swap to xla on growth)",
             "geometry": {"n_pad": _next_pow2(args.fleet),
@@ -3970,8 +3891,6 @@ def _run(args) -> Optional[dict]:
         out["repair_vs_scan"] = repair_vs_scan
     if sharded_fleet_sweep is not None:
         out["sharded_fleet_sweep"] = sharded_fleet_sweep
-    if pipeline_speedup is not None:
-        out["pipeline_speedup"] = pipeline_speedup
     if trace_assembly is not None:
         out["trace_assembly"] = trace_assembly
     if trace_plane_overhead is not None:

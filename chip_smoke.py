@@ -9,8 +9,8 @@ process that owns the chip and has fully exited before the next starts
      over HTTP — create one python:3 action, 8 sequential + a burst of 16
      blocking invokes (inside the default 30-concurrent / 60-per-minute
      throttles), every answer checked, one activation record fetched back
-     by id; then which kernel served, zero unexpected recompiles, and a
-     kernel calibration without errors.
+     by id; then which kernel served and how it was chosen (by
+     kernel_choice's static rule), and zero unexpected recompiles.
   B  fleet at real width: `python tools/loadgen.py --invokers 1024` —
      TpuBalancer.publish_many -> fused step -> readback -> bus -> 1,024
      echo invokers -> ack, the books growing on the device from the
@@ -52,7 +52,7 @@ BUDGET_S = 1150.0
 
 #: a logged-and-swallowed failure: the planes' device programs (shadow
 #: step, quality scorer, telemetry fold, anomaly step/harvest, bucket
-#: prewarm, calibration) and the dispatch/readback paths all say
+#: prewarm) and the dispatch/readback paths all say
 #: "<what> failed: <why>" at WARN or ERROR; any ERROR line counts as well
 _SWALLOWED = re.compile(r"\[ERROR\]|\[WARN\].*failed|Traceback \(most recent")
 
@@ -208,20 +208,13 @@ def leg_a(workdir: str, timeout_s: float) -> dict:
         check(json.loads(text)["response"]["result"]
               == {"n": 3, "square": 9}, "activation record: wrong result")
 
-        # the calibration microbench rides the prewarm drainer thread
-        profile = {}
-        for _ in range(240):
-            status, text = http("GET", f"{base}/admin/profile/kernel", auth)
-            check(status == 200, f"/admin/profile/kernel: {status}")
-            profile = json.loads(text)
-            if "calibration" in profile:
-                break
-            time.sleep(0.5)
+        status, text = http("GET", f"{base}/admin/profile/kernel", auth)
+        check(status == 200, f"/admin/profile/kernel: {status}")
+        profile = json.loads(text)
         check_device(profile.get("device"), "A (/admin/profile/kernel)")
-        check("calibration" in profile,
-              "kernel calibration never ran on the TPU")
-        check("errors" not in profile["calibration"],
-              f"calibration errors: {profile['calibration'].get('errors')}")
+        check(profile["kernel_chosen_by"] == "static",
+              f"kernel chosen by {profile['kernel_chosen_by']!r}, not by "
+              f"the static rule")
         check(profile["compiles"]["unexpected"] == 0,
               f"unexpected recompiles: {profile['compiles']}")
 
@@ -237,6 +230,11 @@ def leg_a(workdir: str, timeout_s: float) -> dict:
         check(all(float(v) == 0 for v in churn),
               f'expected="false" recompiles: {churn}')
         kernel = dict(re.findall(r'(\w+)="([^"]*)"', served[0]))
+        check((kernel.get("backend"), kernel.get("placement"),
+               kernel.get("chosen_by"))
+              == (profile["kernel"], profile["placement_kernel"], "static"),
+              f"kernel_backend gauge {kernel} disagrees with "
+              f"/admin/profile/kernel")
     finally:
         stop_group(proc)
     check(proc.returncode in (0, -signal.SIGTERM),
@@ -245,9 +243,7 @@ def leg_a(workdir: str, timeout_s: float) -> dict:
         bad = swallowed_failures(f.read())
     check(not bad, "standalone logged failures:\n" + "\n".join(bad[:20]))
     return {"device": device, "invokes": 24, "kernel": kernel,
-            "compiles": profile["compiles"]["expected"],
-            "calibration": {k: profile["calibration"].get(k)
-                            for k in ("rates", "winner", "sig")}}
+            "compiles": profile["compiles"]["expected"]}
 
 
 # -- leg B: the fleet at real width ----------------------------------------
@@ -285,7 +281,7 @@ def leg_c_child() -> None:
 
     import bench
     import warmhit
-    from openwhisk_tpu.controller.loadbalancer.tpu_balancer import _xla_pair
+    from openwhisk_tpu.controller.loadbalancer.kernel_choice import xla_pair
     from openwhisk_tpu.models.sharding_policy import ShardingPolicyState
     from openwhisk_tpu.ops.placement import (init_state,
                                              make_fused_step_packed,
@@ -354,7 +350,7 @@ def leg_c_child() -> None:
     batch = _batch_from_trace(st, trace, _make_slot_allocator())
     check(int(np.asarray(batch.step_inv).max()) * (n - 1) > 2 ** 31,
           "north-star trace does not reach the int32 overflow regime")
-    sched, release, _ = _xla_pair("auto")
+    sched, release, _ = xla_pair("auto")
     step = make_fused_step_packed(release, sched)
     rel = np.zeros((5, 32), np.int32)
     rel[3] = 1

@@ -1041,9 +1041,8 @@ class PublishCoalescer:
     tasks per activation.
 
     Built only when the balancer advertises `batch_publish`
-    (CONFIG_whisk_loadBalancer_batchPublish; `maybe_batch_publish`
-    returns None otherwise and callers keep the serial `publish` path
-    bit-exactly)."""
+    (`maybe_batch_publish` returns None otherwise and callers keep the
+    serial `publish` path bit-exactly)."""
 
     def __init__(self, balancer, max_batch: Optional[int] = None):
         self._bal = balancer

@@ -57,11 +57,10 @@ from ...ops.placement import (PlacementState, RequestBatch, init_state,
                               make_fused_step_packed, make_release_packed,
                               make_shadow_admit_step_packed,
                               make_shadow_step_packed,
-                              release_batch, release_batch_vector,
-                              schedule_batch, schedule_batch_repair,
                               journal_words, set_health, unpack_chosen,
                               unpack_step_output, unpack_warm)
 from .journal import decode_array, encode_array
+from .kernel_choice import KernelPlan, choose
 from ...ops.throttle import init_buckets
 from ...utils.config import device_info, load_config
 from ...utils.eventlog import GLOBAL_EVENT_LOG
@@ -90,75 +89,39 @@ def books_ref_copy(free_mb):
 @dataclass(frozen=True)
 class PlacementPathConfig:
     """`CONFIG_whisk_loadBalancer_*` hot-path knobs (constructor arguments
-    override the env).
+    override the env). `placement_kernel` and `kernel` are PINS on what
+    kernel_choice.choose would pick from what it observes (its module doc
+    has the rule): the tests hold the scan as the reference, the
+    benchmark's configurations pass `kernel`.
 
-    placement_kernel: which BATCH ALGORITHM schedules a micro-batch on the
-      XLA path — "scan" (the reference lax.scan: sequential depth B, the
-      bit-exact legacy path), "repair" (speculate-and-repair: sequential
-      depth ~ the intra-batch conflict count; bit-exact with the scan, see
-      ops/placement.schedule_batch_repair), or "auto" (repair on the XLA
-      path; the pallas and sharded schedules keep their own kernels).
-      Orthogonal to the `kernel` knob (xla/pallas device implementation).
-    donate_state: donate the PlacementState (and token-bucket carry) to the
-      fused step via donate_argnums, so the [N, A] concurrency matrix stops
-      round-tripping through fresh HBM allocations every step. Holders of
-      the pre-call state must copy first (see _materialize_state).
-    ring_assembly: assemble the packed request/release matrices from
-      preallocated int32 column rings written at enqueue time (O(1) per
-      activation) instead of per-flush list-of-tuples np.array transposes.
+    placement_kernel: the BATCH ALGORITHM — "scan" (the reference lax.scan:
+      sequential depth B), "repair" (speculate-and-repair: sequential depth
+      ~ the intra-batch conflict count; bit-exact with the scan, see
+      ops/placement.schedule_batch_repair), or "auto" (per bucket: scan
+      below REPAIR_MIN_BATCH, repair from it on).
+    kernel: the device BACKEND — "xla", "pallas" or "auto" (Pallas on a TPU
+      while the state fits VMEM, else XLA). Orthogonal to placement_kernel;
+      ignored on a fleet mesh.
     prewarm: compile successor bucket signatures ahead of traffic on a
       background drainer thread (see _prewarm_buckets). Off = every new
       bucket shape compiles synchronously inside a live dispatch — the
-      legacy behavior, also the right setting for latency-measurement
-      harnesses that can't tolerate background-compile GIL hiccups.
+      right setting for latency-measurement harnesses that can't tolerate
+      background-compile GIL hiccups.
+    fleet_mesh: shard the invoker axis of the placement state over a
+      ('fleet',) device mesh (parallel/fleet_mesh.py) — the horizontal-
+      scale mode where fleet capacity grows with chips instead of one
+      device's HBM. Per-shard speculate-and-repair with a per-round
+      global-occupancy exchange; bit-exact with the single-device kernels
+      at any shard count. Default OFF = the single-device path.
+    fleet_shards: shard count for fleet_mesh (power of two; 0 = every
+      visible device, rounded down to a power of two). Asking for more
+      shards than the default backend has devices is an error.
     """
     placement_kernel: str = "auto"   # scan | repair | auto
-    #: kernel: the device BACKEND (xla | pallas | auto) — orthogonal to
-    #: placement_kernel; "auto" resolves by cached measured rate (see
-    #: calibrate_kernel) with resolve_auto_kernel as the pre-calibration
-    #: guess. Constructor argument overrides the env, like the rest.
     kernel: str = "auto"             # xla | pallas | auto
-    donate_state: bool = True
-    ring_assembly: bool = True
     prewarm: bool = True
-    #: calibrate_kernel: how `kernel="auto"` picks the device backend
-    #: (xla vs pallas). "auto" (default): on a real TPU, a one-shot cached
-    #: per-bucket-shape microbench rides the prewarm drainer and the
-    #: MEASURED packed-step rate picks the backend (never on the event
-    #: loop; on non-TPU backends the static resolver stands — pallas only
-    #: has interpret mode there). "force": calibrate even on the CPU twin
-    #: (tests / bench's auto_pick row). "off": static resolver only.
-    calibrate_kernel: str = "auto"   # auto | force | off
-    #: adaptive_window: under arrival pressure, hold a partly filled
-    #: micro-batch open for DISPATCH_HOLD_K times the loop time one fused
-    #: step is measured to cost (TpuBalancer._note_step_cost) instead of
-    #: dispatching per arrival. An idle or slow-trickle balancer keeps the
-    #: eager fast path (zero added latency); a loaded one stops paying one
-    #: fixed-cost device dispatch per 1-3 arrivals. Off = the exact
-    #: pre-coalescing eager/window policy.
-    adaptive_window: bool = True
-    #: fleet_mesh: shard the invoker axis of the placement state over a
-    #: ('fleet',) device mesh (parallel/fleet_mesh.py) — the horizontal-
-    #: scale mode where fleet capacity grows with chips instead of one
-    #: device's HBM. Per-shard speculate-and-repair with a per-round
-    #: global-occupancy exchange; bit-exact with the single-device
-    #: kernels at any shard count. Default OFF = today's single-device
-    #: path, bit-exact.
     fleet_mesh: bool = False
-    #: fleet_shards: shard count for fleet_mesh (power of two; 0 = every
-    #: visible device, rounded down to a power of two). Asking for more
-    #: shards than the default backend has devices is an error.
     fleet_shards: int = 0
-    #: batch_publish: the batch-shaped publish SPI (ISSUE 14).
-    #: `publish_many` takes a whole admission batch in ONE call — one
-    #: clock read, one arrival-EWMA pass, one stamp_many, one NumPy
-    #: column pass into the request ring, one shared flush decision —
-    #: with per-row continuations as done-callbacks (zero tasks per
-    #: activation) instead of one publish coroutine (plus timer arm and
-    #: stamp) each. False routes publish_many through the serial
-    #: per-pair path, bit-exact; serial `publish` itself is untouched
-    #: either way.
-    batch_publish: bool = True
 
 
 def _next_pow2(n: int) -> int:
@@ -170,281 +133,6 @@ def _next_pow2(n: int) -> int:
 
 def _mod_inverse(step: int, m: int) -> int:
     return pow(step, -1, m) if m > 1 else 0
-
-
-def resolve_auto_kernel(n_pad: int, action_slots: int) -> str:
-    """The STATIC half of the kernel="auto" policy, shared with bench.py's
-    headline selection: the pallas schedule on a TPU when the (n_pad,
-    action_slots) state fits its VMEM budget (bit-exact with XLA; which is
-    faster is not measured — calibration below decides per shape). On
-    non-TPU backends pallas only has interpret mode (a debugging path,
-    orders of magnitude slower), and past the VMEM budget only the XLA
-    kernel scales — both resolve to "xla".
-
-    This is only the pre-calibration guess: once the prewarm drainer's
-    calibration microbench has MEASURED both backends at a live bucket
-    shape (`calibrate_backend_rates`), the cached measured rate replaces
-    this heuristic as the tiebreak (`cached_backend_choice`)."""
-    if jax.default_backend() != "tpu":
-        return "xla"
-    from ...ops.placement_pallas import fits_vmem
-    return "pallas" if fits_vmem(n_pad, action_slots) else "xla"
-
-
-#: batch-bucket width from which placement_kernel="auto" swaps the scan
-#: program for the speculate-and-repair kernel (either backend). Below it
-#: the scan both EXECUTES fine (a handful of sequential probe steps) and
-#: COMPILES ~3x faster (~0.45 s vs ~1.2 s per bucket signature on a dev
-#: box) — and compile latency is what light traffic actually feels, since
-#: a new bucket shape jit-compiles inside a live dispatch. At and above it
-#: the scan's B-length dependency chain dominates and repair wins outright.
-REPAIR_MIN_BATCH = 32
-#: extra CPU-twin fleet gate for "auto" — now 0 (no gate): the PR 5
-#: measurement that justified 256 ("scan beats repair ~4x at N=64,
-#: B<=64") predates PR 9's repair_commit_masks refactor and no longer
-#: reproduces — re-measured on the 1-core twin for ISSUE 12: at N_pad=64
-#: the scan's B-length sequential chain costs 0.6 ms (B=64) to 2.9 ms
-#: (B=256) per step while repair runs the same batches in 0.14-0.52 ms
-#: (rounds=1 on memory-dominant mixes, incl. same-action bursts of 32).
-#: At the batch-shaped hot path's B=256 buckets the scan chain was ~25%
-#: of the 1-core twin's wall. The convoy worst case (overflow chains
-#: serializing the repair loop) remains documented in the repair_vs_scan
-#: rider; REPAIR_MIN_BATCH still routes small batches to the scan for
-#: its 3x faster compiles.
-REPAIR_MIN_FLEET_CPU = 0
-
-
-def _xla_pair(placement_kernel: str):
-    """(schedule_fn, release_fn, resolved_kernel) for the XLA backend,
-    honoring the placement-kernel knob. "repair" pins the speculate-and-
-    repair schedule + vectorized release fold at every size; "scan" keeps
-    the reference lax.scan pair (the true-no-op legacy path); "auto" picks
-    PER BUCKET — batch/release widths are static per jit signature, so the
-    branch resolves at trace time and each compiled program contains
-    exactly one kernel: scan below REPAIR_MIN_BATCH, repair at and above
-    it. All pairs are bit-exact (the fuzz suite asserts it), so the knob
-    only moves compile/run cost, never placements."""
-    if placement_kernel == "repair":
-        return schedule_batch_repair, release_batch_vector, "repair"
-    if placement_kernel == "auto":
-        threshold = REPAIR_MIN_BATCH
-        min_fleet = (REPAIR_MIN_FLEET_CPU
-                     if jax.default_backend() == "cpu" else 0)
-
-        def auto_schedule(state, batch):
-            # both shapes are static at trace time
-            if (batch.valid.shape[0] >= threshold
-                    and state.free_mb.shape[0] >= min_fleet):
-                return schedule_batch_repair(state, batch)
-            return schedule_batch(state, batch)
-
-        def auto_release(state, inv, slot, need_mb, max_conc, valid):
-            if (inv.shape[0] >= threshold
-                    and state.free_mb.shape[0] >= min_fleet):
-                return release_batch_vector(state, inv, slot, need_mb,
-                                            max_conc, valid)
-            return release_batch(state, inv, slot, need_mb, max_conc,
-                                 valid)
-
-        auto_schedule._placement_hybrid = True
-        auto_release._placement_hybrid = True
-        return auto_schedule, auto_release, "repair"
-    return schedule_batch, release_batch, "scan"
-
-
-def _pallas_pair(placement_kernel: str):
-    """(schedule_fn, release_fn, resolved_kernel) for the pallas backend.
-    "scan" is the PR-4 VMEM-resident sequential kernel; "repair" is the
-    fused speculate-and-repair kernel (`schedule_batch_repair_pallas`) —
-    probe + conflict detect + commit + the residue loop in ONE pallas_call
-    with the books resident in VMEM, sharing the conflict rules with the
-    XLA kernel so the two cannot drift; "auto" is the same per-bucket
-    static-branch hybrid as the XLA pair (scan below REPAIR_MIN_BATCH).
-    The kernel layout is conc-transposed; state everywhere else stays
-    [N, A] — converting inside jit keeps both transposes on-device in the
-    same program as the kernel call. The release fold is the XLA pair's
-    (it fuses into the same program around the pallas call)."""
-    from ...ops.placement_pallas import (schedule_batch_pallas,
-                                         schedule_batch_repair_pallas,
-                                         to_transposed)
-    interpret = jax.default_backend() == "cpu"
-
-    @jax.jit
-    def sched_scan(st, batch):
-        ts, *out = schedule_batch_pallas(
-            to_transposed(st), batch, interpret=interpret)
-        return (PlacementState(ts.free_mb, ts.conc_free.T, ts.health),
-                *out)
-
-    @jax.jit
-    def sched_repair(st, batch):
-        ts, *out = schedule_batch_repair_pallas(
-            to_transposed(st), batch, interpret=interpret)
-        return (PlacementState(ts.free_mb, ts.conc_free.T, ts.health),
-                *out)
-
-    sched_scan._pallas_kind = "scan"
-    sched_repair._pallas_kind = "repair"
-    if placement_kernel == "scan":
-        return sched_scan, release_batch, "scan"
-    if placement_kernel == "repair":
-        return sched_repair, release_batch_vector, "repair"
-    threshold = REPAIR_MIN_BATCH
-
-    def auto_schedule(state, batch):
-        if batch.valid.shape[0] >= threshold:
-            return sched_repair(state, batch)
-        return sched_scan(state, batch)
-
-    def auto_release(state, inv, slot, need_mb, max_conc, valid):
-        if inv.shape[0] >= threshold:
-            return release_batch_vector(state, inv, slot, need_mb,
-                                        max_conc, valid)
-        return release_batch(state, inv, slot, need_mb, max_conc, valid)
-
-    auto_schedule._placement_hybrid = True
-    auto_schedule._pallas_kind = "auto"
-    auto_release._placement_hybrid = True
-    return auto_schedule, auto_release, "repair"
-
-
-#: one-shot calibration results: (platform, SHARD_ROWS, action_slots,
-#: placement_kernel, R, H, B) -> {"rates": {...}, "winner": ...}. Keyed by
-#: PER-SHARD rows (n_pad // n_shards), not global fleet size: a 256k-
-#: invoker fleet over 8 shards runs a 32k-row program per device, so that
-#: is the shape worth measuring — and a measurement taken single-device at
-#: 32k rows is the same program. Module-level on purpose — a restarted
-#: balancer (or a standby promoting) with the same PER-SHARD geometry
-#: adopts the measured choice without re-benching.
-_KERNEL_CALIBRATION: Dict[tuple, dict] = {}
-
-#: a backend must measure this much faster to displace the incumbent —
-#: damps flip-flopping between buckets whose rates are within noise
-CALIBRATION_HYSTERESIS = 1.1
-
-
-def _calibration_batch_buffer(n_pad: int, action_slots: int, r: int, h: int,
-                              b: int) -> np.ndarray:
-    """A packed (rel ++ health ++ req) buffer for the calibration
-    microbench: a realistic all-valid batch over the whole (healthy) pad —
-    memory-dominant traffic with spread homes/slots, the production bulk
-    the kernels are picked for."""
-    rng = np.random.RandomState(1234)
-    rel = np.zeros((5, r), np.int32)
-    rel[3] = 1  # padded rows: maxc=1
-    health = np.zeros((3, h), np.int32)
-    req = np.zeros((9, b), np.int32)
-    req[1] = n_pad                       # size: the whole pad
-    req[2] = rng.randint(0, n_pad, b)    # home
-    req[3] = 1                           # step_inv (step 1 is coprime)
-    req[4] = 128                         # need_mb
-    req[5] = rng.randint(0, max(1, min(64, action_slots)), b)
-    req[6] = 1                           # max_conc
-    req[7] = rng.randint(0, n_pad, b)    # rand
-    req[8] = 1                           # valid
-    return np.concatenate([rel.ravel(), health.ravel(), req.ravel()])
-
-
-def calibrate_backend_rates(n_pad: int, action_slots: int, r: int, h: int,
-                            b: int, *, placement_kernel: str = "auto",
-                            include_pallas: bool = True, iters: int = 4,
-                            warmup: int = 1, use_cache: bool = True,
-                            n_shards: int = 1) -> dict:
-    """The kernel="auto" tiebreak: measure the fused packed step's rate for
-    both device backends at ONE bucket signature and cache the result
-    (one-shot per shape — `_KERNEL_CALIBRATION`). Runs wherever the caller
-    is (the balancer calls it on the prewarm drainer thread, bench.py's
-    auto_pick row inline); compiles its own non-donated fn instances, so
-    it never touches a live balancer's jit caches or donated buffers. The
-    plain (non-admit) step is measured even when device rate-admission is
-    on: the admission fold is identical XLA on both backends, so the
-    relative rate is what matters. A backend that fails to build or run
-    reports a null rate and its exception under `errors`; the balancer
-    logs that as an error (`_maybe_calibrate`) — a kernel the compiler
-    refuses is a defect to repair or cut, not a quiet loss.
-
-    `n_shards`: the microbench builds and keys the PER-SHARD program —
-    `n_pad // n_shards` invoker rows, the shape one device of a
-    fleet-mesh balancer actually runs. n_shards=1 (the default) is the
-    single-device balancer, where shard_rows == n_pad."""
-    platform = jax.default_backend()
-    shard_rows = max(1, n_pad // max(1, n_shards))
-    key = (platform, shard_rows, action_slots, placement_kernel, r, h, b)
-    if use_cache:
-        hit = _KERNEL_CALIBRATION.get(key)
-        if hit is not None:
-            if (hit.get("n_pad") != n_pad
-                    or hit.get("n_shards") != n_shards):
-                # same per-shard program measured under a different
-                # topology (the key deliberately omits n_pad/n_shards):
-                # re-stamp the CALLER's view so admin planes report their
-                # own geometry, not the first measurer's
-                hit = dict(hit, n_pad=n_pad, n_shards=n_shards)
-            return hit
-    buf = _calibration_batch_buffer(shard_rows, action_slots, r, h, b)
-    rates: Dict[str, Optional[float]] = {}
-    errors: Dict[str, str] = {}
-    backends = ["xla"] + (["pallas"] if include_pallas else [])
-    for backend in backends:
-        try:
-            sched, release, _ = (_pallas_pair if backend == "pallas"
-                                 else _xla_pair)(placement_kernel)
-            fn = make_fused_step_packed(release, sched)
-            state = init_state(shard_rows, [1 << 20] * shard_rows,
-                               n_pad=shard_rows, action_slots=action_slots)
-            out = None
-            for _ in range(max(1, warmup)):
-                _st, out = fn(state, buf, r, h, b)
-            jax.block_until_ready(out)
-            t0 = time.perf_counter()
-            for _ in range(max(1, iters)):
-                _st, out = fn(state, buf, r, h, b)
-                jax.block_until_ready(out)
-            dt = time.perf_counter() - t0
-            rates[backend] = round(b * max(1, iters) / dt, 1)
-        except Exception as e:  # noqa: BLE001 — a backend that cannot run
-            # cannot win; the caller sees why in `errors`
-            rates[backend] = None
-            errors[backend] = repr(e)
-    live = {k: v for k, v in rates.items() if v}
-    winner = max(live, key=live.get) if live else "xla"
-    if (winner == "pallas" and live.get("xla")
-            and live["pallas"] < live["xla"] * CALIBRATION_HYSTERESIS):
-        winner = "xla"  # incumbent keeps ties-within-noise
-    out = {"rates": rates, "winner": winner, "platform": platform,
-           "n_pad": n_pad, "shard_rows": shard_rows, "n_shards": n_shards,
-           "action_slots": action_slots,
-           "placement_kernel": placement_kernel, "sig": [r, h, b],
-           "iters": iters}
-    if errors:
-        out["errors"] = errors
-    _KERNEL_CALIBRATION[key] = out
-    return out
-
-
-def cached_backend_choice(n_pad: int, action_slots: int,
-                          placement_kernel: str,
-                          n_shards: int = 1) -> Optional[str]:
-    """The cached calibration verdict for a geometry (largest measured
-    batch bucket wins — most representative of loaded traffic), or None
-    when nothing was measured yet. The restart rule is PER-SHARD-SHAPE:
-    the lookup keys on `n_pad // n_shards`, so a 256k-invoker fleet over
-    8 shards calibrates the 32k-row program it actually runs and the
-    verdict transfers to whoever next needs that shape's backend choice —
-    a single-device balancer at 32k rows resolving kernel="auto", or a
-    prior fleet run / bench auto_pick row seeding it. (A fleet-mesh
-    balancer itself never swaps on the verdict: its sharded pair has no
-    xla/pallas choice, so it calibrates advisorily — see
-    _maybe_calibrate.)"""
-    platform = jax.default_backend()
-    shard_rows = max(1, n_pad // max(1, n_shards))
-    best = None
-    # snapshot: the warm-drainer thread inserts concurrently
-    for key, cal in list(_KERNEL_CALIBRATION.items()):
-        if key[:4] == (platform, shard_rows, action_slots, placement_kernel):
-            if best is None or cal["sig"][2] > best["sig"][2]:
-                best = cal
-    return best["winner"] if best else None
 
 
 class _SlotAllocator:
@@ -549,13 +237,9 @@ class TpuBalancer(CommonLoadBalancer):
                  rate_limit_per_minute: Optional[int] = None,
                  placement_kernel: Optional[str] = None,
                  donate_state: Optional[bool] = None,
-                 ring_assembly: Optional[bool] = None,
                  prewarm: Optional[bool] = None,
-                 adaptive_window: Optional[bool] = None,
-                 calibrate_kernel: Optional[str] = None,
                  fleet_mesh: Optional[bool] = None,
                  fleet_shards: Optional[int] = None,
-                 batch_publish: Optional[bool] = None,
                  profiler=None, anomaly=None, waterfall=None, quality=None):
         super().__init__(messaging_provider, controller_instance, logger,
                          metrics, profiler=profiler, anomaly=anomaly,
@@ -572,42 +256,28 @@ class TpuBalancer(CommonLoadBalancer):
         if self.kernel not in ("auto", "xla", "pallas"):
             raise ValueError(
                 f"kernel must be auto|xla|pallas, got {self.kernel!r}")
-        #: scan | repair | auto — the batch algorithm on the XLA path
+        #: scan | repair | auto — the batch algorithm
         self.placement_kernel = (placement_kernel if placement_kernel
                                  is not None else path_cfg.placement_kernel)
         if self.placement_kernel not in ("scan", "repair", "auto"):
             raise ValueError(
                 f"placement_kernel must be scan|repair|auto, "
                 f"got {self.placement_kernel!r}")
-        self.donate_state = (donate_state if donate_state is not None
-                             else path_cfg.donate_state)
-        #: explicit constructor True pins donation even where the backend
-        #: auto-gate would drop it (tests exercising materialize
-        #: boundaries on the CPU twin)
+        #: donation is not a knob: `_build_packed_fns` decides it from what
+        #: it observes (a device backend, no mesh). `donate_state=True` is
+        #: the pin of the materialize-boundary tests: the donated path is
+        #: production's on the chip, and the CPU twin cannot reach it
+        #: otherwise.
         self._donate_pinned = donate_state is True
-        self.ring_assembly = (ring_assembly if ring_assembly is not None
-                              else path_cfg.ring_assembly)
         self.prewarm = (prewarm if prewarm is not None
                         else path_cfg.prewarm)
-        self.calibrate_kernel = (calibrate_kernel if calibrate_kernel
-                                 is not None else path_cfg.calibrate_kernel)
-        if self.calibrate_kernel not in ("auto", "force", "off"):
-            raise ValueError(
-                f"calibrate_kernel must be auto|force|off, "
-                f"got {self.calibrate_kernel!r}")
-        #: how the running backend was picked: "explicit" (kernel knob),
-        #: "static" (resolve_auto_kernel guess), "calibration" (measured
-        #: rate), or "fallback" (pallas outgrew its VMEM budget)
-        self._kernel_chosen_by = ("explicit" if self.kernel != "auto"
-                                  else "static")
-        #: the latest calibration result applied/considered (admin/bench)
-        self._calibration: Optional[dict] = None
-        self.adaptive_window = (adaptive_window if adaptive_window is not None
-                                else path_cfg.adaptive_window)
-        #: batch-shaped publish SPI (ISSUE 14): advertised to the front
-        #: end (maybe_batch_publish builds a PublishCoalescer off it)
-        self.batch_publish = (batch_publish if batch_publish is not None
-                              else path_cfg.batch_publish)
+        #: what runs (`_adopt_plan`): xla | pallas | sharded, scan | repair
+        self.kernel_resolved: Optional[str] = None
+        self.placement_kernel_resolved: Optional[str] = None
+        #: how it was picked: "explicit" (the kernel knob), "static"
+        #: (kernel_choice's rule) or "fallback" (Pallas outgrew its VMEM
+        #: budget)
+        self._kernel_chosen_by: Optional[str] = None
         #: pure-function memos on the publish hot path: (ns, fqn) -> crc32
         #: home hash and (step, size) -> modular inverse. Both are
         #: deterministic (never invalidated); bounded by a clear at 64k.
@@ -619,7 +289,7 @@ class TpuBalancer(CommonLoadBalancer):
         #: failing queued publishers, so every caller-facing future
         #: resolves before the producer goes away.
         self._publish_finishers: set = set()
-        #: publish inter-arrival EWMA (ms) — the adaptive window's pressure
+        #: publish inter-arrival EWMA (ms) — the dispatch hold's pressure
         #: signal. Initialized sparse so a fresh balancer is eager.
         self._gap_ewma_ms = 1000.0
         self._last_gap_ms = 1e9
@@ -649,8 +319,7 @@ class TpuBalancer(CommonLoadBalancer):
             mesh = make_fleet_mesh(shards_cfg or None)
         self.mesh = mesh
         #: mesh axis name and shard count (1 without a mesh) — the admin/
-        #: occupancy planes, journal topology records and per-shard
-        #: calibration keying all read these
+        #: occupancy planes and journal topology records read these
         self.fleet_axis = mesh.axis_names[0] if mesh is not None else None
         self.n_shards = (int(np.prod(list(mesh.shape.values())))
                          if mesh is not None else 1)
@@ -723,10 +392,9 @@ class TpuBalancer(CommonLoadBalancer):
         self._quality_batches = 0
         self._init_device_state()
 
-        # pending request queue + delta buffers; with ring_assembly the int
-        # fields mirror into preallocated column rings at enqueue time so
-        # the per-flush packed matrices assemble with two slice copies
-        # instead of a list-of-tuples np.array transpose
+        # pending request queue + delta buffers; the int fields mirror into
+        # preallocated column rings at enqueue time so the per-flush packed
+        # matrices assemble with two slice copies
         self._pending: List[tuple] = []      # (req_tuple, future, slot_key)
         self._releases: List[tuple] = []     # (inv_idx, slot, mem, maxc, key)
         self._req_ring = ColumnRing(10, max_batch * 4)
@@ -804,18 +472,57 @@ class TpuBalancer(CommonLoadBalancer):
             self._export_shard_gauges()
 
     # -- device state ------------------------------------------------------
-    def _resolve_kernel(self) -> str:
-        if self.kernel != "auto":
-            return self.kernel
-        # a cached MEASURED rate beats the static heuristic: a restarted
-        # balancer (or a promoted standby) with the same geometry adopts
-        # the calibration verdict immediately
-        cal = cached_backend_choice(self._n_pad, self.action_slots,
-                                    self.placement_kernel, self.n_shards)
-        if cal is not None:
-            self._kernel_chosen_by = "calibration"
-            return cal
-        return resolve_auto_kernel(self._n_pad, self.action_slots)
+    def _choose_plan(self) -> KernelPlan:
+        return choose(self._n_pad, self.action_slots, self.max_batch,
+                      kernel=self.kernel,
+                      placement_kernel=self.placement_kernel,
+                      mesh=self.mesh, axis=self.fleet_axis)
+
+    def _adopt_plan(self, plan: KernelPlan, rebuild: bool = False) -> None:
+        """Run what kernel_choice picked for the geometry the state now
+        has. Called wherever the state is built or changes shape; a plan
+        that names what runs already is a no-op unless `rebuild` (fresh
+        state: fresh programs). A live balancer whose backend or algorithm
+        changes (Pallas outgrew its VMEM budget through growth or a
+        snapshot restore) swaps under an expect window, so the recompile
+        watchdog reads the fresh programs' compiles as the swap they are,
+        and says so in the event log. One that fell back to XLA for want
+        of VMEM stays there for the life of the process: `self.kernel` is
+        pinned, so no later plan offers Pallas again."""
+        first = self.kernel_resolved is None
+        changed = ((self.kernel_resolved, self.placement_kernel_resolved)
+                   != (plan.backend, plan.algorithm))
+        if first:
+            self._kernel_chosen_by = plan.chosen_by
+        elif changed:
+            self.profiler.expect("kernel_swap")
+            GLOBAL_EVENT_LOG.record(
+                "kernel_swap", instance=self.controller.instance,
+                to=(plan.backend if plan.backend != self.kernel_resolved
+                    else f"{plan.backend}_{plan.algorithm}"),
+                why=plan.why)
+        if (plan.why == "vmem_fallback" and changed
+                and (plan.chosen_by == "fallback" or not first)):
+            # Pallas was running, or asked for by name, and does not fit
+            if self.logger:
+                self.logger.warn(
+                    None, f"pallas kernel needs VMEM-resident state; "
+                    f"{self._n_pad}x{self.action_slots} "
+                    f"(placement_kernel={self.placement_kernel}, "
+                    f"max_batch={self.max_batch}) does not fit — using the "
+                    f"XLA kernel")
+            self.kernel = plan.backend
+            self._kernel_chosen_by = "fallback"
+        if not (changed or rebuild):
+            return
+        self.kernel_resolved = plan.backend
+        self.placement_kernel_resolved = plan.algorithm
+        self._sched_fn, self._release_fn = plan.schedule, plan.release
+        # release + health-fold + schedule as ONE compiled program (vs
+        # three dispatches per micro-batch), fed through the transfer-packed
+        # wrappers (3 host->device transfers per step instead of 16)
+        self._build_packed_fns(plan.shadow_schedule)
+        self._export_kernel_gauge()
 
     def _init_device_state(self) -> None:
         n = len(self._registry)
@@ -827,43 +534,11 @@ class TpuBalancer(CommonLoadBalancer):
             health = health.at[jnp.arange(len(self._healthy))].set(
                 jnp.asarray(self._healthy, bool))
         state = state._replace(health=health)
-        self.kernel_resolved = (
-            "sharded" if self.mesh is not None else self._resolve_kernel())
-        installed = False
         if self.mesh is not None:
-            from ...parallel.fleet_mesh import fleet_pair
             from ...parallel.sharded_state import shard_state
-            self.state = shard_state(state, self.mesh, axis=self.fleet_axis)
-            # the full placementKernel knob works on the mesh: scan keeps
-            # the prototype sharded scan, repair installs the per-shard
-            # speculate-and-repair kernel with the global-occupancy
-            # exchange, auto is the shared per-bucket static hybrid
-            (self._sched_fn, self._release_fn,
-             self.placement_kernel_resolved) = fleet_pair(
-                self.mesh, self.placement_kernel,
-                repair_min_batch=self.REPAIR_MIN_BATCH,
-                axis=self.fleet_axis)
-            installed = True
-        elif self.kernel_resolved == "pallas":
-            plan = self._pallas_plan()
-            if plan is not None:
-                self.state = state
-                pk = self.placement_kernel if plan == "repair" else "scan"
-                (self._sched_fn, self._release_fn,
-                 self.placement_kernel_resolved) = _pallas_pair(pk)
-                installed = True
-        if not installed and self.mesh is None:
-            self.state = state
-            self._sched_fn, self._release_fn = self._xla_fns()
-            if self.kernel_resolved == "pallas":
-                # explicit kernel="pallas" that failed the VMEM fit:
-                # report what actually runs
-                self.kernel_resolved = "xla"
-        # release + health-fold + schedule as ONE compiled program (vs
-        # three dispatches per micro-batch), fed through the transfer-packed
-        # wrappers (3 host->device transfers per step instead of 16)
-        self._build_packed_fns()
-        self._export_kernel_gauge()
+            state = shard_state(state, self.mesh, axis=self.fleet_axis)
+        self.state = state
+        self._adopt_plan(self._choose_plan(), rebuild=True)
         self._set_books_now(np.asarray(self.state.free_mb))
         # placement-quality plane: device accumulator + jitted scorer keyed
         # to the current invoker pad (a geometry rebuild restarts the
@@ -875,44 +550,33 @@ class TpuBalancer(CommonLoadBalancer):
             self.quality.use_device(self._n_pad)
             self._refresh_quality_signals()
 
-    #: class aliases of the module constants (tests and subclasses key off
-    #: these; the schedule-pair builders live at module level so the
-    #: calibration microbench can build pairs without a balancer)
-    REPAIR_MIN_BATCH = REPAIR_MIN_BATCH
-    REPAIR_MIN_FLEET_CPU = REPAIR_MIN_FLEET_CPU
+    #: the batch-shaped publish SPI (ISSUE 14), advertised to the front
+    #: end: `maybe_batch_publish` builds a PublishCoalescer off it
+    batch_publish = True
 
-    def _xla_fns(self):
-        """(schedule_fn, release_fn) for the XLA backend — see
-        `_xla_pair`; this wrapper records the resolved algorithm."""
-        sched, release, resolved = _xla_pair(self.placement_kernel)
-        self.placement_kernel_resolved = resolved
-        return sched, release
-
-    def _make_packed_fns(self, sched_fn, release_fn):
-        """Build (packed_step, release_packed) for a schedule pair —
-        profiler-wrapped, donation per the current gate — WITHOUT
-        installing them, so the calibration path can compile a candidate
-        backend's fns on the drainer thread and hand the loop finished
-        programs. The profiler interposes on every jitted entry point:
-        compile events classify by first-call / expect-window / rebuild
-        window / pow2-bucketed statics (the only shapes _bucket may
-        produce) — anything else is shape churn and trips the recompile
-        watchdog."""
+    def _build_packed_fns(self, shadow_sched_fn) -> None:
+        """(Re)build the packed step, the release-only program and the
+        shadow twin for the adopted schedule pair. The profiler interposes
+        on every jitted entry point: compile events classify by first-call
+        / expect-window / rebuild window / pow2-bucketed statics (the only
+        shapes _bucket may produce) — anything else is shape churn and
+        trips the recompile watchdog."""
         from ...ops.profiler import pow2_statics
         # buffer donation: XLA reuses the state's buffers for the output, so
-        # the [N, A] concurrency matrix stops round-tripping HBM every step.
-        # Off on a mesh (sharded buffers stay owned by their own path) and
-        # on the CPU backend: XLA:CPU cannot alias donated buffers and runs
-        # the donated program SYNCHRONOUSLY at dispatch — the event loop
-        # blocks for the whole step, the RTT EWMA reads ~0 and flips the
-        # dispatch regime to eager micro-batches (measured 5x rate loss on
-        # the CPU twin) — all cost, no HBM to save. An explicit
-        # donate_state=True constructor argument pins it on anyway.
-        self._donate = (self.donate_state and self.mesh is None
-                        and (jax.default_backend() != "cpu"
-                             or self._donate_pinned))
+        # the [N, A] concurrency matrix stops round-tripping HBM every step
+        # (holders of the pre-call state must copy first, see
+        # _materialize_state). Off on a mesh (sharded buffers stay owned by
+        # their own path) and on the CPU backend: XLA:CPU cannot alias
+        # donated buffers and runs the donated program SYNCHRONOUSLY at
+        # dispatch — the event loop blocks for the whole step, the RTT EWMA
+        # reads ~0 and flips the dispatch regime to eager micro-batches
+        # (measured 5x rate loss on the CPU twin) — all cost, no HBM to
+        # save.
+        self._donate = self.mesh is None and (
+            jax.default_backend() != "cpu" or self._donate_pinned)
+        sched_fn, release_fn = self._sched_fn, self._release_fn
         if self.rate_limit_per_minute is not None:
-            packed = self.profiler.wrap(
+            self._packed_fn = self.profiler.wrap(
                 "fused_admit_step",
                 make_fused_admit_step_packed(release_fn, sched_fn,
                                              donate=self._donate),
@@ -925,70 +589,32 @@ class TpuBalancer(CommonLoadBalancer):
                 self._bucket_state = init_buckets(self.RATE_NS_BUCKETS,
                                                   self.rate_limit_per_minute)
         else:
-            packed = self.profiler.wrap(
+            self._packed_fn = self.profiler.wrap(
                 "fused_step",
                 make_fused_step_packed(release_fn, sched_fn,
                                        donate=self._donate),
                 expected=pow2_statics)
-        release_packed = self.profiler.wrap(
+        self._release_packed_fn = self.profiler.wrap(
             "release_packed",
             make_release_packed(release_fn, donate=self._donate),
             expected=lambda st, rel: _next_pow2(rel.shape[1]) == rel.shape[1])
-        return packed, release_packed
-
-    def _build_packed_fns(self) -> None:
-        self._packed_fn, self._release_packed_fn = self._make_packed_fns(
-            self._sched_fn, self._release_fn)
         # fn rebuild = fresh jit caches: everything needs re-warming (the
         # queue entries pin the fn they were enqueued for, so stale warms
         # drain harmlessly against the abandoned cache)
         self._warm_sigs = set()
         self._warm_queue = []
         self._warm_task = getattr(self, "_warm_task", None)
-        self._build_shadow_fn()
-
-    def _build_shadow_fn(self) -> None:
-        """(Re)build the decision-only shadow twin for the resolved
-        backend (quality plane). The twin runs the penalty-augmented
-        variant of the PRODUCTION kernel family over the same packed
-        buffer and release/health folds, so divergence measures the
-        penalty, not a kernel swap; it never donates and writes nothing
-        back — production stays bit-exact with the plane on."""
+        # the decision-only shadow twin (quality plane) runs the penalised
+        # variant of the PRODUCTION kernel family over the same packed
+        # buffer and release/health folds, so divergence measures the
+        # penalty, not a kernel swap; it never donates and writes nothing
+        # back — production stays bit-exact with the plane on
         self._shadow_fn = None
-        if not (self.quality.enabled and self.quality.shadow_every_n > 0):
-            return
-        if self.mesh is not None:
-            # every schedule pair is bit-exact with every other, so the
-            # mesh shadow always runs the penalized sharded repair kernel
-            # regardless of which pair fleet_pair resolved for production
-            from ...parallel.fleet_mesh import make_fleet_repair_schedule
-            sched = make_fleet_repair_schedule(self.mesh,
-                                               axis=self.fleet_axis,
-                                               penalized=True)
-        elif self.kernel_resolved == "pallas":
-            from ...ops.placement_pallas import (
-                schedule_batch_pallas, schedule_batch_repair_pallas,
-                to_transposed)
-            interpret = jax.default_backend() == "cpu"
-            repair = self.placement_kernel_resolved == "repair"
-
-            def sched(st, batch, penalty, _repair=repair):
-                # the transposed result state is dead in the shadow
-                # program (decisions only) — XLA drops the transposes
-                fn = (schedule_batch_repair_pallas if _repair
-                      else schedule_batch_pallas)
-                return fn(to_transposed(st), batch, interpret=interpret,
-                          penalty=penalty)
-        elif self.placement_kernel_resolved == "repair":
-            sched = schedule_batch_repair
-        else:
-            sched = schedule_batch
-        if self.rate_limit_per_minute is not None:
-            self._shadow_fn = make_shadow_admit_step_packed(
-                self._release_fn, sched)
-        else:
-            self._shadow_fn = make_shadow_step_packed(self._release_fn,
-                                                      sched)
+        if self.quality.enabled and self.quality.shadow_every_n > 0:
+            make_shadow = (make_shadow_admit_step_packed
+                           if self.rate_limit_per_minute is not None
+                           else make_shadow_step_packed)
+            self._shadow_fn = make_shadow(release_fn, shadow_sched_fn)
 
     def _refresh_quality_signals(self) -> None:
         """Host-side refresh of the quality-plane input vectors (1 Hz
@@ -1064,21 +690,27 @@ class TpuBalancer(CommonLoadBalancer):
         async def _drain():
             while self._warm_queue and not getattr(self, "_closing", False):
                 sig, fn = self._warm_queue.pop(0)
-                decision = await asyncio.to_thread(self._warm_one, sig, fn)
-                if decision is not None:
-                    # calibration picked a different backend: the swap
-                    # applies HERE, back on the event loop, with fns that
-                    # compiled on the drainer thread — the loop never
-                    # compiles or calibrates
-                    self._apply_backend_decision(decision)
+                await asyncio.to_thread(self._warm_one, sig, fn)
 
         self._warm_task = asyncio.get_event_loop().create_task(_drain())
         self._readbacks.add(self._warm_task)
         self._warm_task.add_done_callback(self._readbacks.discard)
 
-    def _warm_fns(self, sig: tuple, fn, release_packed_fn) -> None:
+    def _warm_one(self, sig: tuple, fn) -> None:
         """Compile one (R, H, B) signature of a packed step + its
-        release-only program (drainer thread; XLA compiles drop the GIL)."""
+        release-only program (drainer thread; XLA compiles drop the GIL).
+        Warming is best-effort, the live path compiles on demand anyway;
+        but a SILENT fail would make a systematically broken prewarm (dummy
+        inputs drifting from the real signature) look identical to a
+        working one, so say why."""
+        try:
+            self._warm_fns(sig, fn)
+        except Exception as e:  # noqa: BLE001
+            if self.logger:
+                self.logger.warn(None, f"bucket prewarm {sig} failed: {e!r}",
+                                 "TpuBalancer")
+
+    def _warm_fns(self, sig: tuple, fn) -> None:
         wr, wh, wb = sig
         rate_on = self.rate_limit_per_minute is not None
         rows = 10 if rate_on else 9
@@ -1111,7 +743,7 @@ class TpuBalancer(CommonLoadBalancer):
         # the idle release fold compiles its own release-only program
         # per R bucket — warm it too, or a drain-only lull still eats
         # the in-dispatch compile stall this plane exists to avoid
-        release_packed_fn(dummy_state(), np.zeros((5, wr), np.int32))
+        self._release_packed_fn(dummy_state(), np.zeros((5, wr), np.int32))
         # shadow + quality-scorer programs ride the same warm ladder: a
         # first-sight compile inside a live dispatch would stall the loop
         # exactly like an unwarmed packed step. The warm step's own
@@ -1137,157 +769,14 @@ class TpuBalancer(CommonLoadBalancer):
                 step(qs, st_w.free_mb, st_w.conc_free, st_w.health, ewma,
                      caps, req9, out_w, sv)
 
-    def _warm_one(self, sig: tuple, fn) -> Optional[dict]:
-        """One warm-drainer unit of work (worker thread): compile the
-        signature, then — for kernel="auto" — run the one-shot calibration
-        microbench for it. Returns a backend-swap decision for the loop to
-        apply, or None."""
-        try:
-            self._warm_fns(sig, fn, self._release_packed_fn)
-        except Exception as e:  # noqa: BLE001 — warming is best-effort;
-            # the live path compiles on demand anyway. But a SILENT fail
-            # would make a systematically broken prewarm (dummy inputs
-            # drifting from the real signature) look identical to a
-            # working one, so say why.
-            if self.logger:
-                self.logger.warn(None, f"bucket prewarm {sig} failed: {e!r}",
-                                 "TpuBalancer")
-            return None
-        try:
-            return self._maybe_calibrate(sig)
-        except Exception as e:  # noqa: BLE001 — calibration is advisory:
-            # a failed microbench must never take the warm drainer down
-            if self.logger:
-                self.logger.warn(None, f"kernel calibration {sig} failed: "
-                                 f"{e!r}", "TpuBalancer")
-            return None
-
-    def _calibration_enabled(self) -> bool:
-        """Calibration requires an auto kernel knob and a backend where
-        the pallas kernels actually compile (a TPU) — unless "force"
-        overrides for the CPU-twin tests/bench. A FLEET-MESH balancer
-        calibrates too — the microbench measures the single-device fused
-        step at the PER-SHARD shape, the compute each of its devices
-        runs — but only advisorily (see _maybe_calibrate): the sharded
-        pair is not swappable, so the measurement populates the shared
-        per-shard cache and the admin plane without ever moving the
-        running kernels."""
-        if self.kernel != "auto" or self.calibrate_kernel == "off":
-            return False
-        if self.calibrate_kernel == "force":
-            return True
-        return jax.default_backend() == "tpu"
-
-    def _maybe_calibrate(self, sig: tuple) -> Optional[dict]:
-        """Drainer-thread half of the measured-rate auto policy: run (or
-        look up) the one-shot calibration for this bucket signature; when
-        the measured winner differs from the running backend, build AND
-        prewarm the winner's packed fns here so the loop-side swap
-        installs finished programs."""
-        if not self._calibration_enabled():
-            return None
-        from ...ops.placement_pallas import fits_vmem, fits_vmem_repair
-        # the fit (like the microbench itself) is judged at the PER-SHARD
-        # shape — the rows one device actually holds
-        rows = max(1, self._n_pad // self.n_shards)
-        pallas_ok = (
-            fits_vmem_repair(rows, self.action_slots, self.max_batch)
-            if self.placement_kernel != "scan"
-            else fits_vmem(rows, self.action_slots))
-        if not pallas_ok:
-            # one-sided measurement cannot pick a winner: an xla-only
-            # bench would "win" by default and demote a statically-chosen
-            # (and unmeasured) pallas scan. The fit-based choice stands.
-            return None
-        r, h, b = sig
-        cal = calibrate_backend_rates(
-            self._n_pad, self.action_slots, r, h, b,
-            placement_kernel=self.placement_kernel,
-            iters=2 if self.calibrate_kernel == "force" else 5,
-            n_shards=self.n_shards)
-        self._calibration = cal
-        if cal.get("errors") and self.logger:
-            self.logger.error(None, f"kernel calibration {sig}: a backend "
-                              f"failed to build or run: {cal['errors']}",
-                              "TpuBalancer")
-        if self.mesh is not None:
-            # ADVISORY on a fleet mesh: the sharded pair has no backend
-            # swap, so the per-shard measurement only feeds the shared
-            # cache (a restarted balancer whose shard shape matches — at
-            # any topology — adopts it) and /admin/profile/kernel
-            return None
-        # the SWAP decision follows the largest measured bucket for this
-        # geometry (cached_backend_choice — the same rule a restarted
-        # balancer applies at construction), not this signature's own row:
-        # a small bucket's noise verdict must not ping-pong the backend,
-        # since every swap flushes the warm jit caches
-        winner = (cached_backend_choice(self._n_pad, self.action_slots,
-                                        self.placement_kernel,
-                                        self.n_shards)
-                  or cal["winner"])
-        if winner == self.kernel_resolved:
-            self._kernel_chosen_by = "calibration"
-            self._export_kernel_gauge()
-            return None
-        pair = (_pallas_pair if winner == "pallas"
-                else _xla_pair)(self.placement_kernel)
-        packed, release_packed = self._make_packed_fns(pair[0], pair[1])
-        self._warm_fns(sig, packed, release_packed)
-        return {"kernel": winner, "pair": pair, "packed": packed,
-                "release_packed": release_packed, "sig": sig,
-                "n_pad": self._n_pad, "action_slots": self.action_slots,
-                "cal": cal}
-
-    def _apply_backend_decision(self, decision: dict) -> None:
-        """Event-loop half of the measured-rate auto policy: install a
-        calibration-chosen backend whose fns arrived compiled from the
-        drainer. Dropped when the world moved while calibration ran (fleet
-        growth re-keyed the geometry, the knobs changed, close() started).
-        The swap compiles nothing on the loop; the profiler's expect
-        window + rebuild-window classification keep the recompile watchdog
-        quiet through it."""
-        if (getattr(self, "_closing", False) or self.kernel != "auto"
-                or self.mesh is not None
-                or decision["n_pad"] != self._n_pad
-                or decision["action_slots"] != self.action_slots
-                or decision["kernel"] == self.kernel_resolved):
-            return
-        self.profiler.expect("kernel_swap")
-        GLOBAL_EVENT_LOG.record("kernel_swap",
-                                instance=self.controller.instance,
-                                to=decision["kernel"], why="auto_calibrated")
-        sched, release, resolved = decision["pair"]
-        self.kernel_resolved = decision["kernel"]
-        self.placement_kernel_resolved = resolved
-        self._sched_fn, self._release_fn = sched, release
-        self._packed_fn = decision["packed"]
-        self._release_packed_fn = decision["release_packed"]
-        # the shadow twin tracks the production kernel family
-        self._build_shadow_fn()
-        # fresh jit caches behind the installed fns: only the calibrated
-        # signature is warm; successor shapes re-enter the drainer as
-        # traffic hits them
-        self._warm_sigs = {decision["sig"]}
-        self._warm_queue = []
-        self._kernel_chosen_by = "calibration"
-        self._calibration = decision["cal"]
-        self._export_kernel_gauge()
-        if self.logger:
-            rates = decision["cal"]["rates"]
-            self.logger.info(
-                None, f"kernel calibration swapped the placement backend "
-                f"to {decision['kernel']} at sig={decision['sig']} "
-                f"(measured rates: {rates})", "TpuBalancer")
-
     def _export_kernel_gauge(self) -> None:
         """Info-style backend gauge: exactly one live
         `loadbalancer_kernel_backend{backend,placement,chosen_by} 1`
         series; the superseded combination is zeroed on swaps so a scrape
         sees the flip, not two live backends."""
         tags = {"backend": self.kernel_resolved,
-                "placement": getattr(self, "placement_kernel_resolved",
-                                     self.placement_kernel),
-                "chosen_by": getattr(self, "_kernel_chosen_by", "static")}
+                "placement": self.placement_kernel_resolved,
+                "chosen_by": self._kernel_chosen_by}
         prev = getattr(self, "_kernel_gauge_tags", None)
         if prev is not None and prev != tags:
             self.metrics.gauge("loadbalancer_kernel_backend", 0, tags=prev)
@@ -1310,49 +799,6 @@ class TpuBalancer(CommonLoadBalancer):
                 slot = dedicated + (zlib.crc32(ns_id.encode())
                                     % self.RATE_NS_SHARED_BUCKETS)
         return slot
-
-    def _use_xla_kernels(self) -> None:
-        """Swap the XLA schedule/release kernels in (pallas state outgrew
-        the VMEM budget, via growth or snapshot restore)."""
-        self.profiler.expect("kernel_swap")
-        GLOBAL_EVENT_LOG.record("kernel_swap",
-                                instance=self.controller.instance,
-                                to="xla", why="vmem_fallback")
-        self.kernel_resolved = "xla"
-        self._kernel_chosen_by = "fallback"
-        self._sched_fn, self._release_fn = self._xla_fns()
-        self._build_packed_fns()
-        self._export_kernel_gauge()
-
-    def _pallas_plan(self) -> Optional[str]:
-        """What the pallas backend can run at the current geometry:
-        "repair" (state + the repair kernel's residue scratch fit VMEM),
-        "scan" (only the resident state fits — placement_kernel="auto"
-        downgrades to the VMEM scan, which needs no [B, N] scratch), or
-        None (nothing fits). Explicit
-        placement_kernel="repair" never silently downgrades to the pallas
-        scan — it falls through to the XLA repair kernel instead. On None
-        the explicit-pallas fall-back-and-log contract applies: say why,
-        run XLA."""
-        from ...ops.placement_pallas import fits_vmem, fits_vmem_repair
-        repair_ok = (self.placement_kernel != "scan"
-                     and fits_vmem_repair(self._n_pad, self.action_slots,
-                                          self.max_batch))
-        if repair_ok:
-            return "repair"
-        scan_ok = (self.placement_kernel != "repair"
-                   and fits_vmem(self._n_pad, self.action_slots))
-        if scan_ok:
-            return "scan"
-        if self.logger:
-            self.logger.warn(
-                None, f"pallas kernel needs VMEM-resident state; "
-                f"{self._n_pad}x{self.action_slots} "
-                f"(placement_kernel={self.placement_kernel}, "
-                f"max_batch={self.max_batch}) does not fit — using the "
-                f"XLA kernel")
-        self.kernel = "xla"
-        return None
 
     def _slot_mb(self, user_memory_mb: int) -> int:
         return max(user_memory_mb // self._cluster_size, MIN_SLOT_MB)
@@ -1522,7 +968,8 @@ class TpuBalancer(CommonLoadBalancer):
 
     def _install_state(self, state: PlacementState) -> None:
         """Adopt new-shape device arrays: shard onto the mesh (if any) and
-        drop pallas if the shapes outgrew its VMEM budget. On a mesh this
+        run what kernel_choice picks for the new shapes (Pallas goes when
+        they outgrow its VMEM budget). On a mesh this
         IS a reshard event — the new-shape shard_map programs compile
         under an expect window (the caller's growth/restore window, plus
         this explicit reshard stamp) so the recompile watchdog stays
@@ -1533,25 +980,7 @@ class TpuBalancer(CommonLoadBalancer):
             state = shard_state(state, self.mesh, axis=self.fleet_axis)
         self.state = state
         self._set_books_now(np.asarray(state.free_mb))
-        if getattr(self, "kernel_resolved", self.kernel) == "pallas":
-            plan = self._pallas_plan()
-            if plan is None:
-                self._use_xla_kernels()
-            elif (plan == "scan"
-                  and getattr(self, "placement_kernel_resolved",
-                              "scan") == "repair"):
-                # growth kept the resident state inside the budget but
-                # evicted the repair kernel's residue scratch: downgrade
-                # to the VMEM scan in place
-                self.profiler.expect("kernel_swap")
-                GLOBAL_EVENT_LOG.record("kernel_swap",
-                                        instance=self.controller.instance,
-                                        to="pallas_scan",
-                                        why="scratch_evicted")
-                (self._sched_fn, self._release_fn,
-                 self.placement_kernel_resolved) = _pallas_pair("scan")
-                self._build_packed_fns()
-                self._export_kernel_gauge()
+        self._adopt_plan(self._choose_plan())
 
     def _grow_slots(self, new_slots: int) -> None:
         """Widen conc_free's action axis, preserving every live permit."""
@@ -1752,13 +1181,12 @@ class TpuBalancer(CommonLoadBalancer):
             # waterfall: the activation is now IN the balancer's queue — the
             # delta from here to batch_assemble is pure queueing/window wait
             self.waterfall.stamp(aid_str, STAGE_PUBLISH_ENQUEUE)
-            if self.ring_assembly:
-                # the packed-matrix column lands in the preallocated ring NOW
-                # (one C-speed write) — flush-time assembly is two slice
-                # copies. The entry is built FIRST: an exception between a
-                # ring push and its queue append would desync the two FIFOs
-                # and shift every later request's geometry.
-                self._req_ring.push(req)
+            # the packed-matrix column lands in the preallocated ring NOW
+            # (one C-speed write) — flush-time assembly is two slice
+            # copies. The entry is built FIRST: an exception between a
+            # ring push and its queue append would desync the two FIFOs
+            # and shift every later request's geometry.
+            self._req_ring.push(req)
             self._pending.append(entry)
             # inline fast path: with free pipeline capacity, dispatch NOW
             # (synchronously — the assembly+enqueue body has no awaits) when
@@ -1839,11 +1267,7 @@ class TpuBalancer(CommonLoadBalancer):
         resolves to the completion promise (what `publish` returns) or
         raises `publish`'s exact exceptions; per-row decisions, waterfall
         stamps, 429 texts and abandonment capacity-returns are the serial
-        path's, row for row (parity-fuzzed). Off switch
-        (CONFIG_whisk_loadBalancer_batchPublish=false): the serial
-        per-pair default."""
-        if not self.batch_publish:
-            return super().publish_many(pairs)
+        path's, row for row (parity-fuzzed)."""
         with span("ow_admit", n=len(pairs)):
             return self._admit_many(pairs)
 
@@ -1909,14 +1333,13 @@ class TpuBalancer(CommonLoadBalancer):
         # flip _coalesce_window_s where serial stays eager
         t_now = time.monotonic()
         self._note_arrivals(t_now, len(built))
-        if self.ring_assembly:
-            # the NumPy column pass: every built row's packed column lands
-            # in the preallocated ring in one [rows, k] block write (two
-            # slice copies), replacing k per-row ring assignments. The
-            # pending entries append in the SAME synchronous block, so the
-            # two FIFOs cannot desync.
-            self._req_ring.push_block(
-                np.asarray([b[0] for b in built], np.int32).T)
+        # the NumPy column pass: every built row's packed column lands in
+        # the preallocated ring in one [rows, k] block write (two slice
+        # copies), replacing k per-row ring assignments. The pending
+        # entries append in the SAME synchronous block, so the two FIFOs
+        # cannot desync.
+        self._req_ring.push_block(
+            np.asarray([b[0] for b in built], np.int32).T)
         for req, fut, slot_key, aid, msg, _action, _out, fqn_str, pid \
                 in built:
             entry = (req, fut, slot_key, t_now, aid, fqn_str,
@@ -2162,8 +1585,7 @@ class TpuBalancer(CommonLoadBalancer):
         """Buffer one capacity release for the next device step (the slot
         KEY rides host-side for drain-time slot bookkeeping; the int column
         mirrors into the release ring for flush assembly)."""
-        if self.ring_assembly:
-            self._rel_ring.push((inv, slot, mem, maxc))
+        self._rel_ring.push((inv, slot, mem, maxc))
         self._releases.append((inv, slot, mem, maxc, key))
 
     # -- completion hooks --------------------------------------------------
@@ -2263,15 +1685,12 @@ class TpuBalancer(CommonLoadBalancer):
         running (xla / pallas / sharded) — host-side reads only, no device
         sync (memory_stats is a runtime counter read, not an array pull)."""
         out = self.profiler.profile_json(kernel=self.kernel_resolved)
-        out["placement_kernel"] = getattr(self, "placement_kernel_resolved",
-                                          self.placement_kernel)
-        out["kernel_chosen_by"] = getattr(self, "_kernel_chosen_by", "static")
+        out["placement_kernel"] = self.placement_kernel_resolved
+        out["kernel_chosen_by"] = self._kernel_chosen_by
         out["device"] = self.device
         if self.mesh is not None:
             out["mesh"] = {"n_shards": self.n_shards,
                            "axis": self.fleet_axis}
-        if self._calibration is not None:
-            out["calibration"] = self._calibration
         return out
 
     # -- placement journal (HA plane; loadbalancer/journal.py) -------------
@@ -2789,9 +2208,10 @@ class TpuBalancer(CommonLoadBalancer):
 
     # -- the device step ---------------------------------------------------
 
-    #: the dispatch hold (see PlacementPathConfig.adaptive_window): how long
-    #: a partly filled batch is held open = DISPATCH_HOLD_K x what one fused
-    #: step costs the loop, measured (`_note_step_cost`). What a hold
+    #: the dispatch hold: under arrival pressure a partly filled batch is
+    #: held open for DISPATCH_HOLD_K x what one fused step costs the loop,
+    #: measured (`_note_step_cost`), instead of dispatching per arrival; an
+    #: idle or slow-trickle balancer keeps the eager fast path. What a hold
     #: amortises is that cost, so it is the only thing that sizes it: a
     #: hold of K step costs keeps the steps' share of the loop at or under
     #: 1 / (K + 1) whatever the fleet, the planes or the rows per step, and
@@ -2815,7 +2235,7 @@ class TpuBalancer(CommonLoadBalancer):
 
     def _note_arrival(self, now: float) -> None:
         """Track the publish inter-arrival EWMA — the pressure signal the
-        adaptive window switches on. One subtract + one blend per publish."""
+        dispatch hold switches on. One subtract + one blend per publish."""
         gap_ms = (now - self._last_pub_t) * 1e3
         self._last_pub_t = now
         self._last_gap_ms = gap_ms
@@ -2855,8 +2275,7 @@ class TpuBalancer(CommonLoadBalancer):
         still flowing (a lone request after a burst must not inherit the
         burst's hold)."""
         hold_ms = self._hold_s * 1e3
-        if (self.adaptive_window and self._gap_ewma_ms <= hold_ms
-                and self._last_gap_ms <= hold_ms):
+        if self._gap_ewma_ms <= hold_ms and self._last_gap_ms <= hold_ms:
             return self._hold_s
         return 0.0
 
@@ -2867,7 +2286,7 @@ class TpuBalancer(CommonLoadBalancer):
         # idle fast path: with no step in flight there is nothing to batch
         # WITH — waiting out the window would only add latency (the window
         # exists to amortize a round trip that is already being paid).
-        # Under arrival pressure the adaptive window overrides: the batch
+        # Under arrival pressure the dispatch hold overrides: the batch
         # forming over the next few ms IS the thing to batch with.
         if self._inflight_steps == 0 and self._pending and window == 0.0:
             urgent = True
@@ -2930,10 +2349,9 @@ class TpuBalancer(CommonLoadBalancer):
 
     def _release_packed(self, pad_to: Optional[int] = None) -> np.ndarray:
         """Drain buffered releases into ONE packed int32[5,R] host array
-        (+ host-side slot bookkeeping) — same padding as _release_arrays.
-        With ring_assembly the int columns were written at enqueue time, so
-        assembly is two contiguous slice copies instead of a list-of-tuples
-        np.array transpose.
+        (+ host-side slot bookkeeping).
+        The int columns were written into the release ring at enqueue
+        time, so assembly is two contiguous slice copies.
 
         The per-step drain cap equals max_batch (not a multiple): the
         batch-shaped ack path (ISSUE 12) lands a whole completion
@@ -2954,19 +2372,15 @@ class TpuBalancer(CommonLoadBalancer):
         out = np.zeros((5, b), np.int32)
         out[3, len(rel):] = 1  # padded rows: maxc=1
         if rel:
-            if self.ring_assembly:
-                self._rel_ring.pop_into(out[:4], len(rel))
-            else:
-                out[:4, :len(rel)] = np.array([r[:4] for r in rel],
-                                              np.int32).T
+            self._rel_ring.pop_into(out[:4], len(rel))
             out[4, :len(rel)] = 1
         for r in rel:
             self._slots.release(r[4], r[1])
         return out
 
     def _health_packed(self) -> np.ndarray:
-        """Drain up to HEALTH_BATCH flips into ONE packed int32[3,H] array —
-        same repeat-last padding rule as _health_arrays."""
+        """Drain up to HEALTH_BATCH flips into ONE packed int32[3,H] array,
+        padded by repeating the last flip."""
         b = self.HEALTH_BATCH
         take = list(self._health_updates.items())[:b]
         for k, _ in take:
@@ -3076,14 +2490,10 @@ class TpuBalancer(CommonLoadBalancer):
         req_np = np.zeros((rows, bp), np.int32)
         req_np[1, b:] = 1  # size
         req_np[6, b:] = 1  # max_conc
-        if self.ring_assembly:
-            # columns were written at publish() time: drain the b oldest
-            # (rate off drops the ring's ns_slot row — pop_into copies only
-            # the rows req_np carries)
-            self._req_ring.pop_into(req_np, b)
-        else:
-            req_np[:, :b] = np.array(
-                [entry[0][:rows] for entry in batch], np.int32).T
+        # columns were written at publish() time: drain the b oldest
+        # (rate off drops the ring's ns_slot row — pop_into copies only
+        # the rows req_np carries)
+        self._req_ring.pop_into(req_np, b)
         # flight-recorder input digest, captured host-side before the step
         # (batch is FIFO: batch[0] carries the oldest enqueue time)
         rec = None

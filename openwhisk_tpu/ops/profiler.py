@@ -203,8 +203,8 @@ class KernelProfiler:
         entry = self._entries.get(name)
         if entry is None or entry["fn"] is not fn:
             # re-registering a NAME with a new callable is a kernel/backend
-            # swap (pallas<->xla calibration, VMEM fallback, growth
-            # rebuild): stamp it so the fresh cache's compiles classify as
+            # swap (pallas->xla VMEM fallback, growth rebuild): stamp it
+            # so the fresh cache's compiles classify as
             # the swap they are — for expect_window_s after the rebuild —
             # instead of leaning on first_call (one compile only) or the
             # shape predicate, and never as shape_churn

@@ -32,11 +32,11 @@ PRODUCTION device mesh the balancer can run at 100k-1M invokers:
                                land on one shard, so the sequential
                                semantics argument of `release_batch_vector`
                                carries over unchanged. No collectives.
-  * `fleet_pair`             — the (schedule, release, resolved) selector
-                               mirroring `_xla_pair`: scan | repair |
-                               auto (per-bucket static hybrid), so the
-                               placementKernel knob means the same thing
-                               on a mesh as on one device.
+  * `fleet_pair`             — the mesh's (schedule, release) pair for
+                               one algorithm, scan | repair; which one
+                               runs is loadbalancer/kernel_choice.py's
+                               choice, so the placementKernel knob means
+                               the same thing on a mesh as on one device.
 
 Why the collectives are cheap: per repair round the wire traffic is ONE
 [B, 2] all_gather (winner election) plus three [B] psums (occupancy
@@ -297,41 +297,21 @@ def make_fleet_release_vector(mesh: Mesh, axis: Optional[str] = None):
     return jax.jit(fn)
 
 
-def fleet_pair(mesh: Mesh, placement_kernel: str,
-               repair_min_batch: int = 32, axis: Optional[str] = None):
-    """(schedule_fn, release_fn, resolved_kernel) for the fleet mesh,
-    honoring the placement-kernel knob exactly like `_xla_pair`: "repair"
-    pins the sharded speculate-and-repair pair, "scan" keeps the
-    prototype scan pair (sharded_state — the bit-exact legacy mesh path),
-    "auto" resolves PER BUCKET at trace time (scan below
-    `repair_min_batch`, repair at and above it — batch/release widths are
-    static per jit signature). All pairs are bit-exact with each other
-    and with the single-device kernels, so the knob moves only cost."""
+def fleet_pair(mesh: Mesh, algorithm: str, axis: Optional[str] = None):
+    """(schedule_fn, release_fn, algorithm) of the fleet mesh: "repair" is
+    the sharded speculate-and-repair pair, "scan" the prototype scan pair
+    (sharded_state). Both are bit-exact with each other and with the
+    single-device kernels. Which of them a balancer runs, and the
+    per-bucket hybrid over the two, is
+    controller/loadbalancer/kernel_choice.py's to decide."""
     axis = axis or mesh_axis(mesh)
-    sched_scan = make_sharded_schedule(mesh, axis=axis)
-    rel_scan = make_sharded_release(mesh, axis=axis)
-    if placement_kernel == "scan":
-        return sched_scan, rel_scan, "scan"
-    sched_repair = make_fleet_repair_schedule(mesh, axis=axis)
-    rel_repair = make_fleet_release_vector(mesh, axis=axis)
-    if placement_kernel == "repair":
-        return sched_repair, rel_repair, "repair"
-    threshold = repair_min_batch
-
-    def auto_schedule(state, batch):
-        # both shapes are static at trace time
-        if batch.valid.shape[0] >= threshold:
-            return sched_repair(state, batch)
-        return sched_scan(state, batch)
-
-    def auto_release(state, inv, slot, need_mb, max_conc, valid):
-        if inv.shape[0] >= threshold:
-            return rel_repair(state, inv, slot, need_mb, max_conc, valid)
-        return rel_scan(state, inv, slot, need_mb, max_conc, valid)
-
-    auto_schedule._placement_hybrid = True
-    auto_release._placement_hybrid = True
-    return auto_schedule, auto_release, "repair"
+    if algorithm == "scan":
+        return (make_sharded_schedule(mesh, axis=axis),
+                make_sharded_release(mesh, axis=axis), "scan")
+    if algorithm == "repair":
+        return (make_fleet_repair_schedule(mesh, axis=axis),
+                make_fleet_release_vector(mesh, axis=axis), "repair")
+    raise ValueError(f"algorithm must be scan|repair, got {algorithm!r}")
 
 
 __all__ = ["FLEET_AXIS", "make_fleet_mesh", "mesh_axis", "mesh_shards",

@@ -89,20 +89,19 @@ def test_the_hold_is_k_times_the_estimate(cost_s):
         == K * cost_s * 1e3
 
 
-@pytest.mark.parametrize("gap_ms, last_gap_ms, fed, adaptive, held", [
-    (0.01, 0.0, N, True, True),       # a burst: the hold is on
-    (2.9, 2.9, N, True, True),        # one more row expected inside it
-    (3.1, 0.0, N, True, False),       # a trickle: none expected
-    (50.0, 50.0, N, True, False),
-    (0.01, 3.1, N, True, False),      # a lone request after a burst
-    (0.01, 500.0, N, True, False),
-    (0.01, 0.0, 0, True, False),      # no step measured yet
-    (0.01, 0.0, 1, True, False),      # one sample is no estimate
-    (0.01, 0.0, N, False, False),     # the switch keeps its meaning
+@pytest.mark.parametrize("gap_ms, last_gap_ms, fed, held", [
+    (0.01, 0.0, N, True),       # a burst: the hold is on
+    (2.9, 2.9, N, True),        # one more row expected inside it
+    (3.1, 0.0, N, False),       # a trickle: none expected
+    (50.0, 50.0, N, False),
+    (0.01, 3.1, N, False),      # a lone request after a burst
+    (0.01, 500.0, N, False),
+    (0.01, 0.0, 0, False),      # no step measured yet
+    (0.01, 0.0, 1, False),      # one sample is no estimate
 ])
 def test_the_hold_is_on_only_when_it_will_gather_rows(
-        gap_ms, last_gap_ms, fed, adaptive, held):
-    bal = _policy(adaptive_window=adaptive)
+        gap_ms, last_gap_ms, fed, held):
+    bal = _policy()
     hold_s = 0.003
     _feed(bal, hold_s / K, fed)
     _pressure(bal, gap_ms, last_gap_ms)

@@ -197,12 +197,15 @@ class TestFleetKernelParity:
         assert _states_equal(s1, s2)
 
     def test_auto_pair_is_per_bucket_hybrid(self, mesh):
-        """fleet_pair('auto') routes by static batch width exactly like
-        _xla_pair: scan below repair_min_batch (rounds absent/0), repair
-        at and above it (rounds >= 1) — both bit-exact with the oracle."""
-        sched, rel, resolved = fleet_pair(mesh, "auto",
-                                          repair_min_batch=32)
-        assert resolved == "repair"
+        """kernel_choice's mesh leg under placement_kernel="auto" routes
+        by static batch width like every backend: scan below
+        REPAIR_MIN_BATCH (rounds absent/0), repair at and above it
+        (rounds >= 1) — both bit-exact with the oracle."""
+        from openwhisk_tpu.controller.loadbalancer.kernel_choice import \
+            choose
+        plan = choose(32, 64, 64, placement_kernel="auto", mesh=mesh)
+        sched, rel, resolved = plan.schedule, plan.release, plan.algorithm
+        assert plan.backend == "sharded" and resolved == "repair"
         assert getattr(sched, "_placement_hybrid", False)
         rng = np.random.RandomState(29)
         n = 32
@@ -451,131 +454,6 @@ class TestFleetBalancer:
             await back.close()
 
         asyncio.run(go())
-
-
-class TestPerShardCalibration:
-    """Satellite: `calibrate_backend_rates`/`cached_backend_choice` key by
-    PER-SHARD shape (n_pad // n_shards), so a 256k-fleet/8-shard balancer
-    calibrates — and a restarted one adopts — the 32k-row program it
-    actually runs."""
-
-    def test_cache_keys_by_shard_rows(self):
-        import openwhisk_tpu.controller.loadbalancer.tpu_balancer as tb
-        saved = dict(tb._KERNEL_CALIBRATION)
-        tb._KERNEL_CALIBRATION.clear()
-        try:
-            platform = jax.default_backend()
-            # a verdict measured at 64 rows (single device, n_pad=64)...
-            tb._KERNEL_CALIBRATION[(platform, 64, 64, "auto", 8, 8, 8)] = {
-                "rates": {"xla": 1.0, "pallas": 9.0}, "winner": "pallas",
-                "platform": platform, "n_pad": 64, "shard_rows": 64,
-                "n_shards": 1, "action_slots": 64,
-                "placement_kernel": "auto", "sig": [8, 8, 8], "iters": 1}
-            # ...is THE verdict for a 512-invoker fleet over 8 shards
-            # (512 // 8 == 64 rows per device: the same program)
-            assert tb.cached_backend_choice(512, 64, "auto",
-                                            n_shards=8) == "pallas"
-            # and calibrating that fleet geometry cache-hits it
-            cal = tb.calibrate_backend_rates(512, 64, 8, 8, 8,
-                                             placement_kernel="auto",
-                                             n_shards=8)
-            assert cal["winner"] == "pallas" and cal["shard_rows"] == 64
-            # a cache hit re-stamps the CALLER's topology (the cached
-            # value was measured single-device at n_pad=64) so admin
-            # planes report their own geometry
-            assert cal["n_pad"] == 512 and cal["n_shards"] == 8
-            # a DIFFERENT per-shard shape does not match
-            assert tb.cached_backend_choice(512, 64, "auto",
-                                            n_shards=4) is None
-        finally:
-            tb._KERNEL_CALIBRATION.clear()
-            tb._KERNEL_CALIBRATION.update(saved)
-
-    def test_calibration_benches_the_per_shard_program(self):
-        """An actual (tiny) calibration run at n_shards=2 must build and
-        measure the shard_rows-row program and record both key halves."""
-        import openwhisk_tpu.controller.loadbalancer.tpu_balancer as tb
-        saved = dict(tb._KERNEL_CALIBRATION)
-        tb._KERNEL_CALIBRATION.clear()
-        try:
-            cal = tb.calibrate_backend_rates(
-                32, 16, 8, 8, 8, placement_kernel="scan",
-                include_pallas=False, iters=1, warmup=1, n_shards=2)
-            assert cal["shard_rows"] == 16 and cal["n_shards"] == 2
-            assert cal["rates"]["xla"]
-            key = (jax.default_backend(), 16, 16, "scan", 8, 8, 8)
-            assert key in tb._KERNEL_CALIBRATION
-        finally:
-            tb._KERNEL_CALIBRATION.clear()
-            tb._KERNEL_CALIBRATION.update(saved)
-
-    def test_fleet_balancer_calibrates_per_shard_advisorily(self):
-        """A fleet-mesh balancer with kernel='auto' + calibration forced
-        runs the microbench at the PER-SHARD shape on its prewarm
-        drainer: the verdict lands in the shared cache keyed by
-        shard_rows and on the admin plane, but the running kernels never
-        swap (the sharded pair has no xla/pallas choice)."""
-        import openwhisk_tpu.controller.loadbalancer.tpu_balancer as tb
-        saved = dict(tb._KERNEL_CALIBRATION)
-        tb._KERNEL_CALIBRATION.clear()
-
-        async def go():
-            bal = _mk_balancer(MemoryMessagingProvider(), fleet_mesh=True,
-                               fleet_shards=N_SHARDS, kernel="auto",
-                               calibrate_kernel="force", prewarm=True)
-            try:
-                await bal.start()
-                await _drive(bal, waves=1, per_wave=20)
-                for _ in range(200):
-                    if (bal._calibration is not None
-                            and (bal._warm_task is None
-                                 or bal._warm_task.done())):
-                        break
-                    await asyncio.sleep(0.05)
-                assert bal._calibration is not None
-                assert bal._calibration["n_shards"] == N_SHARDS
-                assert bal._calibration["shard_rows"] == \
-                    bal._n_pad // N_SHARDS
-                # the cache is keyed by the per-shard rows
-                assert any(k[1] == bal._n_pad // N_SHARDS
-                           for k in tb._KERNEL_CALIBRATION)
-                # advisory only: the sharded pair never swaps
-                assert bal.kernel_resolved == "sharded"
-                assert bal.kernel_profile()["calibration"]["shard_rows"] \
-                    == bal._n_pad // N_SHARDS
-            finally:
-                await bal.close()
-
-        try:
-            asyncio.run(go())
-        finally:
-            tb._KERNEL_CALIBRATION.clear()
-            tb._KERNEL_CALIBRATION.update(saved)
-
-    def test_restart_rule_adopts_per_shard_verdict(self):
-        """A fresh fleet-mesh-geometry balancer construction consults the
-        per-shard cache (the cached-choice restart rule) — exercised via
-        _resolve_kernel on a single-device balancer whose n_pad matches
-        the seeded shard shape."""
-        import openwhisk_tpu.controller.loadbalancer.tpu_balancer as tb
-        saved = dict(tb._KERNEL_CALIBRATION)
-        tb._KERNEL_CALIBRATION.clear()
-        platform = jax.default_backend()
-        tb._KERNEL_CALIBRATION[(platform, 16, 4096, "auto", 8, 8, 8)] = {
-            "rates": {"xla": 1.0, "pallas": 9.0}, "winner": "pallas",
-            "platform": platform, "n_pad": 16, "shard_rows": 16,
-            "n_shards": 1, "action_slots": 4096,
-            "placement_kernel": "auto", "sig": [8, 8, 8], "iters": 1}
-        try:
-            bal = _mk_balancer(MemoryMessagingProvider(), kernel="auto",
-                               calibrate_kernel="off")
-            assert bal._n_pad == 16
-            assert bal.kernel_resolved == "pallas"
-            assert bal._kernel_chosen_by == "calibration"
-            asyncio.run(bal.close())
-        finally:
-            tb._KERNEL_CALIBRATION.clear()
-            tb._KERNEL_CALIBRATION.update(saved)
 
 
 class TestMeshTopologyHelpers:

@@ -119,7 +119,8 @@ class TestBenchFailsLoudly:
         import bench
         for gone in ("_ensure_backend", "_probe_backend", "_probe_mesh",
                      "_run_rider", "_rider_subprocess_cpu",
-                     "_backend_unavailable"):
+                     "_backend_unavailable", "_pipeline_speedup",
+                     "_auto_pick_row"):
             assert not hasattr(bench, gone), gone
 
     def test_missing_device_exits_nonzero_with_the_error_line(

@@ -373,22 +373,17 @@ class TestBalancerHostPath:
         with pytest.raises(ValueError):
             _mk_balancer(MemoryMessagingProvider(), placement_kernel="bogus")
 
-    def test_donation_env_knob_off(self, monkeypatch):
-        monkeypatch.setenv("CONFIG_whisk_loadBalancer_donateState", "false")
-        bal = _mk_balancer(MemoryMessagingProvider())
-        assert bal.donate_state is False and bal._donate is False
-        # materialize is then a pass-through of the live reference
-        assert bal._materialize_state() is bal.state
-
     @pytest.mark.skipif(jax.default_backend() != "cpu",
                         reason="exercises the CPU-backend donation gate")
     def test_donation_auto_gates_off_on_cpu_backend(self):
         """XLA:CPU cannot alias donated buffers and runs donated programs
-        synchronously at dispatch — the default config must auto-gate
-        donation off there (knob intent preserved for real devices), while
-        an explicit constructor True still pins it for boundary tests."""
+        synchronously at dispatch — the balancer must gate donation off
+        there (it is on for real devices), while an explicit constructor
+        True still pins it for boundary tests."""
         bal = _mk_balancer(MemoryMessagingProvider())
-        assert bal.donate_state is True and bal._donate is False
+        assert bal._donate is False
+        # materialize is then a pass-through of the live reference
+        assert bal._materialize_state() is bal.state
         pinned = _mk_balancer(MemoryMessagingProvider(), donate_state=True)
         assert pinned._donate is True
 
@@ -564,9 +559,9 @@ class TestBalancerHostPath:
         assert bool(TpuBalancer.OCCUPANCY_SYNCS_DEVICE) is False
 
     def test_scan_depth1_legacy_path_is_bit_exact(self):
-        """placement_kernel=scan + pipeline_depth=1 + no donation + legacy
-        assembly must place a deterministic request sequence on EXACTLY the
-        invokers the default (repair+pipelined+donated+ring) path picks."""
+        """placement_kernel=scan + pipeline_depth=1 must place a
+        deterministic request sequence on EXACTLY the invokers the default
+        (repair+pipelined) path picks."""
         def run(**cfg):
             async def go():
                 provider = MemoryMessagingProvider()
@@ -593,6 +588,5 @@ class TestBalancerHostPath:
             return asyncio.run(go())
 
         modern = run()
-        legacy = run(placement_kernel="scan", pipeline_depth=1,
-                     donate_state=False, ring_assembly=False)
+        legacy = run(placement_kernel="scan", pipeline_depth=1)
         assert modern == legacy

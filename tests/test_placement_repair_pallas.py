@@ -1,4 +1,4 @@
-"""Fused Pallas speculate-and-repair kernel: parity, selector, calibration
+"""Fused Pallas speculate-and-repair kernel: parity, selector, kernel swaps
 (ISSUE 10).
 
 The Pallas repair kernel (`ops.placement_pallas.schedule_batch_repair_pallas`)
@@ -19,9 +19,8 @@ the CPU twin (the bench parity stage asserts the same on live hardware):
   * compile census through the packed entry point (1 compile/signature,
     zero unexpected — speculation in VMEM must not reintroduce churn);
   * the 3x3 placementKernel x kernel selector matrix (repair no longer
-    pins XLA), the VMEM-budget fallback regression, and the
-    calibration-driven backend swap riding the prewarm drainer with a
-    quiet recompile watchdog.
+    pins XLA), the VMEM-budget fallback regression, and a backend swap's
+    compiles read as the swap by the recompile watchdog.
 """
 import asyncio
 
@@ -235,9 +234,9 @@ def _packed_buf(rng, n, r, h, b, slots=16):
 
 
 def _pallas_repair_sched():
-    from openwhisk_tpu.controller.loadbalancer.tpu_balancer import \
-        _pallas_pair
-    return _pallas_pair("repair")
+    from openwhisk_tpu.controller.loadbalancer.kernel_choice import \
+        pallas_pair
+    return pallas_pair("repair")
 
 
 @pallas_mark
@@ -293,7 +292,7 @@ class TestPallasPackedPath:
 
 
 # ---------------------------------------------------------------------------
-# balancer selector, VMEM fallback, calibration
+# balancer selector, VMEM fallback, kernel swaps
 # ---------------------------------------------------------------------------
 
 from openwhisk_tpu.controller.loadbalancer import TpuBalancer  # noqa: E402
@@ -334,7 +333,6 @@ class TestSelectorMatrix:
         XLA path (the fused pallas repair kernel exists now)."""
         monkeypatch.setenv("CONFIG_whisk_loadBalancer_placementKernel", pk)
         monkeypatch.setenv("CONFIG_whisk_loadBalancer_kernel", kernel)
-        monkeypatch.setenv("CONFIG_whisk_loadBalancer_calibrateKernel", "off")
         bal = _mk_balancer(MemoryMessagingProvider())
         assert bal.kernel == kernel  # the backend knob reads the env too
         assert bal.kernel_resolved == want_backend
@@ -419,135 +417,7 @@ class TestSelectorMatrix:
 
 
 @pallas_mark
-class TestCalibration:
-    def test_auto_picks_by_measured_rate_off_the_event_loop(self):
-        """kernel=auto + calibrate_kernel=force on the CPU twin: the
-        calibration microbench rides the prewarm drainer (never the event
-        loop), caches per-bucket measured rates, applies the winner with
-        prewarmed fns, and the recompile watchdog records ZERO
-        expected=false trips across the mid-run swap."""
-        import openwhisk_tpu.controller.loadbalancer.tpu_balancer as tb
-
-        async def go():
-            provider = MemoryMessagingProvider()
-            bal = _mk_balancer(provider, kernel="auto",
-                               calibrate_kernel="force", max_batch=32,
-                               batch_window=0.001)
-            assert bal._kernel_chosen_by in ("static", "calibration")
-            await bal.start()
-            invokers, producer = await _fleet(provider, 2, memory_mb=2048)
-            await _ping_all(invokers, producer)
-            ident = Identity.generate("guest")
-            for i in range(8):
-                a = make_action(f"cal{i % 2}", memory=128)
-                await (await bal.publish(a, make_msg(a, ident, True)))
-            for _ in range(200):
-                if (bal._calibration is not None
-                        and (bal._warm_task is None
-                             or bal._warm_task.done())):
-                    break
-                await asyncio.sleep(0.05)
-            assert bal._calibration is not None
-            rates = bal._calibration["rates"]
-            assert rates.get("xla")  # both backends actually measured
-            assert "pallas" in rates
-            assert bal._kernel_chosen_by == "calibration"
-            # the running backend follows the geometry's largest-bucket
-            # verdict (the restart rule), not any single row
-            assert bal.kernel_resolved == tb.cached_backend_choice(
-                bal._n_pad, bal.action_slots, bal.placement_kernel)
-            # the cache is module-level and keyed per bucket shape
-            assert any(k[0] == jax.default_backend()
-                       for k in tb._KERNEL_CALIBRATION)
-            # a swap (if any) left the watchdog silent
-            assert bal.kernel_profile()["compiles"]["unexpected"] == 0
-            # and the balancer still places on the chosen backend
-            a = make_action("cal9", memory=128)
-            await (await bal.publish(a, make_msg(a, ident, True)))
-            assert bal.kernel_profile()["compiles"]["unexpected"] == 0
-            # the info-style gauge carries the verdict
-            assert bal.metrics.gauge_value(
-                "loadbalancer_kernel_backend",
-                tags={"backend": bal.kernel_resolved,
-                      "placement": bal.placement_kernel_resolved,
-                      "chosen_by": "calibration"}) == 1
-            await bal.close()
-            for inv in invokers:
-                await inv.stop()
-
-        asyncio.run(go())
-
-    def test_calibration_off_on_cpu_by_default(self):
-        bal = _mk_balancer(MemoryMessagingProvider(), kernel="auto")
-        assert bal.calibrate_kernel == "auto"
-        assert bal._calibration_enabled() is (jax.default_backend() == "tpu")
-
-    def test_cached_choice_survives_restart(self):
-        """A fresh balancer with a calibrated geometry adopts the cached
-        measured verdict at construction (no re-bench, no loop work)."""
-        import openwhisk_tpu.controller.loadbalancer.tpu_balancer as tb
-        saved = dict(tb._KERNEL_CALIBRATION)
-        tb._KERNEL_CALIBRATION.clear()  # hermetic: module cache is global
-        key = (jax.default_backend(), 16, 64, "auto", 8, 8, 8)
-        tb._KERNEL_CALIBRATION[key] = {
-            "rates": {"xla": 1.0, "pallas": 99.0}, "winner": "pallas",
-            "platform": key[0], "n_pad": 16, "action_slots": 64,
-            "placement_kernel": "auto", "sig": [8, 8, 8], "iters": 1}
-        try:
-            bal = _mk_balancer(MemoryMessagingProvider(), kernel="auto",
-                               calibrate_kernel="off")
-            assert bal.kernel_resolved == "pallas"
-            assert bal._kernel_chosen_by == "calibration"
-        finally:
-            tb._KERNEL_CALIBRATION.clear()
-            tb._KERNEL_CALIBRATION.update(saved)
-
-    def test_one_sided_calibration_keeps_incumbent(self, monkeypatch):
-        """Review regression: when pallas cannot be measured at the live
-        geometry (repair scratch does not fit), calibration must NOT let
-        an xla-only bench "win" by default and demote the statically
-        chosen backend — it stands down entirely."""
-        from openwhisk_tpu.ops import placement_pallas as pp
-        bal = _mk_balancer(MemoryMessagingProvider(), kernel="auto",
-                           calibrate_kernel="force")
-        monkeypatch.setattr(pp, "fits_vmem_repair", lambda *a: False)
-        monkeypatch.setattr(pp, "fits_vmem", lambda *a: False)
-        assert bal._maybe_calibrate((8, 8, 8)) is None
-        assert bal._calibration is None
-
-    def test_swap_verdict_follows_largest_measured_bucket(self):
-        """Review regression: the swap decision follows the LARGEST
-        measured bucket for the geometry (the cached_backend_choice
-        restart rule), not the just-calibrated signature's own row — a
-        small bucket's noise verdict must not ping-pong the backend."""
-        import openwhisk_tpu.controller.loadbalancer.tpu_balancer as tb
-        saved = dict(tb._KERNEL_CALIBRATION)
-        tb._KERNEL_CALIBRATION.clear()  # hermetic: module cache is global
-        try:
-            bal = _mk_balancer(MemoryMessagingProvider(), kernel="auto",
-                               calibrate_kernel="force", max_batch=32)
-            assert bal.kernel_resolved == "xla"  # static CPU resolve
-            platform = jax.default_backend()
-            geo = (platform, bal._n_pad, bal.action_slots, "auto")
-            tb._KERNEL_CALIBRATION[geo + (8, 8, 8)] = {
-                "rates": {"xla": 9.0, "pallas": 1.0}, "winner": "xla",
-                "platform": platform, "n_pad": bal._n_pad,
-                "action_slots": bal.action_slots, "placement_kernel": "auto",
-                "sig": [8, 8, 8], "iters": 1}
-            tb._KERNEL_CALIBRATION[geo + (8, 8, 32)] = {
-                "rates": {"xla": 1.0, "pallas": 9.0}, "winner": "pallas",
-                "platform": platform, "n_pad": bal._n_pad,
-                "action_slots": bal.action_slots, "placement_kernel": "auto",
-                "sig": [8, 8, 32], "iters": 1}
-            # calibrating the SMALL sig cache-hits its xla row, but the
-            # decision must carry the big bucket's pallas verdict
-            decision = bal._maybe_calibrate((8, 8, 8))
-            assert decision is not None
-            assert decision["kernel"] == "pallas"
-        finally:
-            tb._KERNEL_CALIBRATION.clear()
-            tb._KERNEL_CALIBRATION.update(saved)
-
+class TestKernelSwap:
     def test_profiler_classifies_swap_compiles_as_expected(self):
         """Satellite: re-wrapping an entry point (a backend swap) opens a
         rebuild window — compiles of the fresh cache classify as

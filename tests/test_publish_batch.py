@@ -6,10 +6,8 @@ Covers the acceptance contracts:
     stamps, the serial path's exact exception texts (standby /
     no-invoker / device-throttle 429), and per-row capacity return on
     cancellation/abandonment;
-  * off-switches: CONFIG_whisk_loadBalancer_batchPublish=false routes
-    publish_many through the serial per-pair path (and
-    maybe_batch_publish builds nothing); lazy_results=False keeps the
-    PR 11 ack batch record byte-exact;
+  * off-switch: lazy_results=False keeps the PR 11 ack batch record
+    byte-exact;
   * the one-shared-clock arrival fix: _note_arrivals(now, 1) is
     bit-exact with _note_arrival(now);
   * lazy ack result column: framed-wire roundtrip for every ack kind,
@@ -259,27 +257,6 @@ class TestPublishManyParity:
                 # host slot refcounts balanced back to the survivors
                 assert bal._slots.refcount.get(
                     f"{action.fully_qualified_name}:256") == 4
-            finally:
-                await bal.close()
-
-        asyncio.run(go())
-
-    def test_off_switch_serial_path(self):
-        """batch_publish=False: publish_many degrades to the serial
-        per-pair path (no finisher tasks), and maybe_batch_publish
-        builds nothing."""
-        async def go():
-            ident = Identity.generate("guest")
-            action = make_action("o")
-            provider = MemoryMessagingProvider()
-            bal = await _healthy_balancer(provider, batch_publish=False)
-            try:
-                assert maybe_batch_publish(bal) is None
-                outs = bal.publish_many([(action, make_msg(action, ident))
-                                         for _ in range(4)])
-                await asyncio.gather(*outs)
-                assert not bal._publish_finishers
-                assert bal.total_active_activations == 4
             finally:
                 await bal.close()
 

@@ -150,7 +150,7 @@ class TestDeviceAdmissionInBalancer:
         bal._bucket_state = st._replace(tokens=st.tokens * 0.0)
         bal.update_cluster(2)            # _init_device_state -> rebuild
         assert float(np.asarray(bal._bucket_state.tokens).max()) == 0.0
-        bal._use_xla_kernels()           # kernel swap -> rebuild
+        bal._adopt_plan(bal._choose_plan(), rebuild=True)  # as a swap does
         assert float(np.asarray(bal._bucket_state.tokens).max()) == 0.0
 
     def test_refill_readmits_like_rate_window(self):

@@ -1,7 +1,7 @@
 """Diff two BENCH_*.json rounds mechanically.
 
 ROADMAP house-keeping: the outstanding PR 9 claim (>5M placements/s for
-`pallas_repair`, a sane `auto_pick` verdict) needs a clean device round —
+`pallas_repair`) needs a clean device round —
 when one lands, it should be judged by a tool, not by eyeballing two
 JSON blobs. This CLI prints a per-rider delta table between two rounds and
 exits nonzero when any HEADLINE metric regressed by more than the
