@@ -2866,7 +2866,7 @@ def _fleet_sweep_row(mesh, fleet: int, batch_size: int, iters: int,
     for _ in range(2):
         pstate, out = packed(pstate, buf, batch_size, 8, batch_size)
     jax.block_until_ready(out)
-    rounds_packed = unpack_step_output(np.asarray(out))[3]
+    rounds_packed = unpack_step_output(np.asarray(out), batch_size).rounds
 
     med = statistics.median(rates)
     return {

@@ -281,10 +281,12 @@ def leg_c_child() -> None:
 
     import bench
     import warmhit
-    from openwhisk_tpu.controller.loadbalancer.kernel_choice import xla_pair
+    from openwhisk_tpu.controller.loadbalancer.kernel_choice import (
+        pallas_pair, xla_pair)
     from openwhisk_tpu.models.sharding_policy import ShardingPolicyState
     from openwhisk_tpu.ops.placement import (init_state,
                                              make_fused_step_packed,
+                                             make_release_packed,
                                              schedule_batch,
                                              schedule_batch_repair,
                                              unpack_step_output)
@@ -294,7 +296,8 @@ def leg_c_child() -> None:
     from tests.test_placement_kernel import (_batch_from_trace,
                                              _make_slot_allocator,
                                              _random_trace, _run_oracle)
-    from tests.test_placement_repair import _random_batch, _random_state
+    from tests.test_placement_repair import (_packed_buf, _random_batch,
+                                             _random_state)
 
     out = {"device": device}
 
@@ -359,8 +362,8 @@ def leg_c_child() -> None:
                           req.ravel()])
     kstate = init_state(n, [st.invoker_slot_mb(2048)] * n, action_slots=256)
     kstate, packed = step(kstate, buf, 32, 64, 32)
-    chosen, forced, _throttled, rounds = unpack_step_output(
-        np.asarray(packed))
+    chosen, forced, _throttled, rounds, _warm, books = unpack_step_output(
+        np.asarray(packed), 32)
     oracle = _run_oracle(st, trace)
     check([(int(c), bool(f)) for c, f in zip(chosen, forced)] == oracle,
           "north-star step: decisions differ from the oracle")
@@ -368,9 +371,41 @@ def leg_c_child() -> None:
                          [i.semaphore.available_permits
                           for i in st.invokers]),
           "north-star step: books differ from the oracle")
+    check(np.array_equal(books, np.asarray(kstate.free_mb)),
+          "north-star step: the output's books differ from the state's")
     out["north_star"] = {"invokers": n, "action_slots": 256,
                          "requests": len(trace), "repair_rounds": rounds,
                          "books_bytes": int(kstate.conc_free.nbytes)}
+
+    # 4. the books a step and a release fold return stay readable after
+    # LATER donating calls have consumed the state they came from (the
+    # balancer's readback worker converts them that late), on both
+    # backends at the standalone geometry
+    n, a, b = 64, 4096, 8
+    for backend, (sched, release, _) in (("xla", xla_pair("auto")),
+                                         ("pallas", pallas_pair("auto"))):
+        rng = np.random.RandomState(31)
+        bufs = [_packed_buf(rng, n, b, 64, b, slots=64) for _ in range(2)]
+        rel = np.zeros((5, b), np.int32)
+        rel[2], rel[3], rel[4] = 128, 1, 1    # 128 MB back to invoker 0
+        want, got = [], []
+        for donate, books in ((False, want), (True, got)):
+            dstate = _random_state(n, np.random.RandomState(32), mem=2048,
+                                   slots=a, conc_p=0.05)
+            dstep = make_fused_step_packed(release, sched, donate=donate)
+            dfold = make_release_packed(release, donate=donate)
+            dstate, o1 = dstep(dstate, bufs[0], b, 64, b)
+            dstate, o2 = dfold(dstate, rel)
+            dstate, o3 = dstep(dstate, bufs[1], b, 64, b)
+            books += [unpack_step_output(np.asarray(o1), b).books,
+                      np.asarray(o2),
+                      unpack_step_output(np.asarray(o3), b).books,
+                      np.asarray(dstate.free_mb)]
+        check(all(np.array_equal(w, g) for w, g in zip(want, got))
+              and np.array_equal(got[2], got[3])
+              and not np.array_equal(got[0], got[1]),
+              f"{backend}: books read after later donating calls differ")
+    out["donated_books_cases"] = 2
     print(json.dumps(out))
 
 

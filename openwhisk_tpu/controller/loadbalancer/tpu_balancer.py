@@ -57,8 +57,8 @@ from ...ops.placement import (PlacementState, RequestBatch, init_state,
                               make_fused_step_packed, make_release_packed,
                               make_shadow_admit_step_packed,
                               make_shadow_step_packed,
-                              journal_words, set_health, unpack_chosen,
-                              unpack_step_output, unpack_warm)
+                              journal_words, set_health,
+                              unpack_step_output)
 from .journal import decode_array, encode_array
 from .kernel_choice import KernelPlan, choose
 from ...ops.throttle import init_buckets
@@ -77,13 +77,6 @@ from .base import (HEALTHY, CommonLoadBalancer, InvokerHealth,
 from .flight_recorder import (BatchRecord, free_slot_histogram,
                               occupancy_json)
 from .supervision import InvokerPool
-
-
-@jax.jit
-def books_ref_copy(free_mb):
-    """`_books_ref`'s device-side copy, jitted under its own name so a
-    trace's module line reads `jit_books_ref_copy`, not `jit_copy`."""
-    return jnp.copy(free_mb)
 
 
 @dataclass(frozen=True)
@@ -870,10 +863,11 @@ class TpuBalancer(CommonLoadBalancer):
             return False
         bucket_gone = (self._bucket_state is not None
                        and self._bucket_state.tokens.is_deleted())
-        # check conc_free AND free_mb: on the CPU twin np.asarray is a
-        # zero-copy view, so the books cache PINS free_mb from donation
-        # (it survives undeleted) while the unreferenced conc_free/health
-        # buffers are consumed — free_mb alone would miss the outage
+        # check conc_free AND free_mb: a host view can pin one leaf from
+        # donation (on the CPU twin np.asarray is zero-copy, and
+        # _set_books_now's callers hand the cache such a view of free_mb
+        # until a step's own output replaces it) while the unreferenced
+        # leaves are consumed — one leaf alone would miss the outage
         if not (self.state.free_mb.is_deleted()
                 or self.state.conc_free.is_deleted() or bucket_gone):
             return False
@@ -888,15 +882,6 @@ class TpuBalancer(CommonLoadBalancer):
             # books were rebuilt at full capacity: replay must do the same
             self._journal_append({"t": "reinit"})
         return True
-
-    def _books_ref(self):
-        """Donation-safe reference to the post-step books vector, taken on
-        the event loop BEFORE any later dispatch can consume the live
-        buffers: under donation the next dispatched step invalidates
-        self.state, so holders crossing an await/thread boundary get their
-        own device-side copy (n_pad int32s — never the [N, A] matrix)."""
-        return (books_ref_copy(self.state.free_mb) if self._donate
-                else self.state.free_mb)
 
     def _releases_queued(self) -> int:
         return len(self._releases)
@@ -1610,10 +1595,13 @@ class TpuBalancer(CommonLoadBalancer):
     OCCUPANCY_SYNCS_DEVICE = False
 
     def occupancy(self) -> dict:
-        """Per-invoker slots-in-use/capacity from the last device-step
-        readback's cached free_mb copy (refreshed on every readback and
-        every state install, so it exists from construction onward). Under
-        a full pipeline the cache lags the dispatched state by up to
+        """Per-invoker slots-in-use/capacity from the cached books: the
+        post-step `free_mb` that every fused step appends to its own output
+        vector (a slice of the host copy the readback worker makes anyway;
+        a release-only fold returns them beside the state), installed on
+        the loop under a sequence guard, and re-set on every state
+        install, so the cache exists from construction onward. Under a
+        full pipeline it lags the dispatched state by up to
         `pipeline_depth` unread steps — and never costs a device->host
         transfer on the API path, which under buffer donation would
         additionally race the dispatch loop consuming the live buffer.
@@ -2086,7 +2074,7 @@ class TpuBalancer(CommonLoadBalancer):
     def _replay_fold(self, rec: dict, replay_release) -> None:
         if "rel" in rec:
             rel = decode_array(rec["rel"]).reshape(5, -1)
-            self.state = replay_release(self.state, rel)
+            self.state, _ = replay_release(self.state, rel)
         health = rec.get("health")
         if health:
             self.state = set_health(self.state,
@@ -2226,6 +2214,8 @@ class TpuBalancer(CommonLoadBalancer):
     #: gap at 800/s (a step per arrival: the reverted eager policy), 2
     #: drives fleet1k's half-busy loop into its own queue, 4 holds the
     #: convoy of 128 for 12.6 ms: 3 is the one that costs no cell anything.
+    #: (Read at the step's cost of that day: PR 31 took a launch and a
+    #: transfer off every step and did not read K again, PERF.md section 7.)
     DISPATCH_HOLD_K = 3
     #: step costs kept; the estimate is their second smallest, so it
     #: stands while six of eight samples are stalls (set-up's ladder
@@ -2412,12 +2402,12 @@ class TpuBalancer(CommonLoadBalancer):
         if not self._pending:
             # nothing to schedule: fold releases (padded+masked like the
             # fused path) and health (exact-size; dict keys are unique)
-            folded = bool(self._releases)
+            books = None
             try:
-                if folded or self._health_updates:
+                if self._releases or self._health_updates:
                     with span("ow_fold", rows=min(len(self._releases),
                                                   self.max_batch)):
-                        self._fold_now()
+                        books = self._fold_now()
             except Exception as e:  # noqa: BLE001 — a failed donated fold
                 # may have CONSUMED self.state: without a rebuild every
                 # later idle fold dies on the deleted buffer and a
@@ -2429,10 +2419,10 @@ class TpuBalancer(CommonLoadBalancer):
                 if self.logger:
                     self.logger.error(None, f"idle fold failed: {e!r}",
                                       "TpuBalancer")
-            if folded:
+            if books is not None:
                 # no schedule means no readback to piggyback the occupancy
                 # cache on — refresh it off-loop so idle fleets converge
-                self._refresh_books_async()
+                self._refresh_books_async(books)
             try:
                 self._telemetry_fold()
             except Exception as e:  # noqa: BLE001 — a telemetry failure
@@ -2452,13 +2442,15 @@ class TpuBalancer(CommonLoadBalancer):
         self._set_inflight(1)
         self._dispatch_batch(held_s, due)
 
-    def _fold_now(self) -> None:
+    def _fold_now(self):
         """The release-only / health fold and its journal record (one
-        `ow_fold` span to one `fold` record)."""
-        rel_np = ups = None
+        `ow_fold` span to one `fold` record). Returns the release fold's
+        books output (a device vector of its own), None where only health
+        was folded."""
+        rel_np = ups = books = None
         if self._releases:
             rel_np = self._release_packed()
-            self.state = self._release_packed_fn(self.state, rel_np)
+            self.state, books = self._release_packed_fn(self.state, rel_np)
         if self._health_updates:
             ups, self._health_updates = self._health_updates, {}
             self.state = set_health(self.state, list(ups.keys()),
@@ -2471,6 +2463,7 @@ class TpuBalancer(CommonLoadBalancer):
                 fold["health"] = [[int(k), bool(v)]
                                   for k, v in ups.items()]
             self._journal_append(fold)
+        return books
 
     def _telemetry_fold(self, seq: int = 0) -> None:
         with span("ow_telemetry_fold", seq=seq):
@@ -2717,23 +2710,20 @@ class TpuBalancer(CommonLoadBalancer):
         # throughput at batch/RTT. Dispatch stays event-loop-serialized
         # under the step lock; only readbacks overlap.
         # under donation the NEXT dispatched step consumes self.state's
-        # buffers while this step's readback is still crossing the wire —
-        # _books_ref hands the worker thread its own device-side copy
-        with span("ow_books_ref", seq=seq):
-            books = self._books_ref()
+        # buffers while this step's readback is still crossing the wire:
+        # the worker reads the post-step books off `out`, never the state
         task = asyncio.get_event_loop().create_task(
-            self._readback_step(batch, b, out, t0, req_np, rec, books,
+            self._readback_step(batch, b, out, t0, req_np, rec,
                                 books_seq, jseq, q_summary, seq))
         self._readbacks.add(task)
         task.add_done_callback(self._readbacks.discard)
         self._note_step_cost(time.monotonic() - (due or t0))
 
-    def _refresh_books_async(self) -> None:
+    def _refresh_books_async(self, books) -> None:
         """Refresh occupancy()'s cached books off a device step that has no
-        readback of its own (the idle release/health fold): take a
-        donation-safe reference to the books vector NOW, convert it on a
-        worker thread. Tracked in _readbacks so close() drains it."""
-        books = self._books_ref()
+        readback of its own (the idle release fold): `books` is that
+        fold's own output, which no later dispatch consumes; convert it on
+        a worker thread. Tracked in _readbacks so close() drains it."""
         seq = self._next_books_seq()
 
         async def _pull():
@@ -2744,16 +2734,15 @@ class TpuBalancer(CommonLoadBalancer):
         self._readbacks.add(task)
         task.add_done_callback(self._readbacks.discard)
 
-    def _read_back(self, out):
-        """Device->host conversion seam (runs on the worker thread);
-        a separate method so tests can inject readback failures. The packed
-        step returns B+1 elements: B decision words + the trailing
-        repair-round count (0 for scan/pallas/sharded kernels)."""
-        return unpack_step_output(np.asarray(out))
+    def _read_back(self, step):
+        """Readback seam (runs on the worker thread): what the loop is told
+        a step decided, (chosen, forced, throttled, repair rounds) out of
+        the decoded host copy of its output. A separate method so tests
+        can inject readback failures and altered answers."""
+        return step[:4]
 
     async def _readback_step(self, batch, b, out, t0, req_np, rec=None,
-                             books_free=None, books_seq=0,
-                             journal_seq=0, q_summary=None,
+                             books_seq=0, journal_seq=0, q_summary=None,
                              seq=0) -> None:
         # the step-duration stamp is taken ON the worker thread so the
         # metric measures device step + readback, not loop re-scheduling
@@ -2763,15 +2752,16 @@ class TpuBalancer(CommonLoadBalancer):
 
         def _read_spanned():
             t_r0 = time.monotonic()
-            arrs = self._read_back(out)
-            # the kernels' exact warm bit, off the host copy `_read_back`
-            # just made (the seam's 4-tuple stays what tests inject), and
-            # the step's counts of rows placed on a spare permit of a
-            # container already there and of rows forced: counted here, off
-            # the loop; both count every journaled step's rows, abandoned
-            # ones included
-            warm = unpack_warm(np.asarray(out)[:-1])
-            counts = (int(np.count_nonzero(warm[:b])),
+            # the step's ONE device->host transfer: decision words, repair
+            # rounds and the post-step books are slices of this host copy
+            step = unpack_step_output(np.asarray(out), req_np.shape[1])
+            arrs = self._read_back(step)
+            # the kernels' exact warm bit (the seam's 4-tuple stays what
+            # tests inject), and the step's counts of rows placed on a
+            # spare permit of a container already there and of rows
+            # forced: counted here, off the loop; both count every
+            # journaled step's rows, abandoned ones included
+            counts = (int(np.count_nonzero(step.warm[:b])),
                       int(np.count_nonzero(arrs[1][:b])))
             t_r1 = time.monotonic()
             rb_ms = (t_r1 - t_r0) * 1e3
@@ -2784,13 +2774,12 @@ class TpuBalancer(CommonLoadBalancer):
             # the balancer is in (not just infer it from latency shifts)
             self.metrics.gauge("loadbalancer_readback_rtt_ms",
                                self._rtt_ewma_ms)
-            # POST-step books captured at dispatch: the transfer happens
-            # here on the worker thread (tiny — n_pad int32s — and off the
-            # event loop); the copy also refreshes occupancy()'s cache so
-            # the admin endpoint never needs its own device sync — the
-            # install itself happens back on the loop, sequence-guarded
-            # (worker threads finish out of order under the pipeline)
-            free_np = np.asarray(books_free)
+            # POST-step books, as the step itself returned them: they
+            # also refresh occupancy()'s cache so the admin endpoint never
+            # needs its own device sync — the install itself happens back
+            # on the loop, sequence-guarded (worker threads finish out of
+            # order under the pipeline)
+            free_np = step.books
             if rec is not None:
                 caps = self._caps_mb
                 n_reg = min(len(caps), len(free_np))
@@ -2821,7 +2810,7 @@ class TpuBalancer(CommonLoadBalancer):
                         self.logger.warn(
                             None, f"quality summary failed: {e!r}",
                             "TpuBalancer")
-            return arrs, warm, counts, t_r1, free_np
+            return arrs, step.warm, counts, t_r1, free_np
 
         try:
             (chosen_np, forced_np, throttled_np, rounds), warm_np, \
@@ -2850,14 +2839,14 @@ class TpuBalancer(CommonLoadBalancer):
             # the schedule fold acquired (release_batch is its inverse).
             compensated = True
             try:
-                chosen, _, _ = unpack_chosen(out[:-1])
+                chosen = unpack_step_output(out, req_np.shape[1]).chosen
                 rel = jnp.stack([
                     jnp.maximum(chosen, 0).astype(jnp.int32),
                     jnp.asarray(req_np[5]), jnp.asarray(req_np[4]),
                     jnp.asarray(req_np[6]),
                     jnp.asarray(req_np[8]) * (chosen >= 0).astype(jnp.int32)])
                 with span("ow_fold", rows=b):
-                    self.state = self._release_packed_fn(self.state, rel)
+                    self.state, _ = self._release_packed_fn(self.state, rel)
                     if journal_seq and self._journal_live():
                         # the dispatch-time batch record stands; journal
                         # its on-device reversal so replay undoes it

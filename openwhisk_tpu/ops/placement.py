@@ -730,13 +730,17 @@ def make_fused_step(release_fn=None, schedule_fn=None):
 def make_release_packed(release_fn=None, donate: bool = False):
     """Release-only fold over the packed int32[5,R] matrix (inv, slot, mem,
     maxc, valid) — the idle-drain counterpart of make_fused_step_packed.
-    `donate=True` donates the state (see make_fused_step_packed)."""
+    Returns (state, books): `books` is the post-fold `free_mb` in a buffer
+    of its own, which a later donating call cannot consume (what the
+    step's output vector carries, see make_fused_step_packed).
+    `donate=True` donates the state."""
     release_fn = release_fn or release_batch
 
     @partial(jax.jit, donate_argnums=((0,) if donate else ()))
     def packed(state: PlacementState, rel):
-        return release_fn(state, rel[0], rel[1], rel[2], rel[3],
-                          rel[4].astype(bool))
+        state = release_fn(state, rel[0], rel[1], rel[2], rel[3],
+                           rel[4].astype(bool))
+        return state, jnp.copy(state.free_mb)
 
     return packed
 
@@ -750,11 +754,17 @@ def make_fused_step_packed(release_fn=None, schedule_fn=None,
     TRANSFER COUNT — not the kernel — dominates the step. Packing collapses
     the inputs to ONE flat int32 buffer (rel [5*R] ++ health [3*H] ++ req
     [9*B] here, [10*B] in the admit variant; split by static shape inside
-    the program) and the outputs to ONE int32 vector: B decision words
+    the program) and the outputs to ONE int32 vector (`pack_step_output`;
+    callers decode with `unpack_step_output(out, B)`): B decision words
     (`pack_decisions`: ((chosen+1)<<3) | warm<<2 | throttled<<1 | forced,
-    throttled always 0 here; callers decode with `unpack_chosen`) plus ONE
-    trailing element carrying the repair-round count (0 for schedule
-    kernels without a repair loop).
+    throttled always 0 here), ONE element carrying the repair-round count
+    (0 for schedule kernels without a repair loop), then the POST-step
+    `free_mb`, n_pad words. The books ride the step's own output so that a
+    step is one program launched and one vector read back: whoever wants
+    the post-step books across a later (donating) dispatch — occupancy's
+    cache, the flight recorder — slices them off the host copy of this
+    vector and never touches `state.free_mb`. On a fleet mesh the append
+    is the gather of the row-sharded books.
     R/H/B are static per compile; the balancer's power-of-two bucketing
     bounds the cache-key count.
 
@@ -783,8 +793,8 @@ def make_fused_step_packed(release_fn=None, schedule_fn=None,
         state, chosen, forced, warm, rounds = fused(
             state, rel[0], rel[1], rel[2], rel[3], rel[4].astype(bool),
             health[0], health[1].astype(bool), health[2].astype(bool), batch)
-        out = pack_decisions(chosen, forced, warm)
-        return state, jnp.concatenate([out, rounds.reshape(1)])
+        return state, pack_step_output(
+            pack_decisions(chosen, forced, warm), rounds, state)
 
     return packed
 
@@ -820,8 +830,8 @@ def make_fused_admit_step_packed(release_fn=None, schedule_fn=None,
         state, chosen, forced, warm, rounds = fused(
             state, rel[0], rel[1], rel[2], rel[3], rel[4].astype(bool),
             health[0], health[1].astype(bool), health[2].astype(bool), batch)
-        out = pack_decisions(chosen, forced, warm, throttled)
-        return (state, buckets), jnp.concatenate([out, rounds.reshape(1)])
+        return (state, buckets), pack_step_output(
+            pack_decisions(chosen, forced, warm, throttled), rounds, state)
 
     return packed
 
@@ -906,12 +916,18 @@ def pack_decisions(chosen, forced, warm, throttled=None):
     return out
 
 
+def pack_step_output(words, rounds, state: PlacementState):
+    """The packed step's ONE output vector (device jnp): B decision words,
+    the repair-round count, the post-step books. `unpack_step_output` is
+    its decoder; nobody else knows the layout."""
+    return jnp.concatenate([words, rounds.reshape(1), state.free_mb])
+
+
 def unpack_chosen(out):
-    """Decode the packed step output's per-request slice (host numpy or
-    device jnp) -> (chosen int32, forced bool, throttled bool). Throttled
-    requests carry chosen == -1 (they were never scheduled). NOTE: the
-    packed step returns B+1 elements — slice off the trailing repair-round
-    counter (`out[:-1]`) before decoding, or use `unpack_step_output`."""
+    """Decode decision words (host numpy or device jnp) -> (chosen int32,
+    forced bool, throttled bool). Throttled requests carry chosen == -1
+    (they were never scheduled). NOTE: the packed step's output vector
+    carries more than its B words: decode it with `unpack_step_output`."""
     return (out >> 3) - 1, (out & 1).astype(bool), ((out >> 1) & 1).astype(bool)
 
 
@@ -931,9 +947,25 @@ def journal_words(out):
     return ((out >> 3) << 2) | (out & 3)
 
 
-def unpack_step_output(out):
-    """Decode a full packed step output vector (B+1 elements):
-    -> (chosen, forced, throttled, repair_rounds int); the warm bits are
-    `unpack_warm(out[:-1])`."""
-    chosen, forced, throttled = unpack_chosen(out[:-1])
-    return chosen, forced, throttled, int(out[-1])
+class StepOutput(NamedTuple):
+    """One decoded packed step output (`unpack_step_output`)."""
+    chosen: object      # int32[B], -1 = not scheduled
+    forced: object      # bool[B]
+    throttled: object   # bool[B]
+    rounds: object      # repair rounds (an int off a host vector)
+    warm: object        # bool[B], `unpack_warm`
+    books: object       # int32[n_pad], the post-step free_mb
+
+
+def unpack_step_output(out, B: int) -> StepOutput:
+    """Decode a packed step's whole output vector (`pack_step_output`; host
+    numpy or device jnp), `B` being the static request bucket the step ran
+    with: words [0, B), the repair-round count at B, the post-step books
+    after it. Off a host vector `rounds` is an int and `books` a view of
+    `out`; off a device vector everything stays on the device and nothing
+    syncs (the balancer's compensation path decodes `chosen` that way)."""
+    words = out[:B]
+    chosen, forced, throttled = unpack_chosen(words)
+    rounds = out[B] if isinstance(out, jax.Array) else int(out[B])
+    return StepOutput(chosen, forced, throttled, rounds, unpack_warm(words),
+                      out[B + 1:])

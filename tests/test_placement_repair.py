@@ -248,17 +248,19 @@ class TestFusedPackedParity:
         buf = _packed_buf(rng, n, 8, 4, b)
         fn = make_fused_step_packed(release_batch_vector,
                                     schedule_batch_repair)
-        _, out = fn(state, jnp.asarray(buf), 8, 4, b)
-        assert out.shape == (b + 1,)
-        chosen, forced, throttled, rounds = unpack_step_output(
-            np.asarray(out))
-        assert chosen.shape == (b,)
-        assert rounds >= 1
+        new, out = fn(state, jnp.asarray(buf), 8, 4, b)
+        assert out.shape == (b + 1 + n,)
+        step = unpack_step_output(np.asarray(out), b)
+        assert step.chosen.shape == step.warm.shape == (b,)
+        assert step.rounds >= 1
+        np.testing.assert_array_equal(step.books, np.asarray(new.free_mb))
         # the scan pair reports rounds == 0 through the same contract
-        _, out_s = make_fused_step_packed()(state, jnp.asarray(buf), 8, 4, b)
-        s = unpack_step_output(np.asarray(out_s))
-        assert s[3] == 0
-        np.testing.assert_array_equal(chosen, s[0])
+        new_s, out_s = make_fused_step_packed()(state, jnp.asarray(buf),
+                                                8, 4, b)
+        s = unpack_step_output(np.asarray(out_s), b)
+        assert s.rounds == 0
+        np.testing.assert_array_equal(step.chosen, s.chosen)
+        np.testing.assert_array_equal(s.books, np.asarray(new_s.free_mb))
 
     def test_admit_variant_parity_scan_vs_repair(self):
         """The throttled/admit fused step: same packed buffer + bucket
@@ -276,7 +278,11 @@ class TestFusedPackedParity:
             fn = make_fused_admit_step_packed(rel_fn, sched_fn)
             (state, buckets), out = fn((state, buckets), buf,
                                        np.float32(1.0), r, h, b)
-            outs[name] = (np.asarray(out)[:-1], np.asarray(state.free_mb),
+            step = unpack_step_output(np.asarray(out), b)
+            np.testing.assert_array_equal(step.books,
+                                          np.asarray(state.free_mb))
+            outs[name] = (step.chosen, step.forced, step.throttled,
+                          step.warm, np.asarray(state.free_mb),
                           np.asarray(state.conc_free),
                           np.asarray(buckets.tokens))
         for a, bb in zip(outs["scan"], outs["repair"]):

@@ -242,9 +242,10 @@ def _pallas_repair_sched():
 @pallas_mark
 class TestPallasPackedPath:
     def test_packed_step_trailing_rounds_element(self):
-        """The packed output keeps the B+1 layout (trailing repair-round
-        count), so the flight recorder and loadbalancer_repair_rounds
-        family work unchanged on the pallas backend."""
+        """The packed output keeps the step's one layout (B words, the
+        repair-round count, the post-step books), so the flight recorder
+        and loadbalancer_repair_rounds family work unchanged on the pallas
+        backend."""
         rng = np.random.RandomState(0)
         n, b = 32, 16
         state = _random_state(n, rng)
@@ -252,12 +253,12 @@ class TestPallasPackedPath:
         sched, release, resolved = _pallas_repair_sched()
         assert resolved == "repair"
         fn = make_fused_step_packed(release, sched)
-        _, out = fn(state, jnp.asarray(buf), 8, 4, b)
-        assert out.shape == (b + 1,)
-        chosen, forced, throttled, rounds = unpack_step_output(
-            np.asarray(out))
-        assert chosen.shape == (b,)
-        assert rounds >= 1
+        new, out = fn(state, jnp.asarray(buf), 8, 4, b)
+        assert out.shape == (b + 1 + n,)
+        step = unpack_step_output(np.asarray(out), b)
+        assert step.chosen.shape == (b,)
+        assert step.rounds >= 1
+        np.testing.assert_array_equal(step.books, np.asarray(new.free_mb))
         # and the XLA repair pair derives the SAME decisions and rounds
         fn_x = make_fused_step_packed(release_batch_vector,
                                       schedule_batch_repair)

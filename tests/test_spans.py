@@ -46,7 +46,7 @@ from openwhisk_tpu.utils.transaction import TransactionId
 #: every span name of ISSUE 25's table, plus the row continuation
 SPANS = {
     "ow_admit", "ow_assemble", "ow_step", "ow_fold", "ow_shadow",
-    "ow_quality", "ow_telemetry_fold", "ow_books_ref", "ow_record",
+    "ow_quality", "ow_telemetry_fold", "ow_record",
     "ow_journal", "ow_readback_wait", "ow_readback_resume", "ow_fanout",
     "ow_placed", "ow_produce", "ow_ack_decode", "ow_ack_process", "ow_ping",
     "ow_supervision_tick", "ow_telemetry_tick", "ow_anomaly_tick",
@@ -209,7 +209,7 @@ def _decisions(records: list) -> list:
             if r["t"] == "ack"]
 
 
-def _host_lines(trace_dir: str) -> list:
+def _host_lines(trace_dir: str, prefix: str = "ow_") -> list:
     """[(events of one thread)] with events as (name, start, end, stats)."""
     from jax.profiler import ProfileData
 
@@ -222,7 +222,7 @@ def _host_lines(trace_dir: str) -> list:
         for line in plane.lines:
             evs = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
                     dict(ev.stats)) for ev in line.events
-                   if ev.name.startswith("ow_")]
+                   if ev.name.startswith(prefix)]
             if evs:
                 lines.append(evs)
     return lines
@@ -235,6 +235,7 @@ def runs(tmp_path_factory):
     traced = asyncio.run(_toy_run(str(tmp / "journal-traced"),
                                   str(tmp / "trace")))
     traced["lines"] = _host_lines(str(tmp / "trace"))
+    traced["jit_lines"] = _host_lines(str(tmp / "trace"), "PjitFunction(")
     return plain, traced
 
 
@@ -271,6 +272,41 @@ def test_steps_and_folds_are_the_journal_s_records(runs):
               if name == "ow_ack_decode"]
     assert sum(st["acks"] for st in frames) == traced["published"] - 1
     assert all(st["bytes"] > 0 for st in frames)
+
+
+def test_a_step_is_one_jitted_call(runs):
+    """ISSUE 31: the post-step books ride the step's own output, so an
+    `ow_step` holds ONE jitted call, the `packed` program the device
+    metrics pair with the journal, an `ow_fold` likewise, and nothing is
+    launched for the books. Outermost calls only: a first call traces the
+    jitted functions it is made of."""
+    _plain, traced = runs
+    (loop,) = [line for line in traced["lines"]
+               if any(name == "ow_assemble" for name, *_ in line)]
+    (on_loop,) = [line for line in traced["jit_lines"]
+                  if any(name == "PjitFunction(packed)"
+                         for name, *_ in line)]
+    calls = []
+    for ev in sorted({ev[:3] for ev in on_loop},
+                     key=lambda ev: (ev[1], -ev[2])):
+        if not calls or ev[1] >= calls[-1][2]:
+            calls.append(ev)
+    assert not [name for name, *_ in calls if "books" in name]
+
+    def inside(span):
+        return [name for name, s, e in calls
+                if span[1] <= s and e <= span[2]]
+
+    steps = [ev for ev in loop if ev[0] == "ow_step"]
+    assert len(steps) == len(BATCHES) + 2
+    assert all(inside(ev) == ["PjitFunction(packed)"] for ev in steps)
+    folds = [ev for ev in loop if ev[0] == "ow_fold"]
+    assert folds
+    assert all(inside(ev) == ["PjitFunction(packed)"] for ev in folds)
+    # every jitted call of the loop's thread is inside a span of the table
+    tops = [ev for ev in loop if ev[0] in SPANS]
+    assert all(any(t[1] <= s and e <= t[2] for t in tops)
+               for _name, s, e in calls)
 
 
 def test_loop_spans_overlap_only_by_nesting(runs):
