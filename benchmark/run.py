@@ -4,13 +4,16 @@
 
 One process owns the chip: it builds a `TpuBalancer` over the in-memory bus
 with the benchmark's simulated invoker fleet, warms the cell's own bucket
-shapes with the cell's own traffic (set-up), drives the window through
-`maybe_batch_publish(bal).publish(action, msg)` -> `await promise`, drains,
-reads the peak memory, closes the balancer, and only then replays the plain
-reference over what the window produced. The last line of standard output
-is the result. Nothing here names a cell, a configuration or a metric:
-those are the files under configs/, traffic/, metrics/ and readers/, found
-by the names in BENCHMARK.json.
+shapes with the cell's own traffic (set-up), drives the window through the
+configuration's entry, drains, reads the peak memory, closes the balancer,
+and only then replays the plain reference over what the window produced.
+The entry is the SPI unless the configuration's file states another:
+`maybe_batch_publish(bal).publish(action, msg)` -> `await promise` from this
+process's own event loop (below), or `"entry": "http"`, the program's REST
+API called from a generator in a process of its own (frontdoor.py). The
+last line of standard output is the result. Nothing here names a cell, a
+configuration or a metric: those are the files under configs/, traffic/,
+metrics/ and readers/, found by the names in BENCHMARK.json.
 """
 from __future__ import annotations
 
@@ -37,6 +40,11 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from benchmark import reference, traffic  # noqa: E402
+
+if __name__ == "__main__":
+    # an entry's module (frontdoor.py) builds on this one: one module
+    # object, whatever name it was started under
+    sys.modules.setdefault("benchmark.run", sys.modules[__name__])
 
 #: run-time files (journal, trace) live here, inside the checkout
 RUN_DIR = os.path.join(ROOT, ".bench_run")
@@ -287,6 +295,32 @@ class Sut:
             self.journal.close()
 
 
+# -- the entry ---------------------------------------------------------------
+
+def entry_of(config: dict):
+    """The module that holds a configuration's entry: `make_sut`, the three
+    loops (`burst`, `closed_loop`, `open_loop`) and `hand_over`. A
+    configuration that states none enters at the SPI, in this module."""
+    entry = config.get("entry", "spi")
+    if entry == "http":
+        from benchmark import frontdoor
+        return frontdoor
+    if entry == "spi":
+        return sys.modules[__name__]
+    raise BenchError(f"unknown entry {entry!r}")
+
+
+def make_sut(res: dict, catalog: traffic.Catalog, _seed: int) -> Sut:
+    return Sut(res["config"], catalog, res["cell"]["name"])
+
+
+async def hand_over(_sut: Sut, _win: dict) -> dict:
+    """The window's rows into the `Sut`'s record, and what the generator
+    has for the log line. At the SPI the loops below wrote the rows on this
+    event loop as they happened."""
+    return {}
+
+
 # -- the loops ---------------------------------------------------------------
 
 def burst(sut: Sut, seq: traffic.RankSequence, n: int) -> list:
@@ -393,7 +427,8 @@ async def run_cell(res: dict, seed: int, seconds: float, trace: bool,
     config, mix = res["config"], res["mix"]
     catalog = traffic.make_catalog(config, seed)
     seq = traffic.RankSequence(mix, len(catalog.names), seed)
-    sut = Sut(config, catalog, res["cell"]["name"])
+    entry = entry_of(config)
+    sut = entry.make_sut(res, catalog, seed)
     compiles = _watch_compiles()
     await sut.start()
     if faults:
@@ -404,7 +439,7 @@ async def run_cell(res: dict, seed: int, seconds: float, trace: bool,
     # compile the release-only fold and the telemetry fold of that size
     for n in WARM_BURSTS:
         sut.fleet.hold = True
-        tasks = burst(sut, seq, n)
+        tasks = entry.burst(sut, seq, n)
         deadline = time.monotonic() + COLD_COMPILE_WAIT_S
         while (sut.fleet.held < n and time.monotonic() < deadline
                and not all(t.done() for t in tasks)):
@@ -439,10 +474,10 @@ async def run_cell(res: dict, seed: int, seconds: float, trace: bool,
             _trace_subwindow(sut, res["cell"]["name"], warm_s + 1.0,
                              min(TRACE_SECONDS, max(0.5, seconds - 2.0))))
     if mix["loop"] == "closed":
-        win = await closed_loop(sut, seq, int(mix["clients"]), warm_s,
-                                seconds, on_window)
+        win = await entry.closed_loop(sut, seq, int(mix["clients"]), warm_s,
+                                      seconds, on_window)
     elif mix["loop"] == "open":
-        win = await open_loop(
+        win = await entry.open_loop(
             sut, seq, traffic.arrival_offsets(mix, warm_s, seed, 3),
             traffic.arrival_offsets(mix, seconds, seed, 4), seconds,
             on_window)
@@ -460,6 +495,7 @@ async def run_cell(res: dict, seed: int, seconds: float, trace: bool,
         p.cancel()
     if pending:
         await asyncio.gather(*pending, return_exceptions=True)
+    generator_log = await entry.hand_over(sut, win)
     traced = await tracer if tracer is not None else None
     # let the last releases fold, then read the books the run leaves
     for _ in range(40):
@@ -568,6 +604,7 @@ async def run_cell(res: dict, seed: int, seconds: float, trace: bool,
             str(q): percentile(overhead, q)
             for q in (0.5, 0.75, 0.9, 0.95, 0.98, 0.99, 0.999)},
         "gc_collections": [g["collections"] for g in gc.get_stats()],
+        **generator_log,
     }
     return {"art": art, "verdict": verdict, "attempted": attempted,
             "failed": failed, "peak": peak, "log": log,

@@ -6,9 +6,10 @@ Doubles the offered rate from `--rate0` until a step is not sustainable,
 then bisects twice between the last sustainable rate and the first that
 was not. A step is sustainable iff (after tools/loadgen.verdict): at least
 98% of the window's activations completed inside the drain, the overhead
-p99 stays under 1 s, the generator's fire lag p99 stays under 50 ms, and
-the later half of the window is not slower than the earlier half by more
-than 2x + 5 ms (no growing backlog). The cell's fixed rate is 0.8 x the
+p99 stays under 1 s, the generator's fire lag p99 (and, where the entry's
+generator is a process of its own, its event loop's lag p99) stays under
+50 ms, and the later half of the window is not slower than the earlier half
+by more than 2x + 5 ms (no growing backlog). The cell's fixed rate is 0.8 x the
 highest sustainable rate, written into its traffic file by hand.
 """
 from __future__ import annotations
@@ -39,9 +40,13 @@ def sustainable(out: dict) -> list:
     p99 = run.percentile(art["overhead_ms"], 0.99)
     if p99 is None or p99 > P99_BOUND_MS:
         failed.append(f"overhead p99 {p99} ms")
-    lag = run.percentile(art["fire_lag_ms"], 0.99)
-    if lag is not None and lag > MAX_FIRE_LAG_P99_MS:
-        failed.append(f"fire lag p99 {lag} ms")
+    # a generator in a process of its own (entry `http`) also reports the
+    # lag of its own event loop: a starved generator is not a fast server
+    for what, lag in (
+            ("fire", run.percentile(art["fire_lag_ms"], 0.99)),
+            ("generator", out["log"].get("generator_lag_p99_ms"))):
+        if lag is not None and lag > MAX_FIRE_LAG_P99_MS:
+            failed.append(f"{what} lag p99 {lag} ms")
     first, second = out["log"]["latency_p50_by_half_ms"]
     if first is not None and second > 2 * first + 5.0:
         failed.append(f"backlog grows: p50 {first} -> {second} ms")
@@ -72,6 +77,7 @@ def main(argv=None) -> int:
             "overhead_p50_ms": run.percentile(out["art"]["overhead_ms"], .5),
             "overhead_p99_ms": run.percentile(out["art"]["overhead_ms"], .99),
             "fire_lag_p99_ms": run.percentile(out["art"]["fire_lag_ms"], .99),
+            "generator_lag_p99_ms": out["log"].get("generator_lag_p99_ms"),
             "p50_by_half_ms": out["log"]["latency_p50_by_half_ms"],
             "steps_in_window": out["log"]["steps_in_window"]}), flush=True)
         return not why

@@ -40,8 +40,10 @@ HOST_OTHER = "host_other(balancer,bus,event_loop)"
 #: release-only fold): `jit_<name>(<id>)` on the device's module line,
 #: `PjitFunction(<name>)` where the host dispatches it
 STEP_PROGRAM = "packed"
-#: the device's clock runs some 0.1-0.3 ms ahead of the host's in a trace
-CLOCK_SLACK_NS = 1e6
+#: the most the device's clock may run ahead of the host's in a trace: the
+#: offset is constant within one, 0.1-0.3 ms in most and 1.4-1.8 ms in two
+#: of PR 32's four
+CLOCK_SLACK_NS = 5e6
 
 Interval = Tuple[float, float]
 
@@ -118,22 +120,37 @@ def _own_time(spans: List[Tuple[str, Interval]]
 def pair_runs(dispatches: List[float], runs: List[Interval],
               slack: float = CLOCK_SLACK_NS) -> List[Optional[float]]:
     """For each host dispatch (start times, in order) the duration of its
-    execution on the device: the first execution not yet taken that starts
-    no earlier than the dispatch, less the clocks' slack. The device runs
-    one program at a time in dispatch order, so an execution that started
-    before a dispatch belongs to an earlier one. None where the trace
-    ended first."""
-    out: List[Optional[float]] = []
-    i = 0
-    for h in dispatches:
-        while i < len(runs) and runs[i][0] < h - slack:
-            i += 1
-        if i < len(runs):
-            out.append(runs[i][1] - runs[i][0])
-            i += 1
-        else:
-            out.append(None)
-    return out
+    execution on the device. The device runs one program at a time in
+    dispatch order, one execution a dispatch, so the window's dispatches
+    and its executions are two slices of ONE sequence, and only their first
+    members have to be matched: the dispatches' first belongs to the first
+    execution that starts no earlier than it, less the clocks' slack, or,
+    where that one was dispatched before the window, to the next. Which of
+    the two is decided by what a right pairing has and a wrong one has not:
+    executions that start a short and steady while after their dispatches
+    (`_misfit`). None where the trace ended first."""
+    if not dispatches:
+        return []
+    first = next((k for k, run in enumerate(runs)
+                  if run[0] >= dispatches[0] - slack), len(runs))
+    first = min((first, first + 1),
+                key=lambda k: _misfit(dispatches, runs[k:]))
+    mine = runs[first:first + len(dispatches)]
+    return [e - s for s, e in mine] + [None] * (len(dispatches) - len(mine))
+
+
+def _misfit(dispatches: List[float], runs: List[Interval]) -> float:
+    """How badly `runs`, taken in order, fit `dispatches`: the band the
+    delays from dispatch to execution lie in (their 10th to 90th
+    percentile), plus the size of their median. Rightly paired the delay is
+    a launch less the clocks' offset, both constant within a trace; paired
+    one off it is the gap between two steps."""
+    delays = sorted(run[0] - h for h, run in zip(dispatches, runs))
+    if not delays:
+        return float("inf")
+    last = len(delays) - 1
+    return (delays[int(0.9 * last)] - delays[int(0.1 * last)]
+            + abs(delays[last // 2]))
 
 
 def reduce_trace(path: str) -> dict:

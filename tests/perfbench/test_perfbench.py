@@ -256,6 +256,21 @@ def test_each_dispatch_is_paired_with_its_own_execution():
     assert trace_reduce.pair_runs([10, 20, 50, 60], runs, slack=1) \
         == pytest.approx([14.8, 5, 2.5, None])
     assert trace_reduce.pair_runs([], runs) == []
+    # PR 32's trace: the device's clock 1.4 ms ahead of the host's, more
+    # than a launch takes; a fused step (0.48) and a release-only fold
+    # (0.04) in turns, tens of ms apart. Paired one off, every fused step
+    # would read as a fold
+    host = [18.8, 89.0, 114.9, 190.5, 215.8]
+    device = [(17.4, 17.88), (87.8, 87.84), (113.5, 113.98),
+              (189.2, 189.24), (214.5, 214.98)]
+    want = [0.48, 0.04, 0.48, 0.04, 0.48]
+    assert trace_reduce.pair_runs(host, device, slack=5) \
+        == pytest.approx(want)
+    # an execution inside the slack that was dispatched before the window
+    assert trace_reduce.pair_runs(host, [(16.0, 16.04)] + device, slack=5) \
+        == pytest.approx(want)
+    assert trace_reduce.pair_runs(host, device[:3], slack=5) \
+        == pytest.approx(want[:3] + [None, None])
 
 
 def test_interval_union_and_a_trace_without_a_device():

@@ -35,7 +35,8 @@ def test_the_cells_that_report_the_two_shares():
     # every closed cell, the accepted one included (no `workloads` key)
     assert reporting["warm_share.closed"] == [CELL]
     assert reporting["forced_share.closed"] == [
-        "fleet1k-zipf-closed", CELL, "standalone16-noop-closed"]
+        "fleet1k-zipf-closed", CELL, "standalone16-noop-closed",
+        "frontdoor16-noop-closed"]
     _res, specs = _specs()
     for name, num in zip(METRICS, ("warm", "forced")):
         assert specs[name]["reader"] == "span_ratio"
