@@ -25,7 +25,7 @@ from typing import Dict, List, Optional, Union
 from ...core.entity import (ActivationId, ExecutableWhiskAction, Identity,
                             InvokerInstanceId, WhiskAction, WhiskActivation)
 from ...messaging.connector import MessageFeed, decode_batch, decode_message
-from ...messaging.columnar import is_batch_payload
+from ...messaging.columnar import KIND_ACK, intern_hits, is_batch_payload
 from ...messaging.message import (AcknowledgementMessage, ActivationMessage,
                                   parse_ack)
 from ...utils.config import load_config
@@ -644,14 +644,20 @@ class CommonLoadBalancer(LoadBalancer):
         return 0
 
     def process_acknowledgement_frame(self, raw: bytes) -> None:
-        """A columnar ack batch frame off the completion feed: ONE decode
-        for the whole frame, then the batched one-pass completion path
-        (or, with `batched_ack` off, a serial replay of each ack —
-        bit-exact with N independent frames)."""
+        """An ack frame off the completion feed, of one ack or many: ONE
+        decode for the whole frame, then the batched one-pass completion
+        path (or, with `batched_ack` off, a serial replay of each ack —
+        bit-exact with N independent frames). A frame that does not
+        decode is logged and dropped whole: none of its acks is applied."""
         try:
             with self._ack_decode_span(raw) as sp:
-                _kind, acks = decode_batch(raw)
-                sp.set_metadata(acks=len(acks))
+                hits = intern_hits()
+                kind, acks = decode_batch(raw)
+                if kind != KIND_ACK:
+                    raise ValueError(f"unexpected batch kind {kind!r} on "
+                                     "the completion topic")
+                sp.set_metadata(acks=len(acks),
+                                interned=intern_hits() - hits)
         except (ValueError, KeyError, IndexError, TypeError,
                 AssertionError) as e:
             if self.logger:

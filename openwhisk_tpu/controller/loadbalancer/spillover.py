@@ -22,7 +22,7 @@ own queue:
     placement happened — the origin's waterfall folds at the
     `spill_forward` stage (the extra hop, stamped) and the peer owns the
     rest of the row's life;
-  * transport is the bus: one columnar `ActivationBatchMessage` frame on
+  * transport is the bus: one activation frame (messaging/columnar.py) on
     the peer's `ctrlspill<N>` topic per forwarded batch.
 
 Off-switch: `CONFIG_whisk_ha_activeActive_spillover=false` (the default)
@@ -34,7 +34,8 @@ import asyncio
 from typing import List, Optional
 
 from ...core.entity import ControllerInstanceId
-from ...messaging.columnar import ActivationBatchMessage, is_batch_payload
+from ...messaging.columnar import (KIND_ACTIVATION, is_batch_payload,
+                                   make_batch)
 from ...messaging.connector import MessageFeed, decode_batch
 from ...utils.eventlog import GLOBAL_EVENT_LOG
 from ...utils.transaction import TransactionId
@@ -88,7 +89,7 @@ class SpilloverSender(FrameSender):
         GLOBAL_EVENT_LOG.record("spill_burst", peer=int(peer),
                                 rows=len(msgs))
         self._emit_hop_spans(msgs, peer)
-        self.send_frame(topic, ActivationBatchMessage(msgs), outs=outs)
+        self.send_frame(topic, make_batch(KIND_ACTIVATION, msgs), outs=outs)
         return outs
 
     def _emit_hop_spans(self, msgs, peer) -> None:

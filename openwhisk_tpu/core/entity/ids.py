@@ -33,8 +33,15 @@ class ActivationId:
         # builds a UUID object, int-converts and re-formats; id minting
         # is once per activation on the publish hot path and showed up
         # in the host observatory's self-time census)
+        return cls.of_hex(os.urandom(16).hex())
+
+    @classmethod
+    def of_hex(cls, hex32: str) -> "ActivationId":
+        """The id of a string that is 32 lowercase hex characters BY
+        CONSTRUCTION (`bytes.hex()` of 16 bytes: `generate`, the wire
+        frame's id column), so the constructor's regex is skipped."""
         aid = object.__new__(cls)
-        aid.asString = os.urandom(16).hex()
+        aid.asString = hex32
         return aid
 
     def to_json(self) -> str:
@@ -155,7 +162,9 @@ class DocInfo:
 class InstanceId:
     """Numbered component instance (ref InstanceId.scala:31-60)."""
 
-    __slots__ = ("instance", "unique_name", "display_name")
+    #: `__weakref__`: the wire encoder keeps an instance's encoded blob
+    #: beside it for as long as the instance lives (messaging/columnar.py)
+    __slots__ = ("instance", "unique_name", "display_name", "__weakref__")
     prefix = "instance"
 
     def __init__(self, instance: int, unique_name: Optional[str] = None,

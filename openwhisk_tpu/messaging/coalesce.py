@@ -18,15 +18,27 @@ flushes at the end of the current event-loop sweep, which still coalesces a
 whole readback wave). Flushes are serialized on one drainer task, so
 per-producer ordering is exactly the serial producer's.
 
+With the batch wire on (`CONFIG_whisk_bus_coalesce_batchWire`, the
+default) an activation or an ack is not encoded on its sender's turn: it
+rides to the flush as an object, and the flush packs the messages of one
+(topic, family), from ONE message up, into one struct-packed frame
+(messaging/columnar.py) whose repeated sub-objects are interned on both
+sides. A lone message is a 1-row frame: these two families have no
+per-message JSON form here. `_STATS` counts the frames and their rows by
+family, `ow_produce` carries `interned`, the blobs reused without an
+encode. Pings, events and pre-encoded bytes pass through as they are.
+
 Backends with a native batch op ship one frame per micro-batch
 (`TcpProducer.send_many` -> the broker's `pubN` op: one length-prefixed
 frame, N payloads, one ack, broker-side dedupe per sub-message); backends
 without one fall back to the base `send_many` (sequential sends — serial
 semantics, no wire-protocol change).
 
-Off switch: `CONFIG_whisk_bus_coalesce_enabled=false` makes
+Off switches: `CONFIG_whisk_bus_coalesce_enabled=false` makes
 `maybe_coalesce()` return the raw producer — the serial path, bit-exact
-with today's behavior.
+with today's behavior; `CONFIG_whisk_bus_coalesce_batchWire=false` keeps
+the coalescing and ships one plain JSON payload per message, byte-exact
+with the serial wire.
 """
 from __future__ import annotations
 
@@ -36,13 +48,18 @@ from typing import Optional
 from ..utils.config import load_config
 from ..utils.microbatch import MicroCoalescer
 from ..utils.waterfall import span
-from .connector import MessageProducer, encode_message
+from .columnar import (KIND_ACK, KIND_ACTIVATION, WIRE_STATS, batch_hop_of,
+                       batchable_family, intern_hits)
+from .connector import MessageProducer, encode_batch, encode_message
 
 #: process-wide coalescing health counters, exported as gauges by the
 #: balancers' supervision tick (export_coalesce_gauges) — one aggregate
-#: across producers, like the tracing gauges
+#: across producers, like the tracing gauges. `wire_frames` / `wire_rows`
+#: count, by family, the frames this process encoded and the messages
+#: inside them (rows / frames = how many messages share a frame's tables)
 _STATS = {"batches": 0, "messages": 0, "max_batch": 0,
-          "wire_batches": 0, "wire_batched_messages": 0}
+          "wire_frames": {KIND_ACTIVATION: 0, KIND_ACK: 0},
+          "wire_rows": {KIND_ACTIVATION: 0, KIND_ACK: 0}}
 
 
 @dataclass(frozen=True)
@@ -58,20 +75,15 @@ class BusCoalesceConfig:
     #: stage p99 stays ~1 ms at the sustained rate). Set ~1 ms on expensive
     #: transports (remote TCP, Kafka) to also batch across waves.
     window_ms: float = 0.0
-    #: columnar batch wire (messaging/columnar.py): same-topic
-    #: activation/ack messages in one flush ship as ONE encoded batch
-    #: record — one json.dumps per batch with per-batch identity/action
-    #: dedup, instead of N independent encodes (and the encode moves to
-    #: flush time, so a message serialized for a batch is encoded exactly
-    #: once). False restores the serial wire format byte-exactly.
+    #: the batch wire (messaging/columnar.py): the activation/ack
+    #: messages of one topic and flush, from ONE message up, ship as one
+    #: struct-packed frame with the repeated identity / action /
+    #: controller / invoker sub-objects interned on both sides (and the
+    #: encode moves to flush time, so a message is encoded exactly once).
+    #: An ack's response record rides as opaque bytes, so the
+    #: controller's completion loop never parses a result nobody reads.
+    #: False restores the serial wire format byte-exactly.
     batch_wire: bool = True
-    #: lazy ack result column (ISSUE 14): ack batch frames carry each
-    #: activation's response payload as an opaque bytes column after the
-    #: JSON header, so the controller's completion loop never parses a
-    #: result nobody reads (blocking invokes parse on the API turn;
-    #: fire-and-forget acks skip the parse entirely). False restores the
-    #: PR 11 ack batch record byte-exactly.
-    lazy_results: bool = True
 
     @classmethod
     def from_env(cls) -> "BusCoalesceConfig":
@@ -84,11 +96,9 @@ class CoalescingProducer(MessageProducer):
     (utils/microbatch.py) — the admission plane rides the same one."""
 
     def __init__(self, inner: MessageProducer, max_batch: int = 64,
-                 window_ms: float = 0.0, batch_wire: bool = False,
-                 lazy_results: bool = False):
+                 window_ms: float = 0.0, batch_wire: bool = False):
         self.inner = inner
         self.batch_wire = batch_wire
-        self.lazy_results = lazy_results
         self._co = MicroCoalescer(self._ship, max_batch,
                                   max(0.0, float(window_ms)) / 1e3,
                                   name="bus-coalesce-drain")
@@ -104,14 +114,13 @@ class CoalescingProducer(MessageProducer):
     async def send(self, topic: str, msg) -> None:
         # Batch wire fast path: a batchable message (activation / ack) is
         # NOT encoded here — it rides to the flush as an object and is
-        # encoded exactly once, inside its batch's single json.dumps.
+        # encoded exactly once, as a row of its group's frame.
         # Everything else serializes on the caller's turn as before (the
         # flush loop then ships bytes without touching message objects,
         # and a slow .serialize() is charged to the sender, not to every
         # batch-mate). encode_message / encode_batch both feed the host
         # observatory's per-hop serde accounting.
         if self.batch_wire and not isinstance(msg, (bytes, bytearray)):
-            from .columnar import batchable_family
             family = batchable_family(msg)
             if family is not None:
                 await self._co.submit((topic, family, msg))
@@ -128,7 +137,6 @@ class CoalescingProducer(MessageProducer):
     def _submit_nowait(self, topic: str, msg) -> "asyncio.Future":
         """send() without the await: enqueue, return the flush future."""
         if self.batch_wire and not isinstance(msg, (bytes, bytearray)):
-            from .columnar import batchable_family
             family = batchable_family(msg)
             if family is not None:
                 return self._co.submit_nowait((topic, family, msg))
@@ -153,11 +161,10 @@ class CoalescingProducer(MessageProducer):
     async def _ship(self, batch) -> None:
         """One coalesced flush: the whole batch rides the provider's
         send_many (one pubN frame on the TCP bus). With the batch wire
-        on, same-topic batchable messages collapse into ONE columnar
-        record per (topic, family) — encoded here, exactly once per
-        message — so the pubN frame carries one payload per topic
-        instead of one per message. The coalescer resolves the waiter
-        futures on return / failure."""
+        on, the batchable messages of one (topic, family) become ONE
+        frame — encoded here, exactly once per message — so the pubN
+        frame carries one payload per topic instead of one per message.
+        The coalescer resolves the waiter futures on return / failure."""
         _STATS["batches"] += 1
         _STATS["messages"] += len(batch)
         _STATS["max_batch"] = max(_STATS["max_batch"], len(batch))
@@ -166,14 +173,16 @@ class CoalescingProducer(MessageProducer):
             return
         # the span covers the encode, not the awaited send
         with span("ow_produce", n=len(batch)) as sp:
+            reused = WIRE_STATS["blob_hits"]
             out = self._encode_flush(batch)
-            sp.set_metadata(bytes=sum(len(p) for _t, p, _m in out))
+            sp.set_metadata(bytes=sum(len(p) for _t, p, _m in out),
+                            interned=WIRE_STATS["blob_hits"] - reused)
         await self.inner.send_many(out)
 
     def _encode_flush(self, batch) -> list:
         """The synchronous half of `_ship` with the batch wire on: one
-        `(topic, payload, msg)` per (topic, family) group or lone item."""
-        from .connector import encode_batch
+        `(topic, payload, msg)` per (topic, family) group or pre-encoded
+        item."""
         # group deferred-encode messages per (topic, family), preserving
         # per-topic arrival order WITHIN a family (the serial ordering
         # contract is per-topic; cross-topic order was never guaranteed —
@@ -203,46 +212,40 @@ class CoalescingProducer(MessageProducer):
         for it in items:
             if isinstance(it, tuple) and len(it) == 2:
                 topic, family = it
-                group = groups[(topic, family)]
-                msgs = [m for (m, _f) in group]
-                if len(msgs) == 1:
-                    # a lone message pays the plain wire format — the
-                    # decode side needs no batch frame for N=1 and the
-                    # serial consumers stay compatible
-                    try:
-                        out.append((topic, encode_message(msgs[0]),
-                                    msgs[0]))
-                    except Exception as e:  # noqa: BLE001
-                        self._fail_group(group, e)
-                    continue
+                group = groups[it]
                 try:
-                    payload, batch_msg = encode_batch(
-                        family, msgs, lazy_results=self.lazy_results)
-                except Exception:  # noqa: BLE001 — deferring the encode
+                    out.append(self._frame(topic, family,
+                                           [m for (m, _f) in group]))
+                    continue
+                except Exception as e:  # noqa: BLE001 — deferring the encode
                     # to flush time must NOT widen one bad message's
                     # blast radius to the whole flush (the serial path
                     # charged a serialize failure to its sender): retry
-                    # each message alone so only the unserializable ones
-                    # fail, and the rest still ship
-                    for m, fut in group:
-                        try:
-                            out.append((topic, encode_message(m), m))
-                        except Exception as e:  # noqa: BLE001
-                            if not fut.done():
-                                fut.set_exception(e)
-                    continue
-                _STATS["wire_batches"] += 1
-                _STATS["wire_batched_messages"] += len(msgs)
-                out.append((topic, payload, batch_msg))
+                    # each message in a frame of its own, so only the
+                    # unserializable ones fail and the rest still ship
+                    if len(group) == 1:
+                        self._fail(group[0][1], e)
+                        continue
+                for m, fut in group:
+                    try:
+                        out.append(self._frame(topic, family, [m]))
+                    except Exception as e:  # noqa: BLE001
+                        self._fail(fut, e)
             else:
                 out.append(it)
         return out
 
     @staticmethod
-    def _fail_group(group, exc) -> None:
-        for _m, fut in group:
-            if not fut.done():
-                fut.set_exception(exc)
+    def _fail(fut, exc) -> None:
+        if not fut.done():
+            fut.set_exception(exc)
+
+    @staticmethod
+    def _frame(topic: str, family: str, msgs: list) -> tuple:
+        payload, frame = encode_batch(family, msgs)
+        _STATS["wire_frames"][family] += 1
+        _STATS["wire_rows"][family] += len(msgs)
+        return topic, payload, frame
 
     async def flush(self) -> None:
         """Wait until everything enqueued so far has shipped (or failed)."""
@@ -263,17 +266,21 @@ def maybe_coalesce(producer: MessageProducer,
     if not cfg.enabled or isinstance(producer, CoalescingProducer):
         return producer
     return CoalescingProducer(producer, cfg.max_batch, cfg.window_ms,
-                              batch_wire=cfg.batch_wire,
-                              lazy_results=cfg.lazy_results)
+                              batch_wire=cfg.batch_wire)
 
 
 def export_coalesce_gauges(metrics) -> None:
     """Coalescing health gauges (ridden by the balancers' supervision tick,
     like export_tracing_gauges): flushed batch/message counts and the
-    largest batch seen — messages/batches is the live amortization factor."""
+    largest batch seen — messages/batches is the live amortization factor
+    — and how often the batch wire's mechanism engaged: frames and rows
+    encoded by family, the decoder's intern-table lookups and hits."""
     metrics.gauge("bus_coalesce_batches", _STATS["batches"])
     metrics.gauge("bus_coalesce_messages", _STATS["messages"])
     metrics.gauge("bus_coalesce_batch_max", _STATS["max_batch"])
-    metrics.gauge("bus_wire_batches", _STATS["wire_batches"])
-    metrics.gauge("bus_wire_batched_messages",
-                  _STATS["wire_batched_messages"])
+    for family, frames in _STATS["wire_frames"].items():
+        tags = {"family": batch_hop_of(family)}
+        metrics.gauge("bus_wire_frames", frames, tags)
+        metrics.gauge("bus_wire_rows", _STATS["wire_rows"][family], tags)
+    metrics.gauge("bus_wire_intern_lookups", WIRE_STATS["intern_lookups"])
+    metrics.gauge("bus_wire_intern_hits", intern_hits())

@@ -1,69 +1,107 @@
-"""Columnar batch wire records: one encoded frame for a whole micro-batch.
+"""The batch wire: one struct-packed frame for the two hot messages.
 
-ISSUE 12's tentpole: the activation BATCH — not the activation — is the
-unit of work on every host hop. The coalescing producer already ships one
-`pubN` frame per micro-batch, but each sub-message inside it is still an
-independently-JSON-encoded ActivationMessage / ack: at 1,000 activations/s
-the host pays ~N `json.dumps` + N `json.loads` per hop, plus N parses of
-the SAME identity/action/controller sub-objects (the host observatory
-measured the serde plane at ~7.7% of wall per hop at 512/s, before
-counting the per-message object construction it feeds).
+The `ActivationMessage` (controller -> invoker) and the three
+`AcknowledgementMessage` kinds (invoker -> controller) cross the bus in
+ONE binary frame per (topic, family) and flush, at every group size
+from 1 up. A lone message is a 1-row frame: behind the coalescing
+producer these two families have no per-message JSON form. The funnel's
+`fun1` / `funA` records (ISSUE 20) are still JSON and live at the end
+of this module.
 
-This module is the wire half of the columnar hot path:
+The frame, byte by byte (little-endian, no padding; docs/spi.md has the
+same table):
 
-  * `ActivationBatchMessage` — N controller->invoker dispatches packed as
-    ONE struct-of-arrays JSON record: per-batch dedup tables for the
-    repeated heavy sub-objects (users, (action, revision) pairs,
-    controller ids) and packed per-row columns (activation ids, user /
-    action indices, transids, blocking bits, arg payloads — the arg
-    column is the "one blob" of the packed form: a single `json.dumps`
-    writes every row's args in one C-speed pass, and sparse columns
-    carry the rarely-present fields). ONE serialize per batch; the
-    decode side rebuilds N `ActivationMessage`s parsing each unique
-    identity/action exactly once.
-  * `AckBatchMessage` — the mirror record for the invoker->controller
-    completion fan-in (kinds, transids, ids, invoker dedup, system-error
-    bits, response payloads).
-  * `is_batch_payload` / `batch_hop_of` — frame sniffing for consumers:
-    every batch payload starts with the `{"whiskBatch":` magic, so a
-    feed handler can route a frame to the batch decode without parsing
-    it (plain per-message frames never start with that key — neither
-    ActivationMessage nor the acks serialize a `whiskBatch` field
-    first, and json.dumps key order is insertion order).
+    offset  size     field
+    0       3        magic `\\xff O W`: 0xFF starts no UTF-8 text, so no
+                     JSON payload can begin with it
+    3       1        version (1)
+    4       1        family: 1 = activation, 2 = ack
+    5       4        n, the row count (uint32)
+    9       2+2+2    the sizes of the three dedup tables (uint16 each):
+                     activation = users, actions, controllers;
+                     ack = invokers, 0, 0
+    15      4        s, the byte length of the sparse section (uint32)
+    19      4*T      the byte length of each table blob (uint32; T = the
+                     three sizes' sum), in table order
+            8*n      transaction `start_wallclock` (float64, bit-exact)
+            1*n      row flags (below)
+            2*n      byte length of the transaction id's UTF-8 (uint16)
+            4*n      byte length of the row's opaque body (uint32; 0 = None)
+            2*n*k    row -> table indices (uint16), one column after the
+                     other: activation k = 3 (user, action, controller),
+                     ack k = 1 (invoker; 0xFFFF = none)
+            ...      the table blobs, back to back
+            s        the sparse section: one compact JSON object
+                     {column: {row: value}} of the rare fields; s = 0
+                     when no row carries one
+            16*n     activation ids (the 32 hex characters as 16 bytes)
+            ...      per row: transaction id bytes, then the opaque body
 
-Off switch: the batch wire rides the coalescing producer
-(`CONFIG_whisk_bus_coalesce_batchWire=false` restores one independently
-encoded payload per message — the serial wire format, byte-exact).
+    activation flags: bit 0 blocking
+    ack flags: bits 0-1 the kind (0 completion, 1 result, 2 combined);
+      bit 2 isSystemError
+    both: bit 3 the id is not 32 lowercase hex and sits in the sparse
+      `ids` column (its 16 bytes are zero)
+
+A table blob is the sub-object's existing compact JSON: a user is
+`Identity.to_json()`, an action `[fqn, revision]`, a controller its
+name, an invoker `InvokerInstanceId.to_json()`: every field the serial
+wire carries, so nothing of an identity's limits or rights is lost.
+Blobs are interned on both sides. The ENCODER keeps a blob beside the
+object it came from (`_blob_beside`: keyed by object identity, dropped
+with the object; identities and instance ids are values nobody
+mutates), so a sub-object is encoded once in its life and not once a
+message. The DECODER keeps bounded tables from blob bytes to the parsed
+object (`_InternTable`: `INTERN_BOUND` entries, reset whole when full):
+a blob seen before costs one dict lookup, and the parsed object is
+SHARED by every message that names it (read-only on the consume side,
+like the reference's case classes); a blob that differs in any byte (a
+namespace whose limits changed, a new revision) is parsed anew.
+
+The opaque body is the user's own data as JSON bytes: an activation's
+`content` (`{}` is two bytes and no `dumps`), an ack's response record.
+The response is parsed only when somebody reads it
+(`LazyWhiskActivation`); the completion loop needs the columns alone.
+Sparse columns: `cause`, `trace`, `init` (non-empty `initArgs`),
+`fence` / `fences`, `fpart` / `fparts` on activations, `trace` on acks,
+`ids` on both.
+
+Consumers route with a sniff, not a parse: `is_batch_payload` is true
+for a frame and for the funnel's JSON records, never for a plain
+message; `parse_batch` returns `(kind, messages)`. A truncated or
+garbled frame raises ValueError / IndexError / KeyError / TypeError,
+which the feeds' handlers log as a corrupt frame before any of its rows
+is applied.
+
+Off switch: `CONFIG_whisk_bus_coalesce_batchWire=false` restores one
+independently encoded JSON payload per message: the serial wire format,
+byte-exact. The plain parsers (`ActivationMessage.parse`, `parse_ack`)
+stay for it and for a peer that still sends it.
 """
 from __future__ import annotations
 
 import json
 import logging
-from typing import Dict, List, Optional, Tuple
+import struct
+import weakref
+from functools import lru_cache
+from typing import Callable, Dict, List, Optional, Tuple
 
-from ..core.entity import ActivationId, ControllerInstanceId, Identity
+from ..core.entity import (ActivationId, ControllerInstanceId, Identity,
+                           InvokerInstanceId)
 from ..core.entity.names import FullyQualifiedEntityName
 from ..utils.transaction import TransactionId
-from .message import (AcknowledgementMessage, ActivationMessage,
-                      CombinedCompletionAndResultMessage, CompletionMessage,
-                      Message, ResultMessage)
+from .message import AcknowledgementMessage, ActivationMessage, Message
 
-#: every batch payload leads with this key (json.dumps preserves insertion
-#: order, so the magic is a stable byte prefix — the cheap routing test)
+#: a frame's first three bytes
+WIRE_MAGIC = b"\xffOW"
+WIRE_VERSION = 1
+#: the funnel's JSON records lead with this key (json.dumps preserves
+#: insertion order, so it is a stable byte prefix)
 BATCH_MAGIC = b'{"whiskBatch":'
-#: the lazy ack frame's exact serialized prefix (compact json.dumps puts
-#: the magic key first): parse_batch sniffs THIS before paying a
-#: full-payload newline scan that plain frames can never satisfy
-_LAZY_PREFIX = b'{"whiskBatch":"ackL"'
 
-KIND_ACTIVATION = "act1"
-KIND_ACK = "ack1"
-#: the LAZY ack frame (ISSUE 14): a JSON header (columns + respLen) then
-#: one raw newline then the concatenated per-row response payloads as
-#: opaque bytes. json.dumps never emits a raw newline (strings escape
-#: theirs), so the first b"\n" in a batch payload is always this frame
-#: delimiter and plain frames never contain one.
-KIND_ACK_LAZY = "ackL"
+KIND_ACTIVATION = "act2"
+KIND_ACK = "ack2"
 #: the admission-funnel frame (ISSUE 20): an activation batch plus a
 #: (origin, seq, epoch) routing header — one front-end process's whole
 #: admission wave shipped to the device-owning balancer as one record.
@@ -75,7 +113,6 @@ KIND_FUNNEL_ACK = "funA"
 #: serde hop labels by batch kind (mirrors connector._SERDE_HOPS so the
 #: host observatory's per-hop accounting survives the batch wire)
 _BATCH_HOPS = {KIND_ACTIVATION: "activation", KIND_ACK: "completion_ack",
-               KIND_ACK_LAZY: "completion_ack",
                KIND_FUNNEL: "activation",
                KIND_FUNNEL_ACK: "completion_ack"}
 
@@ -84,13 +121,46 @@ _BATCH_HOPS = {KIND_ACTIVATION: "activation", KIND_ACK: "completion_ack",
 #: while the frame decode stays under completion_ack
 LAZY_RESULT_HOP = "ack_result"
 
+#: magic, version, family, rows, three table sizes, sparse length
+_HEADER = struct.Struct("<3sBBIHHHI")
+_NO_INDEX = 0xFFFF
+_ZERO_ID = bytes(16)
+#: `json.dumps(obj, separators=(",", ":"))` without the encoder object it
+#: builds per call
+_dumps = json.JSONEncoder(separators=(",", ":")).encode
+#: row flags: bit 3 means the same in both families
+_BLOCKING = 1
+_ACK_KIND_MASK = 3
+_ACK_SYSTEM_ERROR = 4
+_ID_SPARSE = 8
+_ACK_KINDS = ("completion", "result", "combined")
+_ACK_CODES = {kind: code for code, kind in enumerate(_ACK_KINDS)}
+
+#: a decoder table holds at most this many blobs and is reset whole when
+#: full (a process sees thousands of identities and actions, not
+#: millions; a reset costs one parse per blob still in use)
+INTERN_BOUND = 8192
+
+#: how often the interning engaged, process-wide: the decoder's lookups
+#: and those that had to parse (gauges `bus_wire_intern_*`,
+#: messaging/coalesce.py; hits = lookups - misses), and the blobs the
+#: encoder reused without an encode (`ow_produce`'s `interned`)
+WIRE_STATS = {"intern_lookups": 0, "intern_misses": 0, "blob_hits": 0}
+
+
+def intern_hits() -> int:
+    """Decoder lookups answered from a table, process-wide so far."""
+    return WIRE_STATS["intern_lookups"] - WIRE_STATS["intern_misses"]
+
 
 def is_batch_payload(raw) -> bool:
-    """True when `raw` is a batch wire record (magic-prefix sniff; no
-    parse). Accepts bytes/bytearray/str."""
+    """True when `raw` is a batch wire record: a struct-packed frame or
+    one of the funnel's JSON records (prefix sniff; no parse). Accepts
+    bytes/bytearray/str; a str is never a frame."""
     if isinstance(raw, str):
         return raw.startswith('{"whiskBatch":')
-    return bytes(raw[:len(BATCH_MAGIC)]) == BATCH_MAGIC
+    head = bytes(raw[:len(BATCH_MAGIC)])
+    return head.startswith(WIRE_MAGIC) or head == BATCH_MAGIC
 
 
 def batch_hop_of(kind: str) -> str:
@@ -108,37 +178,217 @@ def batchable_family(msg) -> Optional[str]:
     return None
 
 
-class _Dedup:
-    """Insertion-ordered dedup table: intern() returns the index of the
-    (hashable) key, appending `value` on first sight."""
+# -- interning --------------------------------------------------------------
 
-    __slots__ = ("index", "values")
-
-    def __init__(self):
-        self.index: Dict[object, int] = {}
-        self.values: List[object] = []
-
-    def intern(self, key, value) -> int:
-        i = self.index.get(key)
-        if i is None:
-            i = len(self.values)
-            self.index[key] = i
-            self.values.append(value)
-        return i
+#: the encoder's side: id(object) -> (weak reference, blob)
+_BLOBS_BESIDE: Dict[int, tuple] = {}
+#: (path, name, revision) -> blob. A message builds its action's name
+#: anew each time, so this one is keyed by value; bounded like the
+#: decoder's tables
+_ACTION_BLOBS: Dict[tuple, bytes] = {}
 
 
-class ActivationBatchMessage(Message):
-    """N ActivationMessages as one columnar wire record (see module doc).
+def _blob_beside(obj, to_json: Callable) -> bytes:
+    """The compact JSON of `to_json(obj)`, encoded once in `obj`'s life."""
+    key = id(obj)
+    kept = _BLOBS_BESIDE.get(key)
+    if kept is not None and kept[0]() is obj:
+        WIRE_STATS["blob_hits"] += 1
+        return kept[1]
+    blob = _dumps(to_json(obj)).encode()
+    try:
+        _BLOBS_BESIDE[key] = (
+            weakref.ref(obj,
+                        lambda _r, key=key: _BLOBS_BESIDE.pop(key, None)),
+            blob)
+    except TypeError:
+        pass    # an object that takes no weak reference is encoded each time
+    return blob
 
-    The struct-of-arrays layout: `users`/`actions`/`ctrls` are per-batch
-    dedup tables (each unique identity / (fqn, revision) / controller
-    encoded ONCE); `ids`, `u`, `a`, `c`, `tx`, `bl`, `args` are
-    length-N columns; `cause`/`trace`/`init` are sparse {row: value}
-    columns present only when some row carries the field. `fence` is the
+
+def _action_blob(action: FullyQualifiedEntityName,
+                 revision: Optional[str]) -> bytes:
+    key = (action.path.path, action.name.name, revision)
+    blob = _ACTION_BLOBS.get(key)
+    if blob is None:
+        if len(_ACTION_BLOBS) >= INTERN_BOUND:
+            _ACTION_BLOBS.clear()
+        blob = _ACTION_BLOBS[key] = _dumps([str(action), revision]).encode()
+    else:
+        WIRE_STATS["blob_hits"] += 1
+    return blob
+
+
+class _InternTable:
+    """The decoder's side: blob bytes -> the object parsed from them."""
+
+    __slots__ = ("parse", "objects")
+
+    def __init__(self, parse: Callable):
+        self.parse = parse
+        self.objects: Dict[bytes, object] = {}
+
+    def get(self, blob: bytes):
+        obj = self.objects.get(blob)
+        if obj is None:
+            obj = self.parse(json.loads(blob))
+            if len(self.objects) >= INTERN_BOUND:
+                self.objects.clear()
+            self.objects[blob] = obj
+            WIRE_STATS["intern_misses"] += 1
+        return obj
+
+
+_USERS = _InternTable(Identity.from_json)
+_ACTIONS = _InternTable(
+    lambda j: (FullyQualifiedEntityName.parse(j[0]), j[1]))
+_CONTROLLERS = _InternTable(ControllerInstanceId)
+_INVOKERS = _InternTable(InvokerInstanceId.from_json)
+
+
+# -- the frame --------------------------------------------------------------
+
+@lru_cache(maxsize=1024)
+def _columns_struct(blobs: int, n: int, index_columns: int) -> struct.Struct:
+    """The fixed-width columns of a frame of `n` rows and `blobs` table
+    blobs (module doc), compiled once per shape."""
+    return struct.Struct(
+        f"<{blobs}I{n}d{n}B{n}H{n}I{index_columns * n}H")
+
+
+def _id_bytes(aid: str) -> Optional[bytes]:
+    """The id's 16 bytes, or None where its string is not exactly their
+    32 lowercase hex characters (no ActivationId is such, but the wire
+    round-trips whatever it is given)."""
+    if len(aid) == 32:
+        try:
+            raw = bytes.fromhex(aid)
+        except ValueError:
+            return None
+        if len(raw) == 16 and raw.hex() == aid:
+            return raw
+    return None
+
+
+def _pack_frame(code: int, n: int, sizes: tuple, blobs: list, sparse: dict,
+                walls: list, flags: list, tid_lens: list, body_lens: list,
+                indices: list, ids: list, heap: list) -> bytes:
+    if max(sizes) >= _NO_INDEX:
+        raise ValueError(f"a frame's table holds under {_NO_INDEX} blobs")
+    sparse_raw = _dumps(sparse).encode() if sparse else b""
+    return b"".join([
+        _HEADER.pack(WIRE_MAGIC, WIRE_VERSION, code, n, *sizes,
+                     len(sparse_raw)),
+        _columns_struct(len(blobs), n, len(indices) // n if n else 0).pack(
+            *map(len, blobs), *walls, *flags, *tid_lens, *body_lens,
+            *indices),
+        *blobs, sparse_raw, *ids, *heap])
+
+
+def _open_frame(raw: bytes, header: tuple, index_columns: int) -> tuple:
+    """Unpack a frame's fixed-width columns, slice its table blobs and
+    parse its sparse section. Returns (walls, flags, tid lengths, body
+    lengths, indices, blobs, sparse, the ids' hex, offset of the first
+    row's bytes); a frame whose length is not what its own columns add
+    up to is refused here, before a row is built."""
+    _magic, _version, _family, n, t0, t1, t2, sparse_len = header
+    nt = t0 + t1 + t2
+    columns = _columns_struct(nt, n, index_columns)
+    vals = columns.unpack_from(raw, _HEADER.size)
+    a = nt + n
+    tid_lens, body_lens = vals[a + n:a + 2 * n], vals[a + 2 * n:a + 3 * n]
+    off = _HEADER.size + columns.size
+    blobs = []
+    for blob_len in vals[:nt]:
+        blobs.append(raw[off:off + blob_len])
+        off += blob_len
+    rows_at = off + sparse_len + 16 * n
+    if rows_at + sum(tid_lens) + sum(body_lens) != len(raw):
+        raise ValueError(f"wire frame of {len(raw)} bytes, its columns add "
+                         f"up to {rows_at + sum(tid_lens) + sum(body_lens)}")
+    WIRE_STATS["intern_lookups"] += nt
+    sparse = json.loads(raw[off:off + sparse_len]) if sparse_len else {}
+    return (vals[nt:a], vals[a:a + n], tid_lens, body_lens, vals[a + 3 * n:],
+            blobs, sparse, raw[rows_at - 16 * n:rows_at].hex(), rows_at)
+
+
+def _sparse_activation_columns(msgs: List[ActivationMessage]) -> dict:
+    """The rarely-present fields as {column: {row: value}}; shared by the
+    frame's sparse section and the funnel's JSON record. `fence` is the
     batch-level HA epoch (one controller's flush shares one epoch; a
-    rare mixed-epoch flush falls back to a sparse per-row column)."""
+    rare mixed-epoch flush falls back to the per-row `fences`), `fpart`
+    / `fparts` likewise for the active/active partition ids."""
+    cause: Dict[str, str] = {}
+    trace: Dict[str, dict] = {}
+    init: Dict[str, dict] = {}
+    fences: Dict[str, int] = {}
+    fparts: Dict[str, int] = {}
+    for row, m in enumerate(msgs):
+        if m.cause is not None:
+            cause[str(row)] = m.cause.to_json()
+        if m.trace_context is not None:
+            trace[str(row)] = m.trace_context
+        if m.init_args:
+            init[str(row)] = m.init_args
+        if m.fence_epoch is not None:
+            fences[str(row)] = m.fence_epoch
+        if m.fence_part is not None:
+            fparts[str(row)] = m.fence_part
+    out: dict = {}
+    if cause:
+        out["cause"] = cause
+    if trace:
+        out["trace"] = trace
+    if init:
+        out["init"] = init
+    for scalar, per_row, col in (("fence", "fences", fences),
+                                 ("fpart", "fparts", fparts)):
+        if col:
+            vals = set(col.values())
+            if len(vals) == 1 and len(col) == len(msgs):
+                out[scalar] = vals.pop()
+            else:
+                out[per_row] = col
+    return out
 
-    def __init__(self, msgs: List[ActivationMessage]):
+
+class _SparseActivation:
+    """Reader of `_sparse_activation_columns`' record."""
+
+    __slots__ = ("cause", "trace", "init", "fence", "fences", "fpart",
+                 "fparts")
+
+    def __init__(self, j: dict):
+        self.cause = j.get("cause") or {}
+        self.trace = j.get("trace") or {}
+        self.init = j.get("init") or {}
+        self.fence = j.get("fence")
+        self.fences = j.get("fences") or {}
+        self.fpart = j.get("fpart")
+        self.fparts = j.get("fparts") or {}
+
+    def of(self, row: int) -> tuple:
+        """(init_args, cause, trace_context, fence_epoch, fence_part)"""
+        key = str(row)
+        cause = self.cause.get(key)
+        return (self.init.get(key) or {},
+                ActivationId(cause) if cause else None,
+                self.trace.get(key),
+                self.fence if self.fence is not None
+                else self.fences.get(key),
+                self.fpart if self.fpart is not None
+                else self.fparts.get(key))
+
+
+class WireFrame(Message):
+    """N same-family messages as one frame (see module doc); what
+    `make_batch` hands the producer."""
+
+    family = ""
+    #: the header's family byte
+    code = 0
+
+    def __init__(self, msgs: list):
         self.msgs = msgs
 
     #: the waterfall produce edge stamps per activation: connector
@@ -147,131 +397,104 @@ class ActivationBatchMessage(Message):
     def activation_ids(self) -> List[str]:
         return [m.activation_id.asString for m in self.msgs]
 
-    def to_json(self) -> dict:
-        users, actions, ctrls = _Dedup(), _Dedup(), _Dedup()
-        ids: List[str] = []
-        u_col: List[int] = []
-        a_col: List[int] = []
-        c_col: List[int] = []
-        tx_col: List[object] = []
-        bl_col: List[int] = []
-        args_col: List[Optional[dict]] = []
-        cause: Dict[str, str] = {}
-        trace: Dict[str, dict] = {}
-        init: Dict[str, dict] = {}
-        fences: Dict[str, int] = {}
-        fparts: Dict[str, int] = {}
-        for row, m in enumerate(self.msgs):
-            ids.append(m.activation_id.asString)
-            # identity dedup keys on the subject+namespace-uuid pair (the
-            # stable identity key); the action table keys on (fqn, rev)
-            ident = m.user
-            u_col.append(users.intern(
-                (ident.subject, ident.namespace.uuid.asString),
-                ident.to_json()))
-            a_col.append(actions.intern((str(m.action), m.revision),
-                                        [str(m.action), m.revision]))
-            c_col.append(ctrls.intern(m.root_controller_index.name,
-                                      m.root_controller_index.name))
-            tx_col.append(m.transid.to_json())
-            bl_col.append(1 if m.blocking else 0)
-            args_col.append(m.content)
-            if m.cause is not None:
-                cause[str(row)] = m.cause.to_json()
-            if m.trace_context is not None:
-                trace[str(row)] = m.trace_context
-            if m.init_args:
-                init[str(row)] = m.init_args
-            if m.fence_epoch is not None:
-                fences[str(row)] = m.fence_epoch
-            if m.fence_part is not None:
-                fparts[str(row)] = m.fence_part
-        out = {
-            "whiskBatch": KIND_ACTIVATION,
-            "users": users.values,
-            "actions": actions.values,
-            "ctrls": ctrls.values,
-            "ids": ids,
-            "u": u_col, "a": a_col, "c": c_col,
-            "tx": tx_col, "bl": bl_col,
-            "args": args_col,
-        }
-        if cause:
-            out["cause"] = cause
-        if trace:
-            out["trace"] = trace
-        if init:
-            out["init"] = init
-        if fences:
-            # the common case is one shared epoch: collapse to a scalar
-            vals = set(fences.values())
-            if len(vals) == 1 and len(fences) == len(self.msgs):
-                out["fence"] = vals.pop()
+
+def _controller_name(ctrl: ControllerInstanceId) -> str:
+    return ctrl.name
+
+
+class ActivationFrame(WireFrame):
+    family = KIND_ACTIVATION
+    code = 1
+
+    def serialize(self) -> bytes:
+        msgs = self.msgs
+        users: Dict[bytes, int] = {}
+        actions: Dict[bytes, int] = {}
+        ctrls: Dict[bytes, int] = {}
+        walls, flags, tid_lens, body_lens = [], [], [], []
+        u_col, a_col, c_col = [], [], []
+        ids, heap = [], []
+        sparse_ids: Dict[str, str] = {}
+        rare = False
+        for row, m in enumerate(msgs):
+            u_col.append(users.setdefault(
+                _blob_beside(m.user, Identity.to_json), len(users)))
+            a_col.append(actions.setdefault(
+                _action_blob(m.action, m.revision), len(actions)))
+            c_col.append(ctrls.setdefault(
+                _blob_beside(m.root_controller_index, _controller_name),
+                len(ctrls)))
+            tid = str(m.transid.id).encode()
+            walls.append(m.transid.start_wallclock)
+            tid_lens.append(len(tid))
+            heap.append(tid)
+            content = m.content
+            if content is not None:
+                # `{}` is what a parameterless invoke carries
+                body = b"{}" if type(content) is dict and not content \
+                    else _dumps(content).encode()
+                heap.append(body)
+                body_lens.append(len(body))
             else:
-                out["fences"] = fences
-        if fparts:
-            # active/active: per-row partition ids (a batch freely mixes
-            # namespaces, so partitions rarely collapse to one scalar)
-            vals = set(fparts.values())
-            if len(vals) == 1 and len(fparts) == len(self.msgs):
-                out["fpart"] = vals.pop()
-            else:
-                out["fparts"] = fparts
-        return out
+                body_lens.append(0)
+            flag = _BLOCKING if m.blocking else 0
+            aid = _id_bytes(m.activation_id.asString)
+            if aid is None:
+                flag |= _ID_SPARSE
+                sparse_ids[str(row)] = m.activation_id.asString
+                aid = _ZERO_ID
+            ids.append(aid)
+            flags.append(flag)
+            rare = rare or m.cause is not None \
+                or m.trace_context is not None or bool(m.init_args) \
+                or m.fence_epoch is not None or m.fence_part is not None
+        sparse = _sparse_activation_columns(msgs) if rare else {}
+        if sparse_ids:
+            sparse["ids"] = sparse_ids
+        return _pack_frame(
+            self.code, len(msgs), (len(users), len(actions), len(ctrls)),
+            [*users, *actions, *ctrls], sparse, walls, flags, tid_lens,
+            body_lens, u_col + a_col + c_col, ids, heap)
 
     @staticmethod
-    def parse(raw) -> List[ActivationMessage]:
-        """One json.loads + shared-subobject reconstruction: each unique
-        identity/action/controller in the batch is parsed exactly once
-        and the rebuilt objects are SHARED across the batch's messages
-        (read-only on the consume side, like the reference's case
-        classes)."""
-        j = json.loads(raw)
-        return ActivationBatchMessage.from_json(j)
-
-    @staticmethod
-    def from_json(j: dict) -> List[ActivationMessage]:
-        users = [Identity.from_json(u) for u in j["users"]]
-        actions = [(FullyQualifiedEntityName.parse(a), rev)
-                   for a, rev in j["actions"]]
-        ctrls = [ControllerInstanceId(c) for c in j["ctrls"]]
-        cause = j.get("cause") or {}
-        trace = j.get("trace") or {}
-        init = j.get("init") or {}
-        fence = j.get("fence")
-        fences = j.get("fences") or {}
-        fpart = j.get("fpart")
-        fparts = j.get("fparts") or {}
+    def decode(raw: bytes, header: tuple) -> List[ActivationMessage]:
+        """Each distinct identity / action / controller of the frame is
+        looked up once and the objects are SHARED by its messages."""
+        (walls, flags, tid_lens, body_lens, idx, blobs, sparse, ids,
+         off) = _open_frame(raw, header, 3)
+        n, n_users, n_actions = header[3], header[4], header[4] + header[5]
+        users = [_USERS.get(b) for b in blobs[:n_users]]
+        actions = [_ACTIONS.get(b) for b in blobs[n_users:n_actions]]
+        ctrls = [_CONTROLLERS.get(b) for b in blobs[n_actions:]]
+        rare = _SparseActivation(sparse) if sparse else None
         out: List[ActivationMessage] = []
-        for row, (aid, u, a, c, tx, bl, args) in enumerate(zip(
-                j["ids"], j["u"], j["a"], j["c"], j["tx"], j["bl"],
-                j["args"])):
-            key = str(row)
-            fqn, rev = actions[a]
-            row_cause = cause.get(key)
+        for row in range(n):
+            end = off + tid_lens[row]
+            transid = TransactionId(str(raw[off:end], "utf-8"),
+                                    start_wallclock=walls[row])
+            off = end + body_lens[row]
+            body = raw[end:off]
+            content = {} if body == b"{}" \
+                else json.loads(body) if body else None
+            flag = flags[row]
+            aid = ActivationId(sparse["ids"][str(row)]) \
+                if flag & _ID_SPARSE \
+                else ActivationId.of_hex(ids[32 * row:32 * row + 32])
+            fqn, revision = actions[idx[n + row]]
             out.append(ActivationMessage(
-                TransactionId.from_json(tx), fqn, rev, users[u],
-                ActivationId(aid), ctrls[c], bool(bl), args,
-                init.get(key) or {},
-                ActivationId(row_cause) if row_cause else None,
-                trace.get(key),
-                fence if fence is not None else fences.get(key),
-                fpart if fpart is not None else fparts.get(key)))
+                transid, fqn, revision, users[idx[row]], aid,
+                ctrls[idx[2 * n + row]], bool(flag & _BLOCKING), content,
+                *(rare.of(row) if rare is not None else ())))
         return out
-
-
-#: ack kind -> wire code (one char per row in the kinds column)
-_ACK_CODES = {"completion": "c", "result": "r", "combined": "b"}
-_ACK_KINDS = {v: k for k, v in _ACK_CODES.items()}
 
 
 class LazyWhiskActivation:
     """A WhiskActivation that stays raw bytes until somebody reads it.
 
-    The lazy ack frame (ISSUE 14) ships each activation's response
-    payload as an opaque bytes column; the completion hot loop
-    (`process_acknowledgements`) only needs the ack COLUMNS (id, invoker,
-    system-error bit) — the response is dead weight there. This proxy
+    The ack frame ships each activation's response record as an opaque
+    body; the completion hot loop (`process_acknowledgements`) only needs
+    the ack COLUMNS (id, invoker, system-error bit) — the response is
+    dead weight there. This proxy
     carries the raw payload through the promise plumbing and parses it on
     the first attribute access, which for a blocking invoke happens on
     the API handler's own turn and for a fire-and-forget ack happens
@@ -330,156 +553,198 @@ class LazyWhiskActivation:
         return f"LazyWhiskActivation({state})"
 
 
-class AckBatchMessage(Message):
-    """N invoker->controller acks as one columnar wire record. The heavy
-    per-row payload (the WhiskActivation response) stays per-row — it IS
-    the data — but the batch pays ONE json.dumps/loads for all of them,
-    and the invoker table dedups the repeated instance id.
+class AckFrame(WireFrame):
+    """N invoker->controller acks. The heavy per-row payload, the
+    WhiskActivation record, IS the data and stays per row, as opaque
+    bytes: the decode side never parses a response the consumer does not
+    read (a blocking invoke parses it on the API handler's turn, a
+    fire-and-forget ack never)."""
 
-    `lazy_results=True` (the ISSUE 14 wire) moves the response payloads
-    OUT of the JSON record: the frame becomes a JSON header (columns +
-    a `respLen` byte-length column) followed by one raw newline and the
-    concatenated response payloads as opaque bytes. The decode side then
-    never parses a response the consumer doesn't read — the controller's
-    completion loop only touches the columns. False keeps the PR 11
-    format byte-exact."""
-
-    def __init__(self, msgs: List[AcknowledgementMessage],
-                 lazy_results: bool = False):
-        self.msgs = msgs
-        self.lazy_results = lazy_results
-
-    @property
-    def activation_ids(self) -> List[str]:
-        return [m.activation_id.asString for m in self.msgs]
-
-    def _columns(self) -> dict:
-        """The shared (response-free) ack columns: the eager record and
-        the lazy header carry their responses differently, so each
-        caller builds its own resp column. The sparse `trace` column
-        (ISSUE 18) mirrors the activation batch's: present only when
-        some ack carries a trace context, so untraced batches stay
-        byte-exact with the PR 11/14 frames — and because it lives HERE
-        it rides both the eager record and the lazy header."""
-        invs = _Dedup()
-        kinds: List[str] = []
-        tx_col: List[object] = []
-        ids: List[str] = []
-        iv_col: List[int] = []
-        err_col: List[int] = []
-        trace: Dict[str, dict] = {}
-        for row, m in enumerate(self.msgs):
-            kinds.append(_ACK_CODES.get(m.kind, "b"))
-            tx_col.append(m.transid.to_json())
-            ids.append(m.activation_id.asString)
-            iv_col.append(-1 if m.invoker is None
-                          else invs.intern(m.invoker.as_string,
-                                           m.invoker.to_json()))
-            err_col.append(1 if m.is_system_error else 0)
-            tc = getattr(m, "trace_context", None)
-            if tc is not None:
-                trace[str(row)] = tc
-        out = {"invs": invs.values, "kinds": "".join(kinds),
-               "tx": tx_col, "ids": ids, "iv": iv_col, "err": err_col}
-        if trace:
-            out["trace"] = trace
-        return out
-
-    def to_json(self) -> dict:
-        out = {"whiskBatch": KIND_ACK}
-        out.update(self._columns())
-        out["resp"] = [m.activation.to_json()
-                       if m.activation is not None else None
-                       for m in self.msgs]
-        return out
+    family = KIND_ACK
+    code = 2
 
     @staticmethod
-    def _resp_bytes(m: AcknowledgementMessage) -> bytes:
-        """One row's opaque response payload. A still-raw relay (a
+    def _body(m: AcknowledgementMessage) -> bytes:
+        """One row's opaque response record. A still-raw relay (a
         LazyWhiskActivation nobody parsed) passes its bytes through
-        untouched — re-encoding an unread payload would be the exact
-        serde cost the lazy column exists to skip."""
+        untouched: re-encoding an unread payload would be the very cost
+        the opaque column exists to skip."""
         act = m.activation
         if act is None:
             return b""
         if isinstance(act, LazyWhiskActivation) and not act.materialized:
             return act.raw
-        return json.dumps(act.to_json(), separators=(",", ":")).encode()
+        return _dumps(act.to_json()).encode()
 
     def serialize(self) -> bytes:
-        if not self.lazy_results:
-            return super().serialize()
-        bodies = [self._resp_bytes(m) for m in self.msgs]
-        header = {"whiskBatch": KIND_ACK_LAZY}
-        header.update(self._columns())
-        header["respLen"] = [len(b) for b in bodies]
-        return (json.dumps(header, separators=(",", ":")).encode()
-                + b"\n" + b"".join(bodies))
+        msgs = self.msgs
+        invokers: Dict[bytes, int] = {}
+        walls, flags, tid_lens, body_lens, iv_col = [], [], [], [], []
+        ids, heap = [], []
+        trace: Dict[str, dict] = {}
+        sparse_ids: Dict[str, str] = {}
+        for row, m in enumerate(msgs):
+            iv_col.append(
+                _NO_INDEX if m.invoker is None else invokers.setdefault(
+                    _blob_beside(m.invoker, InvokerInstanceId.to_json),
+                    len(invokers)))
+            tid = str(m.transid.id).encode()
+            body = self._body(m)
+            walls.append(m.transid.start_wallclock)
+            tid_lens.append(len(tid))
+            body_lens.append(len(body))
+            heap.append(tid)
+            heap.append(body)
+            flag = _ACK_CODES.get(m.kind, 2)
+            if m.is_system_error:
+                flag |= _ACK_SYSTEM_ERROR
+            aid = _id_bytes(m.activation_id.asString)
+            if aid is None:
+                flag |= _ID_SPARSE
+                sparse_ids[str(row)] = m.activation_id.asString
+                aid = _ZERO_ID
+            ids.append(aid)
+            flags.append(flag)
+            if m.trace_context is not None:
+                trace[str(row)] = m.trace_context
+        sparse: dict = {}
+        if trace:
+            sparse["trace"] = trace
+        if sparse_ids:
+            sparse["ids"] = sparse_ids
+        return _pack_frame(self.code, len(msgs), (len(invokers), 0, 0),
+                           list(invokers), sparse, walls, flags, tid_lens,
+                           body_lens, iv_col, ids, heap)
 
     @staticmethod
-    def parse(raw) -> List[AcknowledgementMessage]:
-        j = json.loads(raw)
-        return AckBatchMessage.from_json(j)
-
-    @staticmethod
-    def from_json(j: dict) -> List[AcknowledgementMessage]:
-        from ..core.entity import InvokerInstanceId, WhiskActivation
-        invs = [InvokerInstanceId.from_json(v) for v in j["invs"]]
-        trace = j.get("trace") or {}
+    def decode(raw: bytes, header: tuple) -> List[AcknowledgementMessage]:
+        """Decode WITHOUT touching a response byte beyond slicing: every
+        ack field comes from the columns (the system-error bit was
+        computed at encode time from the same response the serial parse
+        would re-derive it from), and each present response becomes a
+        LazyWhiskActivation over its slice. Building the base
+        AcknowledgementMessage directly, not the kind subclasses,
+        matters: ResultMessage reads activation_id off the activation
+        and CombinedCompletionAndResultMessage reads
+        response.is_whisk_error, either of which would force the parse
+        this frame exists to defer."""
+        (walls, flags, tid_lens, body_lens, iv_col, blobs, sparse, ids,
+         off) = _open_frame(raw, header, 1)
+        invokers = [_INVOKERS.get(b) for b in blobs]
+        trace = sparse.get("trace") or {}
         out: List[AcknowledgementMessage] = []
-        for row, (code, tx, aid, iv, err, resp) in enumerate(zip(
-                j["kinds"], j["tx"], j["ids"], j["iv"], j["err"],
-                j["resp"])):
-            transid = TransactionId.from_json(tx)
-            inv = invs[iv] if iv >= 0 else None
-            act = WhiskActivation.from_json(resp) if resp else None
-            kind = _ACK_KINDS.get(code, "combined")
-            if kind == "completion":
-                ack = CompletionMessage(transid, ActivationId(aid),
-                                        bool(err), inv)
-            elif kind == "result":
-                ack = ResultMessage(transid, act)
-            else:
-                ack = CombinedCompletionAndResultMessage(transid, act, inv)
-            # set post-construction: the kind ctors are frozen contracts
-            ack.trace_context = trace.get(str(row))
+        for row in range(header[3]):
+            end = off + tid_lens[row]
+            transid = TransactionId(str(raw[off:end], "utf-8"),
+                                    start_wallclock=walls[row])
+            off = end + body_lens[row]
+            flag, iv = flags[row], iv_col[row]
+            aid = ActivationId(sparse["ids"][str(row)]) \
+                if flag & _ID_SPARSE \
+                else ActivationId.of_hex(ids[32 * row:32 * row + 32])
+            ack = AcknowledgementMessage(
+                transid, aid, invokers[iv] if iv != _NO_INDEX else None,
+                bool(flag & _ACK_SYSTEM_ERROR),
+                LazyWhiskActivation(raw[end:off]) if off > end else None)
+            ack.kind = _ACK_KINDS[flag & _ACK_KIND_MASK]
+            if trace:
+                ack.trace_context = trace.get(str(row))
             out.append(ack)
         return out
 
+
+_FRAMES = {frame.family: frame for frame in (ActivationFrame, AckFrame)}
+_FRAME_OF_CODE = {frame.code: frame for frame in _FRAMES.values()}
+
+
+# -- the funnel's JSON records ----------------------------------------------
+
+class _Dedup:
+    """Insertion-ordered dedup table: intern() returns the index of the
+    (hashable) key, appending `value` on first sight."""
+
+    __slots__ = ("index", "values")
+
+    def __init__(self):
+        self.index: Dict[object, int] = {}
+        self.values: List[object] = []
+
+    def intern(self, key, value) -> int:
+        i = self.index.get(key)
+        if i is None:
+            i = len(self.values)
+            self.index[key] = i
+            self.values.append(value)
+        return i
+
+
+class ActivationBatchMessage:
+    """N ActivationMessages as one struct-of-arrays JSON record: the body
+    the funnel's `fun1` embeds (`FunnelBatchMessage`), and nothing else
+    any more: on the bus an activation batch is an `ActivationFrame`.
+
+    `users`/`actions`/`ctrls` are per-batch dedup tables (each unique
+    identity / (fqn, revision) / controller encoded ONCE); `ids`, `u`,
+    `a`, `c`, `tx`, `bl`, `args` are length-N columns; the sparse
+    columns are `_sparse_activation_columns`'."""
+
     @staticmethod
-    def from_lazy(header: dict, body: bytes) -> List[AcknowledgementMessage]:
-        """Decode the lazy frame WITHOUT touching a single response byte
-        beyond slicing: every ack field comes from the columns (the
-        `err` bit was computed at encode time from the same response the
-        eager path would re-derive it from), and each present response
-        becomes a LazyWhiskActivation over its body slice. Building the
-        base AcknowledgementMessage directly — instead of the kind
-        subclasses — matters: ResultMessage reads activation_id off the
-        activation and CombinedCompletionAndResultMessage reads
-        response.is_whisk_error, either of which would force the parse
-        this frame exists to defer."""
-        from ..core.entity import InvokerInstanceId
-        invs = [InvokerInstanceId.from_json(v) for v in header["invs"]]
-        trace = header.get("trace") or {}
-        lens = header["respLen"]
-        out: List[AcknowledgementMessage] = []
-        off = 0
-        for row, (code, tx, aid, iv, err, ln) in enumerate(zip(
-                header["kinds"], header["tx"], header["ids"], header["iv"],
-                header["err"], lens)):
-            raw = body[off:off + ln] if ln else b""
-            off += ln
-            ack = AcknowledgementMessage(
-                TransactionId.from_json(tx), ActivationId(aid),
-                invs[iv] if iv >= 0 else None, bool(err),
-                LazyWhiskActivation(raw) if raw else None)
-            ack.kind = _ACK_KINDS.get(code, "combined")
-            ack.trace_context = trace.get(str(row))
-            out.append(ack)
-        if off != len(body):
-            raise ValueError(
-                f"lazy ack frame body length {len(body)} != respLen "
-                f"sum {off}")
+    def columns(msgs: List[ActivationMessage]) -> dict:
+        users, actions, ctrls = _Dedup(), _Dedup(), _Dedup()
+        ids: List[str] = []
+        u_col: List[int] = []
+        a_col: List[int] = []
+        c_col: List[int] = []
+        tx_col: List[object] = []
+        bl_col: List[int] = []
+        args_col: List[Optional[dict]] = []
+        for m in msgs:
+            ids.append(m.activation_id.asString)
+            # identity dedup keys on the subject+namespace-uuid pair (the
+            # stable identity key); the action table keys on (fqn, rev)
+            ident = m.user
+            u_col.append(users.intern(
+                (ident.subject, ident.namespace.uuid.asString),
+                ident.to_json()))
+            a_col.append(actions.intern((str(m.action), m.revision),
+                                        [str(m.action), m.revision]))
+            c_col.append(ctrls.intern(m.root_controller_index.name,
+                                      m.root_controller_index.name))
+            tx_col.append(m.transid.to_json())
+            bl_col.append(1 if m.blocking else 0)
+            args_col.append(m.content)
+        out = {
+            "users": users.values,
+            "actions": actions.values,
+            "ctrls": ctrls.values,
+            "ids": ids,
+            "u": u_col, "a": a_col, "c": c_col,
+            "tx": tx_col, "bl": bl_col,
+            "args": args_col,
+        }
+        out.update(_sparse_activation_columns(msgs))
+        return out
+
+    @staticmethod
+    def from_json(j: dict) -> List[ActivationMessage]:
+        """Each unique identity/action/controller in the batch is parsed
+        exactly once and the rebuilt objects are SHARED across the
+        batch's messages (read-only on the consume side, like the
+        reference's case classes)."""
+        users = [Identity.from_json(u) for u in j["users"]]
+        actions = [(FullyQualifiedEntityName.parse(a), rev)
+                   for a, rev in j["actions"]]
+        ctrls = [ControllerInstanceId(c) for c in j["ctrls"]]
+        rare = _SparseActivation(j)
+        out: List[ActivationMessage] = []
+        for row, (aid, u, a, c, tx, bl, args) in enumerate(zip(
+                j["ids"], j["u"], j["a"], j["c"], j["tx"], j["bl"],
+                j["args"])):
+            fqn, rev = actions[a]
+            out.append(ActivationMessage(
+                TransactionId.from_json(tx), fqn, rev, users[u],
+                ActivationId(aid), ctrls[c], bool(bl), args,
+                *rare.of(row)))
         return out
 
 
@@ -498,8 +763,8 @@ class FunnelFrame:
 
 
 class FunnelBatchMessage(Message):
-    """ISSUE 20: one front-end admission wave as ONE wire record — the
-    `act1` struct-of-arrays columns (reused verbatim: dedup tables +
+    """ISSUE 20: one front-end admission wave as ONE wire record:
+    `ActivationBatchMessage`'s struct-of-arrays columns (dedup tables +
     packed per-row columns) plus three routing scalars:
 
       * `origin` — the front-end controller instance the per-row outcome
@@ -527,10 +792,9 @@ class FunnelBatchMessage(Message):
         return [m.activation_id.asString for m in self.msgs]
 
     def to_json(self) -> dict:
-        # reuse the act1 columns; overwriting the kind keeps `whiskBatch`
-        # in first position (dict order), so the magic-prefix sniff holds
-        out = ActivationBatchMessage(self.msgs).to_json()
-        out["whiskBatch"] = KIND_FUNNEL
+        # `whiskBatch` in first position (dict order): the prefix sniff
+        out = {"whiskBatch": KIND_FUNNEL}
+        out.update(ActivationBatchMessage.columns(self.msgs))
         out["origin"] = self.origin
         out["seq"] = self.seq
         out["epoch"] = self.epoch
@@ -635,48 +899,35 @@ class FunnelAckMessage(Message):
                               rows)
 
 
-def make_batch(family: str, msgs: list,
-               lazy_results: bool = False) -> Message:
-    """Wrap same-family messages into their batch record (the
-    `serialize_many` entry point the coalescing producer uses).
-    `lazy_results` selects the ISSUE 14 lazy ack frame for the ack
-    family; activation batches ignore it (their args ARE read by every
-    consumer)."""
-    if family == KIND_ACTIVATION:
-        return ActivationBatchMessage(msgs)
-    if family == KIND_ACK:
-        return AckBatchMessage(msgs, lazy_results=lazy_results)
-    raise ValueError(f"not a batchable family: {family!r}")
+def make_batch(family: str, msgs: list) -> WireFrame:
+    """Wrap same-family messages into their frame (the `serialize_many`
+    entry point the coalescing producer uses), from one message up."""
+    frame = _FRAMES.get(family)
+    if frame is None:
+        raise ValueError(f"not a batchable family: {family!r}")
+    return frame(msgs)
 
 
 def parse_batch(raw) -> Tuple[str, list]:
     """Decode one batch payload -> (kind, [messages]). The caller sniffs
-    with is_batch_payload first; an unknown kind raises ValueError (the
-    feed's corrupt-message posture). A lazy ack frame splits at its
-    first raw newline (plain JSON frames never contain one) and parses
-    ONLY the header — the response payloads stay opaque slices."""
+    with is_batch_payload first. A frame that is truncated, garbled or
+    of an unknown family, and a JSON record of an unknown kind, raise
+    ValueError (the feed's corrupt-message posture)."""
     if isinstance(raw, str):
         raw = raw.encode()
     raw = bytes(raw)
-    # sniff the fixed lazy prefix BEFORE scanning for the delimiter:
-    # plain frames can never contain a raw newline, so the full-payload
-    # memchr would be guaranteed-miss work on the completion hot loop's
-    # biggest byte streams (eager ack frames carrying whole responses)
-    if raw.startswith(_LAZY_PREFIX):
-        nl = raw.find(b"\n")
-        if nl < 0:
-            raise ValueError("lazy ack frame missing its body delimiter")
-        header = json.loads(raw[:nl])
-        kind = header.get("whiskBatch")
-        if kind != KIND_ACK_LAZY:
-            raise ValueError(f"framed batch with unknown kind {kind!r}")
-        return kind, AckBatchMessage.from_lazy(header, raw[nl + 1:])
+    if raw.startswith(WIRE_MAGIC):
+        try:
+            header = _HEADER.unpack_from(raw, 0)
+            frame = _FRAME_OF_CODE.get(header[2])
+            if header[1] != WIRE_VERSION or frame is None:
+                raise ValueError(f"wire frame of version {header[1]}, "
+                                 f"family {header[2]}")
+            return frame.family, frame.decode(raw, header)
+        except struct.error as e:
+            raise ValueError(f"wire frame truncated: {e}") from e
     j = json.loads(raw)
     kind = j.get("whiskBatch")
-    if kind == KIND_ACTIVATION:
-        return kind, ActivationBatchMessage.from_json(j)
-    if kind == KIND_ACK:
-        return kind, AckBatchMessage.from_json(j)
     if kind == KIND_FUNNEL:
         # the funnel frame decodes to ONE header-carrying object, not a
         # message list — only the funnel receiver consumes this kind

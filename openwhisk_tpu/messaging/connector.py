@@ -17,11 +17,11 @@ from typing import Awaitable, Callable, List, Optional, Tuple
 from ..utils.hostprof import GLOBAL_HOST_OBSERVATORY
 from ..utils.transaction import TransactionId
 from ..utils.waterfall import GLOBAL_WATERFALL, STAGE_PRODUCE
+from .columnar import batch_hop_of, make_batch, parse_batch
 
-#: serde hop labels by message class (by NAME, so this module needs no
-#: import of messaging/message.py): the controller->invoker dispatch and
-#: the invoker->controller ack are the two hot hops; pings/events are the
-#: background chatter that should NOT hide inside them
+#: serde hop labels by message class name: the controller->invoker
+#: dispatch and the invoker->controller ack are the two hot hops;
+#: pings/events are the background chatter that should NOT hide inside them
 _SERDE_HOPS = {
     "ActivationMessage": "activation",
     "CompletionMessage": "completion_ack",
@@ -67,17 +67,14 @@ def decode_message(parse, raw, hop: str):
     return msg
 
 
-def encode_batch(family: str, msgs: list,
-                 lazy_results: bool = False) -> Tuple[bytes, object]:
-    """ONE serialize for a whole same-family micro-batch (the columnar
-    batch wire, messaging/columnar.py). Returns (payload, batch_message);
-    the host observatory books the batch's bytes + wall time under the
-    SAME hop label as N serial encodes would have used — so the serde
-    counters stay comparable across the knob, and the per-hop byte totals
-    measure the dedup win directly. `lazy_results` selects the ISSUE 14
-    lazy ack frame (opaque response-bytes column) for ack batches."""
-    from .columnar import batch_hop_of, make_batch
-    batch_msg = make_batch(family, msgs, lazy_results=lazy_results)
+def encode_batch(family: str, msgs: list) -> Tuple[bytes, object]:
+    """ONE serialize for a same-family group of one message or more (the
+    struct-packed frame, messaging/columnar.py). Returns (payload,
+    frame); the host observatory books the frame's bytes + wall time
+    under the SAME hop label as N serial encodes would have used — so
+    the serde counters stay comparable across the knob, and the per-hop
+    byte totals measure the dedup win directly."""
+    batch_msg = make_batch(family, msgs)
     obs = GLOBAL_HOST_OBSERVATORY
     if not obs.serde_active:
         return batch_msg.serialize(), batch_msg
@@ -91,7 +88,6 @@ def encode_batch(family: str, msgs: list,
 def decode_batch(raw):
     """Decode one batch payload -> (kind, [messages]) with the matching
     deserialize-side accounting (one observe for the whole frame)."""
-    from .columnar import batch_hop_of, parse_batch
     obs = GLOBAL_HOST_OBSERVATORY
     if not obs.serde_active:
         return parse_batch(raw)

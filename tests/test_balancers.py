@@ -59,9 +59,9 @@ class SimInvoker:
         box = {}
 
         async def handle(payload: bytes):
-            # the batch wire ships one columnar frame per coalesced
-            # micro-batch (messaging/columnar.py); lone messages still
-            # arrive in the plain per-message format
+            # the batch wire ships one frame per topic and flush, from
+            # one message up (messaging/columnar.py); the serial wire
+            # (batchWire off, an uncoalesced producer) one JSON a message
             from openwhisk_tpu.messaging.columnar import (is_batch_payload,
                                                           parse_batch)
             if is_batch_payload(payload):
@@ -519,7 +519,9 @@ class TestHealthTestActions:
             msgs = await probe.peek(10, timeout=1.0)
             await bal.close()
             assert msgs, "no test activation published to the invoker topic"
-            parsed = ActivationMessage.parse(msgs[0][3])
+            # a lone activation is a 1-row frame (messaging/columnar.py)
+            from openwhisk_tpu.messaging.columnar import parse_batch
+            _kind, (parsed,) = parse_batch(msgs[0][3])
             return str(parsed.action), parsed.blocking
 
         action, blocking = asyncio.run(go())

@@ -1,13 +1,19 @@
 """ISSUE 12: columnar hot path — batch wire records, batch-shaped
-completion pipeline, sharded front end.
+completion pipeline, sharded front end. ISSUE 35: the records are ONE
+struct-packed frame for activations and acks, from one row up.
 
 Covers the acceptance contracts:
-  * wire parity: a batch record decodes to field-identical messages
-    (fuzzed over optional columns), and every batch payload sniffs as
-    one while plain payloads never do;
-  * encode-exactly-once: a message riding a batch frame is serialized
-    once, at flush, with the serde byte counters seeing exactly the
-    batch payload's bytes;
+  * wire parity: a frame decodes to messages equal, field for field, to
+    what the serial parsers make of the same messages' JSON (1, 2, 8 and
+    64 rows, every optional column), every batch payload sniffs as one
+    while plain payloads never do, and a frame is never taken for JSON;
+  * the frame's edges: a truncated or garbled frame raises what the
+    feeds' handlers catch and nothing of it is applied; the decoder's
+    tables are bounded and a blob that differs in one byte is parsed
+    anew; the encoder keeps a blob exactly as long as its object; the
+    gauges and the `interned` stat count what they say;
+  * encode-exactly-once: a message riding a frame is serialized once, at
+    flush, with the serde byte counters seeing exactly the frame's bytes;
   * off-switches: batchWire=false ships byte-identical serial payloads;
     batchedAck=false replays a decoded frame through the serial per-ack
     path with identical state transitions;
@@ -21,6 +27,7 @@ Covers the acceptance contracts:
 from __future__ import annotations
 
 import asyncio
+import json
 import random
 import time
 
@@ -39,16 +46,17 @@ from openwhisk_tpu.core.entity import (ActivationId, ActivationResponse,
 from openwhisk_tpu.core.entity.names import FullyQualifiedEntityName
 from openwhisk_tpu.messaging import MemoryMessagingProvider
 from openwhisk_tpu.messaging.coalesce import CoalescingProducer
-from openwhisk_tpu.messaging.columnar import (ActivationBatchMessage,
-                                              AckBatchMessage,
-                                              KIND_ACK, KIND_ACTIVATION,
+from openwhisk_tpu.messaging import columnar
+from openwhisk_tpu.messaging.columnar import (KIND_ACK, KIND_ACTIVATION,
+                                              LazyWhiskActivation,
                                               batchable_family,
                                               is_batch_payload, make_batch,
                                               parse_batch)
+from openwhisk_tpu.messaging.connector import decode_batch, encode_batch
 from openwhisk_tpu.messaging.message import (ActivationMessage,
                                              CombinedCompletionAndResultMessage,
                                              CompletionMessage, PingMessage,
-                                             ResultMessage)
+                                             ResultMessage, parse_ack)
 from openwhisk_tpu.utils.transaction import TransactionId
 from openwhisk_tpu.utils.waterfall import (ActivationWaterfall,
                                            STAGE_COMPLETION_ACK,
@@ -75,66 +83,156 @@ def _activation(ident, msg):
         ActivationResponse.success({"ok": True}), duration=1)
 
 
-def _msg_fields(m: ActivationMessage) -> dict:
-    j = m.to_json()
+def _frame(family, msgs) -> bytes:
+    payload, frame = encode_batch(family, msgs)
+    assert frame.activation_ids == [m.activation_id.asString for m in msgs]
+    return payload
+
+
+def _ack_fields(a) -> dict:
+    """An ack as the serial wire spells it; `updated` is stamped at
+    to_json() call time, so it is no field of the message."""
+    j = a.to_json()
+    if j["response"] is not None:
+        j["response"].pop("updated")
     return j
 
 
-class TestBatchWireRecords:
-    def test_activation_batch_roundtrip_fuzz(self):
-        rng = random.Random(7)
-        idents = [_ident(f"ns{k}") for k in range(3)]
-        for trial in range(20):
-            msgs = []
-            for i in range(rng.randint(1, 12)):
-                kw = {}
-                if rng.random() < 0.3:
-                    kw["cause"] = ActivationId.generate()
-                if rng.random() < 0.3:
-                    kw["trace_context"] = {"traceparent": f"00-{i}"}
-                if rng.random() < 0.3:
-                    kw["init_args"] = {"k": i}
-                if rng.random() < 0.5:
-                    kw["fence_epoch"] = rng.choice([3, 3, 7])
-                msgs.append(_act_msg(idents[rng.randrange(3)],
-                                     name=f"a{i % 4}", i=i, **kw))
-            raw = ActivationBatchMessage(msgs).serialize()
-            assert is_batch_payload(raw)
-            kind, out = parse_batch(raw)
-            assert kind == KIND_ACTIVATION
-            assert len(out) == len(msgs)
-            for a, b in zip(msgs, out):
-                assert _msg_fields(a) == _msg_fields(b)
+#: a result as large as `shrink` leaves it: the cap's own default
+RESULT_AT_THE_CAP = {"blob": "x" * (1024 * 1024 - 64)}
 
-    def test_ack_batch_roundtrip_all_kinds(self):
-        ident = _ident()
-        inv = InvokerInstanceId(0, user_memory=MB(512))
-        msgs = [_act_msg(ident, i=i) for i in range(3)]
-        acks = [
-            CompletionMessage(msgs[0].transid, msgs[0].activation_id, True,
-                              inv),
-            ResultMessage(msgs[1].transid, _activation(ident, msgs[1])),
-            CombinedCompletionAndResultMessage(
-                msgs[2].transid, _activation(ident, msgs[2]), inv),
-        ]
-        raw = AckBatchMessage(acks).serialize()
+
+def _varied_activations(rng, n):
+    """N messages over three identities and four actions that between
+    them carry every optional column, unicode and empty arguments."""
+    idents = [_ident(f"ns{k}") for k in range(3)]
+    contents = [{}, None, {"x": 1}, {"\u00fc\u00f1\u00ee": "\u6f22\u5b57 \U0001f600"},
+                {"nested": {"a": [1, 2.5, None, True]}}, {"": ""}]
+    msgs = []
+    for i in range(n):
+        kw = {}
+        if rng.random() < 0.3:
+            kw["cause"] = ActivationId.generate()
+        if rng.random() < 0.3:
+            kw["trace_context"] = {"traceparent": f"00-{i}"}
+        if rng.random() < 0.3:
+            kw["init_args"] = {"k": i, "\u00e9": "\u00e8"}
+        if rng.random() < 0.5:
+            kw["fence_epoch"] = rng.choice([3, 3, 7])
+            if rng.random() < 0.5:
+                kw["fence_part"] = rng.randrange(8)
+        m = _act_msg(idents[rng.randrange(3)], name=f"a{i % 4}", i=i, **kw)
+        m.content = contents[rng.randrange(len(contents))]
+        m.transid = TransactionId(f"tid_\u00e4_{i}",
+                                  start_wallclock=rng.random() * 2e9)
+        msgs.append(m)
+    return msgs
+
+
+def _varied_acks(rng, n):
+    """N acks of all three kinds from three invokers (and none), some
+    system errors, some traced, one result at `shrink`'s cap."""
+    ident = _ident()
+    invs = [InvokerInstanceId(k, user_memory=MB(512 * (k + 1)))
+            for k in range(3)]
+    acks = []
+    for i in range(n):
+        msg = _act_msg(ident, i=i)
+        msg.transid = TransactionId(f"tid_{i}",
+                                    start_wallclock=rng.random() * 2e9)
+        inv = invs[rng.randrange(3)]
+        kind = rng.randrange(3)
+        if kind == 0:
+            ack = CompletionMessage(msg.transid, msg.activation_id,
+                                    rng.random() < 0.5, inv)
+        else:
+            act = _activation(ident, msg)
+            if rng.random() < 0.2:
+                act.response = ActivationResponse.whisk_error("boom")
+            if i == 1:
+                act.response = ActivationResponse.success(RESULT_AT_THE_CAP)
+            ack = ResultMessage(msg.transid, act) if kind == 1 else \
+                CombinedCompletionAndResultMessage(msg.transid, act, inv)
+        if rng.random() < 0.3:
+            ack.trace_context = {"traceparent": f"00-{i}"}
+        acks.append(ack.shrink())
+    return acks
+
+
+class TestBatchWireRecords:
+    @pytest.mark.parametrize("n", [1, 2, 8, 64])
+    def test_activation_frame_equals_the_serial_parser(self, n):
+        rng = random.Random(n)
+        for _trial in range(4):
+            msgs = _varied_activations(rng, n)
+            raw = _frame(KIND_ACTIVATION, msgs)
+            assert is_batch_payload(raw)
+            kind, out = decode_batch(raw)
+            assert kind == KIND_ACTIVATION and len(out) == n
+            for m, b in zip(msgs, out):
+                a = ActivationMessage.parse(m.serialize())
+                assert a.to_json() == b.to_json() == m.to_json()
+                assert (a.user, a.action, a.revision, a.activation_id,
+                        a.blocking, a.content, a.init_args, a.cause,
+                        a.trace_context, a.fence_epoch, a.fence_part) == \
+                    (b.user, b.action, b.revision, b.activation_id,
+                     b.blocking, b.content, b.init_args, b.cause,
+                     b.trace_context, b.fence_epoch, b.fence_part)
+                assert a.root_controller_index.name == \
+                    b.root_controller_index.name
+                assert a.transid.id == b.transid.id
+                # bit for bit, not to a tolerance
+                assert a.transid.start_wallclock.hex() == \
+                    b.transid.start_wallclock.hex() == \
+                    m.transid.start_wallclock.hex()
+
+    @pytest.mark.parametrize("n", [1, 2, 8, 64])
+    def test_ack_frame_equals_the_serial_parser(self, n):
+        rng = random.Random(100 + n)
+        acks = _varied_acks(rng, n)
+        raw = _frame(KIND_ACK, acks)
         assert is_batch_payload(raw)
-        kind, out = parse_batch(raw)
-        assert kind == KIND_ACK
-        for a, b in zip(acks, out):
-            assert a.kind == b.kind
-            assert a.activation_id == b.activation_id
-            assert a.is_system_error == b.is_system_error
-            assert (a.invoker is None) == (b.invoker is None)
+        kind, out = decode_batch(raw)
+        assert kind == KIND_ACK and len(out) == n
+        for m, b in zip(acks, out):
+            a = parse_ack(m.serialize())
+            # the frame defers the response: nothing is parsed yet
+            assert b.activation is None or (
+                isinstance(b.activation, LazyWhiskActivation)
+                and not b.activation.materialized)
+            assert (a.kind, a.activation_id, a.invoker, a.is_system_error,
+                    a.is_slot_free, a.trace_context, a.transid.id) == \
+                (b.kind, b.activation_id, b.invoker, b.is_system_error,
+                 b.is_slot_free, b.trace_context, b.transid.id)
             if a.invoker is not None:
-                assert a.invoker.as_string == b.invoker.as_string
-            assert (a.activation is None) == (b.activation is None)
-            if a.activation is not None:
-                # `updated` is stamped at to_json() call time — exclude
-                ja = a.activation.to_json()
-                jb = b.activation.to_json()
-                ja.pop("updated"), jb.pop("updated")
-                assert ja == jb
+                assert a.invoker.to_json() == b.invoker.to_json()
+            assert a.transid.start_wallclock.hex() == \
+                b.transid.start_wallclock.hex()
+            assert _ack_fields(a) == _ack_fields(b) == _ack_fields(m)
+            if b.activation is not None:
+                # the record's own bytes, as the invoker encoded them
+                j = json.loads(b.activation.raw)
+                j.pop("updated")
+                assert j == _ack_fields(m)["response"]
+
+    def test_an_id_that_is_not_32_hex_rides_the_sparse_column(self):
+        """No ActivationId is such, but the wire round-trips what it is
+        given: the string travels as it is and the decoder's constructor
+        judges it, as the serial parser's does."""
+        ident = _ident()
+        odd = _act_msg(ident)
+        odd.activation_id = ActivationId.of_hex("ABCDEF" + "0" * 26)
+        fine = _act_msg(ident, i=1)
+        _kind, out = parse_batch(_frame(KIND_ACTIVATION, [odd, fine]))
+        # the constructor lowercases, exactly as ActivationMessage.parse
+        assert out[0].activation_id == \
+            ActivationMessage.parse(odd.serialize()).activation_id
+        assert out[1].activation_id == fine.activation_id
+        odd.activation_id = ActivationId.of_hex("not an id")
+        with pytest.raises(ValueError):
+            parse_batch(_frame(KIND_ACTIVATION, [odd]))
+        with pytest.raises(ValueError):
+            ActivationMessage.parse(odd.serialize())
 
     def test_plain_payloads_never_sniff_as_batch(self):
         ident = _ident()
@@ -146,6 +244,19 @@ class TestBatchWireRecords:
         assert not is_batch_payload(ack.serialize())
         assert not is_batch_payload(PingMessage(
             InvokerInstanceId(0, user_memory=MB(512))).serialize())
+        assert not is_batch_payload(msg.serialize().decode())
+        # ... and a frame is never taken for JSON: no parser of the
+        # serial wire reads past its first byte
+        for raw in (_frame(KIND_ACTIVATION, [msg]), _frame(KIND_ACK, [ack])):
+            assert raw[:1] not in b"{[ \t\r\n"
+            assert is_batch_payload(raw)
+            assert is_batch_payload(bytearray(raw))
+            with pytest.raises(ValueError):
+                json.loads(raw)
+            with pytest.raises(ValueError):
+                ActivationMessage.parse(raw)
+            with pytest.raises(ValueError):
+                parse_ack(raw)
 
     def test_batchable_family(self):
         ident = _ident()
@@ -155,15 +266,304 @@ class TestBatchWireRecords:
             ResultMessage(msg.transid, _activation(ident, msg))) == KIND_ACK
         assert batchable_family(PingMessage(
             InvokerInstanceId(0, user_memory=MB(512)))) is None
+        with pytest.raises(ValueError):
+            make_batch("ping", [msg])
 
     def test_dedup_tables_shrink_the_frame(self):
-        """The columnar record's dedup must beat N serial encodes on a
-        same-user batch — that IS the serde win being shipped."""
+        """The frame's dedup must beat N serial encodes on a same-user
+        batch — and a lone message must not pay for the framing."""
         ident = _ident()
         msgs = [_act_msg(ident, name=f"a{i % 2}", i=i) for i in range(16)]
-        batch_bytes = len(ActivationBatchMessage(msgs).serialize())
+        batch_bytes = len(_frame(KIND_ACTIVATION, msgs))
         serial_bytes = sum(len(m.serialize()) for m in msgs)
         assert batch_bytes < serial_bytes / 2
+        assert len(_frame(KIND_ACTIVATION, msgs[:1])) < \
+            len(msgs[0].serialize())
+
+
+def _mutilations(raw: bytes):
+    """(name, bytes) of frames that must not decode."""
+    header = columnar._HEADER
+    fields = list(header.unpack_from(raw, 0))
+
+    def with_header(**kw):
+        f = list(fields)
+        for name, v in kw.items():
+            f[("magic", "version", "family", "n", "t0", "t1", "t2",
+               "sparse").index(name)] = v
+        return header.pack(*f) + raw[header.size:]
+
+    yield "cut inside the header", raw[:10]
+    yield "cut inside the columns", raw[:header.size + 5]
+    yield "cut inside a table blob", raw[:header.size + 60]
+    yield "last byte gone", raw[:-1]
+    yield "last row gone", raw[:-20]
+    yield "a byte too many", raw + b"\x00"
+    yield "another version", with_header(version=2)
+    yield "an unknown family", with_header(family=9)
+    yield "more rows than it holds", with_header(n=fields[3] + 1)
+    yield "fewer rows than it holds", with_header(n=fields[3] - 1)
+    yield "a table larger than it is", with_header(t0=fields[4] + 1)
+    yield "a sparse section that is not there", with_header(sparse=7)
+    # a row pointing past its table: the index column is the columns'
+    # last; 0xFFFE is no row of any table (0xFFFF = an ack without invoker)
+    idx_end = header.size + columnar._columns_struct(
+        fields[4] + fields[5] + fields[6], fields[3],
+        3 if fields[2] == 1 else 1).size
+    yield "an index past its table", \
+        raw[:idx_end - 2] + b"\xfe\xff" + raw[idx_end:]
+    blob_at = raw.index(b'{"')   # the first table blob's JSON
+    yield "a garbled table blob", \
+        raw[:blob_at] + b"\x00\x01" + raw[blob_at + 2:]
+
+
+class TestFrameEdges:
+    @pytest.mark.parametrize("family", [KIND_ACTIVATION, KIND_ACK])
+    def test_a_mutilated_frame_raises_what_the_handlers_catch(self, family):
+        rng = random.Random(5)
+        msgs = _varied_activations(rng, 3) if family == KIND_ACTIVATION \
+            else [a for a in _varied_acks(rng, 4)
+                  if a.invoker is not None][:2]
+        raw = _frame(family, msgs)
+        parse_batch(raw)
+        seen = 0
+        for name, bad in _mutilations(raw):
+            # what `process_acknowledgement_frame` and the invoker's
+            # `_process_batch` catch and log
+            with pytest.raises((ValueError, KeyError, IndexError,
+                                TypeError)):
+                parse_batch(bad)
+                pytest.fail(f"{name}: decoded")
+            seen += 1
+        assert seen == 14
+
+    def test_a_corrupt_ack_frame_applies_none_of_its_acks(self):
+        async def go():
+            bal = _mk_balancer()
+            msgs, inv, ident = TestBatchedAckPipeline()._setup_entries(bal, 3)
+            acks = [CombinedCompletionAndResultMessage(
+                m.transid, _activation(ident, m), inv) for m in msgs]
+            raw = _frame(KIND_ACK, acks)
+            before = bal.metrics.snapshot()["counters"]
+            for _name, bad in _mutilations(raw):
+                bal.process_acknowledgement_frame(bad)
+            # an activation frame on the completion topic is refused too
+            bal.process_acknowledgement_frame(_frame(KIND_ACTIVATION, msgs))
+            assert bal.total_active_activations == 3
+            assert len(bal.activation_slots) == 3
+            assert bal.waterfall._finished == 0
+            assert bal.metrics.snapshot()["counters"] == before
+            bal.process_acknowledgement_frame(raw)
+            assert bal.total_active_activations == 0
+            await bal.close()
+
+        asyncio.run(go())
+
+    def test_a_corrupt_activation_frame_costs_the_invoker_one_unit(self):
+        """The invoker's handler logs, hands its feed the one capacity
+        unit back and runs nothing."""
+        from openwhisk_tpu.invoker.reactive import InvokerReactive
+
+        class _Feed:
+            released = 0
+
+            def processed(self):
+                self.released += 1
+
+            def consume_extra(self, n):
+                raise AssertionError("a corrupt frame booked rows")
+
+        class _Log:
+            errors = []
+
+            def error(self, _tid, text, *_a):
+                self.errors.append(text)
+
+        async def go():
+            inv = object.__new__(InvokerReactive)
+            inv.logger = _Log()
+            feed = _Feed()
+            raw = _frame(KIND_ACTIVATION,
+                         _varied_activations(random.Random(2), 2))
+            n = 0
+            for _name, bad in _mutilations(raw):
+                await inv._process(bad, feed)
+                n += 1
+            # an ack frame on an invoker's topic is no activation batch
+            await inv._process(_frame(KIND_ACK, _varied_acks(
+                random.Random(2), 2)), feed)
+            assert feed.released == n + 1
+            assert len(inv.logger.errors) == n + 1
+            assert all("corrupt activation batch" in e
+                       for e in inv.logger.errors)
+
+        asyncio.run(go())
+
+    def test_decoder_table_is_bounded_and_a_byte_is_a_new_object(self,
+                                                                  monkeypatch):
+        from openwhisk_tpu.core.entity.identity import UserLimits
+        import dataclasses
+        ident = _ident()
+        # the same subject, namespace and authkey with other limits: one
+        # digit of the blob differs
+        tighter = dataclasses.replace(
+            ident, limits=UserLimits(invocations_per_minute=60))
+        looser = dataclasses.replace(
+            ident, limits=UserLimits(invocations_per_minute=61))
+        users = columnar._USERS
+        users.objects.clear()
+        a1, b1 = parse_batch(_frame(KIND_ACTIVATION, [
+            _act_msg(tighter), _act_msg(looser, i=1)]))[1]
+        assert a1.user == tighter and b1.user == looser
+        assert a1.user.authkey == b1.user.authkey
+        assert a1.user is not b1.user and len(users.objects) == 2
+        # seen before: the very same objects, whatever frame names them
+        a2, b2, c2 = parse_batch(_frame(KIND_ACTIVATION, [
+            _act_msg(tighter), _act_msg(looser), _act_msg(tighter)]))[1]
+        assert a2.user is a1.user is c2.user and b2.user is b1.user
+        assert len(users.objects) == 2
+        # bounded: a table at its bound is reset whole, never grown, and
+        # what it held is parsed anew, equal and not the same
+        monkeypatch.setattr(columnar, "INTERN_BOUND", 4)
+        for k in range(9):
+            parse_batch(_frame(KIND_ACTIVATION, [_act_msg(_ident(f"ns{k}"))]))
+            assert len(users.objects) <= 4
+        a3 = parse_batch(_frame(KIND_ACTIVATION, [_act_msg(tighter)]))[1][0]
+        assert a3.user == a1.user and a3.user is not a1.user
+        encoder = columnar._ACTION_BLOBS
+        encoder.clear()
+        for k in range(9):
+            _frame(KIND_ACTIVATION, [_act_msg(ident, name=f"fresh{k}")])
+            assert len(encoder) <= 4
+
+    def test_encoder_keeps_a_blob_as_long_as_its_object(self):
+        import gc
+        ident = _ident()
+        kept = columnar._BLOBS_BESIDE
+        hits = columnar.WIRE_STATS["blob_hits"]
+        raw1 = _frame(KIND_ACTIVATION, [_act_msg(ident)])
+        assert id(ident) in kept
+        blob = kept[id(ident)][1]
+        assert json.loads(blob) == ident.to_json()
+        # encoded once in its life: the second frame reuses the bytes
+        hits = columnar.WIRE_STATS["blob_hits"]
+        _frame(KIND_ACTIVATION, [_act_msg(ident), _act_msg(ident, i=1)])
+        assert kept[id(ident)][1] is blob
+        # user, action and controller of both rows; the first row's
+        # action and controller may be new
+        assert 4 <= columnar.WIRE_STATS["blob_hits"] - hits <= 6
+        # an equal identity that is another object has a blob of its own
+        twin = Identity.from_json(ident.to_json())
+        assert twin == ident
+        _frame(KIND_ACTIVATION, [_act_msg(twin)])
+        assert kept[id(twin)][1] is not blob and kept[id(twin)][1] == blob
+        key, key_twin = id(ident), id(twin)
+        del ident, twin
+        gc.collect()
+        assert key not in kept and key_twin not in kept
+        assert parse_batch(raw1)[0] == KIND_ACTIVATION
+
+    def test_gauges_and_interned_count_what_they_say(self):
+        from openwhisk_tpu.messaging.coalesce import export_coalesce_gauges
+        from openwhisk_tpu.utils.logging import MetricEmitter
+
+        def gauges():
+            m = MetricEmitter()
+            export_coalesce_gauges(m)
+            return (
+                m.gauge_value("bus_wire_frames", {"family": "activation"}),
+                m.gauge_value("bus_wire_rows", {"family": "activation"}),
+                m.gauge_value("bus_wire_frames",
+                              {"family": "completion_ack"}),
+                m.gauge_value("bus_wire_rows", {"family": "completion_ack"}),
+                m.gauge_value("bus_wire_intern_lookups"),
+                m.gauge_value("bus_wire_intern_hits"))
+
+        ident = _ident("gauged")
+        inv = InvokerInstanceId(7, user_memory=MB(512))
+        msgs = [_act_msg(ident, name="g0", i=i) for i in range(3)]
+        acks = [CombinedCompletionAndResultMessage(
+            m.transid, _activation(ident, m), inv) for m in msgs[:2]]
+
+        async def go():
+            spy = _SpyProducer()
+            prod = CoalescingProducer(spy, max_batch=64, batch_wire=True)
+            await asyncio.gather(
+                *[prod.send("invoker7", m) for m in msgs],
+                prod.send("invoker8", _act_msg(ident, name="g1")),
+                *[prod.send("completed0", a) for a in acks])
+            await prod.flush()
+            return [it for batch in spy.shipped for it in batch]
+
+        g0 = gauges()
+        items = asyncio.run(go())
+        g1 = gauges()
+        # three frames: 3 rows and 1 row of activations, 2 rows of acks;
+        # encoding looks nothing up
+        assert [b - a for a, b in zip(g0, g1)] == [2, 4, 1, 2, 0, 0]
+        for _topic, payload, _m in items:
+            parse_batch(payload)
+        g2 = gauges()
+        # one user + one action + one controller for each activation
+        # frame, one invoker for the ack frame; the identity, the
+        # controller and (second frame) nothing else were seen before
+        assert g2[4] - g1[4] == 3 + 3 + 1
+        first = g2[5] - g1[5]
+        for _topic, payload, _m in items:
+            parse_batch(payload)
+        g3 = gauges()
+        assert g3[4] - g2[4] == 7 and g3[5] - g2[5] == 7   # all seen
+        assert 0 <= first <= 7 - 4   # a new user, 2 actions, an invoker
+
+    def test_ack_decode_span_carries_interned(self, monkeypatch):
+        """`ow_ack_decode` reads the hits of ITS frame: 0 for an invoker
+        never seen, 1 from then on; `ow_produce` the blobs reused."""
+        from openwhisk_tpu.controller.loadbalancer import base
+        from openwhisk_tpu.messaging import coalesce
+        spans = []
+
+        class _Span:
+            def __init__(self, name, **stats):
+                self.name, self.stats = name, stats
+                spans.append(self)
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *a):
+                return False
+
+            def set_metadata(self, **kw):
+                self.stats.update(kw)
+
+        monkeypatch.setattr(base, "span", _Span)
+        monkeypatch.setattr(coalesce, "span", _Span)
+
+        async def go():
+            bal = _mk_balancer()
+            msgs, _inv, ident = TestBatchedAckPipeline()._setup_entries(
+                bal, 3)
+            inv = InvokerInstanceId(4242, user_memory=MB(512))
+            spy = _SpyProducer()
+            prod = CoalescingProducer(spy, max_batch=64, batch_wire=True)
+            for m in msgs:
+                await prod.send("completed0",
+                                CombinedCompletionAndResultMessage(
+                                    m.transid, _activation(ident, m), inv))
+                await prod.flush()
+            for batch in spy.shipped:
+                for _t, payload, _m in batch:
+                    bal.process_acknowledgement_frame(payload)
+            await bal.close()
+
+        asyncio.run(go())
+        decodes = [s.stats for s in spans if s.name == "ow_ack_decode"]
+        assert [(d["acks"], d["interned"]) for d in decodes] == \
+            [(1, 0), (1, 1), (1, 1)]
+        assert all(d["bytes"] > 0 and "free" in d for d in decodes)
+        produces = [s.stats for s in spans if s.name == "ow_produce"]
+        assert [(p["n"], p["interned"]) for p in produces] == \
+            [(1, 0), (1, 1), (1, 1)]
 
 
 class _SpyProducer:
@@ -223,12 +623,48 @@ class TestCoalescerBatchWire:
         for (topic, payload, m), orig in zip(items, msgs):
             assert payload == orig.serialize()
 
-    def test_lone_message_stays_plain_format(self):
+    def test_lone_message_is_a_one_row_frame(self):
+        """No per-message JSON form behind the coalescing producer: a
+        lone activation (and a lone ack) is its family's 1-row frame."""
         ident = _ident()
-        shipped = self._drive(True, [_act_msg(ident)])
-        items = [it for batch in shipped for it in batch]
-        assert len(items) == 1
-        assert not is_batch_payload(items[0][1])
+        msg = _act_msg(ident)
+        ack = CompletionMessage(msg.transid, msg.activation_id, False,
+                                InvokerInstanceId(0, user_memory=MB(512)))
+        for lone, kind in ((msg, KIND_ACTIVATION), (ack, KIND_ACK)):
+            shipped = self._drive(True, [lone])
+            items = [it for batch in shipped for it in batch]
+            assert len(items) == 1
+            _topic, payload, frame = items[0]
+            assert is_batch_payload(payload)
+            got_kind, out = parse_batch(payload)
+            assert got_kind == kind and len(out) == 1
+            assert out[0].activation_id == lone.activation_id
+            assert frame.activation_ids == [lone.activation_id.asString]
+
+    def test_one_unserializable_message_fails_alone(self):
+        """Deferring the encode to the flush must not widen one bad
+        message's blast radius: its frame-mates ship, it alone fails."""
+        ident = _ident()
+        good = [_act_msg(ident, i=i) for i in range(3)]
+        bad = _act_msg(ident, i=9)
+        bad.content = {"unserializable": object()}
+
+        async def go():
+            spy = _SpyProducer()
+            prod = CoalescingProducer(spy, max_batch=64, batch_wire=True)
+            results = await asyncio.gather(
+                prod.send("invoker0", good[0]), prod.send("invoker0", bad),
+                prod.send("invoker0", good[1]), prod.send("invoker1", bad),
+                prod.send("invoker0", good[2]), return_exceptions=True)
+            await prod.flush()
+            return results, [it for b in spy.shipped for it in b]
+
+        results, items = asyncio.run(go())
+        assert [type(r) for r in results] == \
+            [type(None), TypeError, type(None), TypeError, type(None)]
+        shipped = [m.activation_id for _t, payload, _m in items
+                   for m in parse_batch(payload)[1]]
+        assert shipped == [m.activation_id for m in good]
 
     def test_unbatchable_messages_pass_through(self):
         inv = InvokerInstanceId(0, user_memory=MB(512))
@@ -321,7 +757,7 @@ class TestBatchedAckPipeline:
             msgs, inv, ident = self._setup_entries(bal, 5)
             acks = [CombinedCompletionAndResultMessage(
                 m.transid, _activation(ident, m), inv) for m in msgs]
-            raw = AckBatchMessage(acks).serialize()
+            raw = _frame(KIND_ACK, acks)
             bal.process_acknowledgement_frame(raw)
             assert bal.total_active_activations == 0
             assert not bal.activation_slots
@@ -345,7 +781,7 @@ class TestBatchedAckPipeline:
                 acks = [CombinedCompletionAndResultMessage(
                     m.transid, _activation(ident, m), inv) for m in msgs]
                 bal.process_acknowledgement_frame(
-                    AckBatchMessage(acks).serialize())
+                    _frame(KIND_ACK, acks))
                 out[flag] = (bal.total_active_activations,
                              len(bal.activation_slots),
                              bal.waterfall._finished,
@@ -371,7 +807,7 @@ class TestBatchedAckPipeline:
             acks = [CombinedCompletionAndResultMessage(
                 m.transid, _activation(ident, m), inv) for m in mixed]
             bal.process_acknowledgement_frame(
-                AckBatchMessage(acks).serialize())
+                _frame(KIND_ACK, acks))
             assert bal.total_active_activations == 0
             assert bal.waterfall._finished == 6
             assert bal.waterfall.active == 0
@@ -395,7 +831,7 @@ class TestBatchedAckPipeline:
             acks = [CombinedCompletionAndResultMessage(
                 m.transid, _activation(ident, m), inv) for m in msgs]
             bal.process_acknowledgement_frame(
-                AckBatchMessage(acks).serialize())
+                _frame(KIND_ACK, acks))
             assert bal.total_active_activations == 0
             assert not bal.activation_slots
             assert bal.metrics.counter_value(
@@ -477,7 +913,7 @@ class TestInvokerBatchPickup:
             results = await asyncio.gather(*[
                 asyncio.wait_for(p, 10) for p in promises])
             from openwhisk_tpu.messaging.coalesce import _STATS
-            wire_batches = _STATS["wire_batches"]
+            wire_batches = sum(_STATS["wire_frames"].values())
             await stop()
             await bal.close()
             for f in feeds:

@@ -388,7 +388,8 @@ class TestSpilloverContinuity:
                                                ControllerInstanceId,
                                                FullyQualifiedEntityName,
                                                Identity)
-        from openwhisk_tpu.messaging.columnar import (ActivationBatchMessage,
+        from openwhisk_tpu.messaging.columnar import (KIND_ACTIVATION,
+                                                      make_batch,
                                                       parse_batch)
         from openwhisk_tpu.messaging.message import ActivationMessage
         from openwhisk_tpu.utils.transaction import TransactionId
@@ -404,7 +405,7 @@ class TestSpilloverContinuity:
             "1-a", Identity.generate("guest"), ActivationId.generate(),
             ControllerInstanceId("0"), True, {})
         _, out = parse_batch(
-            ActivationBatchMessage([msg, plain]).serialize())
+            make_batch(KIND_ACTIVATION, [msg, plain]).serialize())
         assert out[0].trace_context == tc
         assert out[1].trace_context is None
 

@@ -1,7 +1,7 @@
 """ISSUE 20: front-end -> balancer admission funnel, tier-1 half.
 
 Covers the acceptance contracts:
-  * wire roundtrips for the `fun1` admission frame (act1 columns +
+  * wire roundtrips for the `fun1` admission frame (activation columns +
     origin/seq/epoch header) and the `funA` per-row outcome frame;
   * partial-dedupe replay over the REAL TCP bus: a retried frame places
     only rows whose first delivery was lost — zero double executions;

@@ -6,15 +6,17 @@ Covers the acceptance contracts:
     stamps, the serial path's exact exception texts (standby /
     no-invoker / device-throttle 429), and per-row capacity return on
     cancellation/abandonment;
-  * off-switch: lazy_results=False keeps the PR 11 ack batch record
+  * off-switch: batch_wire=False ships every ack as its serial JSON,
     byte-exact;
   * the one-shared-clock arrival fix: _note_arrivals(now, 1) is
     bit-exact with _note_arrival(now);
-  * lazy ack result column: framed-wire roundtrip for every ack kind,
-    the consumer-never-reads case asserted via the host observatory's
+  * lazy ack result column (ISSUE 35: the ack frame's opaque body, for
+    a lone ack too): framed-wire roundtrip for every ack kind, the
+    consumer-never-reads case asserted via the host observatory's
     `openwhisk_host_serde_*` counters (zero `ack_result` deserializes
-    until a consumer touches the result), and the coalescing producer
-    shipping the lazy frame end-to-end.
+    until a consumer touches the result), the coalescing producer
+    shipping the frame end-to-end, and a 1-row ack frame leaving the
+    balancer exactly as the serial pass leaves it.
 """
 from __future__ import annotations
 
@@ -41,12 +43,12 @@ from openwhisk_tpu.core.entity.ids import DocRevision
 from openwhisk_tpu.messaging import (ActivationMessage,
                                      MemoryMessagingProvider, PingMessage)
 from openwhisk_tpu.messaging.coalesce import CoalescingProducer
-from openwhisk_tpu.messaging.columnar import (AckBatchMessage, KIND_ACK,
-                                              KIND_ACK_LAZY,
-                                              LazyWhiskActivation,
-                                              is_batch_payload, parse_batch)
+from openwhisk_tpu.messaging.columnar import (KIND_ACK, LazyWhiskActivation,
+                                              is_batch_payload, make_batch,
+                                              parse_batch)
 from openwhisk_tpu.messaging.message import (
-    CombinedCompletionAndResultMessage, CompletionMessage, ResultMessage)
+    CombinedCompletionAndResultMessage, CompletionMessage, ResultMessage,
+    parse_ack)
 from openwhisk_tpu.utils.hostprof import GLOBAL_HOST_OBSERVATORY
 from openwhisk_tpu.utils.ring_buffer import ColumnRing
 from openwhisk_tpu.utils.transaction import TransactionId
@@ -455,14 +457,13 @@ class TestLazyAckResults:
         return acks
 
     def test_lazy_frame_roundtrip_all_kinds(self):
+        """The frame's acks against the serial parser's eager ones."""
         acks = self._acks()
-        plain = AckBatchMessage(acks).serialize()
-        lazy = AckBatchMessage(acks, lazy_results=True).serialize()
-        assert is_batch_payload(plain) and is_batch_payload(lazy)
-        assert b"\n" not in plain and b"\n" in lazy
-        k1, out1 = parse_batch(plain)
+        lazy = make_batch(KIND_ACK, acks).serialize()
+        assert is_batch_payload(lazy)
+        out1 = [parse_ack(a.serialize()) for a in acks]
         k2, out2 = parse_batch(lazy)
-        assert (k1, k2) == (KIND_ACK, KIND_ACK_LAZY)
+        assert k2 == KIND_ACK
         for a, b in zip(out1, out2):
             assert a.kind == b.kind
             assert a.activation_id.asString == b.activation_id.asString
@@ -486,9 +487,9 @@ class TestLazyAckResults:
         """Re-encoding an unread lazy ack reuses the raw payload — no
         parse, no re-serialize."""
         acks = self._acks(2)
-        lazy = AckBatchMessage(acks, lazy_results=True).serialize()
+        lazy = make_batch(KIND_ACK, acks).serialize()
         _k, out = parse_batch(lazy)
-        relay = AckBatchMessage(out, lazy_results=True).serialize()
+        relay = make_batch(KIND_ACK, out).serialize()
         _k2, out2 = parse_batch(relay)
         for a, b in zip(out, out2):
             assert not (a.activation is not None
@@ -497,18 +498,41 @@ class TestLazyAckResults:
                 assert b.activation.raw == a.activation.raw
 
     def test_off_switch_byte_exact(self):
-        """lazy_results=False serializes exactly the PR 11 record."""
+        """batch_wire=False ships every ack as the serial wire's own
+        JSON, one payload a message, byte for byte."""
         acks = self._acks(2)
-        msg = AckBatchMessage(acks)
-        assert not msg.lazy_results
-        assert msg.serialize() == json.dumps(
-            msg.to_json(), separators=(",", ":")).encode()
+
+        async def go():
+            provider = MemoryMessagingProvider()
+            provider.ensure_topic("completed0")
+            consumer = provider.get_consumer("completed0", "g0")
+            prod = CoalescingProducer(provider.get_producer(),
+                                      batch_wire=False)
+            await prod.send_batch("completed0", acks)
+            await prod.flush()
+            got = await consumer.peek(8, timeout=1.0)
+            await prod.close()
+            return [bytes(g[3]) for g in got]
+
+        payloads = asyncio.run(go())
+        assert len(payloads) == len(acks)
+        for payload, ack in zip(payloads, acks):
+            assert not is_batch_payload(payload)
+            assert parse_ack(payload).kind == ack.kind
+            if ack.activation is None:
+                assert payload == ack.serialize()
+                continue
+            # a record's `updated` is stamped anew by every to_json()
+            cut = payload.index(b'"updated"')
+            assert payload[:cut] == ack.serialize()[:cut]
+            tail = payload.index(b",", cut)
+            assert payload[tail:] == ack.serialize()[tail:]
 
     def test_corrupt_lazy_body_rejected(self):
         acks = self._acks(2)
-        lazy = AckBatchMessage(acks, lazy_results=True).serialize()
+        lazy = make_batch(KIND_ACK, acks).serialize()
         with pytest.raises(ValueError):
-            parse_batch(lazy[:-3])  # truncated body != respLen sum
+            parse_batch(lazy[:-3])  # truncated body != the lengths' sum
 
     def test_corrupt_body_behind_consistent_frame(self):
         """A garbled response payload behind a CONSISTENT frame (header
@@ -517,9 +541,9 @@ class TestLazyAckResults:
         'corrupt lazy ack result' ValueError, not a JSONDecodeError
         escaping deep inside response rendering."""
         acks = self._acks(2)
-        lazy = AckBatchMessage(acks, lazy_results=True).serialize()
-        header, _, body = lazy.partition(b"\n")
-        garbled = header + b"\n" + b"\x00" * len(body)
+        lazy = make_batch(KIND_ACK, acks).serialize()
+        body_at = lazy.index(b'{"namespace"')   # the first response record
+        garbled = lazy[:body_at] + b"\x00" * (len(lazy) - body_at)
         _k, out = parse_batch(garbled)  # frame-level decode succeeds
         bad = next(a.activation for a in out if a.activation is not None)
         assert isinstance(bad, LazyWhiskActivation)
@@ -527,11 +551,12 @@ class TestLazyAckResults:
         with pytest.raises(ValueError, match="corrupt lazy ack result"):
             _ = bad.response
 
-    def test_consumer_never_reads_skips_parse(self):
-        """The acceptance counter check: a lazy ack frame processed by
-        the balancer's completion path books ZERO `ack_result`
-        deserializes until a consumer touches the result — then exactly
-        the touched rows parse."""
+    @pytest.mark.parametrize("n", [1, 4])
+    def test_consumer_never_reads_skips_parse(self, n):
+        """The acceptance counter check: an ack frame, of one ack or of
+        several, processed by the balancer's completion path books ZERO
+        `ack_result` deserializes until a consumer touches the result —
+        then exactly the touched rows parse."""
         async def go():
             ident = Identity.generate("guest")
             action = make_action("z", memory=128)
@@ -543,21 +568,12 @@ class TestLazyAckResults:
                 GLOBAL_HOST_OBSERVATORY.reset()
                 inv = InvokerInstanceId(0, user_memory=MB(4096))
                 msgs, promises = [], []
-                for i in range(4):
+                for i in range(n):
                     msg = make_msg(action, ident, blocking=True)
                     msgs.append(msg)
                     promises.append(bal.setup_activation(msg, action, inv))
-                now = time.time()
-                acks = [CombinedCompletionAndResultMessage(
-                    m.transid,
-                    WhiskActivation(EntityPath("guest"), EntityName("z"),
-                                    ident.subject, m.activation_id, now,
-                                    now,
-                                    ActivationResponse.success({"ok": 1}),
-                                    duration=1),
-                    inv) for m in msgs]
-                payload = AckBatchMessage(
-                    acks, lazy_results=True).serialize()
+                payload = make_batch(
+                    KIND_ACK, _combined_acks(msgs, ident, inv)).serialize()
                 bal.process_acknowledgement_frame(payload)
 
                 def ack_result_count():
@@ -581,31 +597,109 @@ class TestLazyAckResults:
 
         asyncio.run(go())
 
-    def test_coalescing_producer_ships_lazy_frames(self):
-        """End to end through the CoalescingProducer: two acks to one
-        topic flush as ONE lazy frame; lazy_results=False ships the
-        plain columnar record."""
+    @pytest.mark.parametrize("n", [1, 2])
+    def test_coalescing_producer_ships_lazy_frames(self, n):
+        """End to end through the CoalescingProducer: the acks of one
+        topic and flush, a lone one too, are ONE frame."""
         async def go():
-            for lazy in (True, False):
-                provider = MemoryMessagingProvider()
-                provider.ensure_topic("completed0")
-                consumer = provider.get_consumer("completed0", "g0")
-                prod = CoalescingProducer(provider.get_producer(),
-                                          batch_wire=True,
-                                          lazy_results=lazy)
-                await prod.send_batch("completed0", self._acks(2)[:2])
-                await prod.flush()
-                got = await consumer.peek(8, timeout=1.0)
-                assert len(got) == 1
-                payload = got[0][3]
-                assert is_batch_payload(payload)
-                assert (b"\n" in bytes(payload)) == lazy
-                kind, out = parse_batch(payload)
-                assert kind == (KIND_ACK_LAZY if lazy else KIND_ACK)
-                assert len(out) == 2
-                await prod.close()
+            provider = MemoryMessagingProvider()
+            provider.ensure_topic("completed0")
+            consumer = provider.get_consumer("completed0", "g0")
+            prod = CoalescingProducer(provider.get_producer(),
+                                      batch_wire=True)
+            await prod.send_batch("completed0", self._acks(2)[:n])
+            await prod.flush()
+            got = await consumer.peek(8, timeout=1.0)
+            assert len(got) == 1
+            payload = got[0][3]
+            assert is_batch_payload(payload)
+            kind, out = parse_batch(payload)
+            assert kind == KIND_ACK
+            assert len(out) == n
+            assert all(isinstance(a.activation, LazyWhiskActivation)
+                       and not a.activation.materialized for a in out)
+            await prod.close()
 
         asyncio.run(go())
+
+    def test_one_row_frame_leaves_what_the_serial_pass_leaves(self):
+        """A lone ack used to take `process_acknowledgement` (serial
+        JSON, the per-ack pass); it now arrives as a 1-row frame and
+        takes the batched pass. Counters, gauges, waterfall rows, the
+        queued releases and the promises' results must not tell the two
+        apart, for a tracked ack, a late one and a health probe's."""
+        async def run(wire):
+            ident = Identity.generate("guest")
+            action = make_action("z", memory=128)
+            bal = await _healthy_balancer(MemoryMessagingProvider(),
+                                          n_invokers=2)
+            bal.waterfall = ActivationWaterfall(WaterfallConfig())
+            try:
+                inv = InvokerInstanceId(1, user_memory=MB(4096))
+                msgs, promises = [], []
+                for i in range(3):
+                    msg = make_msg(action, ident, blocking=True)
+                    msg.activation_id = ActivationId(f"{i + 1:032x}")
+                    msg.transid = TransactionId(f"tid_{i}",
+                                                start_wallclock=1.0 + i)
+                    bal.waterfall.begin(msg.activation_id.asString,
+                                        t0_ns=1)
+                    bal.waterfall.stamp(msg.activation_id.asString,
+                                        STAGE_PUBLISH_ENQUEUE, 1000)
+                    msgs.append(msg)
+                    promises.append(bal.setup_activation(msg, action, inv))
+                late = make_msg(action, ident)
+                late.activation_id = ActivationId(f"{99:032x}")
+                probe = make_msg(action, ident)
+                probe.activation_id = ActivationId(f"{98:032x}")
+                bal._health_probe_ids.add(probe.activation_id.asString)
+                acks = _combined_acks(msgs + [late, probe], ident, inv)
+                acks[1].is_system_error = True
+                before = bal.metrics.snapshot()
+                for ack in acks:
+                    if wire == "frame":
+                        bal.process_acknowledgement_frame(
+                            make_batch(KIND_ACK, [ack]).serialize())
+                    else:
+                        bal.process_acknowledgement(ack.serialize())
+                after = bal.metrics.snapshot()
+                # which rows folded, in which order, with which stages
+                # stamped; the durations are the clock's own readings
+                rows = [(r["activation_id"], r["trace_id"], r["clamped"],
+                         sorted(r["stages_ms"]))
+                        for r in bal.waterfall.recent(8)]
+                return {
+                    "counters": {k: v - before["counters"].get(k, 0)
+                                 for k, v in after["counters"].items()},
+                    "gauges": {k: v for k, v in after["gauges"].items()
+                               if not k[0].startswith(("host_", "bus_"))},
+                    "releases": list(bal._releases),
+                    "active": (bal.total_active_activations,
+                               sorted(bal.activation_slots)),
+                    "waterfall": (bal.waterfall._finished,
+                                  bal.waterfall.active, rows),
+                    "results": [p.result().to_json()["response"]
+                                for p in promises],
+                    "probes": sorted(bal._health_probe_ids),
+                }
+            finally:
+                await bal.close()
+
+        serial = asyncio.run(run("serial"))
+        frame = asyncio.run(run("frame"))
+        assert frame == serial
+        assert len(serial["releases"]) == 3
+        assert serial["waterfall"][0] == 3 and serial["active"][0] == 0
+
+
+def _combined_acks(msgs, ident, inv):
+    now = time.time()
+    return [CombinedCompletionAndResultMessage(
+        m.transid,
+        WhiskActivation(EntityPath("guest"), EntityName("z"), ident.subject,
+                        m.activation_id, now, now,
+                        ActivationResponse.success({"ok": 1}), duration=1),
+        inv) for m in msgs]
 
 
 class TestColumnRingPushBlock:

@@ -550,36 +550,40 @@ class TestAckTraceContext:
         assert parse_ack(bare.serialize()).trace_context is None
 
     def test_eager_batch_sparse_column_roundtrip(self):
-        import json
-        from openwhisk_tpu.messaging.columnar import (AckBatchMessage,
+        from openwhisk_tpu.messaging.columnar import (KIND_ACK, make_batch,
                                                       parse_batch)
         combined, completion = self._fixtures()
         tc = {"traceparent": "00-" + "11" * 16 + "-" + "22" * 8 + "-01"}
         acks = [completion(None), combined(tc), completion(None)]
-        raw = AckBatchMessage(acks).serialize()
+        raw = make_batch(KIND_ACK, acks).serialize()
         _kind, out = parse_batch(raw)
         assert [m.trace_context for m in out] == [None, tc, None]
-        # untraced batches never grow the column: byte-exact absent
-        untraced = AckBatchMessage([completion(None), combined(None)])
-        assert "trace" not in json.loads(untraced.serialize())
+        # untraced frames never grow the column: no sparse section at all
+        untraced = make_batch(
+            KIND_ACK, [completion(None), combined(None)]).serialize()
+        assert b"trace" not in untraced
+        assert [m.trace_context for m in parse_batch(untraced)[1]] == \
+            [None, None]
 
     def test_lazy_batch_header_carries_the_column(self):
+        """A lone traced ack is a 1-row frame: its context rides the
+        sparse section, its response stays unparsed."""
         import json
-        from openwhisk_tpu.messaging.columnar import (AckBatchMessage,
+        from openwhisk_tpu.messaging.columnar import (KIND_ACK, make_batch,
                                                       parse_batch)
         combined, completion = self._fixtures()
         tc = {"traceparent": "00-" + "33" * 16 + "-" + "44" * 8 + "-01"}
-        acks = [combined(tc), completion(None)]
-        raw = AckBatchMessage(acks, lazy_results=True).serialize()
+        raw = make_batch(KIND_ACK, [combined(tc)]).serialize()
         _kind, out = parse_batch(raw)
-        assert [m.trace_context for m in out] == [tc, None]
+        assert [m.trace_context for m in out] == [tc]
+        assert not out[0].activation.materialized
         # the traced ack's response survives the lazy wire untouched
         assert out[0].activation.response.result == {"ok": True}
-        header = json.loads(raw.split(b"\n", 1)[0])
-        assert header["trace"] == {"0": tc}
-        untraced = AckBatchMessage([completion(None)],
-                                   lazy_results=True).serialize()
-        assert "trace" not in json.loads(untraced.split(b"\n", 1)[0])
+        sparse = json.dumps({"trace": {"0": tc}},
+                            separators=(",", ":")).encode()
+        assert sparse in raw
+        untraced = make_batch(KIND_ACK, [completion(None)]).serialize()
+        assert b"trace" not in untraced
 
 
 # -- satellite: ring-shaped span buffer (regression companion) ---------------
