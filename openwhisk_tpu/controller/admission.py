@@ -139,6 +139,10 @@ class AdmissionPlane:
         self.batches = 0
         self.checked = 0
 
+    def close(self) -> None:
+        """End the coalescer's parked drainer (call on its loop)."""
+        self._co.close()
+
     async def check_throttles(self, identity, is_trigger_fire: bool) -> None:
         """The batched stand-in for `_check_throttles`: returns on admit,
         raises the serial path's exact `ThrottleRejectRequest` on reject."""

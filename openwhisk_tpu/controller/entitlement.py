@@ -229,9 +229,12 @@ class LocalEntitlementProvider:
                 raise ThrottleRejectRequest(CONCURRENT_LIMIT_MESSAGE)
 
     async def close(self) -> None:
-        """Stop the sharded front end's worker loops (no-op at shards=1).
-        The thread joins run on the executor — a slow shard must not
-        stall the controller loop mid-shutdown."""
+        """End the admission plane's parked drainer and stop the sharded
+        front end's worker loops (no-op at shards=1). The thread joins run
+        on the executor — a slow shard must not stall the controller loop
+        mid-shutdown."""
+        if self.admission is not None:
+            self.admission.close()
         if self.frontend is not None:
             import asyncio
             await asyncio.get_event_loop().run_in_executor(

@@ -101,7 +101,13 @@ class _Shard:
 
     def signal_stop(self) -> None:
         if self.loop.is_running():
-            self.loop.call_soon_threadsafe(self.loop.stop)
+            self.loop.call_soon_threadsafe(self._stop)
+
+    def _stop(self) -> None:
+        # on the shard's loop: the parked drainer takes its last step in
+        # the sweep after this one, then the loop stops
+        self.admission.close()
+        self.loop.call_soon(self.loop.stop)
 
     def join(self, timeout: float = 5.0) -> None:
         self._thread.join(timeout=timeout)

@@ -2217,6 +2217,16 @@ class TpuBalancer(CommonLoadBalancer):
     #: (Read at the step's cost of that day: PR 31 took a launch and a
     #: transfer off every step and did not read K again, PERF.md section 7.)
     DISPATCH_HOLD_K = 3
+    #: the loop's selector sleeps in whole milliseconds (epoll_wait; CPython
+    #: rounds a timeout UP to the next one), and a loop woken from its sleep
+    #: starts cold: on an idle loop the hold's timer fired up to a
+    #: millisecond late and the estimate below read that lateness as the
+    #: step's cost, K + 1 times over (PERF.md section 6, PR 38: until then
+    #: the bus's lingering drainers kept the loop turning by accident). So
+    #: the flush task sleeps to within one such tick of the hold's end and
+    #: yields sweep by sweep through the rest: the hold ends on time
+    #: whether the loop is idle or not, at under a tick of turning a step.
+    SELECTOR_TICK_S = 1e-3
     #: step costs kept; the estimate is their second smallest, so it
     #: stands while six of eight samples are stalls (set-up's ladder
     #: compiles six bucket shapes in a row; a collection pauses one step
@@ -2290,8 +2300,10 @@ class TpuBalancer(CommonLoadBalancer):
         # be a no-op (this task is not done() yet) and strand leftover work
         while True:
             due = time.monotonic() + delay
-            if delay:
-                await asyncio.sleep(delay)
+            if delay > self.SELECTOR_TICK_S:
+                await asyncio.sleep(delay - self.SELECTOR_TICK_S)
+            while time.monotonic() < due:
+                await asyncio.sleep(0)
             async with self._step_lock:
                 await self._device_step(delay, due)
             if not (self._pending or self._releases or self._health_updates):

@@ -51,13 +51,16 @@ from ..utils.waterfall import span
 from .columnar import (KIND_ACK, KIND_ACTIVATION, WIRE_STATS, batch_hop_of,
                        batchable_family, intern_hits)
 from .connector import MessageProducer, encode_batch, encode_message
+from .memory import BUS_STATS
 
 #: process-wide coalescing health counters, exported as gauges by the
 #: balancers' supervision tick (export_coalesce_gauges) — one aggregate
 #: across producers, like the tracing gauges. `wire_frames` / `wire_rows`
 #: count, by family, the frames this process encoded and the messages
-#: inside them (rows / frames = how many messages share a frame's tables)
-_STATS = {"batches": 0, "messages": 0, "max_batch": 0,
+#: inside them (rows / frames = how many messages share a frame's tables);
+#: `parked_flushes` the flushes whose wave found its drainer parked, not
+#: running (over `batches`: how sparse this process's producers are)
+_STATS = {"batches": 0, "messages": 0, "max_batch": 0, "parked_flushes": 0,
           "wire_frames": {KIND_ACTIVATION: 0, KIND_ACK: 0},
           "wire_rows": {KIND_ACTIVATION: 0, KIND_ACK: 0}}
 
@@ -168,11 +171,13 @@ class CoalescingProducer(MessageProducer):
         _STATS["batches"] += 1
         _STATS["messages"] += len(batch)
         _STATS["max_batch"] = max(_STATS["max_batch"], len(batch))
+        parked = int(self._co.parked_flush)
+        _STATS["parked_flushes"] += parked
         if not self.batch_wire:
             await self.inner.send_many([item for (item, _fut) in batch])
             return
         # the span covers the encode, not the awaited send
-        with span("ow_produce", n=len(batch)) as sp:
+        with span("ow_produce", n=len(batch), parked=parked) as sp:
             reused = WIRE_STATS["blob_hits"]
             out = self._encode_flush(batch)
             sp.set_metadata(bytes=sum(len(p) for _t, p, _m in out),
@@ -253,6 +258,7 @@ class CoalescingProducer(MessageProducer):
 
     async def close(self) -> None:
         await self.flush()
+        self._co.close()
         await self.inner.close()
 
 
@@ -278,6 +284,9 @@ def export_coalesce_gauges(metrics) -> None:
     metrics.gauge("bus_coalesce_batches", _STATS["batches"])
     metrics.gauge("bus_coalesce_messages", _STATS["messages"])
     metrics.gauge("bus_coalesce_batch_max", _STATS["max_batch"])
+    metrics.gauge("bus_coalesce_parked_flushes", _STATS["parked_flushes"])
+    metrics.gauge("bus_consumer_parks", BUS_STATS["parks"])
+    metrics.gauge("bus_consumer_poll_timeouts", BUS_STATS["poll_timeouts"])
     for family, frames in _STATS["wire_frames"].items():
         tags = {"family": batch_hop_of(family)}
         metrics.gauge("bus_wire_frames", frames, tags)

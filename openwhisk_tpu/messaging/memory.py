@@ -26,13 +26,34 @@ from .connector import (MessageConsumer, MessageProducer, MessagingProvider,
 DEFAULT_MAX_MESSAGES = 1_000_000
 
 
+#: process-wide counts of how the memory bus's consumers waited, exported
+#: as gauges by the balancers' supervision tick (export_coalesce_gauges):
+#: parks that ended in a message against parks that ended in the peek's
+#: time-out (an idle consumer's long poll)
+BUS_STATS = {"parks": 0, "poll_timeouts": 0}
+
+
 class _Topic:
     def __init__(self, name: str, max_messages: int = DEFAULT_MAX_MESSAGES):
         self.name = name
         self.max_messages = max_messages
         self.offset = itertools.count()
         self.groups: Dict[str, deque] = {}
-        self.cond = asyncio.Condition()
+        #: the futures of the consumers parked on this topic. Every
+        #: append fans out to every group, so a produce wakes them all;
+        #: nothing on the bus awaits between looking at a queue and
+        #: parking here, so no lock orders anything
+        self.waiters: List[asyncio.Future] = []
+
+    def wake(self) -> None:
+        """Messages were appended: resolve every parked consumer, once
+        per produce call however many messages it appended."""
+        waiters = self.waiters
+        if waiters:
+            self.waiters = []
+            for w in waiters:
+                if not w.done():
+                    w.set_result(True)
 
     def queue_for(self, group: str) -> deque:
         if group not in self.groups:
@@ -75,8 +96,8 @@ class MemoryProducer(MessageProducer):
     def sent_count(self) -> int:
         return self._sent
 
-    def _append_locked(self, t: _Topic, payload) -> None:
-        """Fan one payload out to every group (t.cond must be held)."""
+    def _append(self, t: _Topic, payload) -> None:
+        """Fan one payload out to every group."""
         off = next(t.offset)
         for q in t.groups.values():
             q.append((off, bytes(payload)))
@@ -88,14 +109,13 @@ class MemoryProducer(MessageProducer):
     async def send(self, topic: str, msg) -> None:
         payload = msg if isinstance(msg, (bytes, bytearray)) else msg.serialize()
         t = self.bus.topic(topic)
-        async with t.cond:
-            self._append_locked(t, payload)
-            t.cond.notify_all()
+        self._append(t, payload)
+        t.wake()
         stamp_produce(msg)  # waterfall produce edge
 
     async def send_many(self, items) -> None:
-        """Coalesced produce: one condition acquire + one notify per TOPIC
-        per micro-batch instead of per message (the controller's readback
+        """Coalesced produce: one wake of a topic's parked consumers per
+        micro-batch instead of per message (the controller's readback
         fan-out spreads one batch over N invoker topics; the ack path is a
         single topic). Order within a topic is arrival order, exactly like
         serial sends."""
@@ -104,10 +124,9 @@ class MemoryProducer(MessageProducer):
             by_topic.setdefault(topic, []).append((payload, msg))
         for topic, group in by_topic.items():
             t = self.bus.topic(topic)
-            async with t.cond:
-                for payload, _m in group:
-                    self._append_locked(t, payload)
-                t.cond.notify_all()
+            for payload, _m in group:
+                self._append(t, payload)
+            t.wake()
             for _p, m in group:
                 if m is not None:
                     stamp_produce(m)  # waterfall produce edge (per message)
@@ -144,23 +163,49 @@ class MemoryConsumer(MessageConsumer):
                    ) -> List[Tuple[str, int, int, bytes]]:
         n = min(max_messages, self.max_peek)
         t = self.bus.topic(self.topic_name)
-        out: List[Tuple[str, int, int, bytes]] = []
-        async with t.cond:
-            # look the queue up inside the predicate: set_max_messages may
-            # swap the deque object while we are parked on the condition
-            if not t.queue_for(self.group):
-                try:
-                    await asyncio.wait_for(
-                        t.cond.wait_for(
-                            lambda: len(t.queue_for(self.group)) > 0), timeout)
-                except asyncio.TimeoutError:
-                    return []
+        # look the queue up anew after every wait: set_max_messages may
+        # swap the deque object while we are parked
+        q = t.queue_for(self.group)
+        if not q:
+            if timeout <= 0 or not await self._park(t, timeout):
+                return []
             q = t.queue_for(self.group)
-            while q and len(out) < n:
-                off, payload = q.popleft()
-                out.append((self.topic_name, 0, off, payload))
+        out: List[Tuple[str, int, int, bytes]] = []
+        while q and len(out) < n:
+            off, payload = q.popleft()
+            out.append((self.topic_name, 0, off, payload))
         self._uncommitted = out
         return out
+
+    async def _park(self, t: _Topic, timeout: float) -> bool:
+        """Wait, at no cost to the loop, until this group's queue holds a
+        message (True) or `timeout` has passed (False): one future on the
+        topic that a produce resolves, one timer for the whole wait. A
+        consumer woken to a queue a competitor of its group has drained
+        parks again inside the same time-out."""
+        loop = asyncio.get_event_loop()
+        fut = loop.create_future()
+
+        def expire() -> None:  # resolves whichever future we wait on now
+            if not fut.done():
+                fut.set_result(False)
+
+        timer = loop.call_later(timeout, expire)
+        try:
+            while True:
+                t.waiters.append(fut)
+                produced = await fut
+                if t.queue_for(self.group):
+                    BUS_STATS["parks"] += 1
+                    return True
+                if not produced:  # the timer resolved it
+                    BUS_STATS["poll_timeouts"] += 1
+                    return False
+                fut = loop.create_future()
+        finally:
+            timer.cancel()
+            if fut in t.waiters:  # timed out or cancelled while parked
+                t.waiters.remove(fut)
 
     def commit(self) -> None:
         self._uncommitted = []

@@ -7,10 +7,19 @@ enough that copies drift (database/batcher.py keeps its own variant
 because its flushes run CONCURRENTLY under a semaphore; this one
 serializes flushes to preserve submission order).
 
-Liveness (same argument as database/batcher.py): the drainer's only exit
-is an empty queue checked synchronously before the coroutine returns, and
-submitters re-arm whenever the previous drainer is done() — a submission
-can never strand between the check and the task finishing.
+Waiting for work: ONE drainer task serves the coalescer for its whole
+life. With nothing pending it parks on a future (`_wake`) that the next
+`submit_nowait` resolves: an idle coalescer costs the loop no turn and
+no timer, a wave costs one task step to wake it and mints no task.
+`close()` ends the parked task; one that nobody closes is dropped
+silently with its coalescer (a parked drainer holds no work).
+
+Liveness: `_wake` is set exactly while the drainer is suspended in its
+park, and the empty check that leads there is SYNCHRONOUS with setting
+it (no await in between), so a submission either finds `_wake` and
+resolves it, or finds the drainer still running and is seen by its next
+check — it can never strand between the two. A drainer that is gone
+(never started, cancelled, closed) is re-armed by the next submission.
 
 Window semantics: `window_s == 0` flushes at the end of the current
 event-loop sweep, so everything scheduled in the same sweep (e.g. one
@@ -42,10 +51,20 @@ class MicroCoalescer:
         self.name = name
         self._pending: List[tuple] = []  # (item, fut, t_enqueue)
         self._drainer: Optional[asyncio.Task] = None
+        #: the parked drainer's future; None while it runs (or is gone)
+        self._wake: Optional[asyncio.Future] = None
+        #: drain_all()'s waiters, resolved when the drainer parks or ends
+        self._idle: Optional[asyncio.Future] = None
+        self._closed = False
         #: set by submit() when the batch fills — interrupts a window sleep
         #: so max_batch really bounds latency DURING the window, not just
         #: between windows
         self._full = asyncio.Event()
+        #: True while the flush in flight is the first since the drainer
+        #: parked (or started): its wave found the drainer waiting, not
+        #: running. The flush function may read it (`ow_produce`'s
+        #: `parked`)
+        self.parked_flush = False
 
     @property
     def pending_count(self) -> int:
@@ -64,76 +83,91 @@ class MicroCoalescer:
         self._pending.append((item, fut, loop.time()))
         if len(self._pending) >= self.max_batch:
             self._full.set()  # wake a drainer sleeping out its window
-        self._arm()
+        self._arm(loop)
         return fut
 
-    def _arm(self) -> None:
-        if self._drainer is None or self._drainer.done():
-            self._drainer = asyncio.get_event_loop().create_task(
-                self._drain(), name=self.name)
+    def _arm(self, loop) -> None:
+        wake = self._wake
+        if wake is not None:
+            if not wake.done():  # the wave's first submission wakes it
+                wake.set_result(None)
+        elif self._drainer is None or self._drainer.done():
+            self._drainer = loop.create_task(self._drain(), name=self.name)
 
-    #: post-drain linger: how many ZERO-DELAY sweeps an emptied drainer
-    #: waits for the next wave before exiting. Steady traffic re-fills
-    #: within a sweep or two, and re-arming a fresh drainer task per wave
-    #: was measurable churn (~0.2 tasks/activation at 4k/s across the
-    #: process's producers). A submission landing during the linger
-    #: flushes on the NEXT sweep — exactly when a freshly-armed drainer
-    #: would have — so the zero-idle-latency contract is unchanged.
-    LINGER_SWEEPS = 32
+    def _busy(self) -> bool:
+        """A drainer is between its wake-up and its next park."""
+        return (self._wake is None and self._drainer is not None
+                and not self._drainer.done())
+
+    def _signal_idle(self) -> None:
+        idle, self._idle = self._idle, None
+        if idle is not None and not idle.done():
+            idle.set_result(None)
+
+    async def _park(self, loop, me) -> None:
+        """Wait for the next submission at no cost to the loop."""
+        self._wake = wake = loop.create_future()
+        self._signal_idle()
+        # a parked drainer holds no work: if its coalescer is dropped
+        # unclosed, or the loop closes under it, there is nothing to
+        # report ("Task was destroyed but it is pending")
+        me._log_destroy_pending = False
+        try:
+            await wake
+        finally:
+            self._wake = None
+        me._log_destroy_pending = True
 
     async def _drain(self) -> None:
         loop = asyncio.get_event_loop()
+        me = asyncio.current_task()
         batch: List[tuple] = []
+        woke = True  # a fresh drainer's first flush found none running
         try:
             while True:
-                while self._pending:
-                    if len(self._pending) < self.max_batch:
-                        if self.window_s > 0:
-                            lag = self.window_s - (loop.time()
-                                                   - self._pending[0][2])
-                            if lag > 0:
-                                # interruptible window: a batch filling
-                                # while we sleep flushes NOW (submit
-                                # sets _full)
-                                self._full.clear()
-                                if len(self._pending) < self.max_batch:
-                                    try:
-                                        await asyncio.wait_for(
-                                            self._full.wait(), lag)
-                                    except asyncio.TimeoutError:
-                                        pass
-                        else:
-                            await asyncio.sleep(0)  # end-of-sweep coalesce
-                    batch = [(item, fut) for (item, fut, _t)
-                             in self._pending[:self.max_batch]]
-                    del self._pending[:len(batch)]
-                    try:
-                        await self._flush(batch)
-                    except Exception as e:  # noqa: BLE001 — fan out to
-                        # waiters
-                        for _item, fut in batch:
-                            if not fut.done():
-                                fut.set_exception(e)
-                    else:
-                        for _item, fut in batch:
-                            if not fut.done():
-                                fut.set_result(None)
-                for _ in range(self.LINGER_SWEEPS):
-                    await asyncio.sleep(0)
-                    if self._pending:
-                        break
-                # liveness: the empty check is SYNCHRONOUS right before
-                # the return (no await in between), and submitters re-arm
-                # whenever the previous drainer is done() — a submission
-                # can never strand between the check and the task
-                # finishing
                 if not self._pending:
-                    return
+                    if self._closed:
+                        return
+                    await self._park(loop, me)
+                    woke = True
+                    continue
+                if len(self._pending) < self.max_batch:
+                    if self.window_s > 0:
+                        lag = self.window_s - (loop.time()
+                                               - self._pending[0][2])
+                        if lag > 0:
+                            # interruptible window: a batch filling
+                            # while we sleep flushes NOW (submit
+                            # sets _full)
+                            self._full.clear()
+                            if len(self._pending) < self.max_batch:
+                                try:
+                                    await asyncio.wait_for(
+                                        self._full.wait(), lag)
+                                except asyncio.TimeoutError:
+                                    pass
+                    else:
+                        await asyncio.sleep(0)  # end-of-sweep coalesce
+                batch = [(item, fut) for (item, fut, _t)
+                         in self._pending[:self.max_batch]]
+                del self._pending[:len(batch)]
+                self.parked_flush, woke = woke, False
+                try:
+                    await self._flush(batch)
+                except Exception as e:  # noqa: BLE001 — fan out to
+                    # waiters
+                    for _item, fut in batch:
+                        if not fut.done():
+                            fut.set_exception(e)
+                else:
+                    for _item, fut in batch:
+                        if not fut.done():
+                            fut.set_result(None)
         except asyncio.CancelledError:
-            # the loop is going down mid-drain (sleep or flush cancelled):
-            # nobody will ever flush the remainder — cancel every waiter
-            # (the popped in-flight batch included) instead of leaving
-            # them pending forever
+            # the loop is going down mid-drain (park, sleep or flush
+            # cancelled): nobody will ever flush the remainder — cancel
+            # every waiter (the popped in-flight batch included) instead
+            # of leaving them pending forever
             for _item, fut in batch:
                 if not fut.done():
                     fut.cancel()
@@ -142,13 +176,27 @@ class MicroCoalescer:
                     fut.cancel()
             self._pending.clear()
             raise
+        finally:
+            self._signal_idle()
 
     async def drain_all(self) -> None:
-        """Wait until everything submitted so far has flushed (or failed)."""
-        while self._pending or (self._drainer and not self._drainer.done()):
+        """Wait until everything submitted so far has flushed (or failed):
+        nothing pending and no flush in flight."""
+        loop = asyncio.get_event_loop()
+        while self._pending or self._busy():
             if self._pending:
-                self._arm()
-            if self._drainer and not self._drainer.done():
-                await asyncio.gather(self._drainer, return_exceptions=True)
-            else:
-                await asyncio.sleep(0)
+                self._arm(loop)
+            if self._idle is None:
+                self._idle = loop.create_future()
+            # wait(), not await: a cancelled caller must not cancel the
+            # future its fellow waiters share
+            await asyncio.wait([self._idle])
+
+    def close(self) -> None:
+        """End the drainer once it has nothing left to flush (call on its
+        loop). A submission after close() still flushes: it arms a
+        drainer that ends when it runs dry."""
+        self._closed = True
+        wake = self._wake
+        if wake is not None and not wake.done():
+            wake.set_result(None)

@@ -334,6 +334,353 @@ class TestMicroCoalescer:
         assert all(cancelled)
 
 
+async def _sweeps(n):
+    for _ in range(n):
+        await asyncio.sleep(0)
+
+
+async def _coalescer(state, flush, max_batch=64, window_s=0.0):
+    """A MicroCoalescer whose drainer is in `state`: "fresh" = never
+    armed (the first submission mints the task), "parked" = one wave has
+    flushed and the drainer waits on its future for the next."""
+    from openwhisk_tpu.utils.microbatch import MicroCoalescer
+    co = MicroCoalescer(flush, max_batch=max_batch, window_s=window_s,
+                        name="drainer-under-test")
+    if state == "parked":
+        seen = []
+        co._flush = lambda batch: _record(seen, batch)
+        await co.submit("warm")
+        await co.drain_all()
+        co._flush = flush
+        assert seen == [["warm"]]
+        assert co._wake is not None and not co._drainer.done()
+    return co
+
+
+async def _record(seen, batch):
+    seen.append([item for item, _f in batch])
+
+
+class _HandleCount:
+    """Every callback and timer the loop is handed while installed, by
+    the object it is bound to (a task's step and wake-up are bound to
+    the task)."""
+
+    def __init__(self, loop):
+        self.loop, self.owners, self.timers = loop, [], 0
+        self._soon, self._at = loop.call_soon, loop.call_at
+
+    def __enter__(self):
+        def call_soon(cb, *a, **kw):
+            self.owners.append(getattr(cb, "__self__", None))
+            return self._soon(cb, *a, **kw)
+
+        def call_at(when, cb, *a, **kw):
+            self.timers += 1
+            return self._at(when, cb, *a, **kw)
+
+        self.loop.call_soon, self.loop.call_at = call_soon, call_at
+        return self
+
+    def __exit__(self, *exc):
+        del self.loop.call_soon, self.loop.call_at
+        return False
+
+    def steps_of(self, task) -> int:
+        return sum(1 for o in self.owners if o is task)
+
+
+_STATES = pytest.mark.parametrize("state", ["fresh", "parked"])
+
+
+class TestParkedDrainer:
+    """ISSUE 38: the drainer parks on a future between waves. It neither
+    spins sweeps waiting for the next wave nor dies and is minted anew."""
+
+    def test_a_parked_drainer_takes_no_step_and_arms_no_timer(self):
+        async def go():
+            seen = []
+            co = await _coalescer("parked", lambda b: _record(seen, b))
+            loop = asyncio.get_event_loop()
+            with _HandleCount(loop) as idle:
+                await _sweeps(50)
+            with _HandleCount(loop) as woken:   # the counter does count
+                await co.submit("next")
+            return (idle.steps_of(co._drainer), idle.timers,
+                    len(idle.owners), woken.steps_of(co._drainer), seen)
+
+        idle_steps, idle_timers, idle_all, woken_steps, seen = \
+            asyncio.run(go())
+        assert idle_steps == 0 and idle_timers == 0
+        assert idle_all == 50          # the test's own 50 sleeps, no more
+        assert woken_steps == 2        # the wake-up, the end-of-sweep yield
+        assert seen == [["next"]]
+
+    def test_a_thousand_waves_mint_one_task(self):
+        async def go():
+            seen = []
+            loop = asyncio.get_event_loop()
+            minted = []
+            create_task = loop.create_task
+
+            def counting(coro, **kw):
+                minted.append(kw.get("name"))
+                return create_task(coro, **kw)
+
+            loop.create_task = counting
+            try:
+                co = await _coalescer("fresh", lambda b: _record(seen, b))
+                for i in range(1000):
+                    await co.submit(i)
+                    if i % 3 == 0:      # some waves meet a parked drainer,
+                        await _sweeps(2)  # some one that has just flushed
+            finally:
+                del loop.create_task
+            return minted.count(co.name), seen
+
+        minted, seen = asyncio.run(go())
+        assert minted == 1
+        assert [i for b in seen for i in b] == list(range(1000))
+
+    @pytest.mark.parametrize("wave", [1, 5])
+    def test_a_parked_drainer_flushes_on_a_fresh_drainers_sweep(self, wave):
+        """What `ack_batch_fill` rests on: a wave that meets a parked
+        drainer coalesces as, and flushes on the very sweep on which, a
+        wave that arms a fresh one does — submissions of the wave's own
+        sweep and of the next ride the same flush."""
+        async def flushed_after(state):
+            seen = []
+            co = await _coalescer(state, lambda b: _record(seen, b))
+            for i in range(wave):
+                co.submit_nowait(i)
+            await asyncio.sleep(0)
+            co.submit_nowait("next-sweep")
+            sweeps = 1
+            while not seen:
+                await asyncio.sleep(0)
+                sweeps += 1
+            await co.drain_all()
+            return sweeps, seen
+
+        async def go():
+            return (await flushed_after("fresh"),
+                    await flushed_after("parked"))
+
+        fresh, parked = asyncio.run(go())
+        assert parked == fresh
+        assert fresh == (2, [list(range(wave)) + ["next-sweep"]])
+
+    @_STATES
+    def test_order_and_max_batch(self, state):
+        async def go():
+            seen = []
+            co = await _coalescer(state, lambda b: _record(seen, b),
+                                  max_batch=4)
+            await asyncio.gather(*[co.submit(i) for i in range(10)])
+            return seen
+
+        assert asyncio.run(go()) == [[0, 1, 2, 3], [4, 5, 6, 7], [8, 9]]
+
+    @_STATES
+    def test_one_flush_at_a_time(self, state):
+        async def go():
+            inside, most, seen = [0], [0], []
+
+            async def flush(batch):
+                inside[0] += 1
+                most[0] = max(most[0], inside[0])
+                await asyncio.sleep(0.001)
+                seen.append([item for item, _f in batch])
+                inside[0] -= 1
+
+            co = await _coalescer(state, flush, max_batch=2)
+            await asyncio.gather(*[co.submit(i) for i in range(6)])
+            return most[0], seen
+
+        assert asyncio.run(go()) == (1, [[0, 1], [2, 3], [4, 5]])
+
+    @_STATES
+    def test_window_holds_a_lone_item_and_a_full_batch_cuts_it(self, state):
+        async def go():
+            seen = []
+            co = await _coalescer(state, lambda b: _record(seen, b),
+                                  max_batch=3, window_s=0.05)
+            loop = asyncio.get_event_loop()
+            t0 = loop.time()
+            await co.submit("lone")
+            lone = loop.time() - t0
+            t0 = loop.time()
+            await asyncio.gather(*[co.submit(i) for i in range(3)])
+            full = loop.time() - t0
+            return lone, full, seen
+
+        lone, full, seen = asyncio.run(go())
+        assert 0.04 <= lone < 0.5 and full < 0.04
+        assert seen == [["lone"], [0, 1, 2]]
+
+    @_STATES
+    def test_per_item_failure_reaches_its_waiter_alone(self, state):
+        async def go():
+            async def flush(batch):
+                for item, fut in batch:
+                    if item == "bad":
+                        fut.set_exception(ValueError(item))
+
+            co = await _coalescer(state, flush)
+            return await asyncio.gather(
+                co.submit("ok"), co.submit("bad"), co.submit("ok2"),
+                return_exceptions=True)
+
+        ok, bad, ok2 = asyncio.run(go())
+        assert ok is None and ok2 is None and isinstance(bad, ValueError)
+
+    @_STATES
+    def test_a_raising_flush_fails_its_batch_and_the_next_still_flushes(
+            self, state):
+        async def go():
+            seen = []
+
+            async def flush(batch):
+                if batch[0][0] == "boom":
+                    raise ConnectionError("bus down")
+                await _record(seen, batch)
+
+            co = await _coalescer(state, flush)
+            failed = await asyncio.gather(co.submit("boom"), co.submit("b2"),
+                                          return_exceptions=True)
+            await co.submit("after")
+            return failed, seen
+
+        failed, seen = asyncio.run(go())
+        assert all(isinstance(f, ConnectionError) for f in failed)
+        assert seen == [["after"]]
+
+    @_STATES
+    def test_drain_all_waits_for_pending_and_for_the_flush_in_flight(
+            self, state):
+        async def go():
+            seen, gate = [], asyncio.Event()
+
+            async def flush(batch):
+                await gate.wait()
+                await _record(seen, batch)
+
+            co = await _coalescer(state, flush, max_batch=2)
+            futs = [co.submit_nowait(i) for i in range(3)]
+            drained = asyncio.ensure_future(co.drain_all())
+            await _sweeps(5)           # first batch is inside flush
+            held = (drained.done(), co.pending_count, list(seen))
+            gate.set()
+            await asyncio.wait_for(drained, 2.0)
+            after = ([f.done() for f in futs], co.pending_count, seen)
+            await asyncio.wait_for(co.drain_all(), 2.0)   # idle: at once
+            return held, after
+
+        held, after = asyncio.run(go())
+        assert held == (False, 1, [])
+        assert after == ([True] * 3, 0, [[0, 1], [2]])
+
+    @_STATES
+    def test_close_ends_the_drainer_after_what_is_pending(self, state):
+        async def go():
+            seen = []
+            co = await _coalescer(state, lambda b: _record(seen, b))
+            fut = co.submit_nowait("last")
+            co.close()
+            await fut
+            await _sweeps(2)
+            ended = co._drainer.done() and not co._drainer.cancelled()
+            await co.submit("after-close")    # still flushes
+            await _sweeps(2)
+            return ended, co._drainer.done(), seen
+
+        ended, ended_again, seen = asyncio.run(go())
+        assert ended and ended_again
+        assert seen == [["last"], ["after-close"]]
+
+    def test_close_of_an_idle_parked_drainer_ends_its_task(self):
+        async def go():
+            co = await _coalescer("parked", lambda b: _record([], b))
+            co.close()
+            await _sweeps(2)
+            return co._drainer.done(), co._drainer.cancelled()
+
+        assert asyncio.run(go()) == (True, False)
+
+    def test_cancelling_a_parked_drainer_cancels_the_wave_that_met_it(self):
+        async def go():
+            co = await _coalescer("parked", lambda b: _record([], b))
+            co._drainer.cancel()
+            # a submission of the very sweep of the cancel is cancelled
+            # with it, not left waiting; the next one arms a new drainer
+            orphan = co.submit_nowait("orphan")
+            await asyncio.wait([orphan], timeout=2.0)
+            await asyncio.wait_for(co.submit("next"), 2.0)
+            return orphan.cancelled(), co._drainer.done()
+
+        assert asyncio.run(go()) == (True, False)
+
+    def test_unclosed_coalescers_leave_no_pending_task_warning(self, caplog):
+        """Hundreds of coalescers that nobody closes, dropped with their
+        loop: a parked drainer holds no work, so nothing is reported."""
+        import gc
+        import logging
+        from openwhisk_tpu.utils.microbatch import MicroCoalescer
+
+        async def go():
+            seen = []
+            cos = [MicroCoalescer(lambda b: _record(seen, b), 64, 0.0)
+                   for _ in range(300)]
+            await asyncio.gather(*[co.submit(i)
+                                   for i, co in enumerate(cos)])
+            for co in cos:
+                await co.drain_all()
+            return len(seen), sum(co._wake is not None for co in cos)
+
+        loop = asyncio.new_event_loop()
+        with caplog.at_level(logging.ERROR, logger="asyncio"):
+            try:
+                # not asyncio.run: that would cancel the parked tasks
+                flushed, parked = loop.run_until_complete(go())
+            finally:
+                loop.close()
+            gc.collect()
+        assert (flushed, parked) == (300, 300)
+        assert "Task was destroyed" not in caplog.text
+
+    def test_produce_counts_the_flushes_that_woke_a_parked_drainer(self):
+        """`parked_flush` as `_ship` reads it: 1 on a lone flush after a
+        park (and on a fresh drainer's first), 0 on the next flush of a
+        drainer kept busy."""
+        from openwhisk_tpu.messaging import coalesce
+
+        async def go():
+            provider = MemoryMessagingProvider()
+            producer = CoalescingProducer(provider.get_producer(),
+                                          max_batch=2, window_ms=0.0)
+            flags = []
+            ship = producer._co._flush
+
+            async def spy(batch):
+                flags.append((len(batch), producer._co.parked_flush))
+                await ship(batch)
+
+            producer._co._flush = spy
+            n0 = coalesce._STATS["parked_flushes"]
+            await producer.send("t", b"lone")              # fresh drainer
+            await _sweeps(3)
+            await producer.send("t", b"lone-after-a-park")
+            await asyncio.gather(*[producer.send("t", b"m")  # 3 flushes,
+                                   for _ in range(5)])     # one wake
+            await producer.close()
+            return flags, coalesce._STATS["parked_flushes"] - n0
+
+        flags, counted = asyncio.run(go())
+        assert flags == [(1, True), (1, True),
+                         (2, True), (2, False), (1, False)]
+        assert counted == 3
+
+
 class TestPeekBackoff:
     def test_dead_broker_returns_after_timeout_with_retries(self):
         async def go():

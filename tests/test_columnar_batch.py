@@ -518,26 +518,7 @@ class TestFrameEdges:
     def test_ack_decode_span_carries_interned(self, monkeypatch):
         """`ow_ack_decode` reads the hits of ITS frame: 0 for an invoker
         never seen, 1 from then on; `ow_produce` the blobs reused."""
-        from openwhisk_tpu.controller.loadbalancer import base
-        from openwhisk_tpu.messaging import coalesce
-        spans = []
-
-        class _Span:
-            def __init__(self, name, **stats):
-                self.name, self.stats = name, stats
-                spans.append(self)
-
-            def __enter__(self):
-                return self
-
-            def __exit__(self, *a):
-                return False
-
-            def set_metadata(self, **kw):
-                self.stats.update(kw)
-
-        monkeypatch.setattr(base, "span", _Span)
-        monkeypatch.setattr(coalesce, "span", _Span)
+        spans = _recorded_spans(monkeypatch)
 
         async def go():
             bal = _mk_balancer()
@@ -564,6 +545,100 @@ class TestFrameEdges:
         produces = [s.stats for s in spans if s.name == "ow_produce"]
         assert [(p["n"], p["interned"]) for p in produces] == \
             [(1, 0), (1, 1), (1, 1)]
+
+    def test_produce_span_carries_parked(self, monkeypatch):
+        """ISSUE 38: `ow_produce`'s `parked` is 1 where the flush's wave
+        found its drainer waiting (a lone flush after a park, a fresh
+        drainer's first), 0 on the later flushes of a drainer kept busy;
+        `bus_coalesce_parked_flushes` counts the ones."""
+        from openwhisk_tpu.messaging.coalesce import export_coalesce_gauges
+        from openwhisk_tpu.utils.logging import MetricEmitter
+        spans = _recorded_spans(monkeypatch)
+
+        def gauge():
+            m = MetricEmitter()
+            export_coalesce_gauges(m)
+            return m.gauge_value("bus_coalesce_parked_flushes")
+
+        ident = _ident("parked")
+
+        async def go():
+            prod = CoalescingProducer(_SpyProducer(), max_batch=2,
+                                      batch_wire=True)
+            await prod.send("invoker1", _act_msg(ident))        # fresh
+            for _ in range(5):
+                await asyncio.sleep(0)
+            await prod.send("invoker1", _act_msg(ident, i=1))   # parked
+            await asyncio.gather(*[                             # one wake,
+                prod.send("invoker1", _act_msg(ident, i=i))     # 3 flushes
+                for i in range(2, 7)])
+            await prod.close()
+
+        g0 = gauge()
+        asyncio.run(go())
+        produces = [s.stats for s in spans if s.name == "ow_produce"]
+        assert [(p["n"], p["parked"]) for p in produces] == \
+            [(1, 1), (1, 1), (2, 1), (2, 0), (1, 0)]
+        assert gauge() - g0 == 3
+
+    def test_the_tick_exports_how_the_bus_s_consumers_waited(self):
+        """`bus_consumer_parks` counts the parks a message ended,
+        `bus_consumer_poll_timeouts` those the peek's time-out ended; a
+        peek that finds its queue filled parks not at all."""
+        from openwhisk_tpu.messaging import MemoryMessagingProvider
+        from openwhisk_tpu.messaging.coalesce import export_coalesce_gauges
+        from openwhisk_tpu.utils.logging import MetricEmitter
+
+        def gauges():
+            m = MetricEmitter()
+            export_coalesce_gauges(m)
+            return (m.gauge_value("bus_consumer_parks"),
+                    m.gauge_value("bus_consumer_poll_timeouts"))
+
+        async def go():
+            prov = MemoryMessagingProvider()
+            prod = prov.get_producer()
+            cons = prov.get_consumer("t", "g")
+            assert await cons.peek(4, timeout=0.02) == []       # time-out
+            assert await cons.peek(4, timeout=0.02) == []       # time-out
+            for _ in range(3):                                  # 3 parks
+                parked = asyncio.ensure_future(cons.peek(4, timeout=5.0))
+                await asyncio.sleep(0)
+                await prod.send("t", b"m")
+                assert len(await parked) == 1
+            await prod.send("t", b"there-already")
+            assert len(await cons.peek(4, timeout=5.0)) == 1    # no park
+
+        g0 = gauges()
+        asyncio.run(go())
+        g1 = gauges()
+        assert (g1[0] - g0[0], g1[1] - g0[1]) == (3, 2)
+
+
+def _recorded_spans(monkeypatch) -> list:
+    """Stand a recorder in for `span` where the bus layer opens one;
+    returns the list the spans land in (`.name`, `.stats`)."""
+    from openwhisk_tpu.controller.loadbalancer import base
+    from openwhisk_tpu.messaging import coalesce
+    spans = []
+
+    class _Span:
+        def __init__(self, name, **stats):
+            self.name, self.stats = name, stats
+            spans.append(self)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *a):
+            return False
+
+        def set_metadata(self, **kw):
+            self.stats.update(kw)
+
+    monkeypatch.setattr(base, "span", _Span)
+    monkeypatch.setattr(coalesce, "span", _Span)
+    return spans
 
 
 class _SpyProducer:

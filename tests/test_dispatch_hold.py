@@ -180,6 +180,55 @@ def test_a_step_that_waited_for_the_loop_cost_that_wait_too():
     _with_balancer(body, n_invokers=4, mem=65536)
 
 
+def test_the_loop_sleeps_through_the_hold_but_not_through_its_last_tick():
+    """ISSUE 38: the selector sleeps in whole milliseconds, so a hold that
+    ended in a sleep ended up to one late, and the estimate read that as
+    the step's cost. The flush task sleeps to within `SELECTOR_TICK_S` of
+    the hold's end and yields through the rest: no sleep of the loop
+    reaches past the end of the hold, and the hold is still mostly slept."""
+    async def body(bal):
+        ident = Identity.generate("guest")
+        loop = asyncio.get_event_loop()
+        select = loop._selector.select
+        asked = []          # (when the loop asked, for how long)
+
+        def recording(timeout=None):
+            asked.append((time.monotonic(), timeout))
+            return select(timeout)
+
+        dues = []
+        step = bal._device_step
+
+        async def device_step(delay, due):
+            dues.append((due, time.monotonic()))
+            return await step(delay, due)
+
+        bal._device_step = device_step
+        hold_s = 0.05
+        _pin(bal, hold_s)
+        loop._selector.select = recording
+        try:
+            outs = bal.publish_many(_rows(3, ident))
+            assert bal._pending            # held, not dispatched inline
+            await asyncio.gather(*outs)
+        finally:
+            del loop._selector.select
+        await _drain(bal)
+        (due, began), *_ = dues
+        assert began >= due                # never early
+        held = [(t, timeout) for t, timeout in asked if t < due]
+        slept = [timeout for t, timeout in held if timeout]
+        # it slept most of the hold away in a few sleeps ...
+        assert slept and sum(slept) > hold_s / 2 and len(slept) < 20
+        # ... none of which could end after the hold did
+        assert all(t + timeout <= due for t, timeout in held if timeout)
+        # and through the last tick it turned, sweep by sweep
+        assert sum(1 for t, timeout in held
+                   if not timeout and t > due - bal.SELECTOR_TICK_S) >= 1
+
+    _with_balancer(body, n_invokers=4, mem=65536)
+
+
 def test_a_full_batch_dispatches_at_once_whatever_the_hold():
     """Closed on size: inline while the pipeline has room, and without a
     sleep from the flush task when it has not."""

@@ -100,6 +100,74 @@ class TestTcpBus:
         assert 0.1 < dt < 1.5  # long-poll, not busy-wait
 
 
+    def test_two_competing_consumers_get_each_of_1000_messages_once(self):
+        """The broker's server side peeks a MemoryConsumer per connection:
+        two connections of one group, both parked, share the queue."""
+        async def go():
+            port = _free_port()
+            server = TcpBusServer(port=port)
+            await server.start()
+            try:
+                provider = TcpMessagingProvider(port=port)
+                prod = provider.get_producer()
+                got = {"c1": [], "c2": []}
+
+                async def consume(name):
+                    cons = provider.get_consumer("t", "g", max_peek=16)
+                    try:
+                        while True:
+                            batch = await cons.peek(16, timeout=0.5)
+                            cons.commit()
+                            got[name].extend(p for *_x, p in batch)
+                    finally:
+                        await cons.close()
+
+                tasks = [asyncio.ensure_future(consume(n)) for n in got]
+                await asyncio.sleep(0.1)      # both parked at the broker
+                msgs = [f"m{i}".encode() for i in range(1000)]
+                for i in range(0, 1000, 25):
+                    await prod.send_many([("t", m, None)
+                                          for m in msgs[i:i + 25]])
+                deadline = time.monotonic() + 10
+                while (len(got["c1"]) + len(got["c2"]) < 1000
+                       and time.monotonic() < deadline):
+                    await asyncio.sleep(0.01)
+                for t in tasks:
+                    t.cancel()
+                await asyncio.wait(tasks)
+                await prod.close()
+                return got, msgs
+            finally:
+                await server.stop()
+
+        got, msgs = asyncio.run(go())
+        assert sorted(got["c1"] + got["c2"]) == sorted(msgs)
+        assert got["c1"] and got["c2"]
+
+    def test_long_poll_times_out_empty_and_leaves_the_broker_clean(self):
+        async def go():
+            port = _free_port()
+            server = TcpBusServer(port=port)
+            await server.start()
+            try:
+                provider = TcpMessagingProvider(port=port)
+                cons = provider.get_consumer("t", "g")
+                t0 = time.monotonic()
+                batch = await cons.peek(4, timeout=0.2)
+                dt = time.monotonic() - t0
+                await cons.close()
+                loop = asyncio.get_event_loop()
+                live = [h for h in loop._scheduled if not h.cancelled()
+                        and "expire" in repr(h)]
+                return batch, dt, server.bus.topic("t").waiters, live
+            finally:
+                await server.stop()
+
+        batch, dt, waiters, live = asyncio.run(go())
+        assert batch == [] and 0.15 < dt < 1.5
+        assert waiters == [] and live == []
+
+
 class TestIdAssigner:
     def test_stable_assignment(self, tmp_path):
         async def go():

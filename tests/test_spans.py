@@ -274,6 +274,23 @@ def test_steps_and_folds_are_the_journal_s_records(runs):
     assert all(st["bytes"] > 0 for st in frames)
 
 
+def test_every_produce_says_whether_it_woke_a_parked_drainer(runs):
+    """ISSUE 38: `ow_produce` carries `parked`; its mean over a run's
+    events says how sparse the producers are (the reader a later
+    benchmark issue needs is `span_mean` of this stat). The toy run's
+    waves are far apart, so nearly every flush meets a parked drainer."""
+    _plain, traced = runs
+    produces = [st for line in traced["lines"] for name, _s, _e, st in line
+                if name == "ow_produce"]
+    # the serial SPI's two `ow_produce` (`send_activation_to_invoker`:
+    # the dispatch's preparation, `n` alone) are no flush
+    assert sum("bytes" not in st for st in produces) == 2
+    produces = [st for st in produces if "bytes" in st]
+    assert produces and all(st["parked"] in (0, 1) for st in produces)
+    mean = sum(st["parked"] for st in produces) / len(produces)
+    assert 0.5 < mean <= 1.0
+
+
 def test_a_step_is_one_jitted_call(runs):
     """ISSUE 31: the post-step books ride the step's own output, so an
     `ow_step` holds ONE jitted call, the `packed` program the device
