@@ -39,6 +39,7 @@ bit-exact with today's behavior.
 """
 from __future__ import annotations
 
+import asyncio
 import time
 from collections import deque
 from dataclasses import dataclass
@@ -48,6 +49,7 @@ import numpy as np
 
 from ..utils.config import load_config
 from ..utils.microbatch import MicroCoalescer
+from ..utils.waterfall import span
 
 
 @dataclass(frozen=True)
@@ -146,7 +148,12 @@ class AdmissionPlane:
     async def check_throttles(self, identity, is_trigger_fire: bool) -> None:
         """The batched stand-in for `_check_throttles`: returns on admit,
         raises the serial path's exact `ThrottleRejectRequest` on reject."""
-        await self._co.submit((identity, is_trigger_fire))
+        await self.submit(identity, is_trigger_fire)
+
+    def submit(self, identity, is_trigger_fire: bool) -> asyncio.Future:
+        """`check_throttles`' synchronous half: enqueue the check; the
+        future is resolved (or given the rejection) by its flush."""
+        return self._co.submit_nowait((identity, is_trigger_fire))
 
     async def _flush(self, batch: List[tuple]) -> None:
         """One vectorized admission pass over the whole batch
@@ -154,7 +161,12 @@ class AdmissionPlane:
         mirrors the serial pipeline exactly: rate first (its rejection
         skips the concurrency read), then concurrency. Rejected futures
         get their exception here; admitted ones are resolved by the
-        coalescer on return."""
+        coalescer on return. No await: one `ow_http_entitle` span (`n`,
+        the checks it resolves) holds all of it."""
+        with span("ow_http_entitle", n=len(batch)):
+            self._decide(batch)
+
+    def _decide(self, batch: List[tuple]) -> None:
         from .entitlement import (CONCURRENT_LIMIT_MESSAGE,
                                   ThrottleRejectRequest, rate_limit_message)
         self.batches += 1

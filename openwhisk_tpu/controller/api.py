@@ -16,6 +16,7 @@ controller/cors.py); web actions manage their own CORS + OPTIONS preflight.
 from __future__ import annotations
 
 import asyncio
+import itertools
 import json
 from typing import Optional
 
@@ -32,6 +33,8 @@ from ..core.entity.action import ActionLimits
 from ..core.entity.names import FullyQualifiedEntityName
 from ..database import DocumentConflict, NoDocumentException
 from ..utils.transaction import TransactionId
+from ..utils.waterfall import span
+from .authentication import UNSETTLED
 from .entitlement import (ACTIVATE, DELETE, EntitlementException, PUT, READ,
                           RejectRequest)
 from .loadbalancer.base import (LoadBalancerException,
@@ -40,6 +43,15 @@ from .invoke import resolve_action
 from .routemgmt import ApiManagementException
 
 MAX_LIST_LIMIT = 200
+
+#: paths `_auth_middleware` serves without credentials (and every
+#: /api/v1/web/ path, and the controller's `public_extra_paths`)
+PUBLIC_PATHS = frozenset(("/ping", "/api/v1", "/metrics", "/docs",
+                          "/api/v1/api-docs", "/api/v1/api-docs/ui"))
+#: a request's id in this process, minted by `_auth_middleware`: the `req`
+#: stat of every front-door span the request makes (`ow_http_*`,
+#: `ow_invoke*`), so a trace groups them by request
+_REQUEST_IDS = itertools.count(1)
 
 
 def _error(status: int, message: str, transid: Optional[TransactionId] = None
@@ -198,22 +210,35 @@ class ControllerApi:
                 e.headers.update(self.c.cors.rest_headers())
             raise
         if applies:
-            resp.headers.update(self.c.cors.rest_headers())
+            with span("ow_http_respond", req=request.get("req", 0)):
+                resp.headers.update(self.c.cors.rest_headers())
         return resp
 
     @web.middleware
     async def _auth_middleware(self, request: web.Request, handler):
-        if request.path in ("/ping", "/api/v1", "/metrics", "/docs",
-                            "/api/v1/api-docs", "/api/v1/api-docs/ui") or \
-                request.path.startswith("/api/v1/web/") or \
-                request.path in self.c.public_extra_paths:
+        req = next(_REQUEST_IDS)
+        auth = self.c.authenticator
+        with span("ow_http_auth", req=req):
+            request["req"] = req
+            path = request.path
+            public = (path in PUBLIC_PATHS or path.startswith("/api/v1/web/")
+                      or path in self.c.public_extra_paths)
+            creds = None if public else auth.credentials(
+                request.headers.get("Authorization"))
+            identity = None if creds is None else auth.identity_now(creds)
+            if isinstance(identity, Identity):
+                self._admit(request, identity)
+        if public:
             return await handler(request)
-        identity = await self.c.authenticator.identity_from_header(
-            request.headers.get("Authorization"))
+        if identity is UNSETTLED:
+            # the key's first lookup, or its cache entry expired: the
+            # store is read outside any span
+            identity = await auth.identity(creds)
+            if identity is not None:
+                with span("ow_http_auth", req=req):
+                    self._admit(request, identity)
         if identity is None:
             return _error(401, "The supplied authentication is invalid.")
-        request["identity"] = identity
-        request["transid"] = TransactionId()
         try:
             return await handler(request)
         except MalformedEntity as e:
@@ -240,6 +265,11 @@ class ControllerApi:
         except KeyError as e:
             return _error(400, f"missing required field: {e}", request.get("transid"))
 
+    @staticmethod
+    def _admit(request: web.Request, identity: Identity) -> None:
+        request["identity"] = identity
+        request["transid"] = TransactionId()
+
     # -------------------------------------------------------------- helpers
     def _namespace(self, request: web.Request) -> str:
         ns = request.match_info["ns"]
@@ -251,7 +281,8 @@ class ControllerApi:
         await self.c.entitlement.check(request["identity"], right, namespace,
                                        throttle=throttle,
                                        is_trigger_fire=is_trigger_fire,
-                                       waterfall_ctx=waterfall_ctx)
+                                       waterfall_ctx=waterfall_ctx,
+                                       req=request.get("req", 0))
 
     @staticmethod
     def _list_params(request):
@@ -1443,19 +1474,25 @@ class ControllerApi:
         GLOBAL_WATERFALL.stamp_ctx(wf_ctx, STAGE_API_ACCEPT)
         await self._check(request, ACTIVATE, ns, throttle=True,
                           waterfall_ctx=wf_ctx)
-        blocking = self._bool_param(request, "blocking")
-        result_only = self._bool_param(request, "result")
-        try:
-            wait_override = float(request.query["timeout"]) / 1000.0 \
-                if "timeout" in request.query else None
-        except ValueError:
-            wait_override = None
-        try:
-            payload = await request.json() if request.can_read_body else {}
-        except json.JSONDecodeError:
-            return _error(400, "malformed JSON body", request["transid"])
+        req = request["req"]
+        body = await request.read() if request.can_read_body else None
+        with span("ow_http_body", req=req,
+                  bytes=0 if body is None else len(body)):
+            blocking = self._bool_param(request, "blocking")
+            result_only = self._bool_param(request, "result")
+            try:
+                wait_override = float(request.query["timeout"]) / 1000.0 \
+                    if "timeout" in request.query else None
+            except ValueError:
+                wait_override = None
+            try:
+                # as `request.json()` parses: the charset's text, json.loads
+                payload = {} if body is None else json.loads(
+                    body.decode(request.charset or "utf-8"))
+            except json.JSONDecodeError:
+                return _error(400, "malformed JSON body", request["transid"])
         action, pkg_params = await resolve_action(self.c.entity_store, fqn,
-                                                  request["identity"])
+                                                  request["identity"], req=req)
         from .conductors import is_conductor
         if action.is_sequence:
             outcome = await self.c.sequencer.invoke_sequence(
@@ -1469,16 +1506,20 @@ class ControllerApi:
             outcome = await self.c.invoker.invoke(
                 request["identity"], action, pkg_params, payload, blocking,
                 transid=request["transid"], wait_override=wait_override,
-                waterfall_ctx=wf_ctx)
-        if outcome.accepted:
-            return web.json_response(
-                {"activationId": outcome.activation_id.asString}, status=202)
-        activation = outcome.activation
-        if result_only:
-            status = 200 if activation.response.is_success else 502
-            return web.json_response(activation.resulting_json(), status=status)
-        status = 200 if activation.response.is_success else 502
-        return web.json_response(activation.to_json(), status=status)
+                waterfall_ctx=wf_ctx, req=req)
+        with span("ow_http_respond", req=req) as answer:
+            if outcome.accepted:
+                resp = web.json_response(
+                    {"activationId": outcome.activation_id.asString},
+                    status=202)
+            else:
+                activation = outcome.activation
+                status = 200 if activation.response.is_success else 502
+                resp = web.json_response(
+                    activation.resulting_json() if result_only
+                    else activation.to_json(), status=status)
+            answer.set_metadata(bytes=len(resp.body))
+        return resp
 
     # ---------------------------------------------------------- activations
     async def list_activations(self, request):

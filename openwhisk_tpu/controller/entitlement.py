@@ -27,6 +27,8 @@ from collections import deque
 from typing import Dict, Optional
 
 from ..core.entity import Identity
+from ..utils.waterfall import (STAGE_ENTITLE, STAGE_THROTTLE,
+                               ActivationWaterfall, span)
 
 READ = "READ"
 PUT = "PUT"
@@ -178,36 +180,43 @@ class LocalEntitlementProvider:
     # -- the check pipeline ------------------------------------------------
     async def check(self, identity: Identity, right: str, namespace: str,
                     throttle: bool = False, is_trigger_fire: bool = False,
-                    waterfall_ctx=None) -> None:
+                    waterfall_ctx=None, req: int = 0) -> None:
         """`waterfall_ctx` (an un-adopted stage vector from the latency
         waterfall plane) gets the entitle/throttle stages stamped between
         the pipeline's two halves, so the end-to-end budget can tell an
-        entitlement-bound tail from a throttle-bound one."""
-        from ..utils.waterfall import (STAGE_ENTITLE, STAGE_THROTTLE,
-                                       ActivationWaterfall)
-        if REJECT in identity.rights:
-            raise RejectRequest("The subject is not entitled to access this API.")
-        if not self._entitled(identity, right, namespace):
-            raise RejectRequest(
-                f"The supplied authentication is not authorized to access "
-                f"'{namespace}' with {right} right.")
-        if waterfall_ctx is not None:
-            ActivationWaterfall.stamp_ctx(waterfall_ctx, STAGE_ENTITLE)
-        if throttle and right == ACTIVATE:
+        entitlement-bound tail from a throttle-bound one. `req` is the
+        REST request's id, the `ow_http_entitle` spans' stat."""
+        admitted = None
+        with span("ow_http_entitle", req=req):
+            if REJECT in identity.rights:
+                raise RejectRequest(
+                    "The subject is not entitled to access this API.")
+            if not self._entitled(identity, right, namespace):
+                raise RejectRequest(
+                    f"The supplied authentication is not authorized to "
+                    f"access '{namespace}' with {right} right.")
+            if waterfall_ctx is not None:
+                ActivationWaterfall.stamp_ctx(waterfall_ctx, STAGE_ENTITLE)
+            if not (throttle and right == ACTIVATE):
+                return
             if self.frontend is not None:
                 # sharded front end: the check runs on the worker loop
                 # owning this namespace's slice of admission state (same
                 # decisions, same exceptions — per-namespace arrival
                 # order is preserved by the hash partition)
-                await self.frontend.check_throttles(identity, is_trigger_fire)
+                admitted = self.frontend.check_throttles(identity,
+                                                         is_trigger_fire)
             elif self.admission is not None:
                 # batched path: this check coalesces with concurrent
                 # arrivals and resolves from one vectorized flush (same
                 # decisions, same exceptions as the serial path)
-                await self.admission.check_throttles(identity, is_trigger_fire)
+                admitted = self.admission.submit(identity, is_trigger_fire)
             else:
                 self._check_throttles(identity, is_trigger_fire)
-            if waterfall_ctx is not None:
+        if admitted is not None:
+            await admitted
+        if waterfall_ctx is not None:
+            with span("ow_http_entitle", req=req):
                 ActivationWaterfall.stamp_ctx(waterfall_ctx, STAGE_THROTTLE)
 
     def _check_throttles(self, identity: Identity, is_trigger_fire: bool) -> None:

@@ -20,6 +20,7 @@ from ..core.entity.names import FullyQualifiedEntityName
 from ..database import EntityStore, NoDocumentException
 from ..messaging.message import ActivationMessage
 from ..utils.transaction import TransactionId
+from ..utils.waterfall import span
 
 MAX_BLOCKING_WAIT = 65.0  # ref controller maxWaitForBlockingActivation ~ 60 s
 
@@ -38,25 +39,36 @@ class InvokeOutcome:
     activation: Optional[WhiskActivation]
     activation_id: ActivationId
     accepted: bool  # True -> 202 (no result within the wait window)
+    #: activation-store polls the blocking wait made (`ow_invoke_done`'s)
+    polls: int = 0
 
 
 async def resolve_action(entity_store: EntityStore, fqn: FullyQualifiedEntityName,
-                         identity: Identity) -> Tuple[WhiskAction, Parameters]:
+                         identity: Identity, req: int = 0
+                         ) -> Tuple[WhiskAction, Parameters]:
     """Resolve an action reference through packages/bindings, returning the
     action and the merged package-level parameters (provider < binding).
-    Ref: WhiskPackage.mergePackageWithBinding + Actions resolution."""
+    Ref: WhiskPackage.mergePackageWithBinding + Actions resolution. The
+    blocks between the store reads are `ow_http_resolve` spans (`req`, the
+    REST request's id); a cached action is read inside one."""
     segments = fqn.path.segments
     if len(segments) <= 1:
-        action = await entity_store.get_action(str(fqn))
-        return action, Parameters()
-    pkg_id = f"{segments[0]}/{segments[1]}"
-    package = await entity_store.get_package(pkg_id)
-    params = package.parameters
-    provider_path = package.namespace.add(package.name)
+        with span("ow_http_resolve", req=req):
+            doc_id = str(fqn)
+            action = entity_store.cached(doc_id)
+            params = Parameters()
+        if action is None:
+            action = await entity_store.get_action(doc_id)
+        return action, params
+    package = await entity_store.get_package(f"{segments[0]}/{segments[1]}")
+    with span("ow_http_resolve", req=req):
+        params = package.parameters
+        provider_path = package.namespace.add(package.name)
     if package.binding is not None:
         provider = await entity_store.get_package(str(package.binding.fqn))
-        params = provider.parameters.merge(package.parameters)
-        provider_path = provider.namespace.add(provider.name)
+        with span("ow_http_resolve", req=req):
+            params = provider.parameters.merge(package.parameters)
+            provider_path = provider.namespace.add(provider.name)
     action = await entity_store.get_action(f"{provider_path}/{fqn.name}")
     return action, params
 
@@ -82,37 +94,45 @@ class ActionInvoker:
                      blocking: bool, transid: Optional[TransactionId] = None,
                      wait_override: Optional[float] = None,
                      cause: Optional[ActivationId] = None,
-                     waterfall_ctx: Optional[list] = None) -> InvokeOutcome:
+                     waterfall_ctx: Optional[list] = None,
+                     req: int = 0) -> InvokeOutcome:
         """invokeSimpleAction (:152-206): parameters merge left-to-right as
         package < action < payload; the message carries only the payload-
         merged arguments. `waterfall_ctx` is the REST handler's stage
         vector (api_accept/entitle/throttle already stamped); direct
         callers (triggers, sequences) get a fresh vector anchored here so
-        every activation carries a waterfall regardless of entry path."""
-        transid = transid or TransactionId()
-        from ..utils.tracing import GLOBAL_TRACER, trace_id_of
-        from ..utils.waterfall import GLOBAL_WATERFALL
-        span = GLOBAL_TRACER.start_span("controller_activation", transid)
-        args = package_params.merge(action.parameters).merge(
-            Parameters.from_arguments(payload or {}))
-        msg = ActivationMessage(
-            transid=transid,
-            action=FullyQualifiedEntityName(action.namespace, action.name),
-            revision=action.rev.rev,
-            user=identity,
-            activation_id=ActivationId.generate(),
-            root_controller_index=self.controller,
-            blocking=blocking,
-            content=args.to_arguments(),
-            cause=cause,
-            trace_context=GLOBAL_TRACER.get_trace_context(transid),
-        )
-        # the activation id exists now: the stage vector becomes reachable
-        # for every later layer (balancer, bus, invoker, pool, batcher)
-        if waterfall_ctx is None:
-            waterfall_ctx = GLOBAL_WATERFALL.open()
-        GLOBAL_WATERFALL.adopt(msg.activation_id.asString, waterfall_ctx,
-                               trace_id=trace_id_of(msg.trace_context))
+        every activation carries a waterfall regardless of entry path.
+        `req` is the REST request's id (0 for those callers): the stat of
+        the `ow_invoke` span up to the publish and the `ow_invoke_done`
+        span after the wait."""
+        with span("ow_invoke", req=req):
+            transid = transid or TransactionId()
+            from ..utils.tracing import GLOBAL_TRACER, trace_id_of
+            from ..utils.waterfall import GLOBAL_WATERFALL
+            trace_span = GLOBAL_TRACER.start_span("controller_activation",
+                                                  transid)
+            args = package_params.merge(action.parameters).merge(
+                Parameters.from_arguments(payload or {}))
+            msg = ActivationMessage(
+                transid=transid,
+                action=FullyQualifiedEntityName(action.namespace, action.name),
+                revision=action.rev.rev,
+                user=identity,
+                activation_id=ActivationId.generate(),
+                root_controller_index=self.controller,
+                blocking=blocking,
+                content=args.to_arguments(),
+                cause=cause,
+                trace_context=GLOBAL_TRACER.get_trace_context(transid),
+            )
+            # the activation id exists now: the stage vector becomes
+            # reachable for every later layer (balancer, bus, invoker,
+            # pool, batcher)
+            if waterfall_ctx is None:
+                waterfall_ctx = GLOBAL_WATERFALL.open()
+            GLOBAL_WATERFALL.adopt(msg.activation_id.asString, waterfall_ctx,
+                                   trace_id=trace_id_of(msg.trace_context))
+        outcome = None
         try:
             try:
                 if self._publish_batcher is not None:
@@ -129,16 +149,21 @@ class ActionInvoker:
                 GLOBAL_WATERFALL.discard(msg.activation_id.asString)
                 raise
             if not blocking:
-                return InvokeOutcome(None, msg.activation_id, accepted=True)
-            wait = min(wait_override or MAX_BLOCKING_WAIT,
-                       action.limits.timeout.seconds + 60.0)
-            return await self._wait_for_response(identity, msg, promise, wait)
+                outcome = InvokeOutcome(None, msg.activation_id, accepted=True)
+            else:
+                wait = min(wait_override or MAX_BLOCKING_WAIT,
+                           action.limits.timeout.seconds + 60.0)
+                outcome = await self._wait_for_response(identity, msg,
+                                                        promise, wait)
+            return outcome
         finally:
-            GLOBAL_TRACER.finish_span(
-                transid, {"action": str(action.fully_qualified_name),
-                          "activationId": msg.activation_id.asString,
-                          "proc": f"controller{self.controller.name}"},
-                span=span)
+            with span("ow_invoke_done", req=req,
+                      polls=0 if outcome is None else outcome.polls):
+                GLOBAL_TRACER.finish_span(
+                    transid, {"action": str(action.fully_qualified_name),
+                              "activationId": msg.activation_id.asString,
+                              "proc": f"controller{self.controller.name}"},
+                    span=trace_span)
 
     async def _wait_for_response(self, identity: Identity, msg: ActivationMessage,
                                  promise: asyncio.Future, wait: float
@@ -152,6 +177,7 @@ class ActionInvoker:
         deadline = time.monotonic() + wait
         interval = POLL_INTERVAL_MIN
         promise_live = True
+        polls = 0
         while True:
             remaining = deadline - time.monotonic()
             if remaining <= 0:
@@ -161,7 +187,7 @@ class ActionInvoker:
                     activation = await asyncio.wait_for(
                         asyncio.shield(promise), min(interval, remaining))
                     return InvokeOutcome(activation, msg.activation_id,
-                                         accepted=False)
+                                         accepted=False, polls=polls)
                 except asyncio.TimeoutError:
                     pass
                 except Exception:  # noqa: BLE001 — forced timeout etc: polls remain
@@ -170,18 +196,22 @@ class ActionInvoker:
                 await asyncio.sleep(min(interval, remaining))
             if time.monotonic() >= deadline:
                 break  # the post-loop poll is the single final one
+            polls += 1
             try:
                 activation = await self.activation_store.get(
                     str(identity.namespace.name), msg.activation_id)
                 return InvokeOutcome(activation, msg.activation_id,
-                                     accepted=False)
+                                     accepted=False, polls=polls)
             except NoDocumentException:
                 pass
             interval = min(interval * 2, POLL_INTERVAL_MAX)
         # window closed: one last poll, then hand back the activation id (202)
+        polls += 1
         try:
             activation = await self.activation_store.get(
                 str(identity.namespace.name), msg.activation_id)
-            return InvokeOutcome(activation, msg.activation_id, accepted=False)
+            return InvokeOutcome(activation, msg.activation_id,
+                                 accepted=False, polls=polls)
         except NoDocumentException:
-            return InvokeOutcome(None, msg.activation_id, accepted=True)
+            return InvokeOutcome(None, msg.activation_id, accepted=True,
+                                 polls=polls)

@@ -29,14 +29,13 @@ class EntityCache:
         self.misses = 0
 
     async def get_or_load(self, key: str, loader: Callable[[], Any]):
-        ent = self._entries.get(key)
-        now = time.monotonic()
-        if ent is not None and (ent[0] is None or ent[0] > now):
+        ent = self._live(key)
+        if ent is not None:
             self.hits += 1
             return await asyncio.shield(ent[1])
         self.misses += 1
         fut = asyncio.ensure_future(_call(loader))
-        expires = now + self.ttl if self.ttl else None
+        expires = time.monotonic() + self.ttl if self.ttl else None
         self._entries[key] = (expires, fut)
         if len(self._entries) > self.max_entries:
             # drop oldest-inserted entry (python dicts preserve order)
@@ -46,6 +45,26 @@ class EntityCache:
         except BaseException:
             self._entries.pop(key, None)
             raise
+
+    def settled(self, key: str, default: Any = None) -> Any:
+        """What `get_or_load(key, ...)` would return without suspending:
+        the value of a live entry whose load has finished, counted as a
+        hit; `default` where a lookup would have to wait or read."""
+        ent = self._live(key)
+        if ent is None:
+            return default
+        fut = ent[1]
+        if not fut.done() or fut.cancelled() or fut.exception() is not None:
+            return default
+        self.hits += 1
+        return fut.result()
+
+    def _live(self, key: str) -> Optional[tuple]:
+        """`key`'s entry (expiry, future) unless absent or expired."""
+        ent = self._entries.get(key)
+        if ent is None or (ent[0] is not None and ent[0] <= time.monotonic()):
+            return None
+        return ent
 
     def update(self, key: str, value: Any) -> None:
         fut: asyncio.Future = asyncio.get_event_loop().create_future()

@@ -8,7 +8,7 @@ the controller's view of persistence (SURVEY §3.5).
 from __future__ import annotations
 
 import uuid
-from typing import Callable, List, Optional, Type
+from typing import Any, Callable, List, Optional, Type
 
 from ..core.entity import (Identity, WhiskAction, WhiskActivation, WhiskEntity,
                            WhiskAuthRecord, WhiskPackage, WhiskRule, WhiskTrigger)
@@ -37,6 +37,13 @@ def _rev_older_than(cached: Optional[str], routed: str) -> bool:
         return int((cached or "0").split("-", 1)[0]) < int(routed.split("-", 1)[0])
     except (ValueError, AttributeError):
         return True
+
+
+def _with_key(ident: Optional[Identity], key: str) -> Optional[Identity]:
+    """`ident` where its auth key is `key`, else None."""
+    if ident is not None and ident.authkey.key.asString == key:
+        return ident
+    return None
 
 
 class EntityStore:
@@ -125,6 +132,11 @@ class EntityStore:
             return ent
         return await load()
 
+    def cached(self, doc_id: str) -> Optional[WhiskEntity]:
+        """What `get(cls, doc_id)` returns without suspending: the cached
+        entity once its load has settled, else None."""
+        return self.cache.settled(doc_id)
+
     async def get_action(self, doc_id: str, rev: Optional[str] = None
                          ) -> WhiskAction:
         return await self.get(WhiskAction, doc_id, rev=rev)
@@ -185,11 +197,15 @@ class AuthStore:
             self.cache.update(f"ns/{ident.namespace.name}", ident)
 
     async def identity_by_key(self, uuid: str, key: str) -> Optional[Identity]:
-        ident = await self._find("uuid/" + uuid,
-                                 lambda i: i.authkey.uuid.asString == uuid)
-        if ident is not None and ident.authkey.key.asString == key:
-            return ident
-        return None
+        return _with_key(await self._find(
+            "uuid/" + uuid, lambda i: i.authkey.uuid.asString == uuid), key)
+
+    def identity_by_key_now(self, uuid: str, key: str, unsettled: Any = None
+                            ) -> Optional[Identity]:
+        """`identity_by_key` where the cache answers without suspending;
+        `unsettled` where the store has to be read."""
+        ident = self.cache.settled("uuid/" + uuid, unsettled)
+        return unsettled if ident is unsettled else _with_key(ident, key)
 
     async def identity_by_namespace(self, namespace: str) -> Optional[Identity]:
         return await self._find("ns/" + namespace,

@@ -16,7 +16,7 @@ from typing import Awaitable, Callable, List, Optional, Tuple
 
 from ..utils.hostprof import GLOBAL_HOST_OBSERVATORY
 from ..utils.transaction import TransactionId
-from ..utils.waterfall import GLOBAL_WATERFALL, STAGE_PRODUCE
+from ..utils.waterfall import GLOBAL_WATERFALL, STAGE_PRODUCE, span
 from .columnar import batch_hop_of, make_batch, parse_batch
 
 #: serde hop labels by message class name: the controller->invoker
@@ -251,9 +251,13 @@ class MessageFeed:
                 batch = await self.consumer.peek(self._free, self.long_poll_timeout)
                 if not batch:
                     continue
-                # commit BEFORE handling: at-most-once hand-off, exactly as
-                # the reference (MessageConsumer.scala:179-190).
-                self.consumer.commit()
+                # one `ow_feed` a wake that brought work (`n` messages):
+                # their count over a window's activations is the feeds'
+                # wakes per activation. Commit BEFORE handling: at-most-once
+                # hand-off, exactly as the reference
+                # (MessageConsumer.scala:179-190).
+                with span("ow_feed", n=len(batch)):
+                    self.consumer.commit()
                 for _topic, _part, _offset, payload in batch:
                     self._free -= 1
                     try:

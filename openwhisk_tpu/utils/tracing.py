@@ -5,11 +5,11 @@ a per-transid stack of spans; the active span's context serializes into
 `ActivationMessage.trace_context` (W3C traceparent style) and is restored on
 the invoker side, so traces survive the bus hop (Message.scala:61,
 InvokerReactive.scala:224). Finished spans go to a pluggable reporter:
-in-memory buffer by default, `ZipkinReporter` (Zipkin v2 JSON over HTTP,
-the reference's reporting backend, OpenTracingProvider.scala:43-160 +
-application.conf:461-476) when CONFIG_whisk_tracing_zipkinUrl is set —
-see `maybe_enable_zipkin`. Span caches expire so abandoned transactions
-don't leak.
+by default a sink that counts them and keeps none, `ZipkinReporter`
+(Zipkin v2 JSON over HTTP, the reference's reporting backend,
+OpenTracingProvider.scala:43-160 + application.conf:461-476) when
+CONFIG_whisk_tracing_zipkinUrl is set — see `maybe_enable_zipkin`. Span
+caches expire so abandoned transactions don't leak.
 """
 from __future__ import annotations
 
@@ -47,24 +47,17 @@ class Reporter:
         raise NotImplementedError
 
 
-class BufferReporter(Reporter):
-    """In-memory span sink, ring-shaped: a full buffer evicts the OLDEST
-    span so the NEWEST always survive — on a long soak the buffer tracks
-    live traffic instead of fossilizing at startup spans. Evictions count
-    as `dropped_spans` (like ZipkinReporter), so a saturated buffer stays
-    visible to tests/operators instead of silently lossy."""
+class CountingReporter(Reporter):
+    """The default sink: counts the finished spans and keeps none. What
+    reads spans is Zipkin (`maybe_enable_zipkin` swaps it in) or the
+    trace store's tail-sampling tee, which sees every span before the
+    sink does; a sink that kept them would only hold objects nobody
+    reads. `sent_spans` feeds `tracing_spans_sent`."""
 
-    def __init__(self, max_spans: int = 10_000):
-        from collections import deque
-        self.spans = deque(maxlen=max(1, max_spans))
-        self.max_spans = max_spans
+    def __init__(self):
         self.sent_spans = 0
-        self.dropped_spans = 0
 
     def report(self, span: Span) -> None:
-        if len(self.spans) >= self.max_spans:
-            self.dropped_spans += 1
-        self.spans.append(span)
         self.sent_spans += 1
 
 
@@ -211,7 +204,7 @@ class Tracer:
 
     def __init__(self, reporter: Optional[Reporter] = None,
                  expiry_seconds: float = 3600.0):
-        self.reporter = reporter or BufferReporter()
+        self.reporter = reporter or CountingReporter()
         self.expiry = expiry_seconds
         #: opportunistic-sweep cadence: a fraction of the expiry so small
         #: populations of abandoned stacks (below the size trigger) still

@@ -147,15 +147,19 @@ def span(name: str, **counts):
     so the program's spans and the device's ops land in one `.xplane.pb`.
     Tracing is on exactly while a profiler session runs (`benchmark/run.py
     --trace 1`, an operator's capture with `trace_dir`); otherwise a span
-    is one object construction and an inactive TraceMe. `counts` (integers
-    already at hand) become the event's stats; one known only at the end
-    goes through `set_metadata` on the entered span. Per thread, so never
+    is one `TraceMe.is_enabled()` call and the shared null span. `counts`
+    (integers already at hand) become the event's stats; one known only at
+    the end goes through `set_metadata` on the entered span. Per thread, so never
     around an `await`: a suspended coroutine would cover other tasks'
     time. Names start `ow_`."""
     jax = sys.modules.get("jax")
     if jax is None:
         return _NO_SPAN
-    return jax.profiler.TraceAnnotation(name, **counts)
+    annotation = jax.profiler.TraceAnnotation
+    if not annotation.is_enabled():
+        # no session: a TraceMe would record nothing; this skips building it
+        return _NO_SPAN
+    return annotation(name, **counts)
 
 
 class ActivationWaterfall:

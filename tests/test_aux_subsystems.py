@@ -20,8 +20,10 @@ from openwhisk_tpu.database.file_activation_store import (
 from openwhisk_tpu.invoker.blacklist import NamespaceBlacklist
 from openwhisk_tpu.messaging import EventMessage, MemoryMessagingProvider
 from openwhisk_tpu.controller.monitoring import UserEventsRecorder
-from openwhisk_tpu.utils.tracing import Tracer
+from openwhisk_tpu.utils.tracing import CountingReporter, Tracer
 from openwhisk_tpu.utils.transaction import TransactionId
+
+from tests.span_buffer import BufferReporter
 
 
 def run(coro):
@@ -30,7 +32,7 @@ def run(coro):
 
 class TestTracing:
     def test_span_hierarchy_and_report(self):
-        tracer = Tracer()
+        tracer = Tracer(BufferReporter())
         tid = TransactionId()
         parent = tracer.start_span("controller_activation", tid)
         child = tracer.start_span("loadbalancer_schedule", tid)
@@ -42,6 +44,15 @@ class TestTracing:
         assert [s.name for s in spans] == ["loadbalancer_schedule",
                                            "controller_activation"]
         assert spans[1].tags["action"] == "ns/a"
+
+    def test_the_default_sink_counts_spans_and_keeps_none(self):
+        tracer = Tracer()
+        assert isinstance(tracer.reporter, CountingReporter)
+        for i in range(3):
+            tid = TransactionId()
+            tracer.start_span("controller_activation", tid)
+            tracer.finish_span(tid, tags={"n": str(i)})
+        assert vars(tracer.reporter) == {"sent_spans": 3}   # nothing kept
 
     def test_context_survives_the_bus(self):
         t_controller, t_invoker = Tracer(), Tracer()

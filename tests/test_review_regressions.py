@@ -72,7 +72,8 @@ def test_invalidation_feed_capacity_not_inflated_by_bad_payloads():
 def test_tracer_concurrent_spans_same_transid_finish_their_own():
     """finish_span(span=...) must close the given span even when a later
     concurrent span sits above it on the per-transid stack."""
-    from openwhisk_tpu.utils.tracing import BufferReporter, Tracer
+    from openwhisk_tpu.utils.tracing import Tracer
+    from tests.span_buffer import BufferReporter
 
     rep = BufferReporter()
     tr = Tracer(reporter=rep)

@@ -11,11 +11,15 @@ No assertion here is on a time.
 from __future__ import annotations
 
 import asyncio
+import base64
 import gc
 import glob
+import itertools
+import json
 import os
 import sys
 import tracemalloc
+import types
 
 import pytest
 
@@ -53,6 +57,9 @@ SPANS = {
     "ow_timeout_fire", "ow_gc"}
 N_INVOKERS = 4
 BATCHES = (5, 9, 3)
+#: how long the echo invokers hold the action named `slow` before its ack:
+#: past `invoke.POLL_INTERVAL_MIN`, so a blocking wait polls the store
+SLOW_S = 0.25
 
 
 def _action(name: str) -> ExecutableWhiskAction:
@@ -72,7 +79,7 @@ def _msg(action, ident) -> ActivationMessage:
 
 def _echo_invoker(provider, instance, service_s: float = 0.0) -> MessageFeed:
     """Acks every activation, at once or `service_s` later, except the
-    action named `lost`."""
+    action named `lost`, and the action named `slow` `SLOW_S` later."""
     topic = instance.as_string
     provider.ensure_topic(topic)
     producer = maybe_coalesce(provider.get_producer())
@@ -94,10 +101,12 @@ def _echo_invoker(provider, instance, service_s: float = 0.0) -> MessageFeed:
             msgs = [decode_message(ActivationMessage.parse, payload,
                                    "activation")]
         for msg in msgs:
-            if str(msg.action.name) == "lost":
+            name = str(msg.action.name)
+            if name == "lost":
                 continue
-            if service_s > 0:
-                asyncio.get_running_loop().call_later(service_s, ack, msg)
+            delay = SLOW_S if name == "slow" else service_s
+            if delay > 0:
+                asyncio.get_running_loop().call_later(delay, ack, msg)
             else:
                 ack(msg)
         box["feed"].processed()
@@ -528,3 +537,341 @@ def test_the_served_path_makes_no_reference_cycles():
 
     unreachable = asyncio.run(go())
     assert unreachable < 0.1 * ACTIVATIONS, unreachable
+
+
+# -- ISSUE 39: the front door's spans and the feeds' wakes -------------------
+
+#: the spans every REST invoke makes, each with the request's `req`
+DOOR_SPANS = ("ow_http_auth", "ow_http_entitle", "ow_http_body",
+              "ow_http_resolve", "ow_invoke", "ow_invoke_done",
+              "ow_http_respond")
+#: a fixed credential and namespace: the answers are the same every run
+DOOR_KEY = "0c0ffee0-0000-4000-8000-000000000039:" + "39" * 32
+DOOR_NS = "guest-door"
+NOOP_CALLS, SLOW_CALLS = 8, 2
+#: the parent commit's answers to DOOR_SCRIPT (PR 38's tree, tracing off)
+ANSWERS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                       "fixtures", "frontdoor_answers_pr38.json")
+
+
+def _invoke_path(name: str, **query) -> str:
+    q = {"blocking": "true", **query}
+    return (f"/api/v1/namespaces/_/actions/{name}?"
+            + "&".join(f"{k}={v}" for k, v in q.items()))
+
+
+def _http(method: str, path: str, body=None, key=DOOR_KEY) -> bytes:
+    """One HTTP/1.1 keep-alive request, byte for byte."""
+    head = [f"{method} {path} HTTP/1.1", "Host: 127.0.0.1"]
+    if key is not None:
+        head.append("Authorization: Basic "
+                    + base64.b64encode(key.encode()).decode())
+    data = b""
+    if body is not None:
+        data = body if isinstance(body, bytes) else json.dumps(body).encode()
+        head += ["Content-Type: application/json",
+                 f"Content-Length: {len(data)}"]
+    return ("\r\n".join(head) + "\r\n\r\n").encode() + data
+
+
+async def _exchange(conn, raw: bytes) -> tuple:
+    """Send one request on a connection; read its whole answer."""
+    reader, writer = conn
+    writer.write(raw)
+    await writer.drain()
+    status = int((await reader.readline()).split()[1])
+    headers = {}
+    while (line := await reader.readline()) not in (b"\r\n", b""):
+        k, _, v = line.decode().partition(":")
+        headers[k.strip().lower()] = v.strip()
+    body = await reader.readexactly(int(headers.get("content-length", 0)))
+    return status, headers, body
+
+
+#: requests whose answers must not change by a byte: invokes blocking, for
+#: the result, without a body, non-blocking; a malformed body and one that
+#: is no UTF-8; an action that does not exist; a wrong key, a key no store
+#: holds (read from the store, outside any span), no key; a public path
+DOOR_SCRIPT = [
+    ("POST", _invoke_path("noop"), {"a": 1}, DOOR_KEY),
+    ("POST", _invoke_path("noop"), {"a": 2, "b": [1, "x"]}, DOOR_KEY),
+    ("POST", _invoke_path("noop", result="true"), {"x": "y"}, DOOR_KEY),
+    ("POST", _invoke_path("noop"), None, DOOR_KEY),
+    ("POST", _invoke_path("noop", blocking="false"), {}, DOOR_KEY),
+    ("POST", _invoke_path("noop"), b"{not json", DOOR_KEY),
+    ("POST", _invoke_path("noop"), b"\xff\xfe{}", DOOR_KEY),
+    ("POST", _invoke_path("nosuch"), {}, DOOR_KEY),
+    ("POST", _invoke_path("noop"), {}, DOOR_KEY.split(":")[0] + ":wrong"),
+    ("POST", _invoke_path("noop"), {},
+     "0c0ffee0-0000-4000-8000-00000000dead:nokey"),
+    ("POST", _invoke_path("noop"), {}, None),
+    ("GET", "/ping", None, None),
+]
+
+
+def _answer(status: int, headers: dict, body: bytes) -> list:
+    """What is compared of an answer: all but `Date` and `Server`."""
+    return [status, {k: v for k, v in sorted(headers.items())
+                     if k not in ("date", "server")}, body.decode()]
+
+
+async def _door(trace_dir=None) -> dict:
+    """A toy of the REST front door on the CPU twin: the program's
+    `Controller` over in-memory stores around a TpuBalancer and
+    N_INVOKERS echo invokers, served as `benchmark/frontdoor.py` serves
+    it; the actions `noop` and `slow` created over the API. Answers
+    DOOR_SCRIPT with tracing off (ids made deterministic). With
+    `trace_dir`, then traces one round of NOOP_CALLS + SLOW_CALLS
+    concurrent blocking invokes, each on a keep-alive connection of its
+    own, after one round like it untraced (every bucket compiled)."""
+    import jax
+
+    from openwhisk_tpu.controller.core import Controller
+    from openwhisk_tpu.core.entity import (BasicAuthenticationAuthKey,
+                                           ExecManifest, Namespace, Subject,
+                                           WhiskAuthRecord,
+                                           limits_from_config)
+    from openwhisk_tpu.core.entity import entity as entity_module
+    from openwhisk_tpu.messaging.memory import MemoryConsumer
+    from openwhisk_tpu.utils import transaction
+    from openwhisk_tpu.utils.logging import Logging
+
+    ExecManifest.initialize(None)
+    limits_from_config()
+    # every wake of a MessageFeed is a non-empty peek of its consumer:
+    # counted from the first peek on, while `wakes[1]` holds
+    wakes = [0, False]
+    real_peek = MemoryConsumer.peek
+
+    async def peek(self, *args, **kwargs):
+        batch = await real_peek(self, *args, **kwargs)
+        wakes[0] += bool(batch and wakes[1])
+        return batch
+    counting = pytest.MonkeyPatch()
+    counting.setattr(MemoryConsumer, "peek", peek)
+    provider = MemoryMessagingProvider()
+    bal = _plain_balancer(provider)
+    await bal.start()
+    feeds, _ping = await _healthy_fleet(provider, bal)
+    controller = Controller(
+        ControllerInstanceId("0"), provider, logger=Logging(level="warn"),
+        load_balancer=bal, invocations_per_minute=10 ** 6,
+        concurrent_invocations=10 ** 4, fires_per_minute=10 ** 6)
+    key = BasicAuthenticationAuthKey.parse(DOOR_KEY)
+    await controller.auth_store.put(WhiskAuthRecord(
+        Subject(DOOR_NS), [Namespace(EntityName(DOOR_NS), key.uuid)], [key]))
+
+    async def serving() -> None:
+        pass
+    # the balancer serves already: `Controller.start` must not start it
+    bal.start = serving
+    try:
+        await controller.start("127.0.0.1", 0)
+    finally:
+        del bal.start
+    port = controller._runner.addresses[0][1]
+    out: dict = {}
+    patch = pytest.MonkeyPatch()
+    conns = []
+    try:
+        conns.append(await asyncio.open_connection("127.0.0.1", port))
+        for name in ("noop", "slow"):
+            status, _h, body = await _exchange(conns[0], _http(
+                "PUT", f"/api/v1/namespaces/_/actions/{name}",
+                {"exec": {"kind": "python:3", "code": "def main(a): return a"},
+                 "limits": {"memory": 256}}))
+            assert status == 200, body
+        patch.setattr(transaction, "_counter", itertools.count(1))
+        ids = itertools.count(1)
+        patch.setattr(ActivationId, "generate", classmethod(
+            lambda cls: cls.of_hex(f"{next(ids):032x}")))
+        # an entity's `updated` is the wall clock where the record is made
+        patch.setattr(entity_module, "time",
+                      types.SimpleNamespace(time=lambda: 39.0))
+        out["answers"] = [_answer(*await _exchange(conns[0], _http(*step)))
+                          for step in DOOR_SCRIPT]
+        patch.undo()
+        if trace_dir is None:
+            return out
+        bodies = ([("noop", {"i": i}) for i in range(NOOP_CALLS)]
+                  + [("slow", {"slow": i}) for i in range(SLOW_CALLS)])
+        reqs = [_http("POST", _invoke_path(name), body)
+                for name, body in bodies]
+        bodies = [body for _name, body in bodies]
+        conns += [await asyncio.open_connection("127.0.0.1", port)
+                  for _ in reqs[1:]]
+        await asyncio.gather(*(_exchange(c, r) for c, r in zip(conns, reqs)))
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(trace_dir, profiler_options=opts)
+        wakes[1] = True
+        try:
+            got = await asyncio.gather(*(_exchange(c, r)
+                                         for c, r in zip(conns, reqs)))
+        finally:
+            wakes[1] = False
+            jax.profiler.stop_trace()
+        out.update(statuses=[g[0] for g in got], wakes=wakes[0],
+                   bodies=sum(len(json.dumps(b).encode()) for b in bodies),
+                   slow_body=len(json.dumps({"slow": 0})))
+        return out
+    finally:
+        patch.undo()
+        for _reader, writer in conns:
+            writer.close()
+        await controller.stop()      # closes the balancer
+        for f in feeds:
+            await f.stop()
+        counting.undo()
+
+
+@pytest.fixture(scope="module")
+def door(tmp_path_factory):
+    from openwhisk_tpu.core.entity import ConcurrencyLimit, MemoryLimit
+    was = (MemoryLimit.MIN, MemoryLimit.STD, MemoryLimit.MAX,
+           ConcurrencyLimit.MIN, ConcurrencyLimit.STD, ConcurrencyLimit.MAX)
+    trace = str(tmp_path_factory.mktemp("door") / "trace")
+    try:
+        out = asyncio.run(asyncio.wait_for(_door(trace), 300.0))
+    finally:
+        (MemoryLimit.MIN, MemoryLimit.STD, MemoryLimit.MAX,
+         ConcurrencyLimit.MIN, ConcurrencyLimit.STD,
+         ConcurrencyLimit.MAX) = was
+    lines = _host_lines(trace)
+    (loop,) = [line for line in lines
+               if any(name == "ow_http_auth" for name, *_ in line)]
+    out.update(lines=lines, loop=loop)
+    return out
+
+
+def _by_req(loop) -> dict:
+    got: dict = {}
+    for name, _s, _e, st in loop:
+        if "req" in st:
+            got.setdefault(st["req"], []).append((name, st))
+    return got
+
+
+def test_every_request_has_the_seven_spans_under_one_req(door):
+    assert door["statuses"] == [200] * (NOOP_CALLS + SLOW_CALLS)
+    by_req = _by_req(door["loop"])
+    # one `req` a request, a different one for each
+    assert len(by_req) == NOOP_CALLS + SLOW_CALLS
+    for req, spans in by_req.items():
+        names = [name for name, _st in spans]
+        assert set(names) >= set(DOOR_SPANS), (req, names)
+        for once in ("ow_http_body", "ow_invoke", "ow_invoke_done"):
+            assert names.count(once) == 1, (req, names)
+        # the answer's own span knows its size; the CORS headers' does not
+        assert [st["bytes"] for name, st in spans
+                if name == "ow_http_respond" and "bytes" in st][0] > 0
+    # the admission plane's flushes carry the checks they resolve
+    flushes = [st["n"] for line in door["lines"] for name, _s, _e, st in line
+               if name == "ow_http_entitle" and "n" in st]
+    assert flushes and sum(flushes) == NOOP_CALLS + SLOW_CALLS
+
+
+def test_the_body_span_counts_every_body_byte_the_clients_sent(door):
+    """`ow_http_body`'s `bytes` are the request bodies, byte for byte; no
+    front-door span runs off the event loop's thread. aiohttp's own parse
+    of the bytes has no span (no public seam: PERF.md section 7)."""
+    sizes = [st["bytes"] for name, _s, _e, st in door["loop"]
+             if name == "ow_http_body"]
+    assert len(sizes) == NOOP_CALLS + SLOW_CALLS
+    assert sum(sizes) == door["bodies"]
+    assert all(not name.startswith(("ow_http", "ow_invoke"))
+               for line in door["lines"] if line is not door["loop"]
+               for name, *_ in line)
+
+
+def test_a_held_promise_is_polled(door):
+    """`ow_invoke_done` carries the store polls of the wait: the two `slow`
+    invokes (acked SLOW_S = 0.25 s late, past POLL_INTERVAL_MIN) poll at
+    least once; `test_the_wait_counts_its_polls` pins the fast case."""
+    spans = [dict(s) for s in _by_req(door["loop"]).values()]
+    assert all("polls" in st["ow_invoke_done"] for st in spans)
+    slow = [st for st in spans
+            if st["ow_http_body"]["bytes"] == door["slow_body"]]
+    assert len(slow) == SLOW_CALLS
+    assert all(st["ow_invoke_done"]["polls"] >= 1 for st in slow)
+
+
+def test_every_feed_wake_is_one_feed_span(door):
+    feeds = [st for line in door["lines"] for name, _s, _e, st in line
+             if name == "ow_feed"]
+    assert door["wakes"] > 0 and len(feeds) == door["wakes"]
+    assert all(st["n"] >= 1 for st in feeds)
+    assert all(name != "ow_feed" for line in door["lines"]
+               if line is not door["loop"] for name, *_ in line)
+
+
+def test_the_front_door_s_spans_overlap_only_by_nesting(door):
+    stack = []
+    for name, start, end, _st in sorted(door["loop"],
+                                        key=lambda e: (e[1], -e[2])):
+        while stack and stack[-1][1] <= start:
+            stack.pop()
+        if stack:
+            assert end <= stack[-1][1], (name, stack[-1][0])
+        stack.append((name, end))
+
+
+def test_tracing_off_the_answers_are_the_parent_s_byte_for_byte(door):
+    with open(ANSWERS) as f:
+        assert door["answers"] == json.load(f)
+
+
+@pytest.mark.parametrize("held", [False, True], ids=["fast", "held"])
+def test_the_wait_counts_its_polls(tmp_path, held):
+    """`ActionInvoker.invoke` over a balancer whose promise is resolved at
+    once, or SLOW_S later, and a store that never holds the record: the
+    outcome's `polls` and the `ow_invoke_done` span's are the store's
+    count, 0 for the fast promise; `ow_invoke` and it carry `req`."""
+    import jax
+
+    from openwhisk_tpu.controller.invoke import POLL_INTERVAL_MIN, ActionInvoker
+    from openwhisk_tpu.core.entity import Parameters
+    from openwhisk_tpu.database import NoDocumentException
+
+    class Store:
+        polls = 0
+
+        async def get(self, namespace, activation_id):
+            Store.polls += 1
+            raise NoDocumentException(str(activation_id))
+
+    class Balancer:
+        async def publish(self, action, msg):
+            fut = asyncio.get_running_loop().create_future()
+            if held:
+                asyncio.get_running_loop().call_later(
+                    SLOW_S, fut.set_result, "done")
+            else:
+                fut.set_result("done")
+            return fut
+
+    assert SLOW_S > POLL_INTERVAL_MIN
+
+    async def go():
+        inv = ActionInvoker(None, Store(), Balancer(),
+                            ControllerInstanceId("0"))
+        return await inv.invoke(Identity.generate("guest"), _action("a"),
+                                Parameters(), {"k": 1}, blocking=True,
+                                req=39)
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        outcome = asyncio.run(go())
+    finally:
+        jax.profiler.stop_trace()
+    assert outcome.activation == "done" and not outcome.accepted
+    assert outcome.polls == Store.polls
+    assert (outcome.polls >= 1) if held else (outcome.polls == 0)
+    spans = {name: st for line in _host_lines(str(tmp_path))
+             for name, _s, _e, st in line}
+    assert spans["ow_invoke"] == {"req": 39}
+    assert spans["ow_invoke_done"] == {"req": 39, "polls": outcome.polls}

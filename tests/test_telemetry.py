@@ -430,7 +430,8 @@ class TestSatellites:
         # the old behavior (drop new, keep stale) made the buffer useless
         # after the first `max_spans` reports. sent counts every report
         # that reached the buffer; dropped counts the evictions.
-        from openwhisk_tpu.utils.tracing import BufferReporter, Span
+        from openwhisk_tpu.utils.tracing import Span
+        from tests.span_buffer import BufferReporter
         rep = BufferReporter(max_spans=2)
         for i in range(5):
             rep.report(Span("t", f"s{i}", None, "op", 0.0, end=1.0))

@@ -33,7 +33,9 @@ from openwhisk_tpu.utils.tracestore import (GLOBAL_TRACE_STORE, REASONS,
                                             TraceStore, TraceTailConfig,
                                             _TeeReporter, assemble_trace,
                                             synthetic_span, tail_config)
-from openwhisk_tpu.utils.tracing import (BufferReporter, Tracer, trace_id_of)
+from openwhisk_tpu.utils.tracing import Tracer, trace_id_of
+
+from tests.span_buffer import BufferReporter
 from openwhisk_tpu.utils.waterfall import (N_STAGES, STAGE_API_ACCEPT,
                                            STAGE_COMPLETION_ACK,
                                            STAGE_INVOKER_PICKUP,
