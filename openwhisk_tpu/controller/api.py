@@ -18,20 +18,20 @@ from __future__ import annotations
 import asyncio
 import itertools
 import json
-from typing import Optional
+from typing import Optional, Tuple
 
 from aiohttp import web
 
 from ..core.entity import (ACTIVE, ActivationId, Binding, EntityName,
                            EntityPath, Exec, ExecManifest, Identity,
                            LimitViolation, MalformedEntity, MemoryLimit,
-                           Parameters,
-                           ReducedRule, SequenceExec, TimeLimit, WhiskAction,
-                           WhiskActivation, WhiskPackage, WhiskRule,
-                           WhiskTrigger)
+                           Parameters, ReducedRule, SUCCESS, SequenceExec,
+                           TimeLimit, WhiskAction, WhiskActivation,
+                           WhiskPackage, WhiskRule, WhiskTrigger)
 from ..core.entity.action import ActionLimits
 from ..core.entity.names import FullyQualifiedEntityName
 from ..database import DocumentConflict, NoDocumentException
+from ..messaging.columnar import LazyWhiskActivation
 from ..utils.transaction import TransactionId
 from ..utils.waterfall import span
 from .authentication import UNSETTLED
@@ -59,6 +59,26 @@ def _error(status: int, message: str, transid: Optional[TransactionId] = None
     return web.json_response({"error": message,
                               "code": transid.id if transid else None},
                              status=status)
+
+
+def _record_answer(activation, result_only: bool
+                   ) -> Tuple[web.Response, int]:
+    """A blocking invoke's 200 or 502, and 1 where its body is the record's
+    bytes as the invoker framed them, 0 where the record was parsed. A
+    record that came in an ack frame and nobody has read is answered as it
+    is: its status is the frame's, so no parse, no entity and no dump (its
+    `updated`, `start` and `end` are the invoker's own). `?result=true`, a
+    record polled from the store or from the serial wire, and one already
+    parsed are answered from the entity, as `json_response` dumps it."""
+    if (not result_only and isinstance(activation, LazyWhiskActivation)
+            and not activation.materialized):
+        return web.Response(
+            body=activation.raw,
+            status=200 if activation.status_code == SUCCESS else 502,
+            content_type="application/json", charset="utf-8"), 1
+    return web.json_response(
+        activation.resulting_json() if result_only else activation.to_json(),
+        status=200 if activation.response.is_success else 502), 0
 
 
 def _amend_annotations(annotations: Parameters, exec_: Exec,
@@ -1509,16 +1529,12 @@ class ControllerApi:
                 waterfall_ctx=wf_ctx, req=req)
         with span("ow_http_respond", req=req) as answer:
             if outcome.accepted:
-                resp = web.json_response(
+                resp, raw = web.json_response(
                     {"activationId": outcome.activation_id.asString},
-                    status=202)
+                    status=202), 0
             else:
-                activation = outcome.activation
-                status = 200 if activation.response.is_success else 502
-                resp = web.json_response(
-                    activation.resulting_json() if result_only
-                    else activation.to_json(), status=status)
-            answer.set_metadata(bytes=len(resp.body))
+                resp, raw = _record_answer(outcome.activation, result_only)
+            answer.set_metadata(bytes=len(resp.body), raw=raw)
         return resp
 
     # ---------------------------------------------------------- activations

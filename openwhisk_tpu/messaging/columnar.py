@@ -14,7 +14,7 @@ same table):
     offset  size     field
     0       3        magic `\\xff O W`: 0xFF starts no UTF-8 text, so no
                      JSON payload can begin with it
-    3       1        version (1)
+    3       1        version (2)
     4       1        family: 1 = activation, 2 = ack
     5       4        n, the row count (uint32)
     9       2+2+2    the sizes of the three dedup tables (uint16 each):
@@ -39,7 +39,9 @@ same table):
 
     activation flags: bit 0 blocking
     ack flags: bits 0-1 the kind (0 completion, 1 result, 2 combined);
-      bit 2 isSystemError
+      bit 2 isSystemError; bits 4-5 the response's `statusCode` (0..3;
+      0 where the row carries no record). Version 1 had no status bits,
+      so its frames are refused, never read as successes
     both: bit 3 the id is not 32 lowercase hex and sits in the sparse
       `ids` column (its 16 bytes are zero)
 
@@ -61,7 +63,9 @@ namespace whose limits changed, a new revision) is parsed anew.
 The opaque body is the user's own data as JSON bytes: an activation's
 `content` (`{}` is two bytes and no `dumps`), an ack's response record.
 The response is parsed only when somebody reads it
-(`LazyWhiskActivation`); the completion loop needs the columns alone.
+(`LazyWhiskActivation`, which carries the row's status code beside the
+bytes); the completion loop needs the columns alone, and a blocking
+answer the bytes and the status.
 Sparse columns: `cause`, `trace`, `init` (non-empty `initArgs`),
 `fence` / `fences`, `fpart` / `fparts` on activations, `trace` on acks,
 `ids` on both.
@@ -95,7 +99,7 @@ from .message import AcknowledgementMessage, ActivationMessage, Message
 
 #: a frame's first three bytes
 WIRE_MAGIC = b"\xffOW"
-WIRE_VERSION = 1
+WIRE_VERSION = 2
 #: the funnel's JSON records lead with this key (json.dumps preserves
 #: insertion order, so it is a stable byte prefix)
 BATCH_MAGIC = b'{"whiskBatch":'
@@ -133,6 +137,8 @@ _BLOCKING = 1
 _ACK_KIND_MASK = 3
 _ACK_SYSTEM_ERROR = 4
 _ID_SPARSE = 8
+#: ack flags bits 4-5: the response's statusCode
+_ACK_STATUS_SHIFT = 4
 _ACK_KINDS = ("completion", "result", "combined")
 _ACK_CODES = {kind: code for code, kind in enumerate(_ACK_KINDS)}
 
@@ -499,12 +505,15 @@ class LazyWhiskActivation:
     the first attribute access, which for a blocking invoke happens on
     the API handler's own turn and for a fire-and-forget ack happens
     never. The deferred parse books its bytes + wall time under the
-    `ack_result` serde hop, so skipped parses are a measurable zero."""
+    `ack_result` serde hop, so skipped parses are a measurable zero.
+    `status_code` is the response's, from the row's flags: what a
+    blocking answer needs to choose 200 or 502 without the parse."""
 
-    __slots__ = ("raw", "_obj")
+    __slots__ = ("raw", "status_code", "_obj")
 
-    def __init__(self, raw: bytes):
+    def __init__(self, raw: bytes, status_code: int):
         self.raw = raw
+        self.status_code = status_code
         self._obj = None
 
     @property
@@ -564,17 +573,18 @@ class AckFrame(WireFrame):
     code = 2
 
     @staticmethod
-    def _body(m: AcknowledgementMessage) -> bytes:
-        """One row's opaque response record. A still-raw relay (a
-        LazyWhiskActivation nobody parsed) passes its bytes through
-        untouched: re-encoding an unread payload would be the very cost
-        the opaque column exists to skip."""
+    def _record(m: AcknowledgementMessage) -> Tuple[bytes, int]:
+        """One row's opaque response record and its status code. A
+        still-raw relay (a LazyWhiskActivation nobody parsed) passes its
+        bytes and its status through untouched: re-encoding an unread
+        payload would be the very cost the opaque column exists to
+        skip."""
         act = m.activation
         if act is None:
-            return b""
+            return b"", 0
         if isinstance(act, LazyWhiskActivation) and not act.materialized:
-            return act.raw
-        return _dumps(act.to_json()).encode()
+            return act.raw, act.status_code
+        return _dumps(act.to_json()).encode(), act.response.status_code
 
     def serialize(self) -> bytes:
         msgs = self.msgs
@@ -589,13 +599,13 @@ class AckFrame(WireFrame):
                     _blob_beside(m.invoker, InvokerInstanceId.to_json),
                     len(invokers)))
             tid = str(m.transid.id).encode()
-            body = self._body(m)
+            body, status = self._record(m)
             walls.append(m.transid.start_wallclock)
             tid_lens.append(len(tid))
             body_lens.append(len(body))
             heap.append(tid)
             heap.append(body)
-            flag = _ACK_CODES.get(m.kind, 2)
+            flag = _ACK_CODES.get(m.kind, 2) | status << _ACK_STATUS_SHIFT
             if m.is_system_error:
                 flag |= _ACK_SYSTEM_ERROR
             aid = _id_bytes(m.activation_id.asString)
@@ -619,13 +629,13 @@ class AckFrame(WireFrame):
     @staticmethod
     def decode(raw: bytes, header: tuple) -> List[AcknowledgementMessage]:
         """Decode WITHOUT touching a response byte beyond slicing: every
-        ack field comes from the columns (the system-error bit was
-        computed at encode time from the same response the serial parse
-        would re-derive it from), and each present response becomes a
-        LazyWhiskActivation over its slice. Building the base
-        AcknowledgementMessage directly, not the kind subclasses,
-        matters: ResultMessage reads activation_id off the activation
-        and CombinedCompletionAndResultMessage reads
+        ack field comes from the columns (the system-error bit and the
+        status code were computed at encode time from the same response
+        the serial parse would re-derive them from), and each present
+        response becomes a LazyWhiskActivation over its slice. Building
+        the base AcknowledgementMessage directly, not the kind
+        subclasses, matters: ResultMessage reads activation_id off the
+        activation and CombinedCompletionAndResultMessage reads
         response.is_whisk_error, either of which would force the parse
         this frame exists to defer."""
         (walls, flags, tid_lens, body_lens, iv_col, blobs, sparse, ids,
@@ -645,7 +655,9 @@ class AckFrame(WireFrame):
             ack = AcknowledgementMessage(
                 transid, aid, invokers[iv] if iv != _NO_INDEX else None,
                 bool(flag & _ACK_SYSTEM_ERROR),
-                LazyWhiskActivation(raw[end:off]) if off > end else None)
+                LazyWhiskActivation(raw[end:off],
+                                    flag >> _ACK_STATUS_SHIFT & 3)
+                if off > end else None)
             ack.kind = _ACK_KINDS[flag & _ACK_KIND_MASK]
             if trace:
                 ack.trace_context = trace.get(str(row))

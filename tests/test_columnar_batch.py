@@ -7,8 +7,13 @@ Covers the acceptance contracts:
     what the serial parsers make of the same messages' JSON (1, 2, 8 and
     64 rows, every optional column), every batch payload sniffs as one
     while plain payloads never do, and a frame is never taken for JSON;
+  * the record's status: ack flag bits 4-5 carry the response's
+    `statusCode`, a relay passes it on unparsed, and a blocking answer
+    is the record's framed bytes with the status the bits give; every
+    other record is answered from the entity as before;
   * the frame's edges: a truncated or garbled frame raises what the
-    feeds' handlers catch and nothing of it is applied; the decoder's
+    feeds' handlers catch and nothing of it is applied (a frame of
+    another version too); the decoder's
     tables are bounded and a blob that differs in one byte is parsed
     anew; the encoder keeps a blob exactly as long as its object; the
     gauges and the `interned` stat count what they say;
@@ -33,6 +38,9 @@ import time
 
 import pytest
 
+from aiohttp import web
+
+from openwhisk_tpu.controller.api import _record_answer
 from openwhisk_tpu.controller.entitlement import (ACTIVATE,
                                                   LocalEntitlementProvider,
                                                   ThrottleRejectRequest)
@@ -281,6 +289,74 @@ class TestBatchWireRecords:
             len(msgs[0].serialize())
 
 
+def _combined_ack(code: int):
+    """A combined ack whose record has the status `code`."""
+    ident = _ident()
+    msg = _act_msg(ident)
+    act = _activation(ident, msg)
+    act.response = ActivationResponse(code, {"ok": True} if code == 0
+                                      else {"error": f"status {code}"})
+    return CombinedCompletionAndResultMessage(
+        msg.transid, act, InvokerInstanceId(0, user_memory=MB(512)))
+
+
+class TestRecordStatus:
+    @pytest.mark.parametrize("code,http", [(0, 200), (1, 502), (2, 502),
+                                           (3, 502)])
+    def test_the_status_code_rides_ack_flag_bits_4_and_5(self, code, http):
+        raw = _frame(KIND_ACK, [_combined_ack(code)])
+        # a one-row ack frame of one invoker: the header, one blob length
+        # and one wall clock lie before its flags byte
+        flags = raw[columnar._HEADER.size + 4 + 8]
+        assert (flags & 3, flags >> 4) == (2, code)
+        _kind, (ack,) = parse_batch(raw)
+        lazy = ack.activation
+        assert lazy.status_code == code and not lazy.materialized
+        resp, framed = _record_answer(lazy, result_only=False)
+        assert (resp.status, framed, resp.body) == (http, 1, lazy.raw)
+        assert (resp.content_type, resp.charset) == ("application/json",
+                                                      "utf-8")
+        assert not lazy.materialized
+        # the parse, when somebody reads the record, agrees with the bits
+        assert lazy.response.status_code == code
+
+    def test_a_lazy_relay_passes_its_status_on_unparsed(self):
+        codes = [0, 3, 1, 2, 0]
+        raw = _frame(KIND_ACK, [_combined_ack(c) for c in codes])
+        _kind, acks = parse_batch(raw)
+        relayed = _frame(KIND_ACK, acks)
+        assert not any(a.activation.materialized for a in acks)
+        _kind, again = parse_batch(relayed)
+        assert [a.activation.status_code for a in again] == codes
+        assert [a.activation.raw for a in again] == \
+            [a.activation.raw for a in acks]
+
+    @pytest.mark.parametrize("code,http", [(0, 200), (2, 502)])
+    @pytest.mark.parametrize("case", ["result", "serial_wire", "store",
+                                      "parsed"])
+    def test_any_other_record_is_answered_as_before(self, case, code, http):
+        """`?result=true`, the serial wire's ack (`batchWire=false`), a
+        record polled from the store and a framed record somebody already
+        read: the entity's dump, as `json_response` made it before."""
+        ack = _combined_ack(code)
+        if case == "serial_wire":
+            record = parse_ack(ack.serialize()).activation
+        elif case == "store":
+            record = WhiskActivation.from_json(ack.activation.to_json())
+        else:
+            _kind, (decoded,) = parse_batch(_frame(KIND_ACK, [ack]))
+            record = decoded.activation
+            if case == "parsed":
+                assert record.activation_id == ack.activation_id
+        result_only = case == "result"
+        resp, framed = _record_answer(record, result_only)
+        before = web.json_response(
+            record.resulting_json() if result_only else record.to_json(),
+            status=http)
+        assert (resp.status, framed, resp.body) == (http, 0, before.body)
+        assert resp.headers["Content-Type"] == before.headers["Content-Type"]
+
+
 def _mutilations(raw: bytes):
     """(name, bytes) of frames that must not decode."""
     header = columnar._HEADER
@@ -299,7 +375,8 @@ def _mutilations(raw: bytes):
     yield "last byte gone", raw[:-1]
     yield "last row gone", raw[:-20]
     yield "a byte too many", raw + b"\x00"
-    yield "another version", with_header(version=2)
+    yield "the version before", with_header(version=columnar.WIRE_VERSION - 1)
+    yield "a later version", with_header(version=columnar.WIRE_VERSION + 1)
     yield "an unknown family", with_header(family=9)
     yield "more rows than it holds", with_header(n=fields[3] + 1)
     yield "fewer rows than it holds", with_header(n=fields[3] - 1)
@@ -335,7 +412,7 @@ class TestFrameEdges:
                 parse_batch(bad)
                 pytest.fail(f"{name}: decoded")
             seen += 1
-        assert seen == 14
+        assert seen == 15
 
     def test_a_corrupt_ack_frame_applies_none_of_its_acks(self):
         async def go():

@@ -549,6 +549,9 @@ DOOR_SPANS = ("ow_http_auth", "ow_http_entitle", "ow_http_body",
 DOOR_KEY = "0c0ffee0-0000-4000-8000-000000000039:" + "39" * 32
 DOOR_NS = "guest-door"
 NOOP_CALLS, SLOW_CALLS = 8, 2
+#: DOOR_SCRIPT's steps answered with an ack frame's record as it came:
+#: the blocking invokes of `noop` without `?result=true`
+FRAMED_STEPS = (0, 1, 3)
 #: the parent commit's answers to DOOR_SCRIPT (PR 38's tree, tracing off)
 ANSWERS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                        "fixtures", "frontdoor_answers_pr38.json")
@@ -632,6 +635,7 @@ async def _door(trace_dir=None) -> dict:
                                            WhiskAuthRecord,
                                            limits_from_config)
     from openwhisk_tpu.core.entity import entity as entity_module
+    from openwhisk_tpu.messaging import columnar
     from openwhisk_tpu.messaging.memory import MemoryConsumer
     from openwhisk_tpu.utils import transaction
     from openwhisk_tpu.utils.logging import Logging
@@ -688,6 +692,15 @@ async def _door(trace_dir=None) -> dict:
         # an entity's `updated` is the wall clock where the record is made
         patch.setattr(entity_module, "time",
                       types.SimpleNamespace(time=lambda: 39.0))
+        # every record the controller's ack frames brought, as decoded
+        real_decode = columnar.AckFrame.decode
+        out["framed"] = framed = []
+
+        def decode(raw, header):
+            acks = real_decode(raw, header)
+            framed.extend(a.activation for a in acks if a.activation)
+            return acks
+        patch.setattr(columnar.AckFrame, "decode", staticmethod(decode))
         out["answers"] = [_answer(*await _exchange(conns[0], _http(*step)))
                           for step in DOOR_SCRIPT]
         patch.undo()
@@ -763,9 +776,11 @@ def test_every_request_has_the_seven_spans_under_one_req(door):
         assert set(names) >= set(DOOR_SPANS), (req, names)
         for once in ("ow_http_body", "ow_invoke", "ow_invoke_done"):
             assert names.count(once) == 1, (req, names)
-        # the answer's own span knows its size; the CORS headers' does not
-        assert [st["bytes"] for name, st in spans
-                if name == "ow_http_respond" and "bytes" in st][0] > 0
+        # the answer's own span knows its size and that its body is the
+        # framed record; the CORS headers' span does not
+        (answer,) = [st for name, st in spans
+                     if name == "ow_http_respond" and "bytes" in st]
+        assert answer["bytes"] > 0 and answer["raw"] == 1
     # the admission plane's flushes carry the checks they resolve
     flushes = [st["n"] for line in door["lines"] for name, _s, _e, st in line
                if name == "ow_http_entitle" and "n" in st]
@@ -818,8 +833,42 @@ def test_the_front_door_s_spans_overlap_only_by_nesting(door):
 
 
 def test_tracing_off_the_answers_are_the_parent_s_byte_for_byte(door):
+    """Every answer of DOOR_SCRIPT is the parent's byte for byte but the
+    blocking 200s of FRAMED_STEPS. Their bodies are now the record's own
+    bytes as the invoker framed them, compact JSON (`,` and `:`, as
+    upstream's CompactPrinter answers), where the parent parsed the record
+    and dumped the entity again with `json.dumps`'s `, ` and `: `. They are
+    JSON-equal to the parent's (`updated` is the toy's fixed clock on both
+    sides, here the invoker's), with their new Content-Length."""
     with open(ANSWERS) as f:
-        assert door["answers"] == json.load(f)
+        parent = json.load(f)
+    assert len(door["answers"]) == len(parent)
+    for step, (got, was) in enumerate(zip(door["answers"], parent)):
+        if step not in FRAMED_STEPS:
+            assert got == was, step
+            continue
+        status, headers, body = got
+        assert status == was[0] == 200
+        assert headers == {**was[1],
+                           "content-length": str(len(body.encode()))}
+        assert json.loads(body) == json.loads(was[2])
+        assert body != was[2] and ", " not in body
+
+
+def test_a_blocking_answer_is_the_framed_record_s_bytes(door):
+    """The 200 of a blocking invoke is the ack frame's record byte for
+    byte, and the record is still unparsed after the answer went out; the
+    one record the script parses is the `?result=true` invoke's."""
+    framed = {json.loads(lazy.raw)["activationId"]: lazy
+              for lazy in door["framed"]}
+    for step in FRAMED_STEPS:
+        body = door["answers"][step][2].encode()
+        lazy = framed[json.loads(body)["activationId"]]
+        assert body == lazy.raw and not lazy.materialized
+    (parsed,) = [aid for aid, lazy in framed.items() if lazy.materialized]
+    # activation ids count up from 1 with the script's invokes: the third
+    # is `?result=true`'s
+    assert "result=true" in DOOR_SCRIPT[2][1] and parsed == f"{3:032x}"
 
 
 @pytest.mark.parametrize("held", [False, True], ids=["fast", "held"])
