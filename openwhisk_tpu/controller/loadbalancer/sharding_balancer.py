@@ -37,7 +37,7 @@ class ShardingBalancer(CommonLoadBalancer):
         # (on_tick refreshes the telemetry plane's SLO burn-rate gauges on
         # the same 1 Hz watchdog the TPU balancer uses)
         self.supervision = InvokerPool(
-            messaging_provider, on_status_change=self._status_change,
+            messaging_provider, on_status_changes=self._status_changes,
             logger=logger, group=f"health-{controller_instance.as_string}",
             on_tick=self._plane_tick)
         # advisory unhealthy hints from the anomaly plane land on the
@@ -67,17 +67,18 @@ class ShardingBalancer(CommonLoadBalancer):
         cluster size (ref updateCluster :561-584)."""
         self.policy.update_cluster(cluster_size)
 
-    def _status_change(self, instance: InvokerInstanceId, status: str) -> None:
+    def _status_changes(self, wave) -> None:
         # backfill gaps as UNUSABLE placeholders: invoker N's ping may arrive
         # before 0..N-1's (bus ordering race) and never-seen invokers must
         # not receive traffic (their registry entries would misdispatch)
-        idx = instance.instance
-        while idx >= len(self._registry):
-            self._registry.append(InvokerInstanceId(
-                len(self._registry), user_memory=instance.user_memory))
-            self._usable.append(False)
-        self._registry[idx] = instance
-        self._usable[idx] = status == HEALTHY
+        for instance, status in wave:
+            idx = instance.instance
+            while idx >= len(self._registry):
+                self._registry.append(InvokerInstanceId(
+                    len(self._registry), user_memory=instance.user_memory))
+                self._usable.append(False)
+            self._registry[idx] = instance
+            self._usable[idx] = status == HEALTHY
         self.policy.update_invokers(
             [i.user_memory.to_mb for i in self._registry],
             usable=list(self._usable))

@@ -12,19 +12,22 @@ Rebuild of core/controller/.../loadBalancer/InvokerSupervision.scala:
   - unhealthy invokers recover via periodic test traffic; here the FSM
     re-opens the error window after a cooldown (the reference posts a system
     test action once per minute — hook `send_test_action` to enable that).
-Status changes are pushed to the balancer through `on_status_change`, which
-feeds the device health mask in the TPU balancer.
+Status changes are pushed to the balancer through `on_status_changes`, in
+waves: the changes that one feed wake's pings (or one watchdog tick) made,
+in order, so that a fleet registering at once costs the balancer one wave
+of new rows per wake and not one per invoker. A wake's pings are parsed as
+one block under one `ow_ping` span (`n`: pings in the block).
 """
 from __future__ import annotations
 
 import asyncio
+import json
 import time
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 from ...core.entity import InvokerInstanceId
 from ...messaging.connector import MessageFeed, HEALTH_RETENTION_BYTES, HEALTH_TOPIC
-from ...messaging.message import PingMessage
 from ...utils.ring_buffer import RingBuffer
 from ...utils.scheduler import Scheduler
 from ...utils.transaction import TransactionId
@@ -39,12 +42,22 @@ BUFFER_SIZE = 10
 ERROR_TOLERANCE = 3
 PING_TIMEOUT_S = 10.0
 RECOVERY_COOLDOWN_S = 60.0
+#: the watchdog's period: the offline rule is checked once a second
+WATCHDOG_INTERVAL_S = 1.0
+#: a watchdog tick later than its period by more than this found the
+#: controller's loop held (a compile, a collection): for that long no ping
+#: could be read, so that span is nobody's silence and is not counted in
+#: it. One ping period (1 s): a shorter hold makes no invoker miss a ping
+HELD_LOOP_S = 1.0
 
 
 @dataclass
 class InvokerActorState:
     id: InvokerInstanceId
     status: str = OFFLINE
+    #: when the invoker was last heard, moved later by the span of every
+    #: hold of the controller's loop since (`HELD_LOOP_S`): the offline
+    #: rule counts silence from here
     last_ping: float = 0.0
     buffer: RingBuffer = field(default_factory=lambda: RingBuffer(BUFFER_SIZE))
     # seed one cooldown in the past: the FIRST probe of an unhealthy invoker
@@ -62,14 +75,49 @@ class InvokerActorState:
         return HEALTHY
 
 
+#: (the invoker as it pinged, the admin address it announced) of a payload
+Ping = Tuple[InvokerInstanceId, Optional[str]]
+
+
+def _ping_of(j) -> Ping:
+    admin = j.get("admin")
+    return (InvokerInstanceId.from_json(j["name"]),
+            admin if isinstance(admin, str) and admin else None)
+
+
+def parse_pings(payloads: List[bytes]) -> List[Optional[Ping]]:
+    """One JSON parse for a block of `PingMessage` payloads; where the
+    block does not parse whole, each payload alone. None stands for a
+    payload that is no ping."""
+    try:
+        docs = json.loads(b"[" + b",".join(payloads) + b"]")
+    except ValueError:
+        docs = None
+    if docs is None or len(docs) != len(payloads):
+        docs = []
+        for raw in payloads:
+            try:
+                docs.append(json.loads(raw))
+            except ValueError:
+                docs.append(None)
+    out: List[Optional[Ping]] = []
+    for j in docs:
+        try:
+            out.append(_ping_of(j))
+        except (ValueError, KeyError, TypeError, AttributeError):
+            out.append(None)
+    return out
+
+
 class InvokerPool:
     def __init__(self, messaging_provider,
-                 on_status_change: Optional[Callable] = None,
+                 on_status_changes: Optional[Callable] = None,
                  send_test_action: Optional[Callable] = None,
                  logger=None, ping_timeout: float = PING_TIMEOUT_S,
                  group: str = "health", on_tick: Optional[Callable] = None):
         self.provider = messaging_provider
-        self.on_status_change = on_status_change or (lambda inv, status: None)
+        #: takes a wave [(invoker, status)], in order
+        self.on_status_changes = on_status_changes or (lambda wave: None)
         self.send_test_action = send_test_action
         #: optional 1 Hz callback riding the watchdog — the balancer hangs
         #: its telemetry burn-rate gauge refresh here so dashboards stay
@@ -90,6 +138,10 @@ class InvokerPool:
         self.invoker_admin: Dict[int, str] = {}
         self._feed: Optional[MessageFeed] = None
         self._watchdog: Optional[Scheduler] = None
+        #: the changes of the wave being gathered; None between waves
+        self._wave: Optional[List[tuple]] = None
+        #: when the watchdog's last tick ended
+        self._tick_done: Optional[float] = None
 
     # -- lifecycle ---------------------------------------------------------
     def start(self) -> None:
@@ -99,24 +151,11 @@ class InvokerPool:
                                    retention_bytes=HEALTH_RETENTION_BYTES)
         consumer = self.provider.get_consumer(HEALTH_TOPIC, self.group,
                                               max_peek=128, from_latest=True)
-        box = {}
-
-        async def handle(payload: bytes):
-            try:
-                with span("ow_ping"):
-                    ping = PingMessage.parse(payload)
-                    if ping.admin:
-                        self.invoker_admin[ping.instance.instance] = \
-                            ping.admin
-                    self.on_ping(ping.instance)
-            except (ValueError, KeyError):
-                pass
-            box["feed"].processed()
-
-        self._feed = MessageFeed("health", consumer, 128, handle, logger=self.logger)
-        box["feed"] = self._feed
+        self._feed = MessageFeed("health", consumer, 128, logger=self.logger,
+                                 block_handler=self.on_ping_block)
         self._feed.start()
-        self._watchdog = Scheduler(1.0, self._check_offline, name="invoker-watchdog",
+        self._watchdog = Scheduler(WATCHDOG_INTERVAL_S, self._check_offline,
+                                   name="invoker-watchdog",
                                    logger=self.logger).start()
 
     async def stop(self) -> None:
@@ -126,14 +165,34 @@ class InvokerPool:
             await self._feed.stop()
 
     # -- events ------------------------------------------------------------
-    def on_ping(self, instance: InvokerInstanceId) -> None:
+    def on_ping_block(self, payloads: List[bytes]) -> None:
+        """The pings one wake of the health feed brought: parsed as one
+        block (`ow_ping`, `n` = pings in it), each then what `on_ping`
+        makes of it, and the status changes they made handed on as one
+        wave. A payload that is no ping is skipped, as it always was."""
+        self._wave = []
+        try:
+            with span("ow_ping", n=len(payloads)):
+                now = time.monotonic()
+                for ping in parse_pings(payloads):
+                    if ping is None:
+                        continue
+                    instance, admin = ping
+                    if admin:
+                        self.invoker_admin[instance.instance] = admin
+                    self.on_ping(instance, now)
+        finally:
+            self._end_wave()
+
+    def on_ping(self, instance: InvokerInstanceId,
+                now: Optional[float] = None) -> None:
         st = self.invokers.get(instance.instance)
         if st is None:
             # lazy registration on first ping (:191-207)
             st = InvokerActorState(instance, status=OFFLINE)
             self.invokers[instance.instance] = st
         st.id = instance  # refresh user_memory etc.
-        st.last_ping = time.monotonic()
+        st.last_ping = time.monotonic() if now is None else now
         if st.status == OFFLINE:
             self._transition(st, HEALTHY if st.classify() == HEALTHY else st.classify())
         elif st.status in (UNHEALTHY, UNRESPONSIVE):
@@ -154,17 +213,36 @@ class InvokerPool:
             self._transition(st, st.classify())
 
     async def _check_offline(self) -> None:
+        """Offline after `ping_timeout` of silence (:294), counted over the
+        time the controller could hear: a tick that finds the loop was
+        held (`HELD_LOOP_S`) takes the held span, from when the tick was
+        due, out of every invoker's silence, since pings that came
+        meanwhile sit unread; a fleet held silent by its controller's own
+        compile would otherwise all go offline. An invoker heard inside
+        that span (before the loop stopped) has its silence start at the
+        tick. Only the held span is forgiven, so a dead invoker still goes
+        offline under holds that come again and again, later by their
+        sum."""
         with span("ow_supervision_tick", n=len(self.invokers)):
             now = time.monotonic()
-            for st in self.invokers.values():
-                if st.status != OFFLINE \
-                        and now - st.last_ping > self.ping_timeout:
-                    self._transition(st, OFFLINE)
+            held = 0.0 if self._tick_done is None else \
+                now - self._tick_done - WATCHDOG_INTERVAL_S
+            self._wave = []
+            try:
+                for st in self.invokers.values():
+                    if held > HELD_LOOP_S:
+                        st.last_ping = min(st.last_ping + held, now)
+                    if st.status != OFFLINE \
+                            and now - st.last_ping > self.ping_timeout:
+                        self._transition(st, OFFLINE)
+            finally:
+                self._end_wave()
             if self.on_tick is not None:
                 try:
                     self.on_tick()
                 except Exception:  # noqa: BLE001 — a gauge refresh must
                     pass           # never kill the health watchdog
+        self._tick_done = time.monotonic()
 
     def _maybe_recover(self, st: InvokerActorState) -> None:
         now = time.monotonic()
@@ -186,7 +264,15 @@ class InvokerPool:
                 self.logger.info(TransactionId.INVOKER_HEALTH,
                                  f"invoker{st.id.instance} {old} -> {new_status}",
                                  "InvokerPool")
-            self.on_status_change(st.id, new_status)
+            if self._wave is not None:
+                self._wave.append((st.id, new_status))
+            else:
+                self.on_status_changes([(st.id, new_status)])
+
+    def _end_wave(self) -> None:
+        wave, self._wave = self._wave, None
+        if wave:
+            self.on_status_changes(wave)
 
     def set_unhealthy_hints(self, hints: Dict[int, str]) -> None:
         """Replace the advisory hint set (the anomaly plane pushes the full

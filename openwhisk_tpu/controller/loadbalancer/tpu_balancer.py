@@ -39,7 +39,7 @@ import time
 import zlib
 from collections import deque
 from dataclasses import dataclass
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -370,6 +370,10 @@ class TpuBalancer(CommonLoadBalancer):
         self._books_cache: Optional[np.ndarray] = None
         self._books_seq = 0
         self._books_cache_seq = 0
+        #: (books seq, first new row) of each registration that a step
+        #: dispatched before it may still read back: that step read the
+        #: rows unregistered, so its books get their capacity patched in
+        self._reg_marks: List[Tuple[int, int]] = []
         #: placement-quality plane inputs, host-refreshed on the 1 Hz
         #: supervision tick from the anomaly plane's harvested scores:
         #: padded per-invoker cost (latency EWMA) and capacity vectors for
@@ -418,7 +422,7 @@ class TpuBalancer(CommonLoadBalancer):
         # of the ping stream (a shared group would split pings between
         # controllers; ref: each controller runs its own InvokerPool)
         self.supervision = InvokerPool(
-            messaging_provider, on_status_change=self._status_change,
+            messaging_provider, on_status_changes=self._status_changes,
             logger=logger, group=f"health-{controller_instance.as_string}",
             on_tick=self._telemetry_tick)
         # advisory unhealthy hints from the anomaly plane land on the
@@ -429,7 +433,10 @@ class TpuBalancer(CommonLoadBalancer):
         # per dispatch cycle (_dispatch_batch / idle _device_step)
         if self.telemetry.enabled:
             self.telemetry.use_device(self._n_pad)
+        #: partition size -> its coprime probe steps (`_probe_steps`)
+        self._steps_of: Dict[int, List[int]] = {}
         self._recompute_partitions()
+        self._rebuild_caps()
 
     def _telemetry_tick(self) -> None:
         # the supervision watchdog also drains completion events that
@@ -798,34 +805,81 @@ class TpuBalancer(CommonLoadBalancer):
 
     # -- fleet bookkeeping -------------------------------------------------
     def _status_change(self, instance: InvokerInstanceId, status: str) -> None:
-        idx = instance.instance
-        new_rows = []
-        while idx >= len(self._registry):
-            new_rows.append(len(self._registry))
-            self._registry.append(instance)
-            self._healthy.append(False)
-        self._registry[idx] = instance
-        self._healthy[idx] = status == HEALTHY
-        if new_rows:
-            if len(self._registry) > self._n_pad:
-                self._grow_padding(_next_pow2(len(self._registry)))
-            # initialize ONLY the new rows (full capacity, health set below);
-            # existing rows keep their in-flight holds
-            slot_vals = jnp.asarray(
-                [self._slot_mb(self._registry[i].user_memory.to_mb)
-                 for i in new_rows], jnp.int32)
+        """One status change: a wave of one."""
+        self._status_changes([(instance, status)])
+
+    def _status_changes(self, wave) -> None:
+        """A wave of supervision status changes [(invoker, status)], in
+        order: what one wake of the health feed's pings changed. The rows
+        it adds to the registry are registered together (`_register_rows`);
+        every change, of a new row or an old one, is a flip for the next
+        device step, and a flush is armed for now, so that the step comes
+        without traffic to bring it and a publish that follows finds the
+        flips folded. The fleet's partition sizes are worked out again only
+        when new rows move them."""
+        n0 = len(self._registry)
+        for instance, status in wave:
+            idx = instance.instance
+            while idx >= len(self._registry):
+                self._registry.append(instance)
+                self._healthy.append(False)
+            self._registry[idx] = instance
+            self._healthy[idx] = status == HEALTHY
+            self._health_updates[idx] = self._healthy[idx]
+            if idx < n0:
+                # a re-registered row may announce another memory size
+                self._caps_mb[idx] = self._slot_mb(instance.user_memory.to_mb)
+        if len(self._registry) > n0:
+            self._register_rows(n0)
+            self._recompute_partitions()
+        if self._health_updates:
+            try:
+                asyncio.get_running_loop()
+            except RuntimeError:
+                return  # no loop serves this balancer yet: the next step
+            self._arm_flush(urgent=True)
+
+    def _register_rows(self, n0: int) -> None:
+        """Rows [n0, len(registry)) are new: grow the pad if they pass it,
+        then ONE scatter of their capacity into the device books, the same
+        values patched into the host's cached books and into the books of
+        every step already in flight when they read back (known here: no
+        device->host read-back), the capacity vector grown by them, and ONE
+        `reg` journal record. Existing rows keep their in-flight holds. A
+        new row is unusable on the device until its health flip is folded,
+        as the journal's readers hold it."""
+        n = len(self._registry)
+        with span("ow_register", rows=n - n0):
+            if n > self._n_pad:
+                self._grow_padding(_next_pow2(n))
+            caps = np.fromiter(
+                (self._slot_mb(self._registry[i].user_memory.to_mb)
+                 for i in range(n0, n)), np.int32, n - n0)
+            idx, vals = self._padded_rows(np.arange(n0, n, dtype=np.int32),
+                                          caps)
             self.state = self.state._replace(
-                free_mb=self.state.free_mb.at[jnp.asarray(new_rows)].set(slot_vals))
-            # occupancy's cached books must learn the fresh rows' capacity
-            # (registration is rare; the sync transfer is n_pad int32s)
-            self._set_books_now(np.asarray(self.state.free_mb))
+                free_mb=self.state.free_mb.at[idx].set(vals))
+            self._caps_mb = np.concatenate([self._caps_mb[:n0],
+                                            caps.astype(np.int64)])
+            self._reg_marks.append((self._books_seq, n0))
+            self._books_cache = self._with_rows_since(self._books_cache, n0)
             if self._journal_live():
                 self._journal_append({
                     "t": "reg",
-                    "reg": [self._registry[i].to_json() for i in new_rows],
-                    "healthy": [bool(self._healthy[i]) for i in new_rows]})
-        self._health_updates[idx] = self._healthy[idx]
-        self._recompute_partitions()
+                    "reg": [self._registry[i].to_json() for i in range(n0, n)],
+                    "healthy": [bool(h) for h in self._healthy[n0:n]]})
+
+    def _padded_rows(self, idx: np.ndarray, vals: np.ndarray) -> tuple:
+        """A scatter's rows and values padded to a power-of-two bucket by
+        repeating the last pair (the same write again), so that the eager
+        scatter compiles once per bucket, not once per wave size."""
+        n = len(idx)
+        b = self._bucket(n, max(self._n_pad, n))
+        if b > n:
+            idx = np.concatenate([idx, np.full(b - n, idx[-1], idx.dtype)])
+            vals = np.concatenate([vals, np.full(b - n, vals[-1],
+                                                 vals.dtype)])
+        return jnp.asarray(idx), jnp.asarray(vals)
 
     def _next_books_seq(self) -> int:
         """Claim the next books-cache sequence number (event-loop only:
@@ -835,15 +889,31 @@ class TpuBalancer(CommonLoadBalancer):
 
     def _install_books(self, books_np, seq: int) -> None:
         """Install host books into occupancy()'s cache unless a NEWER
-        step's books already landed. Called on the event loop."""
+        step's books already landed; a step dispatched before a
+        registration gets the new rows' capacity patched in. Called on the
+        event loop."""
         if seq >= self._books_cache_seq:
+            since = [n0 for s, n0 in self._reg_marks if s >= seq]
+            if since:
+                books_np = self._with_rows_since(books_np, min(since))
+            self._reg_marks = [m for m in self._reg_marks if m[0] >= seq]
             self._books_cache_seq = seq
             self._books_cache = books_np
 
+    def _with_rows_since(self, books, n0: int) -> np.ndarray:
+        """`books` at the current pad with rows [n0, registry) at their
+        full capacity, as registration scattered them on the device."""
+        out = np.zeros((self._n_pad,), np.int32)
+        m = min(len(books), self._n_pad)
+        out[:m] = books[:m]
+        n = len(self._registry)
+        out[n0:n] = self._caps_mb[n0:n]
+        return out
+
     def _set_books_now(self, books_np) -> None:
         """Synchronous cache install for authoritative state changes
-        (init/registration/growth/restore) — supersedes any in-flight
-        readback's books."""
+        (init/growth/restore) — supersedes any in-flight readback's
+        books."""
         self._install_books(books_np, self._next_books_seq())
 
     def _recover_consumed_state(self) -> bool:
@@ -990,14 +1060,33 @@ class TpuBalancer(CommonLoadBalancer):
         n = len(self._registry)
         self.managed_count = max(int(self.managed_fraction * n), 1) if n else 0
         self.blackbox_count = max(int(self.blackbox_fraction * n), 1) if n else 0
-        self._steps_managed = pairwise_coprimes(max(1, self.managed_count))
-        self._steps_blackbox = pairwise_coprimes(max(1, self.blackbox_count))
-        # host-side per-invoker capacity vector (this controller's memory
-        # share), kept in sync with the registry so the flight recorder's
-        # occupancy digest never needs a per-step rebuild
+
+    def _rebuild_caps(self) -> None:
+        """The host-side per-invoker capacity vector (this controller's
+        memory share) from the whole registry, for when every row's share
+        changes (cluster size, restore); registration grows it instead.
+        Kept in step with the registry so the flight recorder's occupancy
+        digest never needs a per-step rebuild."""
         self._caps_mb = np.asarray(
             [self._slot_mb(i.user_memory.to_mb) for i in self._registry],
             np.int64)
+
+    def _probe_steps(self, size: int) -> List[int]:
+        """The coprime probe steps of a partition of `size` invokers,
+        worked out when a placement first needs them (one list costs
+        O(size^2 / log size), a fifth of a second at 10,240 on one CPU
+        core) and kept per size: a
+        registering fleet passes through every size on its way up and
+        places at few of them."""
+        steps = self._steps_of.get(size)
+        if steps is None:
+            if len(self._steps_of) >= 64:
+                self._steps_of.clear()
+            with span("ow_partitions", managed=self.managed_count,
+                      blackbox=self.blackbox_count):
+                steps = self._steps_of[size] = pairwise_coprimes(
+                    max(1, size))
+        return steps
 
     def update_cluster(self, cluster_size: int) -> None:
         """Controller joined/left: re-shard every invoker's memory
@@ -1007,7 +1096,7 @@ class TpuBalancer(CommonLoadBalancer):
             self.profiler.expect("reshard" if self.mesh is not None
                                  else "cluster_resize")
             self._init_device_state()
-            self._recompute_partitions()  # capacity shares changed
+            self._rebuild_caps()  # capacity shares changed
             if self._journal_live():
                 self._journal_append({"t": "cluster", "size": cluster_size})
 
@@ -1109,7 +1198,7 @@ class TpuBalancer(CommonLoadBalancer):
             if len(self._hash_cache) >= 65536:
                 self._hash_cache.clear()
             h = self._hash_cache[hkey] = generate_hash(*hkey)
-        steps = self._steps_blackbox if blackbox else self._steps_managed
+        steps = self._probe_steps(size)
         step = steps[h % len(steps)]
         ikey = (step, size)
         step_inv = self._modinv_cache.get(ikey)
@@ -2082,26 +2171,20 @@ class TpuBalancer(CommonLoadBalancer):
                                     [bool(v) for _, v in health])
 
     def _replay_reg(self, rec: dict) -> None:
-        new_rows = []
+        # the rows' health comes from the flips journaled after them
+        n0 = len(self._registry)
         for j, healthy in zip(rec["reg"], rec["healthy"]):
             inv = InvokerInstanceId.from_json(j)
             idx = inv.instance
             while idx >= len(self._registry):
-                new_rows.append(len(self._registry))
                 self._registry.append(inv)
                 self._healthy.append(False)
             self._registry[idx] = inv
             self._healthy[idx] = bool(healthy)
-        if new_rows:
-            if len(self._registry) > self._n_pad:
-                self._grow_padding(_next_pow2(len(self._registry)))
-            slot_vals = jnp.asarray(
-                [self._slot_mb(self._registry[i].user_memory.to_mb)
-                 for i in new_rows], jnp.int32)
-            self.state = self.state._replace(
-                free_mb=self.state.free_mb.at[jnp.asarray(new_rows)].set(
-                    slot_vals))
+        if len(self._registry) > n0:
+            self._register_rows(n0)
         self._recompute_partitions()
+        self._rebuild_caps()
 
     # -- checkpoint / resume (SURVEY §5.4) ---------------------------------
     def snapshot_parts(self) -> dict:
@@ -2193,6 +2276,7 @@ class TpuBalancer(CommonLoadBalancer):
         self._slots.free = [s for s in range(self.action_slots - 1, -1, -1)
                             if s not in used]
         self._recompute_partitions()
+        self._rebuild_caps()
 
     # -- the device step ---------------------------------------------------
 
@@ -2324,10 +2408,12 @@ class TpuBalancer(CommonLoadBalancer):
     #: overflow namespaces instead of draining dedicated tenants' tokens
     RATE_NS_SHARED_BUCKETS = 64
 
-    #: health updates drained per device step — a FIXED batch shape, so the
+    #: health updates a fused step carries — a FIXED batch shape, so the
     #: fused program's compile-cache keys vary only in (release, batch)
-    #: buckets; leftovers roll to the next step (fleet churn is slow vs the
-    #: step rate)
+    #: buckets. A step that finds more buffered (a fleet registering) folds
+    #: them all first in one scatter of their own (`_fold_now`, padded to a
+    #: power of two); so does an idle fold, which a flip arms. Either way a
+    #: flip buffered before a device step is on the device after it
     HEALTH_BATCH = 64
 
     #: below this measured round trip the device counts as "fast": eager
@@ -2454,19 +2540,23 @@ class TpuBalancer(CommonLoadBalancer):
         self._set_inflight(1)
         self._dispatch_batch(held_s, due)
 
-    def _fold_now(self):
+    def _fold_now(self, releases: bool = True):
         """The release-only / health fold and its journal record (one
-        `ow_fold` span to one `fold` record). Returns the release fold's
-        books output (a device vector of its own), None where only health
-        was folded."""
+        `ow_fold` span to one `fold` record): every buffered flip, in one
+        scatter padded to a power-of-two bucket, and with `releases` the
+        buffered releases. Returns the release fold's books output (a
+        device vector of its own), None where only health was folded."""
         rel_np = ups = books = None
-        if self._releases:
+        if releases and self._releases:
             rel_np = self._release_packed()
             self.state, books = self._release_packed_fn(self.state, rel_np)
         if self._health_updates:
             ups, self._health_updates = self._health_updates, {}
-            self.state = set_health(self.state, list(ups.keys()),
-                                    list(ups.values()))
+            idx, vals = self._padded_rows(
+                np.fromiter(ups.keys(), np.int32, len(ups)),
+                np.fromiter(ups.values(), bool, len(ups)))
+            self.state = self.state._replace(
+                health=self.state.health.at[idx].set(vals))
         if self._journal_live():
             fold = {"t": "fold"}
             if rel_np is not None:
@@ -2537,6 +2627,13 @@ class TpuBalancer(CommonLoadBalancer):
         """One fused step. `held_s`: what the flush task slept before it,
         and `due`: when that sleep should have ended; 0 and None from the
         inline paths (a full batch, eager on an idle pipeline)."""
+        if len(self._health_updates) > self.HEALTH_BATCH:
+            # more flips than a step's fixed health section holds (a fleet
+            # registering under traffic): all of them fold first, in one
+            # padded scatter of their own, so that no flip waits on the
+            # steps after this one
+            with span("ow_fold", rows=0):
+                self._fold_now(releases=False)
         batch, self._pending = self._pending[: self.max_batch], \
             self._pending[self.max_batch:]
         t0 = time.monotonic()

@@ -183,9 +183,18 @@ class MessagingProvider:
 #: the invoker ping stream: smallest retention of any topic (ref gives the
 #: health topic its tightest retention) and consumed from_latest
 HEALTH_TOPIC = "health"
-HEALTH_RETENTION_BYTES = 512 * 1024
+#: two seconds of a 10,240-invoker fleet's pings (BASELINE configs[2]; one
+#: ping an invoker a second, InvokerReactive.scala:337-342): 2 s x 10,240
+#: pings x 128 B = 2,621,440 B. A ping is 105-110 B of JSON, and 128 B is
+#: what the memory bus books a message at (memory.py set_retention_bytes),
+#: so that bus keeps 20,480 pings: a loop held for up to two seconds loses
+#: none. No topic is created with what its fleet will be, so this is sized
+#: for the largest fleet one controller serves, not read off the registry
+HEALTH_RETENTION_BYTES = 2 * 10_240 * 128
 
 Handler = Callable[[bytes], Awaitable[None]]
+#: takes every payload one wake of the feed brought, at once
+BlockHandler = Callable[[List[bytes]], None]
 
 
 class MessageFeed:
@@ -193,17 +202,25 @@ class MessageFeed:
 
     The handler receives raw payload bytes and MUST call `processed()` when
     it has freed its capacity (mirrors sending `MessageFeed.Processed` to the
-    feed actor in the reference).
+    feed actor in the reference). A feed built with `block_handler` instead
+    hands it every payload of a wake as one list, synchronously; the
+    wake's capacity is free again when it returns (the health feed parses
+    a wake's pings as one block).
     """
 
     def __init__(self, description: str, consumer: MessageConsumer,
-                 maximum_handler_capacity: int, handler: Handler,
+                 maximum_handler_capacity: int,
+                 handler: Optional[Handler] = None,
                  logger=None, long_poll_timeout: float = 0.5,
-                 auto_start: bool = False):
+                 auto_start: bool = False,
+                 block_handler: Optional[BlockHandler] = None):
+        if (handler is None) == (block_handler is None):
+            raise ValueError("a feed takes a handler or a block_handler")
         self.description = description
         self.consumer = consumer
         self.capacity = maximum_handler_capacity
         self.handler = handler
+        self.block_handler = block_handler
         self.logger = logger
         self.long_poll_timeout = long_poll_timeout
         self._free = maximum_handler_capacity
@@ -258,6 +275,14 @@ class MessageFeed:
                 # (MessageConsumer.scala:179-190).
                 with span("ow_feed", n=len(batch)):
                     self.consumer.commit()
+                if self.block_handler is not None:
+                    try:
+                        self.block_handler([m[3] for m in batch])
+                    except Exception as e:  # noqa: BLE001 — feed must survive handler errors
+                        if self.logger:
+                            self.logger.error(TransactionId.SYSTEM,
+                                              f"feed {self.description} handler error: {e!r}")
+                    continue
                 for _topic, _part, _offset, payload in batch:
                     self._free -= 1
                     try:

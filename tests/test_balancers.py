@@ -225,8 +225,8 @@ class TestBalancers:
             provider = MemoryMessagingProvider()
             statuses = {}
             pool = InvokerPool(provider,
-                               on_status_change=lambda i, s: statuses.update(
-                                   {i.instance: s}),
+                               on_status_changes=lambda w: statuses.update(
+                                   {i.instance: s for i, s in w}),
                                ping_timeout=0.3)
             pool.start()
             producer = provider.get_producer()

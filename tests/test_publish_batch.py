@@ -92,6 +92,12 @@ async def _healthy_balancer(provider, n_invokers=4, mem=4096, **kw):
             break
     else:
         raise RuntimeError("fleet never became healthy")
+    # and usable on the device: the flush the registration armed has
+    # folded its flips
+    for _ in range(100):
+        if not bal._health_updates:
+            break
+        await asyncio.sleep(0.01)
     return bal
 
 
