@@ -9,26 +9,46 @@ for): the invoker has no container model. Every delivery and every ack is
 recorded for the comparison that decides `correct`.
 
 The fleet pings as a fleet does: every invoker once a second, at a phase of
-its own, from its very first ping. `ping_schedule(n)` deals the invokers, in
-their order, to ceil(n / 128) ticks spread evenly over the second; one task
-sends a tick (a `bench_ping` span) and gives the loop back before the next,
-also where it runs late. `start()` only opens the invokers' feeds and starts
-that task: registration is second 0 of the same schedule, so an invoker that
-has pinged goes on pinging a second later however long the program takes
-over the rows behind it. The harness never puts more than `PING_TICK` pings
-on the bus between two turns of the loop, whatever the fleet's size: one
-burst of all N once a second is a load no fleet offers, and past the health
-topic's retention (4,096 messages) it is pings lost.
+its own, from its very first ping. `ping_schedule(n)` deals the invokers,
+in their order, to ceil(n / 128) ticks spread evenly over the second. One
+task keeps that schedule on its own clock, as real invokers keep theirs: at
+each wake it sends, in one block (a `bench_ping` span, one `send_many`),
+every invoker whose ping has fallen due since its last wake, so a loop that
+turns late delays each ping by as long as it kept the pinger waiting (a few
+turns at most) and thins none out. On a loop that turns on time a wake is
+one tick, at most `PING_TICK` pings. After a hold longer than a second a
+wake sends each invoker's newest due ping once, not one for every second it
+missed, and the schedule goes on from the current second: a block is never
+more than N pings. At 10,240 invokers that is half of the health topic's
+retention (two seconds of the fleet's pings, `HEALTH_RETENTION_BYTES`), and
+the health feed drains a backlog in one wake, so no ping is lost to
+retention. Such a block begins at the tick then due and wraps round to tick
+0, so the invokers of later ticks can ping before those of earlier ones, as
+a real fleet's may: the rows below them are registered as upstream pads
+them (`reference.ReferenceFleet.register`). `start()` only opens the
+invokers' feeds and starts that task: registration is second 0 of the same
+schedule, so an invoker that has pinged goes on pinging a second later
+however long the program takes over the rows behind it. A ping's bytes are
+the same every second (the invoker's instance; no admin address), so each
+invoker's is serialized once, in `start()`.
+
+The fleet keeps an account of what it delivered over the measured window
+(`window`): the pings sent, and the longest silence of one invoker, on the
+harness's clock.
 """
 from __future__ import annotations
 
 import asyncio
+import bisect
 import contextlib
 import time
 from typing import Dict, List, Tuple
 
-#: most pings sent between two yields: the health feed's `max_peek`
-#: (supervision.py), so one tick is one batch of the program's feed
+import numpy as np
+
+#: most invokers dealt to one tick: the health feed's `max_peek`
+#: (supervision.py), so on a loop that turns on time one wake's block is
+#: one batch of the program's feed
 PING_TICK = 128
 
 
@@ -67,6 +87,16 @@ class SimFleet:
         self._feeds: list = []
         self._timers: set = set()
         self._ping_task = None
+        #: pings sent since `start()`
+        self.pings = 0
+        #: when each invoker's last ping was sent, on the loop's clock
+        self._last_ping = np.zeros(n_invokers)
+        #: the measured window's account (`window`): pings sent in it, and
+        #: the longest silence of one invoker that ended in it
+        self.window_pings = None
+        self.window_gap_max_s = None
+        self._window_from = None
+        self._gap_max = 0.0
 
     async def start(self) -> None:
         from openwhisk_tpu.core.entity import MB, InvokerInstanceId
@@ -75,31 +105,68 @@ class SimFleet:
         pinger = self.provider.get_producer()
         self.provider.ensure_topic("health")
         loop = asyncio.get_event_loop()
-        schedule = ping_schedule(self.n)
+        n = self.n
+        schedule = ping_schedule(n)
+        ticks = len(schedule)
+        phases = [p for p, _lo, _hi in schedule]
         instances = [InvokerInstanceId(i, user_memory=MB(self.memory_mb))
-                     for i in range(self.n)]
+                     for i in range(n)]
+        items = [("health", PingMessage(inst).serialize(), None)
+                 for inst in instances]
         self._feeds = [self._start_invoker(inst) for inst in instances]
         t0 = loop.time()
+        last = self._last_ping
+        last[:] = t0
+
+        def due_at(g: int) -> float:
+            # tick g of the fleet's life: tick g % ticks of second g // ticks
+            return t0 + g // ticks + phases[g % ticks]
 
         async def ping() -> None:
             # supervision marks an invoker Offline after 10 s of silence.
-            # Second `sec` of the fleet's life (an invoker's first ping
-            # registers it), tick by tick on the schedule's own clock: a
-            # late tick does not move the next
-            sec = 0
+            # Tick by tick on the schedule's own clock (an invoker's first
+            # ping registers it): a late wake does not move the next tick
+            g = 0
             while True:
-                for phase, lo, hi in schedule:
-                    # a tick that is overdue still gives the loop back first
-                    await asyncio.sleep(max(0.0, t0 + sec + phase
-                                            - loop.time()))
-                    # `send` awaits the topic's condition, which nobody
-                    # holds across an await: the span holds no other task
-                    with self.span("bench_ping"):
-                        for inst in instances[lo:hi]:
-                            await pinger.send("health", PingMessage(inst))
-                sec += 1
+                await asyncio.sleep(max(0.0, due_at(g) - loop.time()))
+                now = loop.time()
+                sec = int(now - t0)
+                due = sec * ticks + bisect.bisect_right(phases,
+                                                        now - t0 - sec)
+                if due <= g:
+                    continue
+                # every tick due since the last wake; past a second behind,
+                # each invoker's newest due ping alone: at most one round
+                first, g = max(g, due - ticks), due
+                # `send_many` does not suspend on the in-memory bus: the
+                # span holds no other task
+                with self.span("bench_ping"):
+                    block = []
+                    for k in range(first, due):
+                        _phase, lo, hi = schedule[k % ticks]
+                        if self._window_from is not None:
+                            self._gap_max = max(self._gap_max,
+                                                now - last[lo:hi].min())
+                        last[lo:hi] = now
+                        block += items[lo:hi]
+                    self.pings += len(block)
+                    await pinger.send_many(block)
 
         self._ping_task = loop.create_task(ping())
+
+    def window(self, opened: bool) -> None:
+        """Open or close the account of the measured window: the pings
+        sent in it, and the longest silence of one invoker that ended in
+        it (a silence still open at the close counted up to the close)."""
+        now = asyncio.get_event_loop().time()
+        if opened:
+            self._window_from = self.pings
+            self._gap_max = 0.0
+        elif self._window_from is not None:
+            self.window_pings = self.pings - self._window_from
+            self.window_gap_max_s = max(self._gap_max,
+                                        now - float(self._last_ping.min()))
+            self._window_from = None
 
     def _start_invoker(self, instance):
         from openwhisk_tpu.core.entity import (ActivationResponse, EntityPath,

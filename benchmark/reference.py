@@ -113,9 +113,18 @@ class ReferenceFleet:
         self._steps_of: Dict[int, List[int]] = {}
 
     def register(self, idx: int, user_memory_mb: int, usable: bool) -> None:
-        while idx >= len(self.invokers):
-            self.invokers.append(_Invoker(0, False))
-        self.invokers[idx] = _Invoker(max(user_memory_mb, MIN_SLOT_MB), usable)
+        """An invoker's ping, as upstream registers it: the fleet grows up
+        to its index, each row whose invoker has not pinged yet standing in
+        with this invoker's memory, offline (InvokerPool.registerInvoker's
+        padToIndexed, :191-207), and the books take slots for new rows
+        alone (ShardingContainerPoolBalancerState.updateInvokers,
+        :512-551): a row that is there keeps its books and its health,
+        whoever pings for it."""
+        mb = max(user_memory_mb, MIN_SLOT_MB)
+        while idx > len(self.invokers):
+            self.invokers.append(_Invoker(mb, False))
+        if idx == len(self.invokers):
+            self.invokers.append(_Invoker(mb, usable))
 
     @property
     def managed_count(self) -> int:

@@ -512,6 +512,7 @@ async def run_cell(res: dict, seed: int, seconds: float, trace: bool,
 
     def on_window(t0_ns) -> None:
         snap = GLOBAL_WATERFALL.raw_counts()
+        sut.fleet.window(t0_ns is not None)
         if t0_ns is not None:
             if window_opens is not None:
                 window_opens()
@@ -657,6 +658,11 @@ async def run_cell(res: dict, seed: int, seconds: float, trace: bool,
         "lowerings_in_window": compiles["lowered_in_window"],
         "program_compiles": program_compiles,
         "steps_in_window": len(steps), "ack_errors": sut.fleet.ack_errors,
+        # what the simulated fleet delivered: an `unusable` beside pings
+        # that kept their schedule is the program's, not the harness's
+        "pings_per_s": (sut.fleet.window_pings / window_s
+                        if sut.fleet.window_pings is not None else None),
+        "ping_gap_max_s": sut.fleet.window_gap_max_s,
         "traced_records_and_dispatches": pairing,
         "device_programs": (art["trace"] or {}).get("modules"),
         "memory_peak_bytes": peak,

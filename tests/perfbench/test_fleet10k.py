@@ -11,7 +11,7 @@ import os
 
 import pytest
 
-from benchmark import run, span_reduce
+from benchmark import fleet, run, span_reduce
 
 from tests.perfbench.test_perfbench import _dump, _toy_root
 
@@ -101,12 +101,11 @@ def limits_as_they_were():
 TOY_INVOKERS = 300
 
 
-@pytest.fixture(scope="module")
-def toy10k(tmp_path_factory):
-    root = _toy_root(tmp_path_factory.mktemp("toy10k"))
+def _toy10k_run(tmp_path_factory, tag: str, seed: int):
+    root = _toy_root(tmp_path_factory.mktemp(tag))
     # a run directory of its own: the trace read is this run's, whatever
     # the other files' toy runs leave under .bench_run meanwhile
-    run_dir = str(tmp_path_factory.mktemp("toy10k-run"))
+    run_dir = str(tmp_path_factory.mktemp(tag + "-run"))
     patch = pytest.MonkeyPatch()
     patch.setattr(run, "RUN_DIR", run_dir)
     cfg = copy.deepcopy(_load("configs/fleet10k-conc.json"))
@@ -129,11 +128,35 @@ def toy10k(tmp_path_factory):
     res = run.resolve_cell(manifest, "toy10k-closed", root)
     device = run.device_or_exit(1)
     try:
-        out = asyncio.run(run.run_cell(res, 2**31 + 41, 3.5, True, device))
+        out = asyncio.run(run.run_cell(res, seed, 3.5, True, device))
     finally:
         patch.undo()
     return res, device, out, span_reduce.reduce_spans(
         span_reduce.newest_trace(run_dir))
+
+
+@pytest.fixture(scope="module")
+def toy10k(tmp_path_factory):
+    return _toy10k_run(tmp_path_factory, "toy10k", 2**31 + 41)
+
+
+@pytest.fixture(scope="module")
+def toy10k_pinging_from_the_top(tmp_path_factory):
+    """The toy with its ticks dealt from the top of the fleet down: the
+    invokers of the highest indices ping first in every second, as a fleet
+    whose pinger runs late wraps round to them, and the program registers
+    the rows below them as stand-ins until their own pings come."""
+    real = fleet.ping_schedule
+
+    def from_the_top(n):
+        return [(p, n - hi, n - lo) for p, lo, hi in real(n)]
+
+    patch = pytest.MonkeyPatch()
+    patch.setattr(fleet, "ping_schedule", from_the_top)
+    try:
+        return _toy10k_run(tmp_path_factory, "toy10k-top", 2**31 + 43)
+    finally:
+        patch.undo()
 
 
 def test_the_toy_of_the_shape_is_correct_and_reads_the_pings(toy10k,
@@ -166,3 +189,43 @@ def test_the_toy_of_the_shape_is_correct_and_reads_the_pings(toy10k,
                                 out["art"])
     assert per_block == pytest.approx(stats["n"] / stats["_events"])
     assert 1 <= per_block <= 128
+
+
+def test_the_toy_s_log_line_says_what_the_fleet_delivered(toy10k):
+    """`pings_per_s` and `ping_gap_max_s`: the pings the simulated fleet
+    sent in the window over its length, and the longest silence of one
+    invoker in it. 300 invokers ping once a second each; a wake sends every
+    ping that fell due, so no invoker is silent for less than about its
+    second, nor for as long as the program's offline timeout (which
+    `unusable` 0 says too). The two fields bound each other, whatever the
+    loop's lateness: an invoker silent at most G seconds at a time pings at
+    least W / G - 1 times in a window of W, and one whose pings keep their
+    schedule at most once a second, a tick at either end besides."""
+    from openwhisk_tpu.controller.loadbalancer.supervision import \
+        PING_TIMEOUT_S
+    _res, _device, out, _red = toy10k
+    log = out["log"]
+    window_s, gap_s = log["window_s"], log["ping_gap_max_s"]
+    assert 0.9 < gap_s < PING_TIMEOUT_S
+    sent = log["pings_per_s"] * window_s
+    assert TOY_INVOKERS * (window_s / gap_s - 1) <= sent \
+        <= TOY_INVOKERS * (window_s + 2)
+    assert out["verdict"]["numbers"]["unusable"] == 0
+
+
+def test_invokers_that_ping_out_of_order_are_registered_as_upstream_pads(
+        toy10k_pinging_from_the_top):
+    """A row registered ahead of its invoker's ping stands in with the
+    memory of the invoker whose ping padded the fleet (upstream's
+    InvokerPool.registerInvoker) and keeps those books when its own
+    invoker pings; the plain reference pads the same way, so the run is
+    correct. The first `reg` record shows the order: its first entries all
+    name the invoker that padded them."""
+    res, device, out, _red = toy10k_pinging_from_the_top
+    regs = [r for r in out["records"] if r.get("t") == "reg"]
+    first = [int(j["instance"]) for j in regs[0]["reg"]]
+    assert first[0] > 0 and first.count(first[0]) > 1
+    assert sum(len(r["reg"]) for r in regs) == TOY_INVOKERS
+    line = run.build_result(res, out, False, device)
+    assert [v["value"] for v in line["checked"].values()] == [0] * 9
+    assert line["correct"] is True

@@ -1,7 +1,9 @@
 """The harness at a fleet past 1k: the plain reference registers in linear
 time and decides what the eager one decided, the simulated fleet pings at a
-phase per invoker and never in a burst, and set-up ends by the clock. One
-assertion is on a time, and that one is on this process's own CPU seconds."""
+phase per invoker on the schedule's own clock however late the loop turns,
+and set-up ends by the clock. Times are asserted of the pings' gaps, held
+against the schedule's second and the loop's turns measured in the same
+run, and of this process's own CPU seconds."""
 import asyncio
 import json
 import os
@@ -130,6 +132,28 @@ def test_one_step_list_per_fleet_size_at_which_a_placement_happens(
     assert calls == [50, 60, 9, 10]
 
 
+def test_an_invoker_that_pings_ahead_of_lower_ones_pads_the_fleet():
+    """Upstream's padding: the rows below a new invoker's index stand in
+    with its memory, offline, and a row already in the books keeps them,
+    and its health, when its own invoker pings, whatever memory that one
+    announces."""
+    f = reference.ReferenceFleet(1.0)
+    for i in range(4):
+        f.register(i, 512, False)
+    f.register(7, 1024, False)
+    assert f.free_mb() == [512] * 4 + [1024] * 4
+    assert f.unusable(8) == 8
+    for i in range(8):
+        f.set_health(i, True)
+    placed, forced = f.schedule("ns", "ns/a", 256, rand=0)
+    assert placed is not None and not forced
+    before = f.free_mb()
+    for i in range(8):
+        f.register(i, 2048, False)
+    assert f.free_mb() == before
+    assert f.unusable(8) == 0
+
+
 def test_a_10k_fleet_registers_and_places_in_linear_time(monkeypatch):
     calls = _count_coprime_calls(monkeypatch)
     f = reference.ReferenceFleet(1.0)
@@ -164,58 +188,114 @@ def test_the_ping_schedule_is_a_pure_function_of_the_fleet_s_size(n):
     assert all(abs(p - lo / n) < 1.0 / len(sched) for p, lo, _hi in sched)
 
 
-def test_the_fleet_never_sends_more_than_a_tick_between_two_yields():
-    """300 invokers on the in-memory bus: three ticks of 100 a second,
-    from `start()` on (which itself sends nothing and returns at once).
-    A task that runs at every turn of the loop counts what was sent since
-    its last turn."""
-    from openwhisk_tpu.messaging import MemoryMessagingProvider, PingMessage
+class _Pings:
+    """Every ping a fleet sends, as (loop turn, time, invoker): the
+    producer's `send` and `send_many` both counted, a ping read off its
+    message or its bytes. A task that runs at every turn of the loop counts
+    the turns and times them; `hold_s` holds each of its turns that long,
+    as a busy served loop does."""
 
-    async def drive():
-        provider = MemoryMessagingProvider()
+    def __init__(self, provider, hold_s: float = 0.0):
+        from openwhisk_tpu.messaging import PingMessage
+
+        self.sent: list = []
+        self.turn = 0
+        self.turn_max_s = 0.0
+        #: (when a turn ended, how long it took)
+        self.turns_s: list = []
+        self.hold_s = hold_s
         real = provider.get_producer
-        sent, since_yield, worst = [], [0], [0]
+
+        def count(msg) -> None:
+            if isinstance(msg, (bytes, bytearray)):
+                msg = PingMessage.parse(msg)
+            if isinstance(msg, PingMessage):
+                self.sent.append((self.turn, time.monotonic(),
+                                  msg.instance.instance))
 
         def producer():
             prod = real()
-            send = prod.send
+            send, send_many = prod.send, prod.send_many
 
             async def counted(topic, msg):
-                if isinstance(msg, PingMessage):
-                    sent.append((time.monotonic(), msg.instance.instance))
-                    since_yield[0] += 1
-                    worst[0] = max(worst[0], since_yield[0])
+                count(msg)
                 await send(topic, msg)
-            prod.send = counted
+
+            async def counted_many(items):
+                items = list(items)
+                for _topic, payload, msg in items:
+                    count(payload if msg is None else msg)
+                await send_many(items)
+            prod.send, prod.send_many = counted, counted_many
             return prod
 
         provider.get_producer = producer
-        sim = fleet.SimFleet(provider, 300, 2048, {}, {})
 
-        async def every_turn():
-            while True:
-                since_yield[0] = 0
-                await asyncio.sleep(0)
+    async def turns(self) -> None:
+        last = time.monotonic()
+        while True:
+            self.turn += 1
+            if self.hold_s:
+                time.sleep(self.hold_s)
+            await asyncio.sleep(0)
+            now = time.monotonic()
+            self.turn_max_s = max(self.turn_max_s, now - last)
+            self.turns_s.append((now, now - last))
+            last = now
 
-        turns = asyncio.ensure_future(every_turn())
+    def by_invoker(self) -> dict:
+        out: dict = {}
+        for _turn, t, i in self.sent:
+            out.setdefault(i, []).append(t)
+        return out
+
+    def blocks(self) -> dict:
+        """turn -> the invokers pinged in it, in order"""
+        out: dict = {}
+        for turn, _t, i in self.sent:
+            out.setdefault(turn, []).append(i)
+        return out
+
+
+def _drive_fleet(n: int, seconds: float, hold_s: float = 0.0,
+                 stall=None):
+    """A fleet of `n` on the in-memory bus for `seconds`, from `start()`
+    on (which itself sends nothing and returns at once); `stall` is a
+    coroutine function run beside it. Returns the count and its end."""
+    from openwhisk_tpu.messaging import MemoryMessagingProvider
+
+    async def drive():
+        provider = MemoryMessagingProvider()
+        pings = _Pings(provider, hold_s)
+        sim = fleet.SimFleet(provider, n, 2048, {}, {})
+        tasks = [asyncio.ensure_future(pings.turns())]
+        if stall is not None:
+            tasks.append(asyncio.ensure_future(stall()))
         try:
             await sim.start()
-            for _ in range(400):
-                if len(sent) >= 3 * 300:
-                    break
-                await asyncio.sleep(0.01)
+            await asyncio.sleep(seconds)
+            end = time.monotonic()
         finally:
-            turns.cancel()
+            for t in tasks:
+                t.cancel()
             await sim.stop()
-        return sent, worst[0]
+        return pings, end
 
-    sent, worst = asyncio.run(drive())
+    return asyncio.run(drive())
+
+
+def test_the_fleet_never_sends_more_than_a_tick_between_two_yields():
+    """On an idle loop, one tick a wake: 300 invokers are three ticks of
+    100 a second, and no turn of the loop carries more than one."""
+    pings, _end = _drive_fleet(300, 3.0)
+    worst = max(len(b) for b in pings.blocks().values())
     assert worst == 100 <= fleet.PING_TICK
-    by_invoker = {}
-    for t, i in sent:
-        by_invoker.setdefault(i, []).append(t)
+    by_invoker = pings.by_invoker()
     assert sorted(by_invoker) == list(range(300))
-    assert {len(v) for v in by_invoker.values()} == {3}
+    # three rounds in the 3 s, and a fourth begun by the first tick's
+    # invokers at most: no invoker twice in a round, none left out
+    assert {len(v) for v in by_invoker.values()} <= {3, 4}
+    assert {len(v) for i, v in by_invoker.items() if i >= 100} == {3}
     # registration is second 0 of the one schedule: the first pings are
     # spread over a second like every later round, and an invoker's clock
     # runs from its own first ping, whatever the rows behind it take
@@ -224,6 +304,90 @@ def test_the_fleet_never_sends_more_than_a_tick_between_two_yields():
         assert at[-1] - at[0] > 0.5       # spread over the second, no burst
     gaps = [b - a for v in by_invoker.values() for a, b in zip(v, v[1:])]
     assert min(gaps) > 0.5
+
+
+def test_a_held_loop_delays_the_pings_and_thins_none_out():
+    """2,560 invokers (20 ticks a second) on a loop whose every turn is
+    held 200 ms: a wake sends every tick that fell due meanwhile, so each
+    invoker is heard once a second, give or take how late the pinger
+    wakes, the silence still open at the end counted too. That lateness
+    is one to three turns (a timer that falls due in a turn fires in the
+    next, and the task it wakes runs in the one after), so two pings of
+    one invoker lie at most a second and two turns apart. A pinger that
+    sends one tick a turn hears each invoker every 4 s here."""
+    pings, end = _drive_fleet(2_560, 3.5, hold_s=0.2)
+    assert pings.turn_max_s >= 0.2
+    by_invoker = pings.by_invoker()
+    assert sorted(by_invoker) == list(range(2_560))
+    gaps = [b - a for v in by_invoker.values()
+            for a, b in zip(v, v[1:] + [end])]
+    assert max(gaps) <= 1.0 + 2 * pings.turn_max_s + 0.1, \
+        (max(gaps), pings.turn_max_s)
+    # and no invoker twice in one wake
+    assert all(len(set(b)) == len(b) for b in pings.blocks().values())
+
+
+def test_after_a_long_stall_one_ping_an_invoker_and_the_schedule_goes_on():
+    """A 3 s hold of the loop: the next wake sends every invoker's newest
+    due ping once (a block of exactly N, not three rounds), and the
+    schedule goes on from the current second, one tick a wake."""
+    n = 1_024
+    held = {}
+
+    async def stall():
+        await asyncio.sleep(1.3)
+        time.sleep(3.0)
+        held["end"] = time.monotonic()
+
+    pings, end = _drive_fleet(n, 6.0, stall=stall)
+    after = [(turn, t, i) for turn, t, i in pings.sent if t >= held["end"]]
+    first = after[0][0]
+    block = [i for turn, _t, i in after if turn == first]
+    assert sorted(block) == list(range(n))
+    # no missed second is sent later either: one ping a second from here
+    by_invoker = {}
+    for _turn, t, i in after:
+        by_invoker.setdefault(i, []).append(t)
+    assert max(len(v) for v in by_invoker.values()) \
+        <= 2 + int(end - held["end"])
+    assert all(len(set(b)) == len(b) for b in pings.blocks().values())
+    # and every invoker is heard again within a second of that block, give
+    # or take how late the loop woke the pinger
+    turn_max_s = max(d for t, d in pings.turns_s if t > held["end"] + 0.1)
+    assert all(len(v) >= 2 and v[1] - v[0] <= 1.0 + 2 * turn_max_s + 0.1
+               for v in by_invoker.values())
+
+
+def test_the_fleet_accounts_for_what_it_delivered_in_the_window():
+    """`window` opens and closes the account the log line prints: the
+    pings sent in between, and the longest silence of one invoker that
+    ended in it (on an idle loop, a second, give or take how late the
+    pinger woke: two turns at the most)."""
+    from openwhisk_tpu.messaging import MemoryMessagingProvider
+
+    async def drive():
+        provider = MemoryMessagingProvider()
+        pings = _Pings(provider)
+        sim = fleet.SimFleet(provider, 512, 2048, {}, {})
+        turns = asyncio.ensure_future(pings.turns())
+        try:
+            await sim.start()
+            await asyncio.sleep(1.2)
+            assert sim.window_pings is None
+            t0 = time.monotonic()
+            sim.window(True)
+            await asyncio.sleep(2.0)
+            sim.window(False)
+            return sim, time.monotonic() - t0, pings.turn_max_s
+        finally:
+            turns.cancel()
+            await sim.stop()
+
+    sim, window_s, turn_max_s = asyncio.run(drive())
+    assert 512 * 2 - 128 <= sim.window_pings <= 512 * 2 + 128
+    assert sim.window_pings / window_s == pytest.approx(512, rel=0.15)
+    assert 0.9 < sim.window_gap_max_s <= 1.0 + 2 * turn_max_s + 0.1
+    assert sim.pings >= sim.window_pings + 512
 
 
 # -- set-up ends by the clock ----------------------------------------------------
